@@ -4,10 +4,10 @@ Three finding ids, all rooted in incidents from PRs 1-2:
 
 * ``retrace-shard-map`` — any direct use of ``jax.shard_map`` /
   ``jax.experimental.shard_map`` outside ``parallel/mesh.py``.  PR 2's
-  ``shard_map_compat`` is the ONE call site that owns the cross-version
-  API drift (``check_vma`` vs ``check_rep``); a second direct call site
-  reintroduces the exact class of breakage that un-failed fifteen
-  tier-1 tests when it was fixed.
+  ``shard_map_compat`` is the ONE call site of that API (its spelling
+  has changed between JAX releases: ``check_rep`` became ``check_vma``);
+  a second direct call site reintroduces the exact class of breakage
+  that un-failed fifteen tier-1 tests when it was fixed.
 * ``retrace-jit-in-loop`` — ``jax.jit(...)`` (or ``shard_map_compat``)
   invoked lexically inside a ``for``/``while`` body.  Each call builds
   a fresh callable with an empty compilation cache, so every iteration
@@ -132,7 +132,7 @@ class RetraceChecker:
                         node, "retrace-shard-map",
                         "direct shard_map import — route through "
                         "parallel.mesh.shard_map_compat (the one call "
-                        "site that owns the JAX API drift)"))
+                        "site of that API)"))
             elif isinstance(node, ast.Attribute):
                 name = dotted_name(node)
                 if name in ("jax.shard_map",

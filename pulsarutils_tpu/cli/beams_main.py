@@ -125,6 +125,9 @@ def main(args=None):
     opts = build_parser().parse_args(args)
     if not opts.serve and not opts.fnames:
         build_parser().error("give at least one filterbank (or --serve)")
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if opts.serve:
         return _run_service(opts)
     return _run_direct(opts)

@@ -272,6 +272,12 @@ def _run_coordinator(opts):
 
 def _run_worker(opts):
     from ..fleet.worker import FleetWorker
+    from ..utils.compile_cache import enable_compile_cache
+
+    # only the worker role compiles; the coordinator imports no JAX
+    # backend (a coordinator that touched JAX would hold the chip its
+    # workers need — pinned by tests/test_fleet.py)
+    enable_compile_cache()
 
     worker = FleetWorker(opts.coordinator, worker_id=opts.worker_id,
                          http_port=opts.http_port,

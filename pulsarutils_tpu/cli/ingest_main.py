@@ -220,6 +220,10 @@ def main(args=None):
     opts = build_parser().parse_args(args)
     if opts.mode == "feed":
         return _run_feed(opts)
+    if opts.backend == "jax":
+        from ..utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     return _run_listen(opts)
 
 

@@ -94,6 +94,10 @@ def main(argv=None):
     from ..periodicity.driver import periodicity_search
 
     opts = build_parser().parse_args(argv)
+    if opts.backend == "jax":
+        from ..utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     try:
         snr = float(opts.snr_threshold)
     except ValueError:

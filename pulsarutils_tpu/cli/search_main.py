@@ -82,9 +82,8 @@ def build_parser():
                              "(retries+1).  Default off.  CAUTION: the "
                              "watchdog dispatches from a non-main "
                              "thread; device clients that require "
-                             "main-thread dispatch (some tunnelled "
-                             "setups) must be tested before enabling — "
-                             "see docs/robustness.md")
+                             "main-thread dispatch must be tested "
+                             "before enabling — see docs/robustness.md")
     parser.add_argument("--dispatch-retries", type=int, default=1,
                         help="same-backend retries before the numpy "
                              "fallback (default 1, the pre-hardening "
@@ -181,20 +180,20 @@ def build_parser():
     return parser
 
 
-def _enable_compile_cache():
-    """Persist XLA compilations across CLI invocations (big-chunk kernel
-    compiles run minutes cold, seconds cached)."""
-    import os
+#: exit status of a run that finished, persisted everything, and yet did
+#: not search every chunk on the device it was asked to use
+EXIT_DEGRADED = 3
 
-    try:
-        import jax
 
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.expanduser("~/.cache/pulsarutils_tpu_jax"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
-    except Exception:  # cache is an optimisation, never a requirement
-        pass
+def _degraded_counts():
+    """Process-wide total of the ways a run leaves the device path:
+    chunks searched by NumPy / cleaned on the host after a device
+    failure, and chunks quarantined ``oom_floor``.  ``main`` compares it
+    before and after (the registry is process-wide)."""
+    from ..obs.metrics import REGISTRY
+
+    return REGISTRY.total("putpu_host_fallbacks_total",
+                          "putpu_oom_floor_total")
 
 
 def main(args=None):
@@ -202,7 +201,10 @@ def main(args=None):
 
     opts = build_parser().parse_args(args)
     if opts.backend == "jax":
-        _enable_compile_cache()
+        from ..utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
+    degraded_before = _degraded_counts()
     if opts.trace:
         from ..obs import roofline, trace
 
@@ -306,6 +308,13 @@ def main(args=None):
             n = REGISTRY.write_jsonl(opts.metrics_out,
                                      schema_version=SCHEMA_VERSION)
         logger.info("metrics: %d lines -> %s", n, opts.metrics_out)
+    if _degraded_counts() > degraded_before:
+        # everything above was persisted as usual; the status says the
+        # result did not come from the path the user asked for
+        logger.error("run ended on a fall-back path (host search/clean "
+                     "after a device failure, or oom_floor chunks): "
+                     "exit status %d", EXIT_DEGRADED)
+        return EXIT_DEGRADED
     return 0
 
 

@@ -56,8 +56,8 @@ class DispatchPolicy:
     dispatch runs on a daemon thread and a hang is bounded by
     ``timeout_s`` per attempt instead of stalling the stream forever.
     Caveats (``docs/robustness.md``): the watchdog dispatches from a
-    non-main thread, which some tunnelled device clients cannot
-    tolerate — test before enabling there; an abandoned hung attempt
+    non-main thread, which a device client may not tolerate — test
+    before enabling; an abandoned hung attempt
     keeps running in the background (its late budget/trace writes may
     land in a later chunk's buckets, and a retry briefly overlaps it
     on the device).

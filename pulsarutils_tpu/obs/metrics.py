@@ -247,6 +247,14 @@ class MetricsRegistry:
                         "labels": dict(labels), **m._sample()})
         return out
 
+    def total(self, *names):
+        """Sum of the named counters'/gauges' values over every label
+        set (``putpu_oom_events_total`` is labelled by surface,
+        ``putpu_host_fallbacks_total`` by stage; consumers that only
+        ask "did it move" take deltas of this)."""
+        return sum(m.value for (name, _), m in self._items()
+                   if name in names and hasattr(m, "value"))
+
     def write_jsonl(self, path, schema_version=None):
         """JSONL export; ``schema_version`` (when given) is written as a
         ``{"schema_version": N}`` header line so downstream consumers

@@ -191,6 +191,9 @@ METRIC_NAMES = {
         "current verdict as rank (0 OK / 1 DEGRADED / 2 CRITICAL)",
     "putpu_hits_total":
         "chunks whose best S/N cleared the threshold",
+    "putpu_host_fallbacks_total":
+        "device work moved to the host for the rest of a run (labelled "
+        "by stage: search = NumPy backend, clean = host clean)",
     "putpu_ingest_bytes_total":
         "payload bytes accepted from the live feed (wire bandwidth — "
         "bytes, not floats, on the packed path)",

@@ -17,10 +17,12 @@ and cached per signature; with the persistent compilation cache on, the
 extra compile is a disk hit.  When disabled, the call-site hooks
 (:func:`begin` / :func:`end`) are a single global read.
 
-Peaks default per backend (TPU v5e-ish; override with
+Peaks are keyed by the device's own ``device_kind`` (one table,
+:data:`DEVICE_PEAKS`, each entry with its source; override with
 ``PUTPU_PEAK_FLOPS`` / ``PUTPU_PEAK_BYTES_PER_S`` or :func:`set_peaks`).
-On CPU no peak is assumed — achieved rates are still reported, the
-fraction column reads ``-``.
+A device that is not in the table — every CPU, any accelerator nobody
+has looked up — gets no fraction, never a guess: achieved rates are
+still reported, the fraction column reads ``-``.
 """
 
 from __future__ import annotations
@@ -40,13 +42,15 @@ _PEAKS = None            # (flops/s, bytes/s) or (None, None)
 _COSTS = {}              # (name, signature) -> {"flops","bytes"} | None
 _STATS = {}              # name -> accumulated dict
 
-#: approximate single-chip peaks per backend: (FLOP/s f32, HBM bytes/s).
-#: Deliberately round numbers — the fraction column is a sanity scale
-#: ("are we within 2x of the roof or 50x off it"), not a benchmark claim.
-_BACKEND_PEAKS = {
-    "tpu": (9.0e13, 8.0e11),
-    "gpu": (3.0e13, 1.0e12),
-    "cpu": (None, None),
+#: published single-chip peaks by ``jax.devices()[0].device_kind``:
+#: ``(FLOP/s, HBM bytes/s, source)``.  The FLOP/s figure is the chip's
+#: bf16 matrix peak — the roof XLA's cost model counts against; this
+#: package's f32 VPU kernels sit far below it by construction, so their
+#: fraction is bound by the bytes term.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (1.97e14, 8.19e11,
+                    "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                    "bf16, 819 GB/s HBM per chip"),
 }
 
 
@@ -83,13 +87,10 @@ def _peaks():
             _PEAKS = (float(env_f) if env_f else None,
                       float(env_b) if env_b else None)
         else:
-            try:
-                import jax
+            import jax
 
-                _PEAKS = _BACKEND_PEAKS.get(jax.default_backend(),
-                                            (None, None))
-            except Exception:
-                _PEAKS = (None, None)
+            _PEAKS = DEVICE_PEAKS.get(jax.devices()[0].device_kind,
+                                      (None, None))[:2]
     return _PEAKS
 
 

@@ -23,22 +23,14 @@ import numpy as np
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across JAX versions — ONE call site owns the API
-    drift so every mesh kernel builder stays version-agnostic:
-
-    * new API (``jax.shard_map``, ``check_vma=``) when present;
-    * else the long-stable ``jax.experimental.shard_map.shard_map``
-      (``check_rep=`` — the same lint under its older name).
-    """
+    """The ONE call site of ``jax.shard_map`` (every mesh kernel builder
+    routes through here; the ``retrace-shard-map`` lint enforces it), so
+    the next change of that API is one edit.  Written for the installed
+    JAX only: no branch for versions that are not."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(shape=None, axis_names=("dm", "chan"), devices=None):

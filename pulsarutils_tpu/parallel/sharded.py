@@ -64,7 +64,7 @@ def _sharded_kernel(mesh, capture_plane, chan_block, kernel="gather",
             # differs still share this compiled program
             dedisp = jnp.roll(dedisp, -roll_k, axis=1)
         # ONE stacked (5, D_loc) score array -> one host readback (each
-        # fetched array costs a full round trip on tunnelled platforms)
+        # fetched array is a host sync)
         stacked = score_profiles_stacked(dedisp, xp=jnp)
         if capture_plane:
             return stacked, dedisp

@@ -605,12 +605,12 @@ def _ring_kernel(mesh, n_hops, rotation):
             # rotate the ring: this device's view advances one block right
             return acc, nxt, jax.lax.ppermute(nxt, "time", perm=perm)
 
-        acc0 = jnp.zeros((ndm, t_loc), dtype=data_local.dtype)
-        if hasattr(jax.lax, "pcast"):
-            # newer jax tracks varying-mesh-axes: a zeros-constant carry
-            # is UNVARYING while the body's sum varies over the mesh,
-            # and fori_loop rejects the carry-type mismatch
-            acc0 = jax.lax.pcast(acc0, "time", to="varying")
+        # jax tracks varying-mesh-axes: a zeros-constant carry is
+        # UNVARYING while the body's sum varies over the mesh, and
+        # fori_loop rejects the carry-type mismatch
+        acc0 = jax.lax.pcast(jnp.zeros((ndm, t_loc),
+                                       dtype=data_local.dtype),
+                             "time", to="varying")
         nxt0 = jax.lax.ppermute(data_local, "time", perm=perm)
         acc, _, _ = jax.lax.fori_loop(0, n_hops, hop,
                                       (acc0, data_local, nxt0))

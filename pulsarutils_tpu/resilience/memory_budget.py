@@ -216,15 +216,20 @@ def preflight_direct(formulation, nchan, nsamples, ndm, *, dm_block,
 # -- calibration: persisted beside the tune cache ----------------------------
 
 def _direct_key(nchan, nsamples, ndm):
-    """The estimator's calibration key: the tuner's geometry axes."""
+    """The estimator's calibration key: the tuner's geometry axes.
+
+    The backend axis is the one THIS process dispatches to.  A process
+    that never loaded JAX — the fleet coordinator, which sizes leases
+    with this estimate — dispatches nowhere and gets the generic key:
+    asking ``jax.default_backend()`` there would initialise a backend,
+    and on a TPU host take the chip the workers need.
+    """
+    import sys
+
     from ..tuning.geometry import geometry_key
 
-    try:
-        import jax
-
-        backend = jax.default_backend()
-    except Exception:  # putpu-lint: disable=broad-except — capability probe: no jax = generic key
-        backend = "any"
+    jax = sys.modules.get("jax")
+    backend = jax.default_backend() if jax is not None else "any"
     return geometry_key(backend, nchan, nsamples, ndm)
 
 

@@ -553,6 +553,29 @@ def _probe_grid(trial_dms, probe_trials):
     return trial_dms[idx]
 
 
+def _search_candidates(backend, static):
+    """Direct-sweep formulations the tuner may MEASURE on ``backend``.
+
+    On a TPU the list is the static choice alone, so ``kernel="auto"``
+    resolves there without a measurement.  Measuring means RUNNING
+    every candidate at the chunk's own geometry, and neither XLA
+    formulation has ever run on a chip at the geometries above the tune
+    floor.  Both compile for a v5e at the tuner's blocked 32-trial
+    probe of a 1,024 x 2^20 chunk (PR 22's compile probe: ``roll``
+    2.9 s, ``gather`` 3.7 s), so the compiler does not rule them out —
+    but an unblocked gather of that size is refused (a 128 GiB index
+    temporary), older notes say the gather scalarises and takes the
+    worker down at these sizes, and a lost worker is not an exception
+    :meth:`KernelTuner.resolve` can catch.  A formulation joins the TPU
+    list only with a chip run that shows it at survey width.  Off-TPU
+    the traceable formulations compete as before (the Pallas kernel is
+    TPU-only).
+    """
+    if backend == "tpu":
+        return [static]
+    return [static] + [k for k in ("roll", "gather") if k != static]
+
+
 def resolve_search_kernel(nchan, nsamples, ndm, dtype, capture_plane,
                           start_freq, bandwidth, sample_time, trial_dms,
                           dm_block=None, chan_block=None):
@@ -563,7 +586,8 @@ def resolve_search_kernel(nchan, nsamples, ndm, dtype, capture_plane,
     ``"roll"`` (the roll-scan formulation, PR 1's CPU winner).  Plane
     captures resolve statically — the capture variants differ in spill
     strategy, not sweep kernel, and their wall is dominated by the
-    capture itself.
+    capture itself.  On a TPU nothing is measured at all (see
+    :func:`_search_candidates`).
     """
     import jax
     import jax.numpy as jnp
@@ -573,10 +597,7 @@ def resolve_search_kernel(nchan, nsamples, ndm, dtype, capture_plane,
     static = static_search_kernel(backend, f32, capture_plane)
     if capture_plane:
         return static
-    candidates = [static] + [k for k in ("roll", "gather", "pallas")
-                             if k != static
-                             and (k != "pallas"
-                                  or (backend == "tpu" and f32))]
+    candidates = _search_candidates(backend, static)
 
     def runner_factory():
         from ..ops.search import _offsets_for, _search_jax
@@ -630,7 +651,7 @@ def resolve_batched_kernel(nchan, nsamples, ndm, batch, start_freq,
 
     backend = jax.default_backend()
     static = "roll" if backend == "cpu" else "gather"
-    candidates = [static] + [k for k in ("roll", "gather") if k != static]
+    candidates = _search_candidates(backend, static)
 
     def runner_factory():
         from ..beams.batcher import batched_probe_runners
@@ -657,48 +678,26 @@ def resolve_mesh_kernel(mesh, nchan, nsamples, ndm, start_freq, bandwidth,
                         sample_time, trial_dms, dtype=None):
     """Per-shard rescore/sweep kernel for the sharded paths.
 
-    The mesh shape joins the key (a ``(8,1)`` slice-heavy layout and a
-    ``(2,4)`` chan-split one stress different kernels); candidates are
-    ``"pallas"`` (all-TPU meshes, float32) vs ``"gather"`` — the
-    roll-scan is the gather's own CPU formulation inside the shard
-    kernel, so off-TPU meshes have a single applicable variant and
-    resolve statically at zero cost.
+    One applicable variant per mesh, so the resolution is static and
+    costs nothing: ``"pallas"`` on an all-TPU float32 mesh (the XLA
+    gather is not measured on a chip — :func:`_search_candidates`),
+    ``"gather"`` everywhere else (the roll-scan is the gather's own CPU
+    formulation inside the shard kernel).  The decision still goes
+    through the tuner so it lands in the run's decision ledger under
+    the mesh-shaped key; with nothing to measure, the band and trial
+    grid arguments play no part in it.
     """
     import jax.numpy as jnp
 
     all_tpu = all(d.platform == "tpu" for d in mesh.devices.flat)
     f32 = dtype in (None, jnp.float32)
     static = static_mesh_kernel(all_tpu, f32)
-    candidates = ([static] + ["gather"] if static == "pallas" else [static])
     mesh_shape = tuple(int(mesh.shape[a]) for a in mesh.shape)
-
-    def runner_factory():
-        from ..ops.search import _offsets_for
-        from ..parallel.sharded import sharded_dedispersion_search
-
-        sub_dms = _probe_grid(trial_dms, get_tuner().probe_trials)
-        mid = _offsets_for(sub_dms[len(sub_dms) // 2:len(sub_dms) // 2 + 1],
-                           nchan, start_freq, bandwidth, sample_time,
-                           nsamples)[0]
-        synth = synthetic_chunk(nchan, nsamples, mid)
-
-        def make(kern):
-            def run():
-                table = sharded_dedispersion_search(
-                    synth, None, None, start_freq, bandwidth, sample_time,
-                    mesh=mesh, trial_dms=sub_dms, kernel=kern)
-                return tuple(np.asarray(table[c]) for c in
-                             ("max", "std", "snr", "rebin", "peak"))
-            return run
-
-        return {k: make(k) for k in candidates}
-
-    backend = "tpu" if all_tpu else "cpu-mesh"
     return get_tuner().resolve(
-        backend=backend, nchan=nchan, nsamples=nsamples, ndm=ndm,
-        dtype=dtype_name(None if f32 else dtype), candidates=candidates,
-        static=static, runner_factory=runner_factory,
-        mesh_shape=mesh_shape)
+        backend="tpu" if all_tpu else "cpu-mesh", nchan=nchan,
+        nsamples=nsamples, ndm=ndm,
+        dtype=dtype_name(None if f32 else dtype), candidates=[static],
+        static=static, mesh_shape=mesh_shape)
 
 
 # ---------------------------------------------------------------------------

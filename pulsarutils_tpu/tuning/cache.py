@@ -48,12 +48,16 @@ CACHE_ENV = "PUTPU_TUNE_CACHE"
 
 
 def default_cache_path():
-    """``$PUTPU_TUNE_CACHE``, else ``~/.cache/pulsarutils_tpu/tune_cache.json``."""
+    """``$PUTPU_TUNE_CACHE``, else ``tune_cache.json`` in the checkout's
+    ``.pulsarutils_tpu_cache/`` (git-ignored; never under ``$HOME`` — a
+    kernel choice must depend on the tree a run starts from, not on
+    whose account runs it)."""
     env = os.environ.get(CACHE_ENV)
     if env:
         return env
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "pulsarutils_tpu", "tune_cache.json")
+    from ..utils.compile_cache import checkout_cache_dir
+
+    return checkout_cache_dir("tune_cache.json")
 
 
 def check_artifact(path, expect_version=TUNE_SCHEMA_VERSION):

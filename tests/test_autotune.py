@@ -326,6 +326,32 @@ def test_static_heuristic_spellings():
     assert autotune.static_mesh_kernel(False) == "gather"
 
 
+def test_nothing_is_measured_on_a_tpu(monkeypatch):
+    """ISSUE 22: measuring means RUNNING every candidate at the chunk's
+    own geometry, and on a TPU the XLA gather is refused by the
+    compiler at survey width (and is said to take the worker down — not
+    an exception the tuner could catch).  The TPU candidate list is the
+    static choice alone, so ``kernel="auto"`` resolves there with no
+    runner ever built — even far above the tune floor, tuner on."""
+    import jax
+
+    assert autotune._search_candidates("tpu", "pallas") == ["pallas"]
+    assert autotune._search_candidates("tpu", "gather") == ["gather"]
+    assert autotune._search_candidates("cpu", "roll") == ["roll", "gather"]
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("PUTPU_AUTOTUNE", "on")
+    prev = autotune.set_tuner(autotune.KernelTuner(
+        cache=TuneCache(None), min_elements=0,
+        measurer=lambda *a: pytest.fail("measured a candidate on a TPU")))
+    try:
+        assert autotune.resolve_search_kernel(
+            1024, 1 << 20, 154, None, False, 1200.0, 200.0, 5e-4,
+            np.linspace(300.0, 400.0, 154)) == "pallas"
+    finally:
+        autotune.set_tuner(prev)
+
+
 # ---------------------------------------------------------------------------
 # the persistent cache: versioning + torn-file recovery
 # ---------------------------------------------------------------------------

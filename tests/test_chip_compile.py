@@ -1,0 +1,275 @@
+"""Ask the TPU v5e's compiler — installed here, no chip attached — for
+the programs the file-survey path dispatches on the chip at survey width
+(1,024 channels x 2^20 samples, DM 300-400; ``tools/survey_rehearsal.py``).
+
+Interpret-mode Pallas skips Mosaic lowering entirely and the CPU suite
+never takes the ``jax.default_backend() == "tpu"`` branches, so these
+compiles are the only tier-1 guard of what ``chip_smoke.py`` runs: a
+kernel Mosaic refuses, or a program that no longer fits 16 GB of HBM,
+fails HERE at no chip time.  Nothing runs, so nothing is said about
+results or speed.
+
+Rules this file keeps (the on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture that skips when it
+cannot be described — never at import, in a ``skipif`` or a
+``parametrize``; nothing is ``autouse``; no child process (the worker
+that describes the topology holds libtpu's lock); the persistent
+compile cache is off around the compiles (an entry written for a
+described device cannot be read back without one).  One file: a second
+would land on another xdist worker and skip in silence.
+
+Two programs are compiled at a reduced time axis because their compile
+time, not their memory, is what scales: the unpack+clean program (its
+``median`` lowers to a sort: 62 s at 2^20, 10 s at 2^14) and the
+four-device fused mesh hybrid (163 s at 2^20, 14 s at 2^14).  Their
+full-size compiles were made by hand and are recorded in CHANGES.md
+(PR 22).
+"""
+
+import numpy as np
+import pytest
+
+NCHAN = 1024
+T = 1 << 20
+T_SMALL = 1 << 14
+F0, BW, TSAMP = 1200.0, 200.0, 5e-4
+DMMIN, DMMAX = 300.0, 400.0
+HBM_BYTES = 15.75 * 2**30  # what the v5e compiler itself budgets
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch, no_compile_cache):
+    """Code that asks ``jax.default_backend()`` takes its TPU branch
+    (compiled Pallas, fused programs, donation) — steered here, in the
+    test, not through an option of the program."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.fixture(scope="module")
+def plan():
+    """The smoke's trial grid and offset table, from the program's own
+    planners."""
+    from pulsarutils_tpu.ops.fdmt import _pick_fdmt_tile, fdmt_trial_dms
+    from pulsarutils_tpu.ops.pallas_dedisperse import rebase_offsets
+    from pulsarutils_tpu.ops.plan import dedispersion_plan
+    from pulsarutils_tpu.ops.search import _offsets_for
+
+    trial_dms = np.asarray(dedispersion_plan(NCHAN, DMMIN, DMMAX, F0, BW,
+                                             TSAMP), np.float64)
+    offsets = _offsets_for(trial_dms, NCHAN, F0, BW, TSAMP, T)
+    _, roll_k, max_off = rebase_offsets(offsets, T)
+    _, n_lo, n_hi = fdmt_trial_dms(NCHAN, DMMIN, DMMAX, F0, BW, TSAMP)
+    return {"ndm": len(trial_dms), "roll_k": roll_k, "max_off": max_off,
+            "n_lo": n_lo, "n_hi": n_hi, "t_tile": _pick_fdmt_tile(T)}
+
+
+def _sds(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _fits(compiled):
+    """The compile already proves the program alone fits; the number is
+    asserted so a creeping temp shows up as a diff, not an OOM."""
+    m = compiled.memory_analysis()
+    total = (m.temp_size_in_bytes + m.argument_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert total < HBM_BYTES, m
+
+
+def test_rows_kernel_and_scorer(as_tpu, one_chip, plan):
+    """``--kernel auto`` on a TPU: the exact Pallas rows kernel over the
+    whole trial grid (``max_off`` from the plan), then the XLA scorer."""
+    import jax
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops.pallas_dedisperse import (
+        dedisperse_plane_pallas_traced,
+    )
+    from pulsarutils_tpu.ops.search import _jitted_scorer
+
+    def sweep(data, offs):
+        return dedisperse_plane_pallas_traced(
+            data, offs, plan["max_off"], roll_k=plan["roll_k"])
+
+    compiled = jax.jit(sweep).lower(
+        _sds((NCHAN, T), jnp.float32, one_chip),
+        _sds((plan["ndm"], NCHAN), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+    _fits(_jitted_scorer().lower(
+        _sds((plan["ndm"], T), jnp.float32, one_chip)).compile())
+
+
+def test_fdmt_transform_as_resolved_on_tpu(as_tpu, one_chip, plan):
+    """The hybrid's coarse stage exactly as ``_search_jax_fdmt`` builds
+    it on a TPU: resident head, deep pair and the one-pass Pallas
+    scorer, certificate row included."""
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops import fdmt
+
+    use_head = fdmt._head_enabled(True)
+    use_score = fdmt._score_kernel_choice(True, False)
+    deep_pair = fdmt._deep_pair_enabled()
+    assert (use_head, use_score, deep_pair) == (True, True, True)
+    run = fdmt._build_transform(
+        NCHAN, F0, BW, plan["n_hi"], T, plan["t_tile"], True, False,
+        n_lo=plan["n_lo"], with_scores=True, with_plane=False, t_orig=T,
+        with_cert=True, use_head=use_head, use_score=use_score,
+        deep_pair=deep_pair)
+    compiled = run.lower(_sds((NCHAN, T), jnp.float32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("with_cert", [False, True])
+def test_score_plane_pallas(as_tpu, one_chip, with_cert):
+    import jax
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops.score_pallas import score_plane_pallas
+
+    compiled = jax.jit(
+        lambda p: score_plane_pallas(p, with_cert=with_cert)).lower(
+        _sds((128, T), jnp.float32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_hybrid_seed_program(as_tpu, one_chip, plan):
+    """The floorless hybrid's one-dispatch first round on a TPU
+    (``ops/search.py:_fused_hybrid_seed_kernel``)."""
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops import fdmt, search
+
+    ndm = plan["ndm"]
+    kernel = search._fused_hybrid_seed_kernel(
+        NCHAN, F0, BW, plan["n_hi"], T, plan["t_tile"], plan["n_lo"], None,
+        plan["max_off"], ndm, search.HYBRID_SEED_BUCKET,
+        use_head=fdmt._head_enabled(True),
+        bucket2=min(search.HYBRID_NEED_BUCKET, ndm),
+        use_score=fdmt._score_kernel_choice(True, False),
+        deep_pair=fdmt._deep_pair_enabled())
+    compiled = kernel.lower(
+        _sds((NCHAN, T), jnp.float32, one_chip),
+        _sds((ndm,), jnp.int32, one_chip),
+        _sds((ndm, NCHAN), jnp.int32, one_chip),
+        _sds((3,), jnp.float32, one_chip)).compile()
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("bucket", [8, 32])
+def test_fused_rescore_program(as_tpu, one_chip, plan, bucket):
+    """The certificate-mode hybrid's exact rescore (smallest and largest
+    row bucket)."""
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops.search import _fused_rescore_kernel
+
+    _fits(_fused_rescore_kernel(plan["max_off"], bucket).lower(
+        _sds((NCHAN, T), jnp.float32, one_chip),
+        _sds((bucket, NCHAN), jnp.int32, one_chip)).compile())
+
+
+def test_packed_unpack_and_clean_with_donation(as_tpu, one_chip):
+    """The driver's first device program: packed 2-bit frames in, the
+    cleaned ascending-band float chunk out, the raw buffer donated
+    (``search_pipeline.py``; reduced time axis — see the module
+    docstring)."""
+    import jax
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.io.lowbit import device_unpack_block
+    from pulsarutils_tpu.ops.clean_ops import renormalize_data
+
+    def unpack_clean(raw, mask):
+        return renormalize_data(
+            device_unpack_block(raw, 2, NCHAN, band_descending=True,
+                                xp=jnp),
+            badchans_mask=mask, xp=jnp)
+
+    compiled = jax.jit(unpack_clean, donate_argnums=(0,)).lower(
+        _sds((T_SMALL, NCHAN // 4), jnp.uint8, one_chip),
+        _sds((NCHAN,), jnp.bool_, one_chip)).compile()
+    assert compiled.memory_analysis().output_size_in_bytes \
+        == NCHAN * T_SMALL * 4
+
+
+def test_fused_sharded_hybrid_on_four_devices(as_tpu, topo, plan):
+    """``sharded_hybrid_search``'s one ``shard_map`` program on a
+    ``(dm=4, chan=1)`` mesh of described devices, set up the way that
+    function sets it up: the collectives and the Pallas kernels must
+    both survive partitioning (reduced time axis — see the module
+    docstring)."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from pulsarutils_tpu.ops.fdmt import fdmt_plan
+    from pulsarutils_tpu.ops.search import (HYBRID_NEED_BUCKET,
+                                            HYBRID_SEED_BUCKET,
+                                            auto_chan_block)
+    from pulsarutils_tpu.parallel import sharded_fdmt
+
+    t, ndm, dm_size = T_SMALL, plan["ndm"], 4
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(dm_size, 1),
+                ("dm", "chan"))
+    max_off = max(1 << int(np.ceil(np.log2(plan["max_off"] + 1))), 256)
+    bucket = -(-HYBRID_SEED_BUCKET // dm_size) * dm_size
+    bucket2 = -(-min(HYBRID_NEED_BUCKET, ndm) // dm_size) * dm_size
+    plans = [fdmt_plan(NCHAN, F0, BW, hi, lo) for lo, hi in
+             sharded_fdmt.slice_delay_range(plan["n_lo"], plan["n_hi"],
+                                            dm_size)]
+    tables = sharded_fdmt._stacked_tables(plans, plan["t_tile"])
+    plan_key = tuple((it["k_tiles"], it["k_tiles_h"], it["rows_max"])
+                     for it in tables)
+    fn = sharded_fdmt._build_fused_sharded_hybrid(
+        mesh, NCHAN, plans[0].nchan_padded, t, plan["t_tile"], True, False,
+        plan_key, ndm, bucket, bucket2, "pallas",
+        auto_chan_block(NCHAN, t, bucket // dm_size), max_off, NCHAN, None)
+    rep = NamedSharding(mesh, P())
+    by_dm = NamedSharding(mesh, P("dm"))
+    args = [_sds((NCHAN, t), jnp.float32, rep), _sds((ndm,), jnp.int32, rep),
+            _sds((ndm, NCHAN), jnp.int32, rep), _sds((3,), jnp.float32, rep),
+            _sds((), jnp.int32, rep)]
+    args += [_sds(it[k].shape, jnp.int32, by_dm) for it in tables
+             for k in ("idx_low", "idx_high", "shift", "shift_high")]
+    text = fn.lower(*args).compile().as_text()
+    assert "all-gather" in text and "tpu_custom_call" in text

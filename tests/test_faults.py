@@ -866,3 +866,61 @@ def test_corrupt_impulse_rfi_storm_kind():
                                   kind="impulse").to_json()
     clone = FaultPlan.from_json(plan.to_json())
     assert clone.specs[0].amp == 50.0
+
+
+def test_compile_time_error_propagates_not_numpy_fallback(survey_file,
+                                                          tmp_path,
+                                                          monkeypatch):
+    """An error raised while a program is traced, lowered or compiled is
+    deterministic: retrying it, or searching every later chunk with
+    NumPy, hides a broken build behind a slow "successful" run.  It
+    must propagate like the configuration errors do (ISSUE 22); the
+    injected RUNTIME faults above still reach the NumPy floor."""
+    import jax
+
+    from pulsarutils_tpu.faults import compile_phase
+    from pulsarutils_tpu.pipeline import search_pipeline
+
+    @jax.jit
+    def refused(x):
+        raise NotImplementedError("lowering refused (stand-in for Mosaic)")
+
+    def broken_search(data, *args, **kwargs):
+        if kwargs.get("backend") == "numpy":
+            pytest.fail("compile-time failure fell back to NumPy")
+        return refused(np.zeros(3, np.float32))
+
+    monkeypatch.setattr(search_pipeline, "dedispersion_search",
+                        broken_search)
+    retries = _counter("putpu_dispatch_retries_total")
+    with pytest.raises(NotImplementedError) as err:
+        search_by_chunks(survey_file, output_dir=str(tmp_path),
+                         **SEARCH_KW)
+    assert compile_phase.failed_phase(err.value) == "trace"
+    assert _counter("putpu_dispatch_retries_total") == retries
+    # the same type raised at run time carries no mark: still retried
+    assert compile_phase.failed_phase(NotImplementedError("x")) is None
+
+
+def test_cli_exit_status_nonzero_when_run_ends_on_fallback(survey_file,
+                                                           tmp_path):
+    """``PUsearchfrb`` persists everything as before, but a run that
+    ended on the NumPy fall-back backend no longer exits 0."""
+    from pulsarutils_tpu.cli import search_main
+
+    argv = [survey_file, "--dmmin", "100", "--dmmax", "200",
+            "--chunk-length", str(CHUNK_LEN_S), "--plots", "none",
+            "--snr-threshold", "6.5", "--dispatch-retries", "0"]
+    assert search_main.main(argv + ["--output-dir",
+                                    str(tmp_path / "clean")]) == 0
+    plan = FaultPlan([FaultSpec(site="dispatch", kind="error",
+                                times=None)])  # persistent device fault
+    before = REGISTRY.total("putpu_host_fallbacks_total")
+    with plan.armed():
+        rc = search_main.main(argv + ["--output-dir",
+                                      str(tmp_path / "faulted")])
+    assert rc == search_main.EXIT_DEGRADED != 0
+    assert REGISTRY.total("putpu_host_fallbacks_total") == before + 1
+    # the NumPy floor still found and persisted the pulse
+    cands = list(CandidateStore(str(tmp_path / "faulted")).candidates())
+    assert any(lo <= PULSE_T < hi for _, lo, hi in cands)

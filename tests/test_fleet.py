@@ -555,3 +555,28 @@ def test_fleet_chaos_drill_killed_and_wedged_workers():
 
     result = chaos_drill.run_fleet_drill(log=lambda *a: None)
     assert result["all_ok"], json.dumps(result, indent=1)
+
+
+def test_coordinator_role_imports_no_jax(tmp_path):
+    """One process per chip (ISSUE 22): the coordinator plans, leases
+    and serves HTTP, and must never import JAX — a coordinator that
+    touched a backend would hold the chip its workers need.  Run as the
+    real CLI role in a child interpreter that interrupts itself once
+    the survey is planned and being served."""
+    fname = write_file(tmp_path / "a.fil", seed=4)
+    code = (
+        "import os, signal, sys, threading\n"
+        "from pulsarutils_tpu.cli import fleet_main\n"
+        "threading.Timer(2.0, os.kill, (os.getpid(), signal.SIGINT))"
+        ".start()\n"
+        f"rc = fleet_main.main(['coordinator', {fname!r}, '--output-dir', "
+        f"{str(tmp_path / 'fleet')!r}, '--http-port', '0', '--dmmin', "
+        "'100', '--dmmax', '200', '--snr-threshold', '6.5'])\n"
+        "print('JAX', any(m.split('.')[0] in ('jax', 'jaxlib') "
+        "for m in sys.modules))\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert '"chunks_total": 80' in proc.stdout  # it did plan the survey
+    assert proc.stdout.rstrip().endswith("JAX False"), proc.stdout

@@ -464,6 +464,30 @@ def test_roofline_disabled_is_free():
         roofline.disable()
 
 
+def test_roofline_peaks_by_device_kind_unknown_gets_no_fraction(monkeypatch):
+    """ISSUE 22: peaks are looked up by the device's own ``device_kind``
+    in a sourced table; a device that is not in it (every CPU) yields
+    no fraction — never "anything called tpu is 9e13 FLOP/s"."""
+    flops, bw, source = roofline.DEVICE_PEAKS["TPU v5 lite"]
+    assert (flops, bw) == (1.97e14, 8.19e11) and "TPU v5e" in source
+    monkeypatch.delenv("PUTPU_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("PUTPU_PEAK_BYTES_PER_S", raising=False)
+    roofline.reset()
+    try:
+        assert roofline._peaks() == (None, None)     # device_kind "cpu"
+        assert roofline._fraction(1e12, 1e9, 0.5) is None
+        roofline.reset()
+        import jax
+
+        monkeypatch.setattr(type(jax.devices()[0]), "device_kind",
+                            property(lambda self: "TPU v5 lite"),
+                            raising=False)
+        assert roofline._peaks() == (flops, bw)
+        assert roofline._fraction(1.97e14, 0.0, 2.0) == pytest.approx(0.5)
+    finally:
+        roofline.reset()
+
+
 # ---------------------------------------------------------------------------
 # sift telemetry
 # ---------------------------------------------------------------------------

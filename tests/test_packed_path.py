@@ -124,10 +124,24 @@ def test_device_clean_failure_forces_packed_host_fallback(
     with FilterbankWriter(path, header) as w:
         w.write_block(array[::-1])
 
+    import jax
+
     from pulsarutils_tpu.io import lowbit
 
-    def boom(*a, **k):
-        raise RuntimeError("injected device unpack failure")
+    real_unpack = lowbit.device_unpack_block
+
+    def boom(raw, *a, **k):
+        # fails when the compiled program RUNS, like a device fault: a
+        # failure while the program is traced/lowered/compiled is
+        # deterministic and propagates instead (ISSUE 22,
+        # tests/test_faults.py pins that side)
+        out = real_unpack(raw, *a, **k)
+
+        def fail(_):
+            raise RuntimeError("injected device unpack failure")
+
+        return jax.pure_callback(
+            fail, jax.ShapeDtypeStruct(out.shape, out.dtype), out)
 
     monkeypatch.setattr(lowbit, "device_unpack_block", boom)
     import logging

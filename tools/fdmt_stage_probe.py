@@ -38,9 +38,6 @@ def timed(fn, *args, n=2):
 
 
 def main():
-    from tools.tpu_claim import claim_tpu
-
-    claim_tpu()
     import jax
     import jax.numpy as jnp
 

@@ -17,9 +17,6 @@ def main(argv):
     nsamp = int(argv[2]) if len(argv) > 2 else 1 << 20
     ndm = int(argv[3]) if len(argv) > 3 else 512
 
-    from tools.tpu_claim import claim_tpu
-
-    claim_tpu()
     import jax
     import jax.numpy as jnp
 

@@ -18,9 +18,6 @@ def main(argv):
     levels = [int(x) for x in
               (os.environ.get("SWEEP_LEVELS") or "7,8").split(",")]
 
-    from tools.tpu_claim import claim_tpu
-
-    claim_tpu()
     import jax
     import jax.numpy as jnp
 
