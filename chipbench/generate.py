@@ -1,0 +1,237 @@
+"""Seeded SIGPROC files for the benchmark, written packed.
+
+One general generator: everything that distinguishes one traffic mix from
+another (file length in hops, which hops hold a pulse, its S/N, width and
+DM range, the RFI) is a parameter of the traffic file; everything that
+distinguishes one geometry from another (channels, band, sample time, hop)
+is a parameter of the configuration file.
+
+Speed is the point (the program's own rehearsal generator writes 6 MiB/s):
+the radiometer noise is never drawn as floats.  A sample's quantised level
+is a function of one uniform byte (a 256-entry table built from the normal
+distribution's level probabilities), two channels are looked up at once
+from one uniform 16-bit draw, and two such nibbles make a packed byte.
+Pulses, hot channels and the mains comb shift the *mean* of the underlying
+normal, i.e. they select another table.  Blocks are drawn from independent
+child seeds, so the bytes do not depend on the number of threads.
+"""
+
+from __future__ import annotations
+
+import math
+import os
+import struct
+import time
+from concurrent.futures import ThreadPoolExecutor
+
+import numpy as np
+
+from . import dispersion
+
+BLOCK = 1 << 16  # samples per generation block
+
+
+def _phi(x):
+    return 0.5 * (1.0 + math.erf(x / math.sqrt(2.0)))
+
+
+def level_probabilities(mean, sd, nlevels=4):
+    """P(level k) of ``clip(rint(N(mean, sd)), 0, nlevels-1)``."""
+    edges = [-math.inf] + [k + 0.5 for k in range(nlevels - 1)] + [math.inf]
+    cdf = [0.0 if e == -math.inf else 1.0 if e == math.inf
+           else _phi((e - mean) / sd) for e in edges]
+    return np.diff(np.array(cdf))
+
+
+class LevelTables:
+    """Byte -> level and 16-bit -> two-level tables, by mean."""
+
+    def __init__(self, sd, nlevels=4):
+        self.sd = sd
+        self.nlevels = nlevels
+        self._lut8 = {}
+        self._lut16 = {}
+
+    def lut8(self, mean):
+        key = round(float(mean), 9)
+        if key not in self._lut8:
+            cum = np.cumsum(level_probabilities(key, self.sd, self.nlevels))
+            u = (np.arange(256) + 0.5) / 256.0
+            self._lut8[key] = np.minimum(
+                np.searchsorted(cum, u), self.nlevels - 1).astype(np.uint8)
+        return self._lut8[key]
+
+    def lut16(self, mean):
+        key = round(float(mean), 9)
+        if key not in self._lut16:
+            l8 = self.lut8(key)
+            v = np.arange(1 << 16)
+            self._lut16[key] = (l8[v & 255] | (l8[v >> 8] << 2)).astype(
+                np.uint8)
+        return self._lut16[key]
+
+    def moments(self, mean):
+        """Mean and standard deviation of the quantised level, as the
+        byte table realises them."""
+        lv = self.lut8(mean).astype(np.float64)
+        return float(lv.mean()), float(lv.std())
+
+
+def sigproc_header(cfg, source_name="CHIPBENCH"):
+    def s(x):
+        b = x.encode("ascii")
+        return struct.pack("<i", len(b)) + b
+
+    out = s("HEADER_START")
+    out += s("source_name") + s(source_name)
+    for key, val in (("data_type", 1), ("nchans", cfg["nchans"]),
+                     ("nbits", cfg["nbits"]), ("nifs", 1)):
+        out += s(key) + struct.pack("<i", int(val))
+    for key, val in (("tsamp", cfg["tsamp_s"]), ("fch1", cfg["fch1_mhz"]),
+                     ("foff", cfg["foff_mhz"]), ("tstart", 60000.0)):
+        out += s(key) + struct.pack("<d", float(val))
+    return out + s("HEADER_END")
+
+
+def draw_pulses(cfg, traffic, seed, tables):
+    """``[(sample, dm, amp_levels_total, width, target_snr)]``: one pulse
+    in each hop the traffic names, its whole dispersion track inside that
+    hop, so that exactly the chunks covering the hop hold it."""
+    nchan, tsamp = cfg["nchans"], cfg["tsamp_s"]
+    hop = cfg["chunk_samples"] // 2
+    fbottom, bandwidth = dispersion.band_edges(
+        cfg["fch1_mhz"], cfg["foff_mhz"], nchan)
+    rng = np.random.default_rng(np.random.SeedSequence([int(seed), 1]))
+    reach = int(np.abs(dispersion.channel_shifts(
+        cfg["dmmax"], nchan, fbottom, bandwidth, tsamp)).max())
+    margin = reach + max(traffic["pulse_widths"]) + 64
+    if 2 * margin >= hop:
+        raise ValueError("a hop cannot hold a whole track at dmmax")
+    lo_f, hi_f = traffic["pulse_dm_fraction"]
+    span = cfg["dmmax"] - cfg["dmmin"]
+    _, sigma_q = tables.moments(traffic["noise_mean_levels"])
+    ngood = nchan - len(traffic["hot_channels"])
+    pulses = []
+    for h in traffic["pulse_hops"]:
+        pos = h * hop + int(rng.integers(margin, hop - margin))
+        dm = float(rng.uniform(cfg["dmmin"] + lo_f * span,
+                               cfg["dmmin"] + hi_f * span))
+        width = int(rng.choice(traffic["pulse_widths"]))
+        snr = float(rng.uniform(*traffic["pulse_snr"]))
+        amp = snr * sigma_q * math.sqrt(width) / math.sqrt(ngood)
+        pulses.append((pos, dm, amp, width, snr))
+    return pulses
+
+
+def generate(path, cfg, traffic, seed, threads=None):
+    """Write the file; returns a dict describing what is in it."""
+    t0 = time.perf_counter()
+    if cfg["nbits"] != 2:
+        raise ValueError("the generator packs 2-bit samples only")
+    nchan, tsamp = cfg["nchans"], cfg["tsamp_s"]
+    if nchan % 4:
+        raise ValueError("nchans must pack to whole bytes")
+    hop = cfg["chunk_samples"] // 2
+    nsamples = traffic["hops_per_file"] * hop
+    descending = cfg["foff_mhz"] < 0
+    fbottom, bandwidth = dispersion.band_edges(
+        cfg["fch1_mhz"], cfg["foff_mhz"], nchan)
+    tables = LevelTables(traffic["noise_sd_levels"])
+    mu = traffic["noise_mean_levels"]
+    comb = traffic.get("comb") or None
+    comb_amp = comb["amp_levels"] if comb else 0.0
+    # hot channels are given for a 1,024-channel band and scale with it
+    hot = {(int(c["channel_of_1024"]) * nchan) // 1024: c["excess_levels"]
+           for c in traffic["hot_channels"]}
+    pulses = draw_pulses(cfg, traffic, seed, tables)
+
+    def file_chan(c):
+        return nchan - 1 - c if descending else c
+
+    # every (channel, sample) a pulse raises, with the mean it gets
+    p_rows, p_chan, p_mean, p_u = [], [], [], []
+    prng = np.random.default_rng(np.random.SeedSequence([int(seed), 2]))
+    for pos, dm, amp, width, _ in pulses:
+        sh = dispersion.channel_shifts(dm, nchan, fbottom, bandwidth, tsamp)
+        for k in range(width):
+            p_rows.append(pos + sh + k)
+            p_chan.append(np.arange(nchan))
+            p_mean.append(np.full(nchan, mu + amp / width))
+            p_u.append(prng.integers(0, 256, nchan, dtype=np.uint8))
+    p_rows = np.concatenate(p_rows) if p_rows else np.zeros(0, np.int64)
+    p_chan = np.concatenate(p_chan) if p_chan else np.zeros(0, np.int64)
+    p_mean = np.concatenate(p_mean) if p_mean else np.zeros(0)
+    p_u = np.concatenate(p_u) if p_u else np.zeros(0, np.uint8)
+    keep = ~np.isin(p_chan, list(hot))
+    p_rows, p_chan, p_mean, p_u = (p_rows[keep], p_chan[keep], p_mean[keep],
+                                   p_u[keep])
+    if p_rows.size and (p_rows.min() < 0 or p_rows.max() >= nsamples):
+        raise ValueError("a pulse track leaves the file")
+
+    def comb_on(idx):
+        if not comb:
+            return np.zeros(idx.shape, dtype=bool)
+        return np.sin(2 * np.pi * comb["hz"] * idx * tsamp) > 0
+
+    def put(packed, rows, chan, levels):
+        fc = file_chan(np.asarray(chan))
+        rows, levels = np.asarray(rows), np.asarray(levels)
+        # one bit position at a time: neighbouring channels share a byte,
+        # and a fancy assignment keeps only the last write to an element
+        for k in range(4):
+            pick = fc % 4 == k
+            r, col = rows[pick], fc[pick] // 4
+            packed[r, col] = ((packed[r, col] & ~np.uint8(3 << 2 * k))
+                              | (levels[pick] << np.uint8(2 * k)))
+
+    nblocks = -(-nsamples // BLOCK)
+    seeds = np.random.SeedSequence([int(seed), 3]).spawn(nblocks)
+
+    def block(i):
+        lo = i * BLOCK
+        n = min(BLOCK, nsamples - lo)
+        rng = np.random.default_rng(seeds[i])
+        r = rng.integers(0, 1 << 16, size=(n, nchan // 2), dtype=np.uint16)
+        on = comb_on(lo + np.arange(n))
+        t = tables.lut16(mu)[r]
+        rows_on = np.flatnonzero(on)
+        if rows_on.size:
+            t[rows_on] = tables.lut16(mu + comb_amp)[r[rows_on]]
+        del r
+        packed = t[:, 0::2] | (t[:, 1::2] << 4)
+        del t
+        for c, excess in sorted(hot.items()):
+            u = rng.integers(0, 256, n, dtype=np.uint8)
+            lv = np.where(on, tables.lut8(mu + excess + comb_amp)[u],
+                          tables.lut8(mu + excess)[u])
+            put(packed, np.arange(n), np.full(n, c), lv)
+        sel = np.flatnonzero((p_rows >= lo) & (p_rows < lo + n))
+        if sel.size:
+            rows = p_rows[sel] - lo
+            means = p_mean[sel] + np.where(on[rows], comb_amp, 0.0)
+            lv = np.empty(sel.size, dtype=np.uint8)
+            for m in np.unique(means):
+                pick = means == m
+                lv[pick] = tables.lut8(m)[p_u[sel][pick]]
+            put(packed, rows, p_chan[sel], lv)
+        return packed
+
+    threads = threads or min(8, os.cpu_count() or 1)
+    with open(path, "wb") as f, ThreadPoolExecutor(threads) as pool:
+        f.write(sigproc_header(cfg))
+        # bounded look-ahead: at most ``threads`` blocks are alive
+        pending = []
+        for i in range(nblocks):
+            pending.append(pool.submit(block, i))
+            if len(pending) >= threads:
+                pending.pop(0).result().tofile(f)
+        for fut in pending:
+            fut.result().tofile(f)
+    return {
+        "path": path, "nsamples": nsamples, "hop": hop,
+        "bytes": os.path.getsize(path), "seconds": time.perf_counter() - t0,
+        "duration_s": nsamples * tsamp,
+        "pulses": [{"sample": p, "dm": d, "amp_levels": a, "width": w,
+                    "target_snr": s} for p, d, a, w, s in pulses],
+        "hot_channels": sorted(hot),
+    }
