@@ -14,7 +14,9 @@ only in reviewer memory; this package makes them machine-checked
                        only under it (PRs 3-5)
 ``metric-name-*``      every ``putpu_*`` literal resolves against the
                        ``obs/names.py`` manifest, and the manifest covers
-                       the docs + committed gate baseline (PR 3)
+                       the docs + committed gate baseline (PR 3); every
+                       ``pallas_call`` is named from its ``KERNEL_NAMES``
+                       (``kernel-name-unknown``, ISSUE 25)
 ``broad-except``       broad handlers only in the reviewed containment-seam
                        allowlist (PR 4)
 ``float64-leak``       no 64-bit dtypes in jnp expressions in device code

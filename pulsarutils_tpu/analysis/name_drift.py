@@ -20,6 +20,12 @@ single source of truth, and this checker enforces both directions:
   docs, README or the committed gate baseline that the manifest does
   not declare: the doc (or baseline) references a series nothing emits.
 
+* ``kernel-name-unknown`` (per file) — a ``pallas_call(...)`` without a
+  literal ``name=`` declared in the manifest's ``KERNEL_NAMES``: device
+  traces are reduced by kernel name, so an unnamed kernel (the trace
+  then calls it after whatever closure built it) or an undeclared one
+  silently drops out of the per-kernel metrics.
+
 The manifest is read by **parsing** ``obs/names.py`` (AST literal
 extraction), not importing it — the linter must run without the package
 importable, e.g. from a bare CI checkout.
@@ -43,34 +49,60 @@ _PROSE_ALLOWED = {"putpu_budget", "putpu_trace_track", "putpu_plane_",
                   "putpu_plane", "putpu_lint", "putpu_lint_baseline"}
 
 
-def load_manifest(root):
-    """``(static names, dynamic counter suffixes)`` parsed from
-    ``obs/names.py`` under ``root``; empty sets when absent."""
+def _manifest_tree(root):
+    """The parsed ``obs/names.py`` under ``root``, or ``None``."""
     path = os.path.join(root or ".", "pulsarutils_tpu", "obs", "names.py")
     try:
         with open(path, encoding="utf-8") as fh:
-            tree = ast.parse(fh.read(), filename=path)
+            return ast.parse(fh.read(), filename=path)
     except (OSError, SyntaxError):
+        return None
+
+
+def _assigned(tree, name):
+    """The value nodes assigned to the module-level ``name``."""
+    return [node.value for node in ast.walk(tree)
+            if isinstance(node, ast.Assign)
+            and any(isinstance(t, ast.Name) and t.id == name
+                    for t in node.targets)]
+
+
+def load_manifest(root):
+    """``(static names, dynamic counter suffixes)`` parsed from
+    ``obs/names.py`` under ``root``; empty sets when absent."""
+    tree = _manifest_tree(root)
+    if tree is None:
         return set(), set()
     names, dynamic = set(), set()
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.Assign):
-            continue
-        targets = [t.id for t in node.targets
-                   if isinstance(t, ast.Name)]
-        if "METRIC_NAMES" in targets and isinstance(node.value, ast.Dict):
-            names = {k.value for k in node.value.keys
+    for value in _assigned(tree, "METRIC_NAMES"):
+        if isinstance(value, ast.Dict):
+            names = {k.value for k in value.keys
                      if isinstance(k, ast.Constant)
                      and isinstance(k.value, str)}
-        if "BUDGET_COUNTERS" in targets:
-            call = node.value
-            args = (call.args if isinstance(call, ast.Call)
-                    else [call])
-            for arg in args:
-                if isinstance(arg, (ast.Set, ast.List, ast.Tuple)):
-                    dynamic = {e.value for e in arg.elts
-                               if isinstance(e, ast.Constant)}
+    for call in _assigned(tree, "BUDGET_COUNTERS"):
+        for arg in (call.args if isinstance(call, ast.Call) else [call]):
+            if isinstance(arg, (ast.Set, ast.List, ast.Tuple)):
+                dynamic = {e.value for e in arg.elts
+                           if isinstance(e, ast.Constant)}
     return names, dynamic
+
+
+def load_kernel_names(root):
+    """``KERNEL_NAMES`` keys parsed from ``obs/names.py`` under ``root``;
+    empty when absent (then the kernel-name check stays silent)."""
+    tree = _manifest_tree(root)
+    if tree is None:
+        return set()
+    return {k.value for value in _assigned(tree, "KERNEL_NAMES")
+            if isinstance(value, ast.Dict) for k in value.keys
+            if isinstance(k, ast.Constant)}
+
+
+def _kernel_names(project):
+    key = "name-drift/kernels"
+    if key not in project.state:
+        project.state[key] = load_kernel_names(project.root)
+    return project.state[key]
 
 
 def _manifest(project):
@@ -96,7 +128,8 @@ def _known(name, static, dynamic):
 class NameDriftChecker:
     id = "metric-name"
     ids = ("metric-name-unknown", "metric-name-dynamic",
-           "metric-name-unemitted", "metric-name-unknown-ref")
+           "metric-name-unemitted", "metric-name-unknown-ref",
+           "kernel-name-unknown")
 
     def check(self, ctx):
         project = ctx.project
@@ -105,10 +138,21 @@ class NameDriftChecker:
         static, dynamic = _manifest(project)
         emitted = project.state.setdefault("name-drift/emitted", set())
         out = []
+        kernels = _kernel_names(project)
         for node in ast.walk(ctx.tree):
             if not isinstance(node, ast.Call) or not node.args:
                 continue
             callee = (dotted_name(node.func) or "").rsplit(".", 1)[-1]
+            if callee == "pallas_call" and kernels:
+                name = next((kw.value for kw in node.keywords
+                             if kw.arg == "name"), None)
+                if not (isinstance(name, ast.Constant)
+                        and name.value in kernels):
+                    out.append(ctx.finding(
+                        node, "kernel-name-unknown",
+                        "pallas_call needs a literal name= declared in "
+                        "obs/names.py KERNEL_NAMES — trace reductions "
+                        "find a kernel by that name"))
             if callee not in _METRIC_CALLS:
                 continue
             arg = node.args[0]

@@ -7,7 +7,10 @@ Reference counterpart: ``pulsarutils/clean.py:360-373`` (which hardcoded
 from __future__ import annotations
 
 import argparse
+import contextlib
+import os
 
+from ..obs import trace
 from ..pipeline.search_pipeline import search_by_chunks
 from ..utils.logging_utils import logger
 
@@ -109,8 +112,8 @@ def build_parser():
                         help="write a Chrome/Perfetto trace of the run's "
                              "spans to this path AND a jax.profiler "
                              "device trace to '<OUT.json>_device/' (one "
-                             "flag, both traces), and enable per-kernel "
-                             "roofline accounting for the run")
+                             "flag, both traces); the program's spans "
+                             "are annotations in the device trace too")
     parser.add_argument("--metrics-out", default=None, metavar="PATH",
                         help="write the run's metrics-registry snapshot "
                              "(counters/gauges/histograms: candidates, "
@@ -196,9 +199,20 @@ def _degraded_counts():
                           "putpu_oom_floor_total")
 
 
-def main(args=None):
-    import contextlib
+@contextlib.contextmanager
+def _call_span(fname):
+    """The root span of one file's search: ``call``.  Under a tracer the
+    call's spans share one ``trace_id`` across the main, reader and
+    persist threads — a fresh one unless the caller bound a context."""
+    with contextlib.ExitStack() as stack:
+        if trace.is_tracing() and trace.current_trace_context() is None:
+            stack.enter_context(trace.trace_context(trace.new_trace_id()))
+        stack.enter_context(
+            trace.span("call", file=os.path.basename(str(fname))))
+        yield
 
+
+def main(args=None):
     opts = build_parser().parse_args(args)
     if opts.backend == "jax":
         from ..utils.compile_cache import enable_compile_cache
@@ -206,9 +220,8 @@ def main(args=None):
         enable_compile_cache()
     degraded_before = _degraded_counts()
     if opts.trace:
-        from ..obs import roofline, trace
-
-        roofline.enable()  # a traced run is an observability run
+        # roofline accounting stays off (PUTPU_ROOFLINE=1 enables it):
+        # it adds an AOT compile per kernel to the very run being traced
         session = trace.trace_session(
             path=opts.trace, device_trace_dir=opts.trace + "_device")
     else:
@@ -227,9 +240,7 @@ def main(args=None):
                                       snr=opts.canary_snr)
         report_out = opts.report_out
         if report_out and len(opts.fnames) > 1:
-            import os as _os
-
-            root = _os.path.splitext(_os.path.basename(str(fname)))[0]
+            root = os.path.splitext(os.path.basename(str(fname)))[0]
             report_out = f"{report_out}.{root}"
         push = None
         if opts.push_webhook:
@@ -237,63 +248,65 @@ def main(args=None):
                      **({"min_snr": opts.push_min_snr}
                         if opts.push_min_snr is not None else {})}
                     for url in opts.push_webhook]
-        hits, _ = search_by_chunks(
-            fname,
-            chunk_length=opts.chunk_length,
-            new_sample_time=opts.sample_time,
-            tmin=opts.tmin,
-            dmmin=opts.dmmin,
-            dmmax=opts.dmmax,
-            surelybad=opts.surelybad,
-            backend=opts.backend,
-            kernel=opts.kernel,
-            snr_threshold=opts.snr_threshold,
-            output_dir=opts.output_dir,
-            make_plots=False if opts.plots == "none" else opts.plots,
-            show_plots=opts.show_plots,
-            resume=not opts.no_resume,
-            fft_zap=opts.fft_zap,
-            cut_outliers=opts.cut_outliers,
-            zero_dm=opts.zero_dm,
-            max_chunks=opts.max_chunks,
-            period_search=opts.period_search,
-            period_sigma_threshold=opts.period_sigma,
-            dispatch_timeout=opts.dispatch_timeout,
-            dispatch_retries=opts.dispatch_retries,
-            quarantine_policy=opts.quarantine_policy,
-            http_port=opts.http_port,
-            http_host=opts.http_host,
-            canary=canary,
-            report_out=report_out,
-            lineage=opts.lineage,
-            push=push,
-        )
-        total_raw += len(hits)
-        if hits and not opts.no_sift:
-            from ..pipeline.sift import sift_hits
+        with _call_span(fname):
+            hits, _ = search_by_chunks(
+                fname,
+                chunk_length=opts.chunk_length,
+                new_sample_time=opts.sample_time,
+                tmin=opts.tmin,
+                dmmin=opts.dmmin,
+                dmmax=opts.dmmax,
+                surelybad=opts.surelybad,
+                backend=opts.backend,
+                kernel=opts.kernel,
+                snr_threshold=opts.snr_threshold,
+                output_dir=opts.output_dir,
+                make_plots=False if opts.plots == "none" else opts.plots,
+                show_plots=opts.show_plots,
+                resume=not opts.no_resume,
+                fft_zap=opts.fft_zap,
+                cut_outliers=opts.cut_outliers,
+                zero_dm=opts.zero_dm,
+                max_chunks=opts.max_chunks,
+                period_search=opts.period_search,
+                period_sigma_threshold=opts.period_sigma,
+                dispatch_timeout=opts.dispatch_timeout,
+                dispatch_retries=opts.dispatch_retries,
+                quarantine_policy=opts.quarantine_policy,
+                http_port=opts.http_port,
+                http_host=opts.http_host,
+                canary=canary,
+                report_out=report_out,
+                lineage=opts.lineage,
+                push=push,
+            )
+            total_raw += len(hits)
+            if hits and not opts.no_sift:
+                with trace.span("call/sift"):
+                    from ..pipeline.sift import sift_hits
 
-            sift_stats = {}
-            sifted = sift_hits(hits, stats=sift_stats)
-            if report_out and sift_stats:
-                # the driver wrote the report before sift ran: fold
-                # the sift telemetry in now (observability must never
-                # fail the run, hence the containment)
-                from ..obs.report import amend_report
+                    sift_stats = {}
+                    sifted = sift_hits(hits, stats=sift_stats)
+                    if report_out and sift_stats:
+                        # the driver wrote the report before sift ran: fold
+                        # the sift telemetry in now (observability must never
+                        # fail the run, hence the containment)
+                        from ..obs.report import amend_report
 
-                try:
-                    amend_report(report_out, sift=sift_stats)
-                except Exception as exc:
-                    logger.warning("could not amend the survey report "
-                                   "with sift telemetry (%r)", exc)
-            total_cands += len(sifted)
-            logger.info("%s: %d raw detections -> %d sifted candidates",
-                        fname, len(hits), len(sifted))
-            for c in sifted:
-                logger.info("  t=%.4fs DM=%.2f snr=%.2f width=%.4gs "
-                            "(%d detections)", c["time"], c["dm"], c["snr"],
-                            c["width"], c["n_members"])
-        else:
-            total_cands += len(hits)
+                        try:
+                            amend_report(report_out, sift=sift_stats)
+                        except Exception as exc:
+                            logger.warning("could not amend the survey report "
+                                           "with sift telemetry (%r)", exc)
+                    total_cands += len(sifted)
+                    logger.info("%s: %d raw detections -> %d sifted "
+                                "candidates", fname, len(hits), len(sifted))
+                    for c in sifted:
+                        logger.info("  t=%.4fs DM=%.2f snr=%.2f width=%.4gs "
+                                    "(%d detections)", c["time"], c["dm"],
+                                    c["snr"], c["width"], c["n_members"])
+            else:
+                total_cands += len(hits)
     logger.info("total candidates: %d (%d raw detections)",
                 total_cands, total_raw)
     if opts.metrics_out:

@@ -41,7 +41,11 @@ __all__ = ["DEFAULT_REL_TOL", "LANE_KEYS", "SCHEMA_VERSION",
 #: ``precision_policy`` lane stamps (walls are only comparable within
 #: one (JAX backend, precision policy) lane) and the suite grew
 #: config 21 — regenerate baselines.
-SCHEMA_VERSION = 3
+#: v4 (ISSUE 25): BUDGET_JSON grew ``call_s`` (what a call costs outside
+#: its chunks), per-chunk ``on_disk_lag_s`` and the compile-phase
+#: counters, and ``async_s`` splits ``persist``; no bench record changed
+#: meaning, so the committed baseline's header was re-stamped.
+SCHEMA_VERSION = 4
 
 #: header keys that define a snapshot's **bench lane**.  Walls measured
 #: on different JAX backends, or under different accumulation-precision

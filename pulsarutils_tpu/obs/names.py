@@ -23,8 +23,8 @@ importing the package.
 
 from __future__ import annotations
 
-__all__ = ["METRIC_NAMES", "BUDGET_COUNTERS", "budget_counter_metric",
-           "is_known", "warn_unknown"]
+__all__ = ["METRIC_NAMES", "BUDGET_COUNTERS", "KERNEL_NAMES",
+           "budget_counter_metric", "is_known", "warn_unknown"]
 
 #: every statically-named metric: name -> one-line meaning.  Sorted.
 METRIC_NAMES = {
@@ -384,6 +384,48 @@ BUDGET_COUNTERS = frozenset({
     "rescore_calls",
     "rescore_rows",
 })
+
+#: names the device side carries (ISSUE 25): the ``name=`` of every
+#: ``pl.pallas_call`` and the function name of every ``jax.jit`` that a
+#: profiler trace shows as a program (``jit_<name>``) or as the scope of
+#: its operations.  Trace reductions match on them (``chipbench/
+#: layer_metrics``), so a rename is a change to a yardstick: the
+#: ``kernel-name-unknown`` checker holds every ``pallas_call`` to this
+#: table.  The coarse sweep's top-level program is still named after its
+#: closure, ``fn`` (``ops/fdmt.py:_transform_fn``): the accepted
+#: ``fdmt_roofline`` metric matches ``^jit_fn/``; not listed here.
+KERNEL_NAMES = {
+    "clean":
+        "program: device clean of an uploaded float chunk",
+    "dedisperse_flat":
+        "kernel: exact dedispersion of a row bucket, flat time layout",
+    "dedisperse_rows":
+        "kernel: exact dedispersion of a row bucket, (8, L) row layout "
+        "(the hybrid's rescore)",
+    "direct_sweep":
+        "program: the direct (gather/roll) sweep over every trial",
+    "fdd_spectra":
+        "kernel: Fourier-domain dedispersion of one trial superblock",
+    "fdmt_deep_pair":
+        "kernel: the FDMT's last two merge levels as one 4-parent pass",
+    "fdmt_head":
+        "kernel: the FDMT's first levels fused, states resident in VMEM",
+    "fdmt_merge":
+        "kernel: one FDMT merge level (scalar-prefetched parent rows)",
+    "fdmt_resident":
+        "program: the fused FDMT head run alone",
+    "harmonic_sum":
+        "kernel: harmonic sums and their peaks per spectrum row",
+    "rescore_fused":
+        "program: coarse sweep + seed selection + exact rescore in one "
+        "dispatch",
+    "rescore_rows":
+        "program: exact rescore of one row bucket (dedisperse + score)",
+    "score_rows":
+        "kernel: one-pass scorer of the coarse plane's rows",
+    "unpack_clean":
+        "program: bit-unpack + clean of an uploaded packed chunk",
+}
 
 
 def budget_counter_metric(name):

@@ -31,9 +31,22 @@ contextvar override of the process-wide default), so a worker's spans
 drain over the wire under its identity even when coordinator and
 workers share one process.
 
-The module is stdlib-only and never imports jax; :func:`trace_session`
-drives ``jax.profiler`` lazily so one flag can emit both the span JSON
-and the XLA device trace into the same run directory.
+Span identity (ISSUE 25): while a tracer is active every recorded event
+carries a ``span_id`` and the ``parent_id`` of the span that was open
+when it started (:data:`_OPEN_SPAN`; an async span takes the span open
+at ``begin``), beside the bound context's ``trace_id`` — so one
+``PUsearchfrb`` call is one tree across the main, reader and persist
+threads.  Each synchronous span also enters a
+``jax.profiler.TraceAnnotation`` of the same name, which puts the
+program's spans in the profiler's own host plane, on the device trace's
+clock.  With no tracer active none of this runs: no id is allocated and
+no annotation is constructed.
+
+The module is stdlib-only and never imports jax (the annotation class
+is looked up through ``sys.modules``: a process that never imported jax
+has no profiler to annotate); :func:`trace_session` drives
+``jax.profiler`` lazily so one flag can emit both the span JSON and the
+XLA device trace into the same run directory.
 """
 
 from __future__ import annotations
@@ -43,6 +56,7 @@ import contextvars
 import itertools
 import json
 import logging
+import sys
 import threading
 import time
 import uuid
@@ -71,6 +85,11 @@ _TRACE_CTX = contextvars.ContextVar("putpu_trace_ctx", default=None)
 #: Perfetto track.  ContextVar, not thread-local: worker threads started
 #: per chunk inherit the chunk's context.
 _TRACK = contextvars.ContextVar("putpu_trace_track", default=None)
+
+
+#: id of the innermost span open in this context: the ``parent_id`` of
+#: whatever starts next.  Set only while a tracer is active.
+_OPEN_SPAN = contextvars.ContextVar("putpu_trace_open_span", default=None)
 
 
 def new_trace_id():
@@ -114,20 +133,43 @@ def pop_tracer(token):
 
 
 class Span:
-    """One timed interval.  ``dur`` is valid after :func:`close_span`."""
+    """One timed interval, started by :func:`open_span`.  ``dur`` is
+    valid after :func:`close_span`."""
 
-    __slots__ = ("name", "attrs", "t0", "t1", "dur")
+    __slots__ = ("name", "attrs", "t0", "t1", "dur", "span_id",
+                 "parent_id", "_token", "_annotation")
 
     def __init__(self, name, attrs=None):
         self.name = name
         self.attrs = attrs
-        self.t1 = self.dur = None
-        self.t0 = time.perf_counter()
+        self.t0 = self.t1 = self.dur = None
+        self.span_id = self.parent_id = self._token = None
+        self._annotation = None
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` when this process has imported
+    jax, else ``None`` — this module never imports it."""
+    jax = sys.modules.get("jax")
+    return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
 
 
 def open_span(name, attrs=None):
     """Start a span NOW.  Pair with :func:`close_span` in a finally."""
-    return Span(name, attrs)
+    s = Span(name, attrs)
+    tr = _TRACER_VAR.get() or _TRACER
+    if tr is not None:
+        # identity and the profiler annotation first, the clock last:
+        # the bookkeeping stays outside the interval the span measures
+        annotation = _annotation_class()
+        if annotation is not None:
+            s._annotation = annotation(name)
+            s._annotation.__enter__()
+        s.span_id = tr.next_id()
+        s.parent_id = _OPEN_SPAN.get()
+        s._token = _OPEN_SPAN.set(s.span_id)
+    s.t0 = time.perf_counter()
+    return s
 
 
 def close_span(s, track=None):
@@ -136,6 +178,10 @@ def close_span(s, track=None):
     there, so there is exactly one measurement per interval."""
     s.t1 = time.perf_counter()
     s.dur = s.t1 - s.t0
+    if s._token is not None:
+        _OPEN_SPAN.reset(s._token)
+        if s._annotation is not None:
+            s._annotation.__exit__(None, None, None)
     tr = _TRACER_VAR.get() or _TRACER
     if tr is not None:
         tr.complete(s, track)
@@ -173,7 +219,8 @@ class AsyncSpan:
     thread (device dispatch → readback, persist submit → worker done).
     Emitted as a Chrome async ``b``/``e`` pair so it need not nest."""
 
-    __slots__ = ("name", "attrs", "track", "t0", "_tracer", "_id", "_done")
+    __slots__ = ("name", "attrs", "track", "t0", "_tracer", "_id",
+                 "parent_id", "_ctx", "_done")
 
     def __init__(self, name, attrs, track, tracer):
         self.name = name
@@ -181,6 +228,11 @@ class AsyncSpan:
         self.track = track
         self._tracer = tracer
         self._id = tracer.next_id()
+        # caused by the span open where it begins; it may end on a
+        # thread that inherits no context, so parent and trace context
+        # are taken here
+        self.parent_id = _OPEN_SPAN.get()
+        self._ctx = _TRACE_CTX.get()
         self._done = False
         self.t0 = time.perf_counter()
         tracer.async_begin(self)
@@ -270,13 +322,20 @@ class Tracer:
         return round((t - self.epoch) * 1e6, 3)  # perf_counter s -> us
 
     @staticmethod
-    def _stamp_ctx(ev):
-        """Merge the bound distributed-trace context into ``ev`` args —
-        read at record time on the recording thread, so a worker's unit
-        spans carry the lease's ``trace_id`` across the wire."""
-        ctx = _TRACE_CTX.get()
+    def _stamp(ev, span_id, parent_id, ctx):
+        """Merge the span's identity and its distributed-trace context
+        (``trace_id``, the cross-process ``parent_span_id``) into ``ev``
+        args, so a worker's unit spans carry the lease's ``trace_id``
+        across the wire."""
+        args = ev.setdefault("args", {})
+        if span_id is not None:
+            args["span_id"] = span_id
+        if parent_id is not None:
+            args["parent_id"] = parent_id
         if ctx is not None:
-            ev["args"] = {**ev.get("args", {}), **ctx}
+            args.update(ctx)
+        if not args:
+            del ev["args"]
         return ev
 
     def complete(self, s, track=None):
@@ -286,21 +345,23 @@ class Tracer:
               "ts": self._ts(s.t0), "dur": round(s.dur * 1e6, 3)}
         if s.attrs:
             ev["args"] = {k: _jsonable(v) for k, v in s.attrs.items()}
-        self._append(self._stamp_ctx(ev))
+        # the context bound on the recording thread, read at record time
+        self._append(self._stamp(ev, s.span_id, s.parent_id,
+                                 _TRACE_CTX.get()))
 
     def async_begin(self, a):
         ev = {"name": a.name, "ph": "b", "cat": "async", "id": a._id,
               "pid": 1, "tid": self._tid(a.track), "ts": self._ts(a.t0)}
         if a.attrs:
             ev["args"] = {k: _jsonable(v) for k, v in a.attrs.items()}
-        self._append(self._stamp_ctx(ev))
+        self._append(self._stamp(ev, a._id, a.parent_id, a._ctx))
 
     def async_end(self, a, t1, attrs=None):
         ev = {"name": a.name, "ph": "e", "cat": "async", "id": a._id,
               "pid": 1, "tid": self._tid(a.track), "ts": self._ts(t1)}
         if attrs:
             ev["args"] = {k: _jsonable(v) for k, v in attrs.items()}
-        self._append(ev)
+        self._append(self._stamp(ev, a._id, a.parent_id, a._ctx))
 
     def close(self):
         with self._lock:

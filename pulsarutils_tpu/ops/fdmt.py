@@ -480,17 +480,17 @@ def _build_merge_kernel(rows_out, rows_in, t, t_tile, k_tiles, k_tiles_h,
     call = pl.pallas_call(kernel, grid_spec=grid_spec,
                           out_shape=jax.ShapeDtypeStruct(
                               (rows_out, n_t, 8, L), jnp.float32),
-                          interpret=bool(interpret))
+                          interpret=bool(interpret), name="fdmt_merge")
 
     @jax.jit
-    def run(state, idx_low, idx_high, shift, shift_high):
+    def fdmt_merge(state, idx_low, idx_high, shift, shift_high):
         s4 = state.reshape(rows_in, n_t, 8, L)
         n_in = row_block * (k_tiles + kh)
         out = call(idx_low, idx_high, shift, shift_high,
                    *([s4] * n_in))
         return out.reshape(rows_out, t)
 
-    return run
+    return fdmt_merge
 
 
 @functools.lru_cache(maxsize=16)
@@ -565,16 +565,17 @@ def _build_merge4_kernel(rows_out, rows_in, t, t_tile, k_tiles, row_block,
     call = pl.pallas_call(kernel, grid_spec=grid_spec,
                           out_shape=jax.ShapeDtypeStruct(
                               (rows_out, n_t, 8, L), jnp.float32),
-                          interpret=bool(interpret))
+                          interpret=bool(interpret),
+                          name="fdmt_deep_pair")
 
     @jax.jit
-    def run(state, idx, shift):
+    def fdmt_deep_pair(state, idx, shift):
         s4 = state.reshape(rows_in, n_t, 8, L)
         n_in = row_block * P * k_tiles
         out = call(*idx, *shift, *([s4] * n_in))
         return out.reshape(rows_out, t)
 
-    return run
+    return fdmt_deep_pair
 
 
 def _merge4_pallas(state, idx, shift, t_tile, interpret):

@@ -387,7 +387,7 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (head.n_groups * rows_final, c8, _L), jnp.float32),
-        interpret=bool(interpret))
+        interpret=bool(interpret), name="fdmt_head")
 
     flat_tabs = []
     for tab in head.tables:
@@ -439,7 +439,10 @@ def head_transform(data, max_delay, start_freq, bandwidth, min_delay=0,
         data = jnp.concatenate(
             [data, jnp.zeros((head.rows_in * head.n_groups - nchan, t),
                              jnp.float32)])
-    return jax.jit(run)(data)
+    def fdmt_resident(data):  # the program's name in a device trace
+        return run(data)
+
+    return jax.jit(fdmt_resident)(data)
 
 
 def head_supported(nchan_padded, n_iterations, t, t_slice=None,

@@ -164,6 +164,7 @@ def _build_fdd_kernel(n_tiles, superblock, n_cblocks, c_block, interpret,
         out_shape=[jax.ShapeDtypeStruct((superblock, n_tiles, 8, L),
                                         jnp.float32)] * 2,
         interpret=bool(interpret),
+        name="fdd_spectra",
     )
 
     def run(u_re, u_im, s_re, s_im):

@@ -130,6 +130,7 @@ def _build_harmonic_kernel(rows_p, nbins, depths, lo, hi, policy, interpret):
         out_shape=[jax.ShapeDtypeStruct((rows_p, 128), jnp.float32),
                    jax.ShapeDtypeStruct((rows_p, 128), jnp.int32)],
         interpret=bool(interpret),
+        name="harmonic_sum",
     )
 
 

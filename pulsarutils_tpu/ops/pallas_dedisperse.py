@@ -214,15 +214,16 @@ def _build_kernel_rows(ndm_p, nchan_p, t_ext, t_out, dm_block, chan_block,
         scratch_shapes=[pltpu.VMEM((chan_block, k_tiles * 8, L),
                                    jnp.float32)],
         interpret=bool(interpret),
+        name="dedisperse_rows",
     )
 
     @jax.jit
-    def run(offsets, data_ext):
+    def dedisperse_rows(offsets, data_ext):
         data_4d = data_ext.reshape(nchan_p, n_src, 8, L)
         out = call(offsets, *([data_4d] * k_tiles))
         return out.reshape(ndm_p, t_out)
 
-    return run
+    return dedisperse_rows
 
 
 @functools.lru_cache(maxsize=64)
@@ -271,13 +272,14 @@ def _build_kernel(ndm_p, nchan_p, t_ext, t_out, dm_block, chan_block,
         scratch_shapes=[pltpu.VMEM((chan_block, k_tiles * t_tile),
                                    jnp.float32)],
         interpret=bool(interpret),
+        name="dedisperse_flat",
     )
 
     @jax.jit
-    def run(offsets, data_ext):
+    def dedisperse_flat(offsets, data_ext):
         return call(offsets, *([data_ext] * k_tiles))
 
-    return run
+    return dedisperse_flat
 
 
 def _pick_t_tile(max_off, nsamples, layout="flat"):

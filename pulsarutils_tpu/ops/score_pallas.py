@@ -261,6 +261,7 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret):
         out_shape=jax.ShapeDtypeStruct((rows_p, 128), jnp.float32),
         scratch_shapes=[pltpu.VMEM((_NSLOT, 8, 128), jnp.float32)],
         interpret=bool(interpret),
+        name="score_rows",
     )
     return call
 

@@ -357,7 +357,7 @@ def _jax_search_kernel(capture_plane, chan_block, formulation=None,
     import jax.numpy as jnp
 
     @jax.jit
-    def kernel(data, offset_blocks):
+    def direct_sweep(data, offset_blocks):
         if packed is not None:
             from ..io.lowbit import unpack_from_meta
 
@@ -368,7 +368,7 @@ def _jax_search_kernel(capture_plane, chan_block, formulation=None,
                                 formulation=formulation,
                                 policy=policy)
 
-    return kernel
+    return direct_sweep
 
 
 #: trials dedispersed per Pallas pass — bounds the live plane to
@@ -1187,7 +1187,7 @@ def _fused_hybrid_seed_kernel(nchan, start_freq, bandwidth, n_hi, t_run,
     k = min(HYBRID_SEED_TOPK, ndm_plan)  # top_k requires k <= axis size
 
     @jax.jit
-    def run(data, idx_map, offsets_rebased, cert_params):
+    def rescore_fused(data, idx_map, offsets_rebased, cert_params):
         stacked_f = coarse_fn(data)               # (6, ndm_fdmt)
         coarse = stacked_f[:, idx_map]            # (6, ndm_plan)
         _, top = jax.lax.top_k(coarse[2], k)
@@ -1229,7 +1229,7 @@ def _fused_hybrid_seed_kernel(nchan, start_freq, bandwidth, n_hi, t_run,
                       n_need.astype(jnp.float32)[None]]
         return jnp.concatenate(parts)
 
-    return run
+    return rescore_fused
 
 
 @functools.lru_cache(maxsize=4)
@@ -1268,12 +1268,12 @@ def _fused_rescore_kernel(max_off, dm_block):
     from .pallas_dedisperse import dedisperse_plane_pallas_traced
 
     @jax.jit
-    def run(data, offs):
+    def rescore_rows(data, offs):
         plane = dedisperse_plane_pallas_traced(data, offs, max_off,
                                                dm_block=dm_block)
         return score_profiles_stacked(plane, xp=jnp)
 
-    return run
+    return rescore_rows
 
 
 def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,

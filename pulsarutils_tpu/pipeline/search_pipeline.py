@@ -20,6 +20,7 @@ Capability-equivalent of the reference's ``search_by_chunks``
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import time
@@ -713,6 +714,14 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
     _resilience_ladder.reset()
 
     with_timer = timer.bucket
+    # what the call costs outside its chunks (ISSUE 25): ``call/setup``
+    # runs from here to the first chunk, ``call/finish`` from the persist
+    # drain to the return; both reach BUDGET_JSON's ``call_s`` and, under
+    # a tracer, the timeline.  Opened and closed by hand because each
+    # encloses most of this function: when set-up raises the span goes
+    # unrecorded with the call it would have described.
+    call_phase = contextlib.ExitStack()
+    call_phase.enter_context(with_timer("call/setup"))
     with with_timer("badchans"):
         # the pre-scan streams the whole file through the same reader
         # seam the chunk loop uses, but BEFORE the hardened loop
@@ -723,69 +732,72 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         with fault_inject.suppressed():
             mask_fileorder = get_bad_chans(fname, surelybad=surelybad)
 
-    # geometry, resolved threshold and ledger fingerprint all come from
-    # the ONE planning function the fleet coordinator also calls — any
-    # second copy of this logic would let coordinator and worker drift
-    # onto different ledgers (ISSUE 9)
-    sp = plan_survey(fname, chunk_length=chunk_length,
-                     new_sample_time=new_sample_time, tmin=tmin,
-                     dmmin=dmmin, dmmax=dmmax, surelybad=surelybad,
-                     backend=backend, kernel=kernel,
-                     snr_threshold=snr_threshold, fft_zap=fft_zap,
-                     cut_outliers=cut_outliers, zero_dm=zero_dm,
-                     mesh=mesh, exact_floor=exact_floor,
-                     quarantine_policy=quarantine_policy,
-                     period_search=period_search,
-                     period_sigma_threshold=period_sigma_threshold,
-                     fingerprint_extra=fingerprint_extra)
-    reader = sp["reader"]
-    root = sp["root"]
-    header = reader.header
-    nsamples = sp["nsamples"]
-    sample_time = sp["sample_time"]
-    start_freq = header["fbottom"]
-    bandwidth = header["bandwidth"]
-    date = header.get("tstart", None)
+    with with_timer("call/plan"):
+        # geometry, resolved threshold and ledger fingerprint all come from
+        # the ONE planning function the fleet coordinator also calls — any
+        # second copy of this logic would let coordinator and worker drift
+        # onto different ledgers (ISSUE 9)
+        sp = plan_survey(fname, chunk_length=chunk_length,
+                         new_sample_time=new_sample_time, tmin=tmin,
+                         dmmin=dmmin, dmmax=dmmax, surelybad=surelybad,
+                         backend=backend, kernel=kernel,
+                         snr_threshold=snr_threshold, fft_zap=fft_zap,
+                         cut_outliers=cut_outliers, zero_dm=zero_dm,
+                         mesh=mesh, exact_floor=exact_floor,
+                         quarantine_policy=quarantine_policy,
+                         period_search=period_search,
+                         period_sigma_threshold=period_sigma_threshold,
+                         fingerprint_extra=fingerprint_extra)
+        reader = sp["reader"]
+        root = sp["root"]
+        header = reader.header
+        nsamples = sp["nsamples"]
+        sample_time = sp["sample_time"]
+        start_freq = header["fbottom"]
+        bandwidth = header["bandwidth"]
+        date = header.get("tstart", None)
 
-    # single place that owns band orientation: ascending everywhere below
-    mask = mask_fileorder[::-1] if reader.band_descending else mask_fileorder
+        # single place that owns band orientation: ascending everywhere
+        # below
+        mask = (mask_fileorder[::-1] if reader.band_descending
+                else mask_fileorder)
 
-    plan = sp["plan"]
-    eff_tsamp = plan.sample_time
-    snr_threshold = sp["snr_threshold"]
-    search_snr_floor = sp["search_snr_floor"]
-    fingerprint = sp["fingerprint"]
-    # fence (ISSUE 15): the fleet worker's lease epoch — candidate
-    # artifact writes stamped with a higher epoch are refused (see
-    # CandidateStore).  None (every non-fleet caller) is byte-inert.
-    store = CandidateStore(output_dir, fingerprint if resume else None,
-                           fence=fence)
-    # quarantine manifest: created lazily on first record, so a clean
-    # run's output directory is byte-identical to pre-hardening
-    manifest = QuarantineManifest(output_dir,
-                                  fingerprint if resume else None)
+        plan = sp["plan"]
+        eff_tsamp = plan.sample_time
+        snr_threshold = sp["snr_threshold"]
+        search_snr_floor = sp["search_snr_floor"]
+        fingerprint = sp["fingerprint"]
+        # fence (ISSUE 15): the fleet worker's lease epoch — candidate
+        # artifact writes stamped with a higher epoch are refused (see
+        # CandidateStore).  None (every non-fleet caller) is byte-inert.
+        store = CandidateStore(output_dir, fingerprint if resume else None,
+                               fence=fence)
+        # quarantine manifest: created lazily on first record, so a clean
+        # run's output directory is byte-identical to pre-hardening
+        manifest = QuarantineManifest(output_dir,
+                                      fingerprint if resume else None)
 
-    # candidate lifecycle observability (ISSUE 18).  ``lineage=True``
-    # builds a per-run recorder (or pass a LineageRecorder to share one
-    # across files); ``push`` accepts an AlertBroker or a list of
-    # subscriber specs (urls/dicts) — specs build a driver-owned broker
-    # dead-lettering into the output directory, closed (bounded) at the
-    # tail.  Both are None-gated: off is the pre-PR code path and the
-    # output directory is byte-identical.
-    if lineage is True:
-        lineage = LineageRecorder(fingerprint=fingerprint,
-                                  source="search_by_chunks")
-    elif not lineage:
-        lineage = None          # accept False/0/"" as "off" (CLI flag)
-    push_owned = False
-    if not push:
-        push = None
-    elif not isinstance(push, AlertBroker):
-        push = AlertBroker(
-            push, health=health,
-            dead_letter_path=os.path.join(
-                output_dir, f"push_dead_letter_{fingerprint}.jsonl"))
-        push_owned = True
+        # candidate lifecycle observability (ISSUE 18).  ``lineage=True``
+        # builds a per-run recorder (or pass a LineageRecorder to share one
+        # across files); ``push`` accepts an AlertBroker or a list of
+        # subscriber specs (urls/dicts) — specs build a driver-owned broker
+        # dead-lettering into the output directory, closed (bounded) at the
+        # tail.  Both are None-gated: off is the pre-PR code path and the
+        # output directory is byte-identical.
+        if lineage is True:
+            lineage = LineageRecorder(fingerprint=fingerprint,
+                                      source="search_by_chunks")
+        elif not lineage:
+            lineage = None          # accept False/0/"" as "off" (CLI flag)
+        push_owned = False
+        if not push:
+            push = None
+        elif not isinstance(push, AlertBroker):
+            push = AlertBroker(
+                push, health=health,
+                dead_letter_path=os.path.join(
+                    output_dir, f"push_dead_letter_{fingerprint}.jsonl"))
+            push_owned = True
 
     hits = []
     nproc = 0
@@ -830,40 +842,43 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                     resample=plan.resample)
     device_clean = None
     if backend == "jax":
-        import functools
+        with with_timer("call/device_setup"):
+            import jax
+            import jax.numpy as jnp
 
-        import jax
-        import jax.numpy as jnp
+            compile_phase.install()
+            mask_dev = jnp.asarray(np.asarray(mask))
+            # donate the raw chunk buffer into the clean program on
+            # accelerators: it is never touched again (the host copy backs
+            # the fallback), so the cleaned output can reuse its HBM — one
+            # fewer live chunk-sized buffer during the double-buffered
+            # stream.  CPU ignores donation with a per-call warning, so the
+            # flag is backend-gated rather than unconditional.
+            donate = ((0,) if jax.default_backend() in ("tpu", "gpu") else ())
+            if packed_bits:
+                from ..io.lowbit import device_unpack_block
 
-        compile_phase.install()
-        mask_dev = jnp.asarray(np.asarray(mask))
-        # donate the raw chunk buffer into the clean program on
-        # accelerators: it is never touched again (the host copy backs
-        # the fallback), so the cleaned output can reuse its HBM — one
-        # fewer live chunk-sized buffer during the double-buffered
-        # stream.  CPU ignores donation with a per-call warning, so the
-        # flag is backend-gated rather than unconditional.
-        donate = ((0,) if jax.default_backend() in ("tpu", "gpu") else ())
-        if packed_bits:
-            from ..io.lowbit import device_unpack_block
+                nchan_file = header["nchans"]
+                descending = reader.band_descending
 
-            nchan_file = header["nchans"]
-            descending = reader.band_descending
+                # the function's name is the program's in a device trace
+                # (``jit_unpack_clean``; obs/names.py KERNEL_NAMES)
+                def unpack_clean(raw, m):
+                    return _clean(device_unpack_block(
+                        raw, packed_bits, nchan_file,
+                        band_descending=descending, xp=jnp), m, xp=jnp)
 
-            def _unpack_clean(raw, m):
-                return _clean(device_unpack_block(
-                    raw, packed_bits, nchan_file,
-                    band_descending=descending, xp=jnp), m, xp=jnp)
+                device_clean = jax.jit(unpack_clean, donate_argnums=donate)
+            else:
+                def clean(block, m):
+                    return _clean(block, m, xp=jnp)
 
-            device_clean = jax.jit(_unpack_clean, donate_argnums=donate)
-        else:
-            device_clean = jax.jit(functools.partial(_clean, xp=jnp),
-                                   donate_argnums=donate)
-        if timer.rtt_s is None:  # keep a caller-calibrated RTT
-            timer.rtt_s = measure_device_rtt()
-        if timer.rtt_s is not None:
-            logger.info("device round-trip floor: %.4fs per "
-                        "dispatch+readback trip", timer.rtt_s)
+                device_clean = jax.jit(clean, donate_argnums=donate)
+            if timer.rtt_s is None:  # keep a caller-calibrated RTT
+                timer.rtt_s = measure_device_rtt()
+            if timer.rtt_s is not None:
+                logger.info("device round-trip floor: %.4fs per "
+                            "dispatch+readback trip", timer.rtt_s)
 
     # the chunk list is known upfront, so the NEXT chunk's read/decode
     # overlaps the current chunk's device compute (single reader thread —
@@ -964,7 +979,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
 
     from concurrent.futures import ThreadPoolExecutor
 
-    def read_at(s):
+    def read_at(s, rspan):
         """Read (and gate) one chunk on the reader thread.
 
         Returns ``(block, gate_info)`` — ``gate_info`` is ``None`` when
@@ -1058,6 +1073,14 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             # chunk's device work, so accounted but not in any chunk's
             # serial budget
             timer.add_async("read_decode", time.perf_counter() - t0)
+            rspan.end()
+
+    def submit_read(s):
+        # begun here, on the main thread: the pool's thread inherits
+        # neither the trace id nor the open span
+        # putpu-lint: disable=span-leak — ends in read_at on the reader thread (cross-thread by design)
+        rspan = begin_span("read_decode", track="reader", chunk=s)
+        return reader_pool.submit(read_at, s, rspan)
 
     def prefetch_upload(read_future):
         """Start the async device transfer of the NEXT chunk (main thread).
@@ -1101,7 +1124,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                     else None)
     persist_futures = []
 
-    def _persist_and_mark(payload, istart_, iend_, reason=None):
+    def _persist_and_mark(payload, istart_, iend_, ck, reason=None):
         """Persist + mark done, with bounded retry and a dead-letter.
 
         A write failure used to fail the whole run (the overlap only
@@ -1110,8 +1133,12 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         in the quarantine manifest and done-with-reason in the ledger —
         the run continues, the audit knows the candidate is missing on
         purpose.  Only ``OSError`` is retried: anything else is a bug,
-        not a disk hiccup, and still propagates.
+        not a disk hiccup, and still propagates.  ``ck`` (the chunk's
+        entry, see ``budget_chunk``) receives ``t_disk``, the clock when
+        ``mark_done`` returned — candidates and mark are on disk from
+        then on — and the seconds of the save and of the mark.
         """
+        t_save = time.perf_counter()
         if payload is not None:
             for attempt in range(max(int(persist_retries), 0) + 1):
                 try:
@@ -1137,7 +1164,11 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                                         fault_reasons.PERSIST_DEAD_LETTER,
                                         {"error": repr(exc)})
                         reason = fault_reasons.PERSIST_DEAD_LETTER
+        t_mark = time.perf_counter()
         store.mark_done(istart_, reason=reason)
+        ck["t_disk"] = time.perf_counter()
+        ck["save_s"] = t_mark - t_save
+        ck["mark_s"] = ck["t_disk"] - t_mark
         return reason
 
     def _lineage_finish(cl, istart_, iend_, payload, reason_out):
@@ -1162,31 +1193,68 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         else:
             cl.span.end()
 
-    def _persist_async(payload, istart_, iend_, pspan=None, reason=None,
-                       cl=None):
+    def _persist_async(payload, istart_, iend_, ck, t_submit, pspan=None,
+                       reason=None, cl=None):
         t0 = time.perf_counter()
         try:
-            out = _persist_and_mark(payload, istart_, iend_,
+            out = _persist_and_mark(payload, istart_, iend_, ck,
                                     reason=reason)
             _lineage_finish(cl, istart_, iend_, payload, out)
         finally:
             timer.add_async("persist", time.perf_counter() - t0)
+            # the same seconds by phase: waiting for the FIFO worker
+            # (not in the total, which starts with the worker), the
+            # candidate save, the ledger mark
+            timer.add_async("persist/queued", t0 - t_submit)
+            timer.add_async("persist/save", ck.get("save_s", 0.0))
+            timer.add_async("persist/mark", ck.get("mark_s", 0.0))
             if pspan is not None:
                 # async completion: submitted on the main thread inside
                 # the chunk, finished here on the worker — the trace
                 # shows the overlap the serial budget deliberately omits
                 pspan.end()
 
+    def _submit_persist(payload, istart_, iend_, ck, **kw):
+        persist_futures.append((persist_pool.submit(
+            _persist_async, payload, istart_, iend_, ck,
+            time.perf_counter(), **kw), ck))
+
+    def _stamp_on_disk(ck):
+        """``on_disk_lag_s`` on the chunk's own record: seconds from the
+        end of its span to the return of ``mark_done`` (0 when that came
+        first).  Main thread, as soon as both are known."""
+        if ck["t_end"] is not None and ck["t_disk"] is not None:
+            ck["rec"]["on_disk_lag_s"] = round(
+                max(ck["t_disk"] - ck["t_end"], 0.0), 4)
+
+    def _pop_persist():
+        future, ck = persist_futures.pop(0)
+        future.result()
+        _stamp_on_disk(ck)
+
     def _drain_persist(block=False):
         # serial semantics: a persist failure that survives the retry +
         # dead-letter policy (i.e. a bug, not a disk hiccup) must fail
         # the run — the overlap only defers the raise to the next drain
-        while persist_futures and (block or persist_futures[0].done()):
-            persist_futures.pop(0).result()
+        while persist_futures and (block or persist_futures[0][0].done()):
+            _pop_persist()
+
+    @contextlib.contextmanager
+    def budget_chunk(istart_):
+        """``timer.chunk`` plus the chunk's entry ``ck``: its record,
+        when its span ended (``t_end``) and when its persist was on disk
+        (``t_disk``, set by ``_persist_and_mark``) — what
+        ``on_disk_lag_s`` needs."""
+        with timer.chunk(istart_) as rec:
+            ck = {"rec": rec, "t_end": None, "t_disk": None}
+            yield ck
+        ck["t_end"] = timer.last_chunk_end
+        _stamp_on_disk(ck)
 
     reader_pool = ThreadPoolExecutor(max_workers=1)
-    next_read = reader_pool.submit(read_at, todo[0]) if todo else None
+    next_read = submit_read(todo[0]) if todo else None
     array_dev = None  # chunk's prefetched device buffer (if any)
+    call_phase.close()  # call/setup ends where the first chunk starts
     try:
         for ichunk, istart in enumerate(todo):
           if cancel_cb is not None and cancel_cb():
@@ -1197,7 +1265,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                           "chunks left for a resumed session", istart,
                           len(todo) - ichunk, len(todo))
               break
-          with timer.chunk(istart):
+          with budget_chunk(istart) as ck:
             t_chunk = time.perf_counter()
             chunk_size = min(plan.step, nsamples - istart)
             iend = istart + chunk_size
@@ -1205,7 +1273,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
 
             with with_timer("read"):
                 array, gate_info = next_read.result()
-            next_read = (reader_pool.submit(read_at, todo[ichunk + 1])
+            next_read = (submit_read(todo[ichunk + 1])
                          if ichunk + 1 < len(todo) else None)
 
             # -- failure containment: quarantine, never poison/crash --
@@ -1243,12 +1311,11 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                              manifest.path)
                 manifest.record(istart, iend, quarantine_reason, q_stats)
                 if persist_pool is not None:
-                    persist_futures.append(persist_pool.submit(
-                        _persist_async, None, istart, iend,
-                        reason=quarantine_reason))
+                    _submit_persist(None, istart, iend, ck,
+                                    reason=quarantine_reason)
                 else:
                     with with_timer("persist"):
-                        _persist_and_mark(None, istart, iend,
+                        _persist_and_mark(None, istart, iend, ck,
                                           reason=quarantine_reason)
                 array_dev = None  # drop any prefetched device copy
                 nproc += 1
@@ -1363,12 +1430,11 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 manifest.record(istart, iend, fault_reasons.OOM_FLOOR,
                                 {"error": repr(exc)})
                 if persist_pool is not None:
-                    persist_futures.append(persist_pool.submit(
-                        _persist_async, None, istart, iend,
-                        reason=fault_reasons.OOM_FLOOR))
+                    _submit_persist(None, istart, iend, ck,
+                                    reason=fault_reasons.OOM_FLOOR)
                 else:
                     with with_timer("persist"):
-                        _persist_and_mark(None, istart, iend,
+                        _persist_and_mark(None, istart, iend, ck,
                                           reason=fault_reasons.OOM_FLOOR)
                 nproc += 1
                 if canary is not None:
@@ -1630,9 +1696,8 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 # putpu-lint: disable=span-leak — ends in _persist_async on the FIFO persist worker (cross-thread by design; the drain barrier guarantees completion)
                 pspan = begin_span("persist", track="persist-worker",
                                    chunk=istart)
-                persist_futures.append(persist_pool.submit(
-                    _persist_async, payload, istart, iend, pspan,
-                    cl=cl))
+                _submit_persist(payload, istart, iend, ck, pspan=pspan,
+                                cl=cl)
                 # backpressure: each queued payload retains its cutout +
                 # table on the host, so an unbounded backlog on a
                 # hit-dense stream would grow without limit (the serial
@@ -1640,10 +1705,11 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 # the overlap win while bounding retained memory
                 while len(persist_futures) > 2:
                     with with_timer("persist_backpressure"):
-                        persist_futures.pop(0).result()
+                        _pop_persist()
             else:
                 with with_timer("persist"):
-                    reason_out = _persist_and_mark(payload, istart, iend)
+                    reason_out = _persist_and_mark(payload, istart, iend,
+                                                   ck)
                     _lineage_finish(cl, istart, iend, payload,
                                     reason_out)
             # second prefetch window: by the end of the iteration the
@@ -1688,6 +1754,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         with timer.bucket("persist_drain"):
             persist_pool.shutdown(wait=True)
             _drain_persist(block=True)
+    call_phase.enter_context(with_timer("call/finish"))
     if push is not None and push_owned:
         # bounded drain: a wedged subscriber journals to the dead
         # letter and cannot stall the driver's exit.  PUSH_JSON is the
@@ -1717,79 +1784,83 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         # every detection (round-5 survey rehearsal: the injected pulse
         # was found before the interrupt and then absent from the
         # resumed run's report)
-        seen = {(h[0], h[1]) for h in hits}
-        restored = 0
-        for cand_root, lo, hi in store.candidates():
-            # only chunks this fingerprint's ledger marks done: the
-            # store directory may hold same-named candidates persisted
-            # by other configurations
-            if (cand_root != root or (lo, hi) in seen
-                    or not store.is_done(lo)):
-                continue
-            try:
-                info, table = store.load_candidate(root, lo, hi)
-            # the actual load failure modes of a partial/corrupt npz
-            # pair (missing file, truncated zip, bad member, bad json,
-            # bit-rotted deflate stream) — anything else is a bug and
-            # must propagate, and every skip is counted so silent
-            # skips show in the metrics snapshot (ISSUE 4 satellite)
-            except (OSError, ValueError, KeyError, EOFError,
-                    zipfile.BadZipFile, zlib.error) as exc:
-                obs_metrics.counter(
-                    "putpu_resume_pairs_skipped_total").inc()
-                logger.warning("could not restore candidate %s_%d-%d: %r",
-                               root, lo, hi, exc)
-                continue
-            hits.append((lo, hi, info, table))
-            restored += 1
-        if restored:
-            hits.sort(key=lambda h: h[0])
-            logger.info("restored %d persisted candidate(s) from the "
-                        "resume ledger", restored)
+        with with_timer("call/restore"):
+            seen = {(h[0], h[1]) for h in hits}
+            restored = 0
+            for cand_root, lo, hi in store.candidates():
+                # only chunks this fingerprint's ledger marks done: the
+                # store directory may hold same-named candidates persisted
+                # by other configurations
+                if (cand_root != root or (lo, hi) in seen
+                        or not store.is_done(lo)):
+                    continue
+                try:
+                    info, table = store.load_candidate(root, lo, hi)
+                # the actual load failure modes of a partial/corrupt npz
+                # pair (missing file, truncated zip, bad member, bad json,
+                # bit-rotted deflate stream) — anything else is a bug and
+                # must propagate, and every skip is counted so silent
+                # skips show in the metrics snapshot (ISSUE 4 satellite)
+                except (OSError, ValueError, KeyError, EOFError,
+                        zipfile.BadZipFile, zlib.error) as exc:
+                    obs_metrics.counter(
+                        "putpu_resume_pairs_skipped_total").inc()
+                    logger.warning("could not restore candidate %s_%d-%d: %r",
+                                   root, lo, hi, exc)
+                    continue
+                hits.append((lo, hi, info, table))
+                restored += 1
+            if restored:
+                hits.sort(key=lambda h: h[0])
+                logger.info("restored %d persisted candidate(s) from the "
+                            "resume ledger", restored)
         # end-of-run integrity audit: ledger vs candidate files vs
         # quarantine manifest (read-only; inconsistencies are logged
         # and counted, never fatal — observability must not take down
         # a survey run)
         from ..faults.audit import audit_run
 
-        try:
-            report = audit_run(output_dir, fingerprint, root=root)
-        except Exception as exc:  # never fatal — by contract
-            logger.warning("integrity audit failed (%r); run result is "
-                           "unaffected", exc)
-        else:
-            if report["issues"]:
-                logger.warning("integrity audit: %d inconsistencies: %s",
-                               len(report["issues"]), report["issues"])
+        with with_timer("call/audit"):
+            try:
+                report = audit_run(output_dir, fingerprint, root=root)
+            except Exception as exc:  # never fatal — by contract
+                logger.warning("integrity audit failed (%r); run result is "
+                               "unaffected", exc)
             else:
-                logger.info("integrity audit: ok %s", report["checked"])
+                if report["issues"]:
+                    logger.warning("integrity audit: %d inconsistencies: %s",
+                                   len(report["issues"]), report["issues"])
+                else:
+                    logger.info("integrity audit: ok %s", report["checked"])
     if report_out:
         from ..obs import report as obs_report
 
-        try:  # never fatal — observability must not take down a run
-            md_path, html_path = obs_report.write_report(
-                str(report_out),
-                meta={"root": root,
-                      "fname": os.path.abspath(str(fname)),
-                      "fingerprint": fingerprint,
-                      "chunks_processed": nproc, "hits": len(hits),
-                      "certified": ncertified, "backend": backend,
-                      "kernel": kernel,
-                      "snr_threshold": snr_threshold},
-                budget=timer.to_json(max_per_chunk=0),
-                roofline=roofline.table(),
-                health=health.snapshot() if health is not None else None,
-                canary=canary.to_json() if canary is not None else None,
-                quarantine=manifest.records(),
-                metrics=obs_metrics.REGISTRY.snapshot(),
-                lineage=(lineage.summary()
-                         if lineage is not None else None),
-                push=push.stats() if push is not None else None)
-        except Exception as exc:
-            logger.warning("survey report failed (%r); run result is "
-                           "unaffected", exc)
-        else:
-            logger.info("survey report -> %s + %s", md_path, html_path)
+        with with_timer("call/report"):
+            try:  # never fatal — observability must not take down a run
+                md_path, html_path = obs_report.write_report(
+                    str(report_out),
+                    meta={"root": root,
+                          "fname": os.path.abspath(str(fname)),
+                          "fingerprint": fingerprint,
+                          "chunks_processed": nproc, "hits": len(hits),
+                          "certified": ncertified, "backend": backend,
+                          "kernel": kernel,
+                          "snr_threshold": snr_threshold},
+                    budget=timer.to_json(max_per_chunk=0),
+                    roofline=roofline.table(),
+                    health=health.snapshot() if health is not None else None,
+                    canary=canary.to_json() if canary is not None else None,
+                    quarantine=manifest.records(),
+                    metrics=obs_metrics.REGISTRY.snapshot(),
+                    lineage=(lineage.summary()
+                             if lineage is not None else None),
+                    push=push.stats() if push is not None else None)
+            except Exception as exc:
+                logger.warning("survey report failed (%r); run result is "
+                               "unaffected", exc)
+            else:
+                logger.info("survey report -> %s + %s", md_path, html_path)
     if obs_server is not None:
         obs_server.close()
+    call_phase.close()
     return hits, store

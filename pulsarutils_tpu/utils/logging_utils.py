@@ -19,6 +19,7 @@ trace timeline; counters are mirrored into the process metrics registry
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import json
@@ -99,9 +100,36 @@ def _percentile(sorted_values, q):
 
 #: process-wide XLA compile observation (jax.monitoring events); installed
 #: lazily, once — the listener registry has no deregister, so the counts
-#: are cumulative and consumers take deltas
-_COMPILE = {"count": 0, "secs": 0.0, "installed": False}
+#: are cumulative and consumers take deltas.  Beside the backend compile
+#: (on a persistent-cache hit that IS the retrieval), the phases before
+#: it: tracing, lowering to MLIR, and reading the executable back.
+_COMPILE = {"count": 0, "secs": 0.0, "trace_s": 0.0, "lower_s": 0.0,
+            "cache_load_s": 0.0, "installed": False}
 _COMPILE_LOCK = threading.Lock()
+
+#: jax.monitoring duration event (by its last path component) -> the
+#: cumulative ``_COMPILE`` key it adds to
+_COMPILE_PHASES = {"jaxpr_to_mlir_module_duration": "lower_s",
+                   "cache_retrieval_time_sec": "cache_load_s"}
+
+#: ``[start, end)`` of the outermost tracing intervals seen lately, on
+#: the perf_counter clock.  JAX fires ``jaxpr_trace_duration`` for every
+#: nested ``jit`` inside the interval of the one that encloses it (each
+#: jitted ``jnp`` helper the clean program calls is one), so the
+#: durations cannot be summed: an event that arrives later and covers
+#: earlier ones replaces them.
+_TRACE_INTERVALS = collections.deque(maxlen=1024)
+
+
+def _note_trace_interval(secs):
+    """Add one tracing event that ended now (``_COMPILE_LOCK`` held)."""
+    end = time.perf_counter()
+    start = end - secs
+    while _TRACE_INTERVALS and _TRACE_INTERVALS[-1][0] >= start:
+        inner = _TRACE_INTERVALS.pop()
+        _COMPILE["trace_s"] -= inner[1] - inner[0]
+    _TRACE_INTERVALS.append((start, end))
+    _COMPILE["trace_s"] += secs
 
 
 def _install_compile_listener():
@@ -112,10 +140,15 @@ def _install_compile_listener():
         from jax import monitoring
 
         def _on_event(name, secs, **kw):
-            if name.endswith("backend_compile_duration"):
-                with _COMPILE_LOCK:
+            name = name.rsplit("/", 1)[-1]
+            with _COMPILE_LOCK:
+                if name == "backend_compile_duration":
                     _COMPILE["count"] += 1
                     _COMPILE["secs"] += float(secs)
+                elif name == "jaxpr_trace_duration":
+                    _note_trace_interval(float(secs))
+                elif name in _COMPILE_PHASES:
+                    _COMPILE[_COMPILE_PHASES[name]] += float(secs)
 
         monitoring.register_event_duration_secs_listener(_on_event)
 
@@ -126,6 +159,16 @@ def compile_snapshot():
     _install_compile_listener()
     with _COMPILE_LOCK:
         return _COMPILE["count"], _COMPILE["secs"]
+
+
+def compile_phase_snapshot():
+    """Cumulative seconds of the phases before the backend compile:
+    ``{"trace_s", "lower_s", "cache_load_s"}`` (outermost tracing only,
+    lowering to MLIR, retrieval from the persistent cache)."""
+    _install_compile_listener()
+    with _COMPILE_LOCK:
+        return {k: _COMPILE[k]
+                for k in ("trace_s", "lower_s", "cache_load_s")}
 
 
 def measure_device_rtt(n=5):
@@ -167,9 +210,11 @@ class BudgetAccountant(StageTimer):
       ``/`` (``search/coarse``): the residual math uses top-level names
       only, so instrumented sub-phases never double-count;
     * XLA compiles are observed via ``jax.monitoring`` and recorded per
-      chunk (``compiles``/``compile_s`` counters).  A compile in any
-      chunk after the first is flagged as a **retrace** in that chunk's
-      record; the log escalates to a WARNING once retraces appear in 3+
+      chunk (``compiles``/``compile_s`` counters; and, where non-zero,
+      the phases around them: ``trace_s``, ``lower_s``,
+      ``cache_load_s`` — see :func:`compile_phase_snapshot`).  A compile
+      in any chunk after the first is flagged as a **retrace** in that
+      chunk's record; the log escalates to a WARNING once retraces appear in 3+
       chunks (true shape drift recompiles everywhere, while a lazily
       built kernel's first use legitimately compiles once).  NOTE the
       compile listener is process-global: a concurrent JAX compile from
@@ -180,7 +225,11 @@ class BudgetAccountant(StageTimer):
       critical path);
     * ``unattributed`` = chunk wall − Σ top-level buckets, per chunk and
       summed in :meth:`footer`; :meth:`to_json` emits the whole ledger
-      for artifacts.
+      for artifacts;
+    * a :meth:`bucket` that no chunk encloses (the drivers' ``call/*``
+      phases, the bad-channel pre-scan, the persist drain) is a cost of
+      the call, not of a chunk: it lands in ``call_s``, outside the
+      chunk sums.
 
     ``rtt_s`` (see :func:`measure_device_rtt`) prices the per-trip
     floor: the footer reports ``dispatches+readbacks × rtt`` so the
@@ -192,6 +241,9 @@ class BudgetAccountant(StageTimer):
         super().__init__()
         self.rtt_s = rtt_s
         self.chunks = []
+        self.call_buckets = {}
+        #: ``perf_counter`` at the end of the last closed chunk's span
+        self.last_chunk_end = None
         self.async_totals = {}
         self.counters_total = {}
         self._async_lock = threading.Lock()
@@ -234,6 +286,7 @@ class BudgetAccountant(StageTimer):
         if self._active is not None:
             raise RuntimeError("budget chunks cannot nest")
         c0, s0 = compile_snapshot()
+        phases0 = compile_phase_snapshot()
         rec = {"chunk": label, "wall_s": 0.0, "buckets": {}, "counters": {}}
         self._active = rec
         token = _ACTIVE_BUDGET.set(self)
@@ -247,6 +300,7 @@ class BudgetAccountant(StageTimer):
             _trace.close_span(s)
             _trace.pop_track(track_token)
             rec["wall_s"] = s.dur
+            self.last_chunk_end = s.t1
             _ACTIVE_BUDGET.reset(token)
             self._active = None
             self._stream_chunks += 1
@@ -275,6 +329,11 @@ class BudgetAccountant(StageTimer):
                         if self._retrace_chunks >= 3 else
                         "expected for a kernel's first use; repeated "
                         "occurrences escalate to a warning")
+            for key, total in compile_phase_snapshot().items():
+                # chunks that compile nothing keep their bytes
+                delta = round(total - phases0[key], 4)
+                if delta > 0:
+                    rec["counters"][key] = delta
             top = sum(v for k, v in rec["buckets"].items() if "/" not in k)
             rec["unattributed_s"] = round(rec["wall_s"] - top, 4)
             rec["wall_s"] = round(rec["wall_s"], 4)
@@ -313,9 +372,9 @@ class BudgetAccountant(StageTimer):
             self.add(name, s.dur)
 
     def add(self, name, dt):
-        if self._active is not None:
-            b = self._active["buckets"]
-            b[name] = b.get(name, 0.0) + dt
+        b = (self._active["buckets"] if self._active is not None
+             else self.call_buckets)
+        b[name] = b.get(name, 0.0) + dt
         self.totals[name] = self.totals.get(name, 0.0) + dt
         self.counts[name] = self.counts.get(name, 0) + 1
 
@@ -363,7 +422,7 @@ class BudgetAccountant(StageTimer):
             # versioned footer (ISSUE 5 satellite): parsers and the perf
             # gate key off this instead of silently comparing records
             # whose meaning drifted.  ISSUE 14 added chunk_wall_s
-            # percentiles — the schema_version bump that versions it.
+            # percentiles, ISSUE 25 call_s — each a schema_version bump.
             "schema_version": SCHEMA_VERSION,
             "chunks": nchunks,
             "wall_s": round(wall, 3),
@@ -379,6 +438,11 @@ class BudgetAccountant(StageTimer):
             "counters": dict(self.counters_total),
             "async_s": {k: round(v, 3)
                         for k, v in self.async_totals.items()},
+            # what the call cost outside its chunks, as recorded so far
+            # (v4): the drivers' ``call/*`` phases under their bare
+            # names, ``badchans``, ``persist_drain``
+            "call_s": {k.removeprefix("call/"): round(v, 3)
+                       for k, v in self.call_buckets.items()},
             # long streams: keep the JSON line bounded — head + tail
             # chunks (the aggregates above always cover every chunk);
             # max_per_chunk=0 drops the per-chunk detail entirely
@@ -462,6 +526,8 @@ class BudgetAccountant(StageTimer):
                      j["trips"], j["trips_x_rtt_s"])
         for k, v in sorted(j["async_s"].items(), key=lambda kv: -kv[1]):
             log.info("  overlapped %-17s %8.3fs (off critical path)", k, v)
+        for k, v in sorted(j["call_s"].items(), key=lambda kv: -kv[1]):
+            log.info("  outside chunks %-13s %8.3fs", k, v)
         if j["wall_s"]:
             _metrics.gauge("putpu_chunks_per_s").set(
                 round(j["chunks"] / j["wall_s"], 4))

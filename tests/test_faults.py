@@ -247,19 +247,20 @@ def test_default_run_is_inert_and_byte_identical(survey_file, tmp_path):
     assert b"quarantined" not in led_a
     # BUDGET_JSON: same record keys as the round-6/7 ledger (plus the
     # ISSUE-5 schema_version stamp, the ISSUE-14 chunk_wall_s
-    # percentile block and the ISSUE-7 autotune decision
-    # table — present only when kernel="auto" resolved a geometry key
-    # during this stream), and no robustness-named buckets leaked into
-    # the default path
+    # percentile block, the ISSUE-25 call_s block and the ISSUE-7
+    # autotune decision table — present only when kernel="auto"
+    # resolved a geometry key during this stream), and no
+    # robustness-named buckets leaked into the default path
     j = acct.to_json()
     assert set(j) <= {"schema_version", "chunks", "wall_s",
                       "chunk_wall_s", "buckets_s",
                       "unattributed_s", "attributed_pct", "counters",
-                      "async_s", "per_chunk", "per_chunk_truncated",
+                      "async_s", "call_s", "per_chunk",
+                      "per_chunk_truncated",
                       "truncated_chunks", "rtt_s", "trips",
                       "trips_x_rtt_s", "autotune"}
     assert not any(("integrity" in k) or ("sanit" in k) or ("retry" in k)
-                   for k in j["buckets_s"])
+                   for k in list(j["buckets_s"]) + list(j["call_s"]))
 
 
 def test_transient_dispatch_error_retries_without_fallback(survey_file,

@@ -14,6 +14,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 from pulsarutils_tpu.analysis import (LintProject, lint_source,
                                       load_baseline, save_baseline)
 from pulsarutils_tpu.analysis import baseline as baseline_mod
@@ -431,6 +433,32 @@ def test_metric_name_unknown_doc_reference_fires(tmp_path):
     extra = project.finalize()
     assert ids(extra) == ["metric-name-unknown-ref"]
     assert "putpu_ghost_total" in extra[0].message
+
+
+@pytest.mark.parametrize("call, fires", [
+    ('pl.pallas_call(kernel, out_shape=o, name="fdmt_head")', False),
+    ("pl.pallas_call(kernel, out_shape=o)", True),
+    ('pl.pallas_call(kernel, out_shape=o, name="kernel")', True),
+    ("pl.pallas_call(kernel, out_shape=o, name=label)", True),
+])
+def test_kernel_name_must_be_declared(tmp_path, call, fires):
+    # ISSUE 25: trace reductions find a kernel by its name, so every
+    # pallas_call carries a literal name= from the manifest's KERNEL_NAMES
+    pkg = tmp_path / "pulsarutils_tpu" / "obs"
+    pkg.mkdir(parents=True)
+    (pkg / "names.py").write_text(
+        'METRIC_NAMES = {}\nKERNEL_NAMES = {"fdmt_head": "meaning"}\n')
+    project = LintProject(root=str(tmp_path))
+    found = project.check_source(call + "\n", OPS)
+    assert ids(found) == (["kernel-name-unknown"] if fires else [])
+
+
+def test_committed_kernel_names_cover_every_pallas_call():
+    # the committed tree lints clean (test_committed_tree_* below), so
+    # every pallas_call in it is named from this table; none after the
+    # closure that builds it
+    assert names.KERNEL_NAMES
+    assert not {"kernel", "run", "fn"} & set(names.KERNEL_NAMES)
 
 
 def test_runtime_manifest_helpers_agree():

@@ -224,10 +224,11 @@ def test_prometheus_conformance_golden():
 #: "chunk_wall_s" percentile block (schema_version 1 -> 2), ISSUE 17
 #: the snapshot header's backend/precision-policy lane stamps
 #: (schema_version 2 -> 3, no BUDGET_JSON byte change beyond the
-#: version) — all DELIBERATE byte changes, versioned as such; every
-#: other byte is still pinned.
+#: version), ISSUE 25 the "call_s" block of what the call costs outside
+#: its chunks (3 -> 4) — all DELIBERATE byte changes, versioned as such;
+#: every other byte is still pinned.
 _GOLDEN_BUDGET_JSON = (
-    '{"schema_version": 3, '
+    '{"schema_version": 4, '
     '"chunks": 2, "wall_s": 1.125, '
     '"chunk_wall_s": {"p50": 0.5625, "p95": 0.5625, "p99": 0.5625}, '
     '"buckets_s": {"search": 0.625, '
@@ -235,6 +236,7 @@ _GOLDEN_BUDGET_JSON = (
     '"unattributed_s": 0.375, "attributed_pct": 66.7, '
     '"counters": {"dispatches": 2, "readbacks": 4}, '
     '"async_s": {"persist": 0.25}, '
+    '"call_s": {"badchans": 0.062}, '
     '"per_chunk": [{"chunk": 0, "wall_s": 0.5625, "buckets": '
     '{"read": 0.0625, "search/dispatch": 0.0625, "search/readback": '
     '0.0625, "search": 0.3125}, "counters": {"dispatches": 1, '
@@ -251,6 +253,8 @@ def test_budget_json_byte_identical_to_pre_refactor(monkeypatch):
     monkeypatch.setattr(time, "perf_counter", lambda: next(ticks))
     acct = BudgetAccountant(rtt_s=0.015625)
     acct.begin_stream()
+    with acct.bucket("badchans"):  # outside any chunk: call_s
+        pass
     for label in (0, 32768):
         with acct.chunk(label):
             with acct.bucket("read"):
@@ -276,6 +280,8 @@ def test_budget_json_byte_identical_while_tracing(monkeypatch):
         monkeypatch.setattr(time, "perf_counter", lambda: next(ticks))
         acct = BudgetAccountant(rtt_s=0.015625)
         acct.begin_stream()
+        with acct.bucket("badchans"):  # outside any chunk: call_s
+            pass
         for label in (0, 32768):
             with acct.chunk(label):
                 with acct.bucket("read"):
