@@ -230,9 +230,9 @@ class CandidateStore:
 
     #: persisted-waterfall element budget: above this, ``save_candidate``
     #: stores a window around the pulse instead of the whole chunk (a
-    #: 1024 x 1M survey chunk is a multi-GB compressed npz per hit and
-    #: took ~10 min of single-core zlib per candidate — measured in the
-    #: round-5 survey rehearsal, where persist dominated the pipeline)
+    #: 1024 x 1M survey chunk is 4 GiB of float32 per hit).  The record
+    #: is a stored npz, so this is what bounds a hit on disk: at most
+    #: 16 MiB of cutout beside the chunk-long profiles
     WATERFALL_BUDGET = 1 << 22
 
     def save_candidate(self, root, istart, iend, info: PulseInfo,
@@ -244,8 +244,16 @@ class CandidateStore:
             self.trim_waterfall(info, table).save(base + ".info.npz")
             table.to_npz(base + ".table.npz")
 
-        self.fenced_write(base, write)
+        if self.fenced_write(base, write):
+            _metrics.counter("putpu_candidate_bytes_written_total").inc(
+                self.pair_bytes(base))
         return base
+
+    @staticmethod
+    def pair_bytes(base):
+        """Size on disk of a candidate's ``.info.npz`` + ``.table.npz``."""
+        return sum(os.path.getsize(base + ext)
+                   for ext in (".info.npz", ".table.npz"))
 
     def save_lineage(self, root, istart, iend, doc):
         """Persist a candidate's lineage doc beside its npz pair

@@ -83,6 +83,9 @@ METRIC_NAMES = {
         "chunk best rows tagged as the canary and excluded",
     "putpu_canary_window_recall":
         "recall over the rolling canary window",
+    "putpu_candidate_bytes_written_total":
+        "bytes on disk of the candidate pairs (.info.npz + .table.npz) "
+        "this process persisted",
     "putpu_candidate_latency_seconds":
         "histogram of end-to-end candidate latency, sample read to "
         "persist complete (the candidate-latency p95 SLO's source)",

@@ -111,7 +111,15 @@ class PulseInfo:
     # -- persistence --------------------------------------------------------
 
     def save(self, path):
-        """Write as ``<path>`` npz (arrays + a json-encoded scalar record)."""
+        """Write as ``<path>`` npz (arrays + a json-encoded scalar record).
+
+        The members are stored, not deflated: the arrays are float32
+        noise, which zlib shrinks by a fifth to a third at 13-15 MB/s on
+        one thread — longer than the chunk the record came from took to
+        search.  The store bounds a record instead (``CandidateStore.
+        WATERFALL_BUDGET``).  :meth:`load` reads deflated members too, so
+        records written before this still load.
+        """
         scalars = {}
         arrays = {}
         for f in dataclasses.fields(self):
@@ -121,7 +129,7 @@ class PulseInfo:
                     arrays[f.name] = np.asarray(value)
             elif value is not None:
                 scalars[f.name] = value
-        np.savez_compressed(path, __scalars__=json.dumps(scalars), **arrays)
+        np.savez(path, __scalars__=json.dumps(scalars), **arrays)
         return path
 
     @classmethod

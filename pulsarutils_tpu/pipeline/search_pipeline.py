@@ -503,7 +503,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
 
     ``overlap_persist`` (default on, round 6) moves each chunk's
     candidate persist + ledger write onto a single-worker executor so
-    the host-side npz compression of chunk ``k`` overlaps the device
+    the host-side npz write of chunk ``k`` overlaps the device
     search of chunk ``k+1``.  The worker is FIFO, ``save_candidate``
     precedes ``mark_done`` inside one task, and every task is drained
     before the function returns — ledger ordering, crash-safe resume
@@ -1116,7 +1116,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             return None
 
     # persist executor (round 6): one FIFO worker absorbs the per-chunk
-    # candidate compression + ledger write so it overlaps the NEXT
+    # candidate write + ledger write so it overlaps the NEXT
     # chunk's device search.  Single worker + save-before-mark inside
     # one task = ledger order and crash-resume semantics byte-identical
     # to the serial loop.
@@ -1136,13 +1136,15 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         not a disk hiccup, and still propagates.  ``ck`` (the chunk's
         entry, see ``budget_chunk``) receives ``t_disk``, the clock when
         ``mark_done`` returned — candidates and mark are on disk from
-        then on — and the seconds of the save and of the mark.
+        then on — the seconds of the save and of the mark, and for a
+        hit ``save_bytes``, the size on disk of its record.
         """
         t_save = time.perf_counter()
         if payload is not None:
             for attempt in range(max(int(persist_retries), 0) + 1):
                 try:
-                    store.save_candidate(root, istart_, iend_, *payload)
+                    ck["save_bytes"] = store.pair_bytes(
+                        store.save_candidate(root, istart_, iend_, *payload))
                     break
                 except OSError as exc:
                     if attempt < persist_retries:
@@ -1222,10 +1224,13 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
     def _stamp_on_disk(ck):
         """``on_disk_lag_s`` on the chunk's own record: seconds from the
         end of its span to the return of ``mark_done`` (0 when that came
-        first).  Main thread, as soon as both are known."""
+        first), and a hit's ``save_bytes``.  Main thread, as soon as
+        both are known."""
         if ck["t_end"] is not None and ck["t_disk"] is not None:
             ck["rec"]["on_disk_lag_s"] = round(
                 max(ck["t_disk"] - ck["t_end"], 0.0), 4)
+            if "save_bytes" in ck:
+                ck["rec"]["save_bytes"] = ck["save_bytes"]
 
     def _pop_persist():
         future, ck = persist_futures.pop(0)
@@ -1797,8 +1802,9 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 try:
                     info, table = store.load_candidate(root, lo, hi)
                 # the actual load failure modes of a partial/corrupt npz
-                # pair (missing file, truncated zip, bad member, bad json,
-                # bit-rotted deflate stream) — anything else is a bug and
+                # pair (missing file, truncated zip, bad json, a stored
+                # member whose CRC no longer matches, the bit-rotted deflate
+                # stream of an older record) — anything else is a bug and
                 # must propagate, and every skip is counted so silent
                 # skips show in the metrics snapshot (ISSUE 4 satellite)
                 except (OSError, ValueError, KeyError, EOFError,

@@ -709,30 +709,45 @@ def test_corrupt_preserves_floating_dtype():
     assert i8.dtype == np.float32 and np.isnan(i8).any()
 
 
-def test_resume_skips_bitrotted_deflate_member(survey_file, tmp_path):
-    """A .npz with an intact zip directory but a corrupt deflate stream
-    raises zlib.error on load — the restore loop must skip+count it,
-    not die (code-review r8)."""
+@pytest.mark.parametrize("victim", ("table_stored", "info_stored",
+                                    "info_deflated"))
+def test_resume_skips_bitrotted_deflate_member(survey_file, tmp_path,
+                                               victim):
+    """A .npz with an intact zip directory but a rotted member payload
+    fails on load — a stored member (the table always, the record since
+    ISSUE 26) with ``BadZipFile`` on its CRC, a deflated one (a record
+    written before ISSUE 26) with ``zlib.error`` — and the restore loop
+    must skip+count it, not die (code-review r8)."""
+    import struct
     import zipfile as _zipfile
 
     outdir = str(tmp_path)
     hits, store = search_by_chunks(survey_file, output_dir=outdir,
                                    **SEARCH_KW)
     assert len(hits) == 2
-    name = sorted(f for f in os.listdir(outdir)
-                  if f.endswith(".table.npz"))[0]
+    suffix = ".table.npz" if victim == "table_stored" else ".info.npz"
+    name = sorted(f for f in os.listdir(outdir) if f.endswith(suffix))[0]
     path = os.path.join(outdir, name)
-    # bit-rot the first member's compressed payload, keeping the zip
-    # central directory (and the member sizes/offsets) intact
-    import struct
-
+    if victim == "info_deflated":
+        with np.load(path, allow_pickle=False) as data:
+            np.savez_compressed(path, **{k: data[k] for k in data.files})
+    # bit-rot one member's payload, keeping the zip central directory
+    # (and the member sizes/offsets) intact: the start of the first
+    # member's stream, or the middle of the stored cutout's samples
     with _zipfile.ZipFile(path) as z:
-        first = z.infolist()[0]
+        member = (z.getinfo("allprofs.npy") if victim == "info_stored"
+                  else z.infolist()[0])
+    expect = (_zipfile.ZIP_DEFLATED if victim == "info_deflated"
+              else _zipfile.ZIP_STORED)
+    assert member.compress_type == expect
     with open(path, "r+b") as f:
-        f.seek(first.header_offset + 26)
+        f.seek(member.header_offset + 26)
         nlen, elen = struct.unpack("<HH", f.read(4))
-        f.seek(first.header_offset + 30 + nlen + elen + 2)
-        f.write(b"\xde\xad\xbe\xef")
+        f.seek(member.header_offset + 30 + nlen + elen
+               + (member.file_size // 2 if victim == "info_stored" else 2))
+        rot = bytes(b ^ 0xFF for b in f.read(4))
+        f.seek(-4, os.SEEK_CUR)
+        f.write(rot)
     before = _counter("putpu_resume_pairs_skipped_total")
     hits2, _ = search_by_chunks(survey_file, output_dir=outdir,
                                 **SEARCH_KW)
