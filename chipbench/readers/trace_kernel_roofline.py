@@ -1,9 +1,14 @@
 """A kernel's share of its roofline: the least time the chip could take
-for the calls made (``kernel_counts.py`` from the cell's shapes, peaks from
-``peaks.json``) over the kernel's summed device time in the trace.  The
+for the calls made (the ``counts`` function from the cell's shapes, peaks
+from ``peaks.json``) over the kernel's summed device time in the trace.
+``counts`` names a function of ``kernel_counts.py``, or
+``<module>:<function>`` of another module of ``chipbench/``, so that a
+configuration can bring its kernel's counts as a file of its own.  The
 kernel's operations are found by ``match``, a regular expression over the
 trace's operation names; ``calls_per`` says how many calls the stretch made
 (one per chunk)."""
+import importlib
+
 from .. import kernel_counts, trace_reduce
 from .common import chunks_of
 
@@ -16,7 +21,10 @@ def read(source, ctx):
                                                  source["match"])
     if not seconds:
         return None
-    counts = getattr(kernel_counts, source["counts"])(**ctx["shapes"])
+    module, _, function = source["counts"].rpartition(":")
+    counts_of = getattr(importlib.import_module("chipbench." + module)
+                        if module else kernel_counts, function)
+    counts = counts_of(**ctx["shapes"])
     least, roof = kernel_counts.roofline_seconds(counts, ctx["peaks"])
     calls = len(chunks_of(ctx["passes"]))
     ctx["notes"].append(

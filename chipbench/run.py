@@ -17,9 +17,13 @@ One process, no children: whoever imports JAX holds the chip.  A run
 5. with ``--trace 1`` the window is a short stretch inside
    ``jax.profiler`` and the program's span tracer instead, and the
    per-layer metrics are read from it;
-6. after the window: the plain reference (``reference.py``) over the pulse
-   chunk, and the comparison that decides ``correct``;
-7. prints one JSON object as the last line of standard output.
+6. after the window: the plain reference (``reference.py``, or the module
+   of ``chipbench/`` the configuration names under ``"reference"``) over
+   every chunk that holds a pulse, and the comparison that decides
+   ``correct``;
+7. prints every number compared beside its limit as the last lines of
+   standard error, and one JSON object as the last line of standard
+   output, the same numbers under its last key, ``compared``.
 
 Everything of one configuration, traffic mix or per-layer metric lives in
 a file of its own under ``configs/``, ``traffic/``, ``layer_metrics/``
@@ -48,7 +52,7 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench import generate, reference, trace_reduce  # noqa: E402
+from chipbench import generate, trace_reduce  # noqa: E402
 
 #: no TPU with the cell's chips, no program in the checkout, or a rehearsal:
 #: nothing was measured
@@ -230,6 +234,20 @@ def rms_gap(table, ref_rows):
     return rms, len(gaps)
 
 
+def rows_as_table(rows):
+    """The reference's (or its control's) rows as the columns of a
+    persisted table, indexed by trial, for :func:`rms_gap`."""
+    import numpy as np
+
+    n = 1 + max(r["row"] for r in rows)
+    table = {"DM": np.full(n, np.nan), "snr": np.zeros(n),
+             "rebin": np.zeros(n, dtype=int), "peak": np.zeros(n, dtype=int)}
+    for r in rows:
+        for k, col in table.items():
+            col[r["row"]] = r[k]
+    return table
+
+
 def percentile(values, q):
     """Nearest-rank percentile."""
     v = sorted(values)
@@ -252,11 +270,15 @@ def read_layer_metrics(manifest, cell, ctx):
     return out
 
 
+def compared_line(word, name, value, limit, ok):
+    return (f"{word} {name}: value={value!r} limit={limit!r} "
+            f"[{'ok' if ok else 'FAILED'}]")
+
+
 def compare(name, value, limit, results, exact=True):
     ok = (value == limit) if exact else (value <= limit)
-    results.append(ok)
-    say(f"compare {name}: value={value!r} limit={limit!r} "
-        f"[{'ok' if ok else 'FAILED'}]")
+    results.append((name, value, limit, ok))
+    say(compared_line("compare", name, value, limit, ok))
     return ok
 
 
@@ -270,8 +292,9 @@ def main(argv=None):
                     help="run every step on whatever backend JAX has; "
                          "names it in the result and never exits 0")
     ap.add_argument("--control", type=int, choices=(0, 1), default=0,
-                    help="also print the control's reading: the reference "
-                         "with the cleaned chunk stored in bfloat16")
+                    help="also compare the control, which has to fail: the "
+                         "reference with the cleaned chunk stored in "
+                         "bfloat16, in the program's place")
     ap.add_argument("--keep-trace", default=None, metavar="DIR",
                     help="copy the profiler's trace directory here")
     opts = ap.parse_args(argv)
@@ -327,7 +350,7 @@ def main(argv=None):
 def _run(opts, manifest, entry, cfg, traffic, dev, peaks, watch, work):
     import jax
 
-    results = []
+    results, control_results = [], []
     path = os.path.join(work, "obs.fil")
     info = generate.generate(path, cfg, traffic, opts.seed)
     say(f"generated {info['bytes'] / 2**20:.0f} MiB, {info['nsamples']} "
@@ -422,6 +445,10 @@ def _run(opts, manifest, entry, cfg, traffic, dev, peaks, watch, work):
 
     # -- the plain reference, after the window, outside setup_s ------------
     limits = cfg["limits"]
+    # a configuration may bring its own plain reference, a module of
+    # chipbench/ with reference.py's best_row(); absent means reference.py
+    ref_name = cfg.get("reference", "reference")
+    reference = importlib.import_module("chipbench." + ref_name)
     # a pulse sits whole inside one hop, so the chunks that start at that
     # hop and at the one before hold it
     holding = [(pulse, s) for pulse in info["pulses"] for s in chunk_starts
@@ -429,7 +456,8 @@ def _run(opts, manifest, entry, cfg, traffic, dev, peaks, watch, work):
     for pulse, istart in holding:
         ref = reference.best_row(path, cfg, istart, pulse["dm"],
                                  control=bool(opts.control))
-        say(f"reference ({ref['seconds']:.1f} s, {ref['ntrials']} trials, "
+        say(f"reference chipbench.{ref_name} ({ref['seconds']:.1f} s, "
+            f"{ref['ntrials']} trials, "
             f"rows {[r['row'] for r in ref['rows']]}): "
             f"{json.dumps({k: ref[k] for k in ('DM', 'row', 'snr', 'rebin', 'peak')})}")
         side = path + ".badchans"
@@ -461,16 +489,26 @@ def _run(opts, manifest, entry, cfg, traffic, dev, peaks, watch, work):
         compare("snr_rel_gap_rms", rms, limits["snr_rel_gap_rms"], results,
                 exact=False)
         if opts.control:
+            # the control in the program's place: its rows through the
+            # program's own comparison, which has to fail it
             ctl = ref["control"]
-            by_row = {r["row"]: r for r in ref["rows"]}
-            gaps = [(c["snr"] - by_row[c["row"]]["snr"]) / by_row[c["row"]]["snr"]
-                    for c in ctl["rows"]]
+            ctl_rms, _ = rms_gap(rows_as_table(ctl["rows"]), ref["rows"])
             say(f"control (cleaned chunk in bfloat16): best "
                 f"{json.dumps({k: ctl[k] for k in ('DM', 'snr', 'rebin', 'peak')})} "
-                f"row gaps {[float(f'{g:.3e}') for g in gaps]} "
-                f"snr_rel_gap_rms={math.sqrt(sum(g * g for g in gaps) / len(gaps))!r} "
+                f"snr_rel_gap_rms={ctl_rms!r} "
                 f"limit={limits['snr_rel_gap_rms']!r}")
-    correct = all(results)
+            compare("snr_rel_gap_rms.control", ctl_rms,
+                    limits["snr_rel_gap_rms"], control_results, exact=False)
+    correct = all(ok for *_, ok in results)
+    results += control_results
+    compared, seen = {}, {}
+    for name, value, limit, ok in results:
+        # a comparison made once per pulse chunk: name, name.2, name.3
+        seen[name] = seen.get(name, 0) + 1
+        # json has no inf: a gap over no rows is printed as a string
+        compared[name if seen[name] == 1 else f"{name}.{seen[name]}"] = {
+            "value": value if math.isfinite(value) else repr(value),
+            "limit": limit, "ok": ok}
 
     # -- metrics -----------------------------------------------------------
     device = dict(dev, memory_peak_bytes=int(peak_bytes))
@@ -521,6 +559,15 @@ def _run(opts, manifest, entry, cfg, traffic, dev, peaks, watch, work):
             ops = sorted(reduced["op_seconds"].items(), key=lambda kv: -kv[1])
             say(f"device operations by name ({len(ops)} names, s): "
                 f"{json.dumps([[k, round(v, 5)] for k, v in ops[:60]])}")
+    if opts.control:
+        # a control that gave no reading has failed too
+        line["control_correct"] = bool(control_results) and all(
+            ok for *_, ok in control_results)
+    line["compared"] = compared
+    for name, c in compared.items():
+        print(compared_line("compared", name, c["value"], c["limit"],
+                            c["ok"]), file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     if dev["platform"] != "tpu":
         return EXIT_NOT_MEASURED

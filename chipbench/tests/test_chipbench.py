@@ -271,10 +271,17 @@ def test_rehearsal_prints_a_well_formed_last_line(capsys):
     assert control_gap > limit  # the bfloat16 control is not correct
 
 
-def test_traced_rehearsal_reports_layer_metrics(capsys):
+def test_traced_rehearsal_reports_layer_metrics(capsys, monkeypatch):
+    # a metric that lists its cells is read in those alone, so the tiny
+    # geometry runs under a real cell's name
+    real = harness.resolve_cell
+    monkeypatch.setattr(
+        harness, "resolve_cell",
+        lambda workload, rehearsal: real(workload, rehearsal)[:2]
+        + real(CELL, True)[2:])
     rc, line, _ = _last_line(capsys, [
-        "--workload", CELL, "--seed", "7", "--seconds", "1", "--trace", "1",
-        "--rehearsal"])
+        "--workload", "rehearsal_1024ch_2bit.backlog_sparse", "--seed", "7",
+        "--seconds", "1", "--trace", "1", "--rehearsal"])
     assert rc != 0 and line["correct"] is True
     assert line["metrics"]["cold_pass_s"]["value"] > \
         line["metrics"]["prescan_s"]["value"] > 0
