@@ -65,6 +65,13 @@ def build_parser():
     parser.add_argument("--zero-dm", action="store_true",
                         help="subtract the channel-averaged time series "
                              "(broadband un-dispersed RFI filter)")
+    parser.add_argument("--dm-tiers", choices=("smearing",), default=None,
+                        help="search the DM range in tiers: the sample time "
+                             "doubles wherever the intra-channel smearing at "
+                             "the band centre reaches one sample, and each "
+                             "tier searches the band delays of its own "
+                             "sample time (default off: one sample time for "
+                             "the whole range)")
     parser.add_argument("--output-dir", default=None)
     parser.add_argument("--show-plots", action="store_true",
                         help="display each diagnostic figure interactively "
@@ -267,6 +274,7 @@ def main(args=None):
                 fft_zap=opts.fft_zap,
                 cut_outliers=opts.cut_outliers,
                 zero_dm=opts.zero_dm,
+                dm_tiers=opts.dm_tiers,
                 max_chunks=opts.max_chunks,
                 period_search=opts.period_search,
                 period_sigma_threshold=opts.period_sigma,

@@ -360,6 +360,11 @@ METRIC_NAMES = {
         "chunks completed by stream_search",
     "putpu_stream_hits_total":
         "stream chunks whose best S/N cleared the threshold",
+    "putpu_tier_certified_total":
+        "tier sweeps of a tiered search whose noise certificate held",
+    "putpu_tier_sweeps_total":
+        "tier sweeps of a tiered search (dm_tiers): one per tier per "
+        "chunk",
     "putpu_trace_clock_offset_seconds":
         "worker wall clock offset vs the coordinator, midpoint rule "
         "over the register/lease exchange (labelled by worker)",
@@ -426,6 +431,9 @@ KERNEL_NAMES = {
         "program: exact rescore of one row bucket (dedisperse + score)",
     "score_rows":
         "kernel: one-pass scorer of the coarse plane's rows",
+    "tier_downsample":
+        "program: a tiered search's downsample chain, each tier's array "
+        "the previous one summed in pairs",
     "unpack_clean":
         "program: bit-unpack + clean of an uploaded packed chunk",
 }

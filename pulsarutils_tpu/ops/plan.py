@@ -30,6 +30,8 @@ Sign/rounding conventions that the S/N recovery depends on (pinned by tests):
 
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 
 #: Dispersion constant in s MHz^2 cm^3 pc^-1 (reference uses the rounded
@@ -159,6 +161,80 @@ def dedispersion_plan(nchan, dmmin, dmmax, start_freq, bandwidth, sample_time,
     trial_n = xp.arange(min_n, max_n + 1)
     trial_dm = trial_n * sample_time / DM_DELAY_CONST / (f0 ** -2.0 - f1 ** -2.0)
     return trial_dm
+
+
+@dataclasses.dataclass(frozen=True)
+class DMTier:
+    """One tier of a smearing-tiered search (:func:`dm_tier_plan`)."""
+    downsample: int        # time-rebin factor 2^k applied before the search
+    sample_time: float     # downsample x the plan's sample time
+    dm_lo: float           # DM interval this tier answers for
+    dm_hi: float
+    trial_dms: np.ndarray  # its trial grid (float64)
+
+
+def dm_tier_plan(nchan, dmmin, dmmax, start_freq, bandwidth, sample_time,
+                 foff):
+    """The tiers of a smearing-tiered search of ``dmmin..dmmax``.
+
+    Tier ``k`` works at ``2^k * sample_time`` and **ends** at the DM
+    where the intra-channel smearing at the band centre,
+    ``dm_broadening(dm, start_freq + bandwidth / 2, |foff|)``, reaches
+    that sample time: the sample time is doubled where the smearing
+    reaches one sample.  (The reference's ``plan_chunks`` rule takes the
+    bottom of the band and a tenth of the smearing, for its single
+    resampling factor; this is a departure, docs/reference_parity.md.)
+
+    The first tier is :func:`dedispersion_plan`'s grid from ``dmmin``;
+    every later tier searches the integer band delays ``n`` of its own
+    sample time above ``N_k(lower edge)``, ``DM_n`` by the plan's own
+    expression.  Every tier but the last keeps the trials whose band
+    delay is at most ``N_k(upper edge)``; the last runs to the first
+    trial at or past ``dmmax`` as :func:`dedispersion_plan` does, so a
+    plan of one tier is that function's grid to the bit.  Since
+    ``n_(k+1) = n_k / 2`` names the same DM, no DM is searched twice.
+    Tiers below ``dmmin`` are left out, so the first tier's
+    ``downsample`` need not be 1.
+
+    >>> tiers = dm_tier_plan(1024, 0.0, 1000.0, 1182.0, 400.0, 64e-6, 0.390625)
+    >>> [(t.downsample, len(t.trial_dms)) for t in tiers]
+    [(1, 1069), (2, 534), (4, 534), (8, 534), (16, 534), (32, 107)]
+    >>> round(tiers[0].dm_hi, 2)
+    52.1
+    """
+    f0 = float(start_freq)
+    f1 = f0 + float(bandwidth)
+    dmmin, dmmax = float(dmmin), float(dmmax)
+    smear = dm_broadening(1.0, f0 + float(bandwidth) / 2.0, abs(float(foff)))
+    unit = f0 ** -2.0 - f1 ** -2.0
+
+    def edge(k):  # DM at which the smearing reaches 2^k samples
+        return (2 ** k) * sample_time / smear
+
+    k = 0
+    while edge(k) <= dmmin:
+        k += 1
+    tiers = []
+    lo = dmmin
+    while True:
+        tsamp_k = (2 ** k) * sample_time
+        last = edge(k) >= dmmax
+        hi = dmmax if last else edge(k)
+        min_n = delta_delay(lo, f0, f1) / tsamp_k
+        max_n = delta_delay(hi, f0, f1) / tsamp_k
+        if tiers:
+            min_n = np.floor(min_n) + 1.0
+        # the last tier's is dedispersion_plan's own arange
+        trial_n = (np.arange(min_n, max_n + 1) if last
+                   else min_n + np.arange(np.floor(max_n - min_n) + 1))
+        trial_dm = trial_n * tsamp_k / DM_DELAY_CONST / unit
+        tiers.append(DMTier(downsample=2 ** k, sample_time=tsamp_k,
+                            dm_lo=lo, dm_hi=hi,
+                            trial_dms=np.asarray(trial_dm, np.float64)))
+        if last:
+            return tiers
+        lo = hi
+        k += 1
 
 
 def dmmax_for_trials(dmmin, n_trials, start_freq, bandwidth, sample_time):

@@ -61,6 +61,35 @@ def quick_resample(counts, factor, xp=np):
     return out[0] if squeeze else out
 
 
+def downsample_chain(counts, factors, xp=np):
+    """``counts`` rebinned along time by each of ``factors``, ascending and
+    each a multiple of the one before: every array is the previous one
+    block-summed (:func:`quick_resample` semantics, a trailing fragment is
+    truncated), so a chain of doublings sums in pairs.
+
+    Under ``jax.numpy`` a step is a strided window sum
+    (``lax.reduce_window``), not a reshape: the v5e compiler gives the
+    reshape of a 1,024 x 2^19 chunk to ``(..., 2)`` 4 GiB of relayout
+    copies, the window sum none.
+
+    >>> [a.tolist() for a in downsample_chain(np.arange(9.0), (2, 4))]
+    [[1.0, 5.0, 9.0, 13.0], [6.0, 22.0]]
+    """
+    out, have = [], 1
+    for factor in factors:
+        step = factor // have
+        if xp is np:
+            counts = quick_resample(counts, step)
+        else:
+            from jax import lax
+
+            counts = lax.reduce_window(counts, 0.0, lax.add, (1, step),
+                                       (1, step), "VALID")
+        have = factor
+        out.append(counts)
+    return out
+
+
 def stretch_resample(x, indices, xp=np):
     """Resample along the time (last) axis at precomputed sample indices.
 

@@ -43,14 +43,19 @@ class ChunkPlan:
 
 
 def plan_chunks(nsamples, sample_time, dmmin, dmmax, start_freq, stop_freq,
-                foff, chunk_length=None, new_sample_time=None, min_step=128):
+                foff, chunk_length=None, new_sample_time=None, min_step=128,
+                tile_factor=1):
     """Choose chunk size / hop / resampling from the search physics.
 
     * chunk length defaults to the band-crossing delay at ``dmmax`` and the
       chunk holds twice that, so a pulse entering at any phase of the hop
       is fully contained once (reference ``clean.py:296-301``);
     * data are resampled so the new sample time is ~1/10 of the minimum
-      intra-channel DM smearing (reference ``clean.py:304-316``).
+      intra-channel DM smearing (reference ``clean.py:304-316``);
+    * ``tile_factor`` is the largest further downsampling a tiered search
+      (:func:`~pulsarutils_tpu.ops.plan.dm_tier_plan`) applies after the
+      resampling: the chunk is rounded so that axis stays tile-divisible
+      too.
     """
     if chunk_length is None:
         chunk_length = delta_delay(dmmax, start_freq, stop_freq)
@@ -62,7 +67,7 @@ def plan_chunks(nsamples, sample_time, dmmin, dmmax, start_freq, stop_freq,
     ratio = new_sample_time / sample_time
     resample = int(np.rint(ratio)) if ratio >= 2 else 1
 
-    if step >= 1024 * resample:
+    if step >= 1024 * resample * tile_factor:
         # round the chunk up so the POST-RESAMPLE time axis is a
         # multiple of the FDMT/Pallas tile size: a non-tile-divisible
         # searched axis forces the TPU transform to zero-pad (slower,
@@ -70,7 +75,7 @@ def plan_chunks(nsamples, sample_time, dmmin, dmmax, start_freq, stop_freq,
         # breaks the circular-gather model its soundness bound
         # assumes).  A slightly larger chunk keeps the physics
         # guarantee (chunk >= 2x the band-crossing delay).
-        quantum = 1024 * resample
+        quantum = 1024 * resample * tile_factor
         step = -(-step // quantum) * quantum
     return ChunkPlan(step=step, hop=step // 2, resample=resample,
                      sample_time=resample * sample_time)

@@ -21,6 +21,7 @@ Capability-equivalent of the reference's ``search_by_chunks``
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import os
 import time
@@ -49,7 +50,7 @@ from ..obs.lineage import LineageRecorder
 from ..obs.push import AlertBroker
 from ..obs.trace import begin_span, span as trace_span
 from ..ops.clean_ops import (fft_zap_time, renormalize_data, zero_dm_filter)
-from ..ops.rebin import quick_resample
+from ..ops.rebin import downsample_chain, quick_resample
 from ..ops.search import dedispersion_search
 from ..parallel.stream import iter_chunk_starts, plan_chunks
 from ..pipeline.pulse_info import PulseInfo
@@ -63,7 +64,7 @@ from ..utils.table import ResultTable
 def _search_with_fallback(array, dmmin, dmmax, start_freq, bandwidth,
                           eff_tsamp, *, backend, kernel, capture_plane,
                           state=None, mesh=None, snr_floor=None,
-                          chunk=None, policy=None):
+                          chunk=None, policy=None, trial_dms=None):
     """One chunk's search with failure containment.
 
     The reference has no failure handling at all (SURVEY §5).  Policy:
@@ -110,6 +111,9 @@ def _search_with_fallback(array, dmmin, dmmax, start_freq, bandwidth,
     the two-stage composition is kept deliberately, so a certified
     chunk pays one coarse dispatch and no seed rescore — the same
     gating as the single-device fused path.
+
+    ``trial_dms`` is a tier's explicit trial grid (single-device routes
+    only: a tiered plan refuses a mesh before it gets here).
     """
     from ..resilience import ladder as _ladder
 
@@ -168,6 +172,7 @@ def _search_with_fallback(array, dmmin, dmmax, start_freq, bandwidth,
         return dedispersion_search(
             array, dmmin, dmmax, start_freq, bandwidth, eff_tsamp,
             backend=b, kernel=k, capture_plane=capture_plane,
+            **({"trial_dms": trial_dms} if trial_dms is not None else {}),
             **({"snr_floor": snr_floor} if k == "hybrid" else {}))
 
     i = 0
@@ -243,6 +248,68 @@ def _search_with_fallback(array, dmmin, dmmax, start_freq, bandwidth,
     raise last
 
 
+def _clean_block(block, m, xp, cut_outliers, zero_dm, fft_zap, resample):
+    """The conditioning of one chunk, parameterised by array namespace:
+    the device (jitted) and host (fallback) paths call this one
+    function."""
+    cleaned = renormalize_data(block, badchans_mask=m,
+                               cut_outliers=cut_outliers, xp=xp)
+    if zero_dm:
+        cleaned = zero_dm_filter(cleaned, badchans_mask=m, xp=xp)
+    if fft_zap:
+        cleaned, _ = fft_zap_time(cleaned, xp=xp)
+    if resample > 1:
+        cleaned = quick_resample(cleaned, resample, xp=xp)
+    return cleaned
+
+
+@functools.lru_cache(maxsize=16)
+def _device_clean_program(unpack, donate, clean_options):
+    """The jitted device clean, kept across calls (ROADMAP S4): a
+    ``jax.jit`` built inside ``search_by_chunks`` was a new function to
+    JAX in every call, re-traced and its executable read back from the
+    persistent cache on every call's first chunk.  It closes over what
+    keys it here and nothing else: the clean's options and, for a packed
+    low-bit file, ``unpack = (device_unpack_block, nbits, nchans,
+    band_descending)``.  The function's name is the program's in a device
+    trace (``jit_unpack_clean`` / ``jit_clean``; obs/names.py
+    KERNEL_NAMES)."""
+    import jax
+    import jax.numpy as jnp
+
+    if unpack is not None:
+        unpack_block, nbits, nchan_file, descending = unpack
+
+        def unpack_clean(raw, m):
+            return _clean_block(
+                unpack_block(raw, nbits, nchan_file,
+                             band_descending=descending, xp=jnp),
+                m, jnp, *clean_options)
+
+        return jax.jit(unpack_clean, donate_argnums=donate)
+
+    def clean(block, m):
+        return _clean_block(block, m, jnp, *clean_options)
+
+    return jax.jit(clean, donate_argnums=donate)
+
+
+@functools.lru_cache(maxsize=8)
+def _tier_downsample_program(chain_factors):
+    """The jitted downsample chain of a tiered search: each array the one
+    before summed in pairs.  ``jit_tier_downsample`` in a device trace
+    (``obs/names.py`` KERNEL_NAMES).  Kept across calls: it closes over
+    the factors alone, so a second call in one process neither re-traces
+    it nor reads its executable back from the persistent cache."""
+    import jax
+    import jax.numpy as jnp
+
+    def tier_downsample(cleaned):
+        return tuple(downsample_chain(cleaned, chain_factors, xp=jnp))
+
+    return jax.jit(tier_downsample)
+
+
 def _clean_on_host(what, exc):
     """Record that the device ``what`` (upload / clean) failed at run
     time and cleaning moves to the host for the rest of the run; returns
@@ -271,9 +338,18 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 cut_outliers=False, zero_dm=False, mesh=None,
                 exact_floor="auto", quarantine_policy="sanitize",
                 period_search=False, period_sigma_threshold=8.0,
-                fingerprint_extra=None):
+                fingerprint_extra=None, dm_tiers=None):
     """Resolve a survey's geometry, threshold and resume fingerprint
     WITHOUT searching anything.
+
+    ``dm_tiers="smearing"`` plans the DM range in tiers
+    (:func:`~pulsarutils_tpu.ops.plan.dm_tier_plan`): the returned dict's
+    ``tiers`` is then a list with, per tier, its
+    :class:`~pulsarutils_tpu.ops.plan.DMTier` (``tier``), its own resolved
+    ``snr_threshold`` and its own ``search_snr_floor``.  A plan that
+    resolves to one tier at the plan's own sample time is today's flat
+    plan: ``tiers`` is ``None`` and threshold, chunk grid and fingerprint
+    are those of ``dm_tiers=None``, so no existing ledger is orphaned.
 
     ``fingerprint_extra`` (a flat JSON-safe dict) is folded into the
     resume-ledger fingerprint when non-empty — the workload seam
@@ -314,77 +390,116 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
     bandwidth = header["bandwidth"]
     foff = header["foff"]
 
+    if dm_tiers not in (None, "smearing"):
+        raise ValueError(f"dm_tiers={dm_tiers!r}: expected None or "
+                         "'smearing'")
     plan = plan_chunks(nsamples, sample_time, dmmin, dmmax, start_freq,
                        stop_freq, foff, chunk_length=chunk_length,
                        new_sample_time=new_sample_time)
+    dm_plan = None
+    if dm_tiers is not None:
+        from ..ops.plan import dm_tier_plan
+
+        dm_plan = dm_tier_plan(header["nchans"], dmmin, dmmax, start_freq,
+                               bandwidth, plan.sample_time, foff)
+        if len(dm_plan) == 1 and dm_plan[0].downsample == 1:
+            dm_plan = None  # the flat plan, to the bit
+        else:
+            # every tier's time axis stays tile-divisible
+            plan = plan_chunks(nsamples, sample_time, dmmin, dmmax,
+                               start_freq, stop_freq, foff,
+                               chunk_length=chunk_length,
+                               new_sample_time=new_sample_time,
+                               tile_factor=dm_plan[-1].downsample)
     eff_tsamp = plan.sample_time
     logger.info("chunk plan: step=%d hop=%d resample=%d -> tsamp=%g s",
                 plan.step, plan.hop, plan.resample, eff_tsamp)
 
-    def _chunk_cert_floor():
-        """Certifiable floor for this chunk geometry (lazy: the
-        retention bound is a multi-second host computation at
-        multi-thousand-trial configs and only two configurations need
-        it — snr_threshold='certifiable', and the hybrid's
-        exact_floor='auto' comparison)."""
-        from ..ops.certify import certifiable_snr_floor, retention_bound
+    def _resolve(threshold, tsamp, t_eff, grid):
+        """``(snr_threshold, search_snr_floor)`` of one searched geometry:
+        ``t_eff`` samples of ``tsamp`` over the trial DMs ``grid()``."""
+        from ..ops.certify import (certifiable_snr_floor, matched_snr_floor,
+                                   retention_bound)
+
+        def cert_floor():
+            """Certifiable floor for this geometry (lazy: the retention
+            bound is a multi-second host computation at multi-thousand-
+            trial configs and only two configurations need it —
+            snr_threshold='certifiable', and the hybrid's
+            exact_floor='auto' comparison)."""
+            trial_dms = grid()
+            rho = retention_bound(header["nchans"], trial_dms, start_freq,
+                                  bandwidth, tsamp, t_eff, cert=True)
+            return certifiable_snr_floor(t_eff, len(trial_dms), rho)
+
+        if isinstance(threshold, str):
+            if threshold == "auto":
+                # clamped to the reference default (clean.py:349): at short
+                # chunks the matched floor resolves BELOW 6 and "auto" must
+                # never be more permissive than the reference's criterion
+                # (the Gumbel fit is also least validated at small m —
+                # certify.expected_noise_max_snr's stated fit domain)
+                threshold = max(matched_snr_floor(t_eff, len(grid())), 6.0)
+            elif threshold == "certifiable":
+                threshold = cert_floor()
+            else:
+                raise ValueError(
+                    f"snr_threshold={threshold!r}: expected a number, "
+                    "'auto' or 'certifiable'")
+            threshold = round(float(threshold), 2)
+            logger.info("snr_threshold resolved to %.2f for %d-sample "
+                        "chunks", threshold, t_eff)
+
+        # the hybrid gets the threshold as its snr_floor ONLY when the
+        # noise certificate can actually fire at that level: forwarding a
+        # sub-certifiable floor (e.g. the reference default 6.0 on
+        # million-sample chunks) would make the rigorous all-detections-
+        # exact criterion rescan toward a full exact sweep on EVERY chunk —
+        # the round-2 behaviour this round removed.  Below the certifiable
+        # level the hybrid runs floorless (exact-argbest-only contract, the
+        # round-2 streaming semantics), which is both faster and what the
+        # fixed thresholds historically meant.
+        floor = None
+        if kernel == "hybrid" and exact_floor is not False:
+            cert = None if exact_floor is True else cert_floor()
+            if exact_floor is True or threshold >= round(cert, 2) - 1e-9:
+                floor = threshold
+            else:
+                logger.info(
+                    "snr_threshold %.2f sits below the certifiable floor "
+                    "%.2f for this chunk geometry: hybrid runs without "
+                    "snr_floor (exact best row only; pass exact_floor=True "
+                    "to force the all-detections-exact contract, or "
+                    "snr_threshold='certifiable' for the noise-certificate "
+                    "fast path)", threshold, cert)
+        return threshold, floor
+
+    def _flat_grid():
         from ..ops.plan import dedispersion_plan
 
-        nchan = header["nchans"]
-        t_eff = max(plan.step // plan.resample, 2)
-        trial_dms = dedispersion_plan(nchan, dmmin, dmmax, start_freq,
-                                      bandwidth, eff_tsamp)
-        rho = retention_bound(nchan, trial_dms, start_freq, bandwidth,
-                              eff_tsamp, t_eff, cert=True)
-        return certifiable_snr_floor(t_eff, len(trial_dms), rho)
+        return dedispersion_plan(header["nchans"], dmmin, dmmax, start_freq,
+                                 bandwidth, eff_tsamp)
 
-    if isinstance(snr_threshold, str):
-        from ..ops.certify import matched_snr_floor
-        from ..ops.plan import dedispersion_plan
-
-        t_eff = max(plan.step // plan.resample, 2)
-        if snr_threshold == "auto":
-            ndm = len(dedispersion_plan(header["nchans"], dmmin, dmmax,
-                                        start_freq, bandwidth, eff_tsamp))
-            # clamped to the reference default (clean.py:349): at short
-            # chunks the matched floor resolves BELOW 6 and "auto" must
-            # never be more permissive than the reference's criterion
-            # (the Gumbel fit is also least validated at small m —
-            # certify.expected_noise_max_snr's stated fit domain)
-            snr_threshold = max(matched_snr_floor(t_eff, ndm), 6.0)
-        elif snr_threshold == "certifiable":
-            snr_threshold = _chunk_cert_floor()
-        else:
-            raise ValueError(
-                f"snr_threshold={snr_threshold!r}: expected a number, "
-                "'auto' or 'certifiable'")
-        snr_threshold = round(float(snr_threshold), 2)
-        logger.info("snr_threshold resolved to %.2f for %d-sample chunks",
-                    snr_threshold, t_eff)
-
-    # the hybrid gets the threshold as its snr_floor ONLY when the noise
-    # certificate can actually fire at that level: forwarding a
-    # sub-certifiable floor (e.g. the reference default 6.0 on
-    # million-sample chunks) would make the rigorous all-detections-exact
-    # criterion rescan toward a full exact sweep on EVERY chunk — the
-    # round-2 behaviour this round removed.  Below the certifiable level
-    # the hybrid runs floorless (exact-argbest-only contract, the round-2
-    # streaming semantics), which is both faster and what the fixed
-    # thresholds historically meant.
-    search_snr_floor = None
-    if kernel == "hybrid" and exact_floor is not False:
-        cert_floor = None if exact_floor is True else _chunk_cert_floor()
-        if exact_floor is True \
-                or snr_threshold >= round(cert_floor, 2) - 1e-9:
-            search_snr_floor = snr_threshold
-        else:
-            logger.info(
-                "snr_threshold %.2f sits below the certifiable floor "
-                "%.2f for this chunk geometry: hybrid runs without "
-                "snr_floor (exact best row only; pass exact_floor=True "
-                "to force the all-detections-exact contract, or "
-                "snr_threshold='certifiable' for the noise-certificate "
-                "fast path)", snr_threshold, cert_floor)
+    t_flat = max(plan.step // plan.resample, 2)
+    tiers = None
+    if dm_plan is None:
+        snr_threshold, search_snr_floor = _resolve(
+            snr_threshold, eff_tsamp, t_flat, _flat_grid)
+    else:
+        tiers = []
+        for tier in dm_plan:
+            logger.info("DM tier x%d: DM %.2f-%.2f, %d trials at %g s",
+                        tier.downsample, tier.dm_lo, tier.dm_hi,
+                        len(tier.trial_dms), tier.sample_time)
+            thr, floor = _resolve(
+                snr_threshold, tier.sample_time,
+                max(t_flat // tier.downsample, 2),
+                lambda tier=tier: tier.trial_dms)
+            tiers.append({"tier": tier, "snr_threshold": thr,
+                          "search_snr_floor": floor})
+        # what the run reports as "the" threshold is the first tier's
+        snr_threshold = tiers[0]["snr_threshold"]
+        search_snr_floor = tiers[0]["search_snr_floor"]
 
     fingerprint = config_fingerprint(
         fname=os.path.abspath(str(fname)), dmmin=dmmin, dmmax=dmmax,
@@ -407,6 +522,11 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
         surelybad=sorted(int(c) for c in surelybad),
         period_search=bool(period_search),
         period_sigma_threshold=float(period_sigma_threshold),
+        # a tiered plan searches another grid against other thresholds;
+        # the flat plan (dm_tiers off, or one tier) keeps its fingerprint
+        **({"dm_tiers": [[t["tier"].downsample, len(t["tier"].trial_dms),
+                          t["snr_threshold"]] for t in tiers]}
+           if tiers else {}),
         # workload-distinct ledgers (ISSUE 13): merged LAST so a
         # collision with a driver field fails loudly in review, and
         # absent entirely when unset — every pre-existing ledger
@@ -418,6 +538,7 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
         "nsamples": nsamples, "sample_time": sample_time,
         "snr_threshold": snr_threshold,
         "search_snr_floor": search_snr_floor,
+        "tiers": tiers,
         "fingerprint": fingerprint,
         "chunk_starts": list(iter_chunk_starts(nsamples, plan, tmin=tmin,
                                                sample_time=sample_time)),
@@ -439,7 +560,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                      health=None, report_out=None, chunks=None,
                      cancel_cb=None, plane_consumer=None,
                      fingerprint_extra=None, fence=None, lineage=None,
-                     push=None):
+                     push=None, dm_tiers=None):
     """Search a filterbank file for dispersed single pulses.
 
     Parameters follow the reference driver (``clean.py:276``) plus the
@@ -657,6 +778,21 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
       fill the queue (drop-oldest, counted), never stall this loop.
       Canary-tagged rows are excluded before the publish site.
 
+    ``dm_tiers="smearing"`` (default off) searches the DM range in tiers
+    (:func:`~pulsarutils_tpu.ops.plan.dm_tier_plan`): the chunk is cleaned
+    once at the plan's sample time, summed in pairs tier by tier on the
+    device (``jit_tier_downsample``), and every tier goes through the
+    same search call as the flat path with its own array, trial grid,
+    sample time and threshold.  A chunk's table is the tiers' rows
+    concatenated in tier order, with an integer ``downsample`` column;
+    ``peak`` and ``rebin`` stay in the samples of the row's own tier.  A
+    row is a detection against its own tier's threshold, the chunk's hit
+    is the best such row, and its :class:`~.pulse_info.PulseInfo` is
+    built from that tier's array.  A plan of one tier at the plan's own
+    sample time is the flat path: same bytes, same fingerprint.  A mesh,
+    the canary, the period search and a ``plane_consumer`` are refused
+    with it (``ValueError``).
+
     Returns ``(hits, store)`` where hits is a list of
     ``(istart, iend, PulseInfo, ResultTable)``.  NOTE (round 6): when
     plotting is off, a hit's retained/persisted ``info.allprofs`` is the
@@ -672,6 +808,16 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             and exact_floor != "auto":
         raise ValueError(f"exact_floor={exact_floor!r}: expected True, "
                          "False or 'auto'")
+    if dm_tiers is not None:
+        # S9's four-chip tier is its own PR; the other three read one
+        # plane or one time axis per chunk
+        refused = [name for name, on in (
+            ("mesh", mesh is not None), ("canary", bool(canary)),
+            ("period_search", period_search),
+            ("plane_consumer", plane_consumer is not None)) if on]
+        if refused:
+            raise ValueError(f"dm_tiers={dm_tiers!r} does not run with "
+                             + ", ".join(refused))
     if mesh is not None:
         # fail fast: a missing axis would otherwise surface as a KeyError
         # inside the first chunk's search, which the failure-containment
@@ -747,7 +893,8 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                          quarantine_policy=quarantine_policy,
                          period_search=period_search,
                          period_sigma_threshold=period_sigma_threshold,
-                         fingerprint_extra=fingerprint_extra)
+                         fingerprint_extra=fingerprint_extra,
+                         dm_tiers=dm_tiers)
         reader = sp["reader"]
         root = sp["root"]
         header = reader.header
@@ -766,6 +913,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         eff_tsamp = plan.sample_time
         snr_threshold = sp["snr_threshold"]
         search_snr_floor = sp["search_snr_floor"]
+        tiers = sp["tiers"]  # None: the flat plan
         fingerprint = sp["fingerprint"]
         # fence (ISSUE 15): the fleet worker's lease epoch — candidate
         # artifact writes stamped with a higher epoch are refused (see
@@ -808,16 +956,11 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
 
     # one conditioning pipeline, parameterised by array namespace — the
     # device (jitted) and host (fallback) paths must never diverge
-    def _clean(block, m, xp=np):
-        cleaned = renormalize_data(block, badchans_mask=m,
-                                   cut_outliers=cut_outliers, xp=xp)
-        if zero_dm:
-            cleaned = zero_dm_filter(cleaned, badchans_mask=m, xp=xp)
-        if fft_zap:
-            cleaned, _ = fft_zap_time(cleaned, xp=xp)
-        if plan.resample > 1:
-            cleaned = quick_resample(cleaned, plan.resample, xp=xp)
-        return cleaned
+    clean_options = (bool(cut_outliers), bool(zero_dm), bool(fft_zap),
+                     int(plan.resample))
+
+    def _clean(block, m):
+        return _clean_block(block, m, np, *clean_options)
 
     # device-side cleaning: with backend="jax" the chunk is uploaded raw
     # and conditioned on the accelerator (one jitted program reused for
@@ -841,6 +984,12 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                     dmmin=dmmin, dmmax=dmmax,
                     resample=plan.resample)
     device_clean = None
+    device_downsample = None
+    if tiers:
+        # tier k's array is tier k-1's summed in pairs; a first tier at the
+        # plan's own sample time searches the cleaned chunk itself
+        chain_factors = tuple(t["tier"].downsample for t in tiers
+                              if t["tier"].downsample > 1)
     if backend == "jax":
         with with_timer("call/device_setup"):
             import jax
@@ -855,25 +1004,16 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             # stream.  CPU ignores donation with a per-call warning, so the
             # flag is backend-gated rather than unconditional.
             donate = ((0,) if jax.default_backend() in ("tpu", "gpu") else ())
+            unpack = None
             if packed_bits:
                 from ..io.lowbit import device_unpack_block
 
-                nchan_file = header["nchans"]
-                descending = reader.band_descending
-
-                # the function's name is the program's in a device trace
-                # (``jit_unpack_clean``; obs/names.py KERNEL_NAMES)
-                def unpack_clean(raw, m):
-                    return _clean(device_unpack_block(
-                        raw, packed_bits, nchan_file,
-                        band_descending=descending, xp=jnp), m, xp=jnp)
-
-                device_clean = jax.jit(unpack_clean, donate_argnums=donate)
-            else:
-                def clean(block, m):
-                    return _clean(block, m, xp=jnp)
-
-                device_clean = jax.jit(clean, donate_argnums=donate)
+                unpack = (device_unpack_block, packed_bits,
+                          header["nchans"], bool(reader.band_descending))
+            device_clean = _device_clean_program(unpack, donate,
+                                                 clean_options)
+            if tiers:
+                device_downsample = _tier_downsample_program(chain_factors)
             if timer.rtt_s is None:  # keep a caller-calibrated RTT
                 timer.rtt_s = measure_device_rtt()
             if timer.rtt_s is not None:
@@ -1256,6 +1396,100 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         ck["t_end"] = timer.last_chunk_end
         _stamp_on_disk(ck)
 
+    def _pulse_info(arr, tsamp, istart_, t0_):
+        return PulseInfo(
+            allprofs=arr, start_freq=start_freq, bandwidth=bandwidth,
+            nbin=arr.shape[1], nchan=arr.shape[0], date=date, t0=t0_,
+            istart=istart_, pulse_freq=1.0 / (arr.shape[1] * tsamp),
+            # beam provenance from the sigproc header (ISSUE 8):
+            # None on single-beam files, so their persisted bytes
+            # are unchanged — beam-labelled files carry it into the
+            # candidate record for the cross-beam coincidence sift
+            ibeam=reader.ibeam, nbeams=reader.nbeams)
+
+    def _search_tiers(cleaned, istart_, rec):
+        """The tiered search of one cleaned chunk: downsample chain, then
+        every tier through the flat path's own search call.
+
+        Returns ``(table, top)``: the tiers' rows concatenated in tier
+        order with their ``downsample`` column (``meta["certified"]``
+        when every tier certified), and the tier that holds the chunk's
+        best detection, or with no detection its best row: its index
+        ``k``, its own ``table`` and ``plane``, its ``array`` (the only
+        tier array still referenced), ``best``, ``row`` (the best row's
+        index in the concatenated table), ``detection`` and ``n_above``
+        (rows of all tiers above their own tier's threshold)."""
+        with with_timer("search/tier_downsample"):
+            if isinstance(cleaned, np.ndarray):
+                rest = downsample_chain(cleaned, chain_factors)
+            else:
+                import jax as _jax
+
+                rest = device_downsample(cleaned)
+                timer.count("dispatches")
+                _jax.block_until_ready(rest)
+                timer.count("readbacks")
+        arrays = [cleaned] * (len(tiers) - len(chain_factors)) + list(rest)
+        del rest  # a tier's array goes once searched, unless it is on top
+        tables, top, n_above, nrows = [], None, 0, 0
+        rec["tiers"] = []
+
+        def coarse_s():  # the chunk's coarse-sweep seconds so far
+            return sum(rec["buckets"].get(key, 0.0) for key in
+                       ("search/coarse", "search/coarse_readback"))
+
+        for k, t in enumerate(tiers):
+            tier = t["tier"]
+            arr, arrays[k] = arrays[k], None
+            coarse0 = coarse_s()
+            with trace_span("search/tier", tier=k,
+                            downsample=tier.downsample,
+                            trials=len(tier.trial_dms),
+                            certified=False) as tspan:
+                result = _search_with_fallback(
+                    arr, tier.dm_lo, tier.dm_hi, start_freq, bandwidth,
+                    tier.sample_time, backend=backend, kernel=kernel,
+                    capture_plane=capture, state=fallback_state,
+                    snr_floor=t["search_snr_floor"], chunk=istart_,
+                    policy=dispatch_policy, trial_dms=tier.trial_dms)
+                ttable, tplane = result if capture else (result, None)
+                certified = bool(ttable.meta.get("certified"))
+                tspan.attrs["certified"] = certified
+            obs_metrics.counter("putpu_tier_sweeps_total").inc()
+            if certified:
+                obs_metrics.counter("putpu_tier_certified_total").inc()
+            rec["tiers"].append({
+                "downsample": tier.downsample, "trials": ttable.nrows,
+                "coarse_s": round(coarse_s() - coarse0, 4),
+                "certified": certified})
+            snr = np.asarray(ttable["snr"], dtype=np.float64)
+            n_above += int(np.count_nonzero(snr > t["snr_threshold"]))
+            best = ttable.best_row()
+            detection = bool(best["snr"] > t["snr_threshold"])
+            # a detection outranks any row that is none; ties go to the
+            # earlier tier
+            if top is None or ((detection, float(best["snr"]))
+                               > (top["detection"],
+                                  float(top["best"]["snr"]))):
+                top = {"k": k, "table": ttable, "plane": tplane,
+                       "array": arr, "best": best, "detection": detection,
+                       "row": nrows + ttable.argbest()}
+            nrows += ttable.nrows
+            tables.append(ttable)
+        names = [n for n in tables[0].colnames
+                 if all(n in tt.colnames for tt in tables)]
+        cols = {n: np.concatenate([np.asarray(tt[n]) for tt in tables])
+                for n in names}
+        cols["downsample"] = np.concatenate(
+            [np.full(tt.nrows, t["tier"].downsample, dtype=np.int32)
+             for tt, t in zip(tables, tiers)])
+        meta = dict(tables[0].meta)
+        meta["certified"] = all(r["certified"] for r in rec["tiers"])
+        top["best"] = dict(top["best"],
+                           downsample=tiers[top["k"]]["tier"].downsample)
+        top["n_above"] = n_above
+        return ResultTable(cols, meta=meta), top
+
     reader_pool = ThreadPoolExecutor(max_workers=1)
     next_read = submit_read(todo[0]) if todo else None
     array_dev = None  # chunk's prefetched device buffer (if any)
@@ -1397,17 +1631,6 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                             host_raw, band_ascending=True)
                     array = _clean(host_raw, mask)
 
-            info = PulseInfo(
-                allprofs=array, start_freq=start_freq, bandwidth=bandwidth,
-                nbin=array.shape[1], nchan=array.shape[0], date=date, t0=t0,
-                istart=istart,
-                pulse_freq=1.0 / (array.shape[1] * eff_tsamp),
-                # beam provenance from the sigproc header (ISSUE 8):
-                # None on single-beam files, so their persisted bytes
-                # are unchanged — beam-labelled files carry it into the
-                # candidate record for the cross-beam coincidence sift
-                ibeam=reader.ibeam, nbeams=reader.nbeams)
-
             # overlap: start chunk k+1's async upload before chunk k's
             # blocking search (see prefetch_upload)
             array_dev = prefetch_upload(next_read)
@@ -1416,12 +1639,23 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 lineage.mark(istart, "dispatch")
             try:
                 with with_timer("search"):
-                    result = _search_with_fallback(
-                        array, dmmin, dmmax, start_freq, bandwidth,
-                        eff_tsamp, backend=backend, kernel=kernel,
-                        capture_plane=capture, state=fallback_state,
-                        mesh=mesh, snr_floor=search_snr_floor,
-                        chunk=istart, policy=dispatch_policy)
+                    if tiers:
+                        table, top = _search_tiers(array, istart,
+                                                   ck["rec"])
+                        # from here on the chunk is its top tier's: the
+                        # hit's record is built from that tier's array
+                        array = top["array"]
+                        hit_tsamp = tiers[top["k"]]["tier"].sample_time
+                        result = ((table, top["plane"]) if capture
+                                  else table)
+                    else:
+                        top, hit_tsamp = None, eff_tsamp
+                        result = _search_with_fallback(
+                            array, dmmin, dmmax, start_freq, bandwidth,
+                            eff_tsamp, backend=backend, kernel=kernel,
+                            capture_plane=capture, state=fallback_state,
+                            mesh=mesh, snr_floor=search_snr_floor,
+                            chunk=istart, policy=dispatch_policy)
             except _resilience_ladder.OOMFloorError as exc:
                 # the degradation ladder's floor itself OOMed: this
                 # chunk cannot be searched on this host at ANY geometry
@@ -1451,6 +1685,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                                quarantined=True, oom_floor=True)
                 continue
             table, plane = result if capture else (result, None)
+            info = _pulse_info(array, hit_tsamp, istart, t0)
             if lineage is not None:
                 # device ready/readback: the search result is host-
                 # visible from here on
@@ -1477,9 +1712,10 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 # candidate RATE (table rows above threshold), not the
                 # 0/1 hit decision: the engine's RFI-storm detector
                 # needs the many-DM-trials-at-once signature
-                ncand_above = int(np.count_nonzero(
-                    np.asarray(table["snr"], dtype=np.float64)
-                    > float(snr_threshold)))
+                ncand_above = (top["n_above"] if top else int(
+                    np.count_nonzero(
+                        np.asarray(table["snr"], dtype=np.float64)
+                        > float(snr_threshold))))
                 if canary_obs is not None:
                     # rows the injection lit must not feed the storm
                     # detector: an injected chunk's canary sidelobes
@@ -1496,6 +1732,29 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             # promoted in its place.
             sci_table = table
             best_plane_idx = None
+            plot_table = table
+            if top:
+                # a row is a detection against its own tier's threshold
+                # and the hit is the best such row; the plane and the
+                # figure's table are its tier's own
+                best, is_hit = top["best"], top["detection"]
+                best_plane_idx = top["table"].argbest()
+                plot_table = top["table"]
+                if is_hit and table.argbest() != top["row"]:
+                    # rows of other tiers that score higher without
+                    # reaching their own tier's threshold: consumers take
+                    # a table's best row for the hit (sift, cutout), so
+                    # they leave the persisted table
+                    keep = np.asarray(table["snr"]) < best["snr"]
+                    keep[top["row"]] = True
+                    sci_table = ResultTable(
+                        {name: table[name][keep]
+                         for name in table.colnames}, meta=table.meta)
+                    logger.info(
+                        "chunk %d-%d: %d row(s) above the hit's S/N but "
+                        "under their own tier's threshold dropped from "
+                        "the persisted table", istart, iend,
+                        int(np.count_nonzero(~keep)))
             if is_hit and canary_obs is not None \
                     and canary_obs["best_is_canary"]:
                 # the chunk's best row IS this chunk's injected canary
@@ -1610,7 +1869,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             if is_hit:
                 info.dm = float(best["DM"])
                 info.snr = float(best["snr"])
-                info.width = float(best["rebin"]) * eff_tsamp
+                info.width = float(best["rebin"]) * hit_tsamp
                 with with_timer("hit_products"):
                     # readback counters only for DEVICE sources: after a
                     # fallback to the numpy backend these are host
@@ -1684,7 +1943,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 # diagnostics, not a candidate artifact)
                 with with_timer("plot"):
                     plot_diagnostics(
-                        info, table, plane,
+                        info, plot_table, plane,
                         outname=os.path.join(output_dir,
                                              f"{root}_{istart}-{iend}.jpg"),
                         t0=t0, show=show_plots)

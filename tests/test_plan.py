@@ -13,6 +13,7 @@ from pulsarutils_tpu.ops.plan import (
     dedispersion_shifts_batch,
     delta_delay,
     dm_broadening,
+    dm_tier_plan,
     normalize_shifts,
     plan_size,
 )
@@ -106,3 +107,39 @@ def test_batched_shifts_jax_offsets_close_to_numpy():
     diff = np.minimum(diff, 1024 - diff)
     assert diff.max() <= 1
     assert (diff == 0).mean() > 0.95
+
+
+# -- smearing-tiered plan (ISSUE 28) ----------------------------------------
+
+def test_tier_plan_doctest():
+    import doctest
+
+    from pulsarutils_tpu.ops import plan
+
+    assert doctest.testmod(plan).failed == 0
+
+
+@pytest.mark.parametrize("k,edge", [(0, 52.10), (1, 104.21), (2, 208.41),
+                                    (3, 416.83), (4, 833.65)])
+def test_tier_edges_are_where_the_smearing_reaches_a_sample(k, edge):
+    """HTRU/BPSR by hand: 8300 x 0.390625 MHz / (1382 MHz)^3 = 1.22833 us
+    of smearing per DM unit; tier k ends where that is 2^k x 64 us."""
+    tiers = dm_tier_plan(1024, 0.0, 1000.0, 1182.0, 400.0, 64e-6, -0.390625)
+    assert tiers[k].dm_hi == pytest.approx(edge, abs=5e-3)
+    assert dm_broadening(tiers[k].dm_hi, 1382.0, 0.390625) == pytest.approx(
+        2 ** k * 64e-6)
+    assert tiers[k + 1].dm_lo == tiers[k].dm_hi
+    # the same DM is band delay n in tier k and n / 2 in tier k + 1
+    n = delta_delay(tiers[k].dm_hi, 1182.0, 1582.0) / tiers[k].sample_time
+    assert len(tiers[k].trial_dms) == (int(n) + 1 if k == 0
+                                       else int(n) - int(n / 2))
+
+
+def test_a_tiered_range_from_above_the_first_edge_starts_downsampled():
+    tiers = dm_tier_plan(1024, 60.0, 300.0, 1182.0, 400.0, 64e-6, -0.390625)
+    assert [t.downsample for t in tiers] == [2, 4, 8]
+    # the first tier is the flat grid's spelling at its own sample time
+    flat = dedispersion_plan(1024, 60.0, 300.0, 1182.0, 400.0, 128e-6)
+    n = len(tiers[0].trial_dms)
+    assert np.array_equal(tiers[0].trial_dms, flat[:n])
+    assert tiers[-1].trial_dms[-1] >= 300.0 > tiers[-1].trial_dms[-2]
