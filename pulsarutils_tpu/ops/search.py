@@ -41,6 +41,7 @@ from .plan import (
 )
 from .rebin import block_sum_time
 from ..obs import roofline
+from ..tuning.geometry import PLAN_CACHE_SIZE, counted_plan_cache
 from ..utils.logging_utils import budget_bucket, budget_count
 from ..utils.table import ResultTable
 
@@ -250,6 +251,48 @@ def _offsets_for(trial_dms, nchan, start_freq, bandwidth, sample_time, nsamples)
         np.asarray(trial_dms, dtype=np.float64), nchan, start_freq, bandwidth,
         sample_time)
     return normalize_shifts(shifts, nsamples)
+
+
+@counted_plan_cache("hybrid_offsets", maxsize=PLAN_CACHE_SIZE)
+def _hybrid_offsets_by_key(grid_bytes, nchan, start_freq, bandwidth,
+                           sample_time, nsamples):
+    from .pallas_dedisperse import rebase_offsets
+
+    # a miss is the one place this host time can come back: it has a
+    # name in BUDGET_JSON and in the span tree
+    with budget_bucket("search/offsets"):
+        offsets = _offsets_for(np.frombuffer(grid_bytes, dtype=np.float64),
+                               nchan, start_freq, bandwidth, sample_time,
+                               nsamples)
+        # ONE rebase over the full table: every subset then shares the
+        # same static max_off (one compiled program per bucket) and the
+        # same host-side peak correction constant
+        rebased, roll_k, max_off = rebase_offsets(offsets, nsamples)
+    rebased.setflags(write=False)  # shared cache object: fail loudly
+    return rebased, roll_k, max_off
+
+
+def _hybrid_offsets(trial_dms, nchan, start_freq, bandwidth, sample_time,
+                    nsamples):
+    """The exact kernels' rebased offset table for one (trial grid,
+    geometry): ``rebase_offsets(_offsets_for(...))``, built when a
+    rescore first asks and kept by what it is a function of.
+
+    The float64 ``(ndm, nchan)`` delay table depends on the plan, never
+    on the chunk; rebuilding it in every search call cost 45 ms a chunk
+    at 1,067 trials and 145 ms over a tiered chunk's six grids, on the
+    host, before the coarse sweep was dispatched.  The key holds the
+    grid's own bytes (8.5 KB at 1,067 trials), not its end points: a
+    tier's grid is not ``dedispersion_plan``'s.  Size and hit/miss
+    counters (``cache="hybrid_offsets"``) come from
+    :mod:`..tuning.geometry`.  Returns ``(rebased, roll_k, max_off)``;
+    ``rebased`` is the shared cache object, read-only — callers index
+    (``rebased[rows]`` copies), never mutate.
+    """
+    grid = np.ascontiguousarray(trial_dms, dtype=np.float64)
+    return _hybrid_offsets_by_key(grid.tobytes(), int(nchan),
+                                  float(start_freq), float(bandwidth),
+                                  float(sample_time), int(nsamples))
 
 
 def block_offsets(offsets, dm_block):
@@ -1358,16 +1401,14 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
     if use_fused:
         import jax.numpy as jnp
 
-        from .pallas_dedisperse import rebase_offsets
-
-        offsets_full = _offsets_for(trial_dms, nchan, start_freq, bandwidth,
-                                    sample_time, nsamples)
-        # ONE rebase over the full table: every subset then shares the
-        # same static max_off (one compiled program per bucket) and the
-        # same host-side peak correction constant
-        rebased_full, roll_k, max_off = rebase_offsets(offsets_full,
-                                                       nsamples)
         data32 = jnp.asarray(data, jnp.float32)
+
+    def offsets_table():
+        """``(rebased_full, roll_k, max_off)`` of the exact kernels,
+        asked for where a rescore needs it: a call the certificate ends
+        builds nothing and looks nothing up (:func:`_hybrid_offsets`)."""
+        return _hybrid_offsets(trial_dms, nchan, start_freq, bandwidth,
+                               sample_time, nsamples)
 
     # nearest coarse (integer band-delay) row for each plan row —
     # host-computable before any device work
@@ -1425,6 +1466,7 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
         # program after toggling PUTPU_FDMT_HEAD in-process)
         from .fdmt import _deep_pair_enabled, _score_kernel_choice
 
+        rebased_full, roll_k, max_off = offsets_table()
         kernel = _fused_hybrid_seed_kernel(
             nchan, float(start_freq), float(bandwidth), n_hi, nsamples,
             t_tile, n_lo, None, max_off, ndm, bucket,
@@ -1513,6 +1555,8 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
         dispatch/readback time; here only the call/row counters)."""
         budget_count("rescore_calls")
         budget_count("rescore_rows", len(rows))
+        if use_fused:
+            rebased_full, roll_k, max_off = offsets_table()
         for blk, padded in iter_rescore_buckets(rows):
             if use_fused:
                 run = _fused_rescore_kernel(max_off, len(padded))
