@@ -7,9 +7,9 @@ Device code is float32/bfloat16/integer by design: ``jax_enable_x64``
 stays off, accumulation dtypes are chosen per kernel (PR 4's review
 explicitly removed full-size float64 temporaries), and a double-
 precision array sneaking into a jitted program silently doubles HBM
-traffic — the roofline table (PR 3) shows the hot kernels are memory
-bound, so a float64 leak is a straight ~2x slowdown where it hurts
-most.  Host-side float64 (offset planning, reference-semantics numpy
+traffic — the hot kernels are memory bound (the benchmark's
+``fdmt_roofline`` reads them against the HBM roof), so a float64 leak
+is a straight ~2x slowdown where it hurts most.  Host-side float64 (offset planning, reference-semantics numpy
 paths, threshold math) is correct and deliberately common — so the
 checker only flags **jnp/jax expressions**, where a 64-bit dtype is
 either dead (x64 off: silently downcast, a lie in the source) or a

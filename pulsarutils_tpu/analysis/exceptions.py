@@ -42,8 +42,6 @@ CONTAINMENT_SEAMS = {
     ("obs/server.py", "_Handler.do_POST"),  # job API request containment
     ("obs/server.py", "ObsServer.progress_snapshot"),  # user progress_fn
     ("obs/trace.py", "trace_session"),
-    ("obs/roofline.py", "_analyze"),        # AOT lower/compile probe
-    ("obs/roofline.py", "_peaks"),          # backend probe
     ("obs/memory.py", "device_memory_snapshot"),
     # alert fan-out is observability-only (ISSUE 18): a dead webhook,
     # a failing lineage hook or a full disk must be counted,

@@ -124,7 +124,7 @@ def build_parser():
     parser.add_argument("--metrics-out", default=None, metavar="PATH",
                         help="write the run's metrics-registry snapshot "
                              "(counters/gauges/histograms: candidates, "
-                             "trips, bytes moved, roofline, memory "
+                             "trips, bytes moved, memory "
                              "watermarks) to PATH — Prometheus textfile "
                              "format for a .prom suffix, JSONL otherwise")
     parser.add_argument("--http-port", type=int, default=None,
@@ -183,7 +183,7 @@ def build_parser():
     parser.add_argument("--report-out", default=None, metavar="PATH",
                         help="write the end-of-run survey report "
                              "(PATH.md + self-contained PATH.html: "
-                             "budget buckets, roofline, canary recall "
+                             "budget buckets, canary recall "
                              "curve, health incidents, sift counters, "
                              "quarantine manifest); with several input "
                              "files each gets PATH.<root>")
@@ -227,8 +227,6 @@ def main(args=None):
         enable_compile_cache()
     degraded_before = _degraded_counts()
     if opts.trace:
-        # roofline accounting stays off (PUTPU_ROOFLINE=1 enables it):
-        # it adds an AOT compile per kernel to the very run being traced
         session = trace.trace_session(
             path=opts.trace, device_trace_dir=opts.trace + "_device")
     else:

@@ -1,5 +1,5 @@
-"""Survey telemetry: span tracing, metrics registry, roofline + memory
-accounting, and the perf-regression gate's comparison logic.
+"""Survey telemetry: span tracing, metrics registry, memory accounting,
+and the perf-regression gate's comparison logic.
 
 Three pillars (ISSUE 3):
 
@@ -11,9 +11,7 @@ Three pillars (ISSUE 3):
   (per-chunk budget buckets and the event timeline);
 * :mod:`.metrics` — process-wide counters / gauges / histograms with
   JSONL and Prometheus-textfile exporters;
-* :mod:`.roofline` + :mod:`.memory` — per-dispatch FLOPs/bytes from
-  ``compiled.cost_analysis()`` against measured span wall (achieved
-  fraction of ideal per kernel), and device-memory watermarks per chunk.
+* :mod:`.memory` — device-memory watermarks per chunk.
 
 :mod:`.gate` holds the perf-regression comparison consumed by
 ``tools/perf_gate.py``.
@@ -43,7 +41,7 @@ Everything here is dependency-light (stdlib + lazy jax) and safe to
 import before a JAX backend exists.
 """
 
-from . import gate, memory, metrics, roofline, trace
+from . import gate, memory, metrics, trace
 from .metrics import REGISTRY
 from .trace import (begin_span, is_tracing, set_track, span, start_tracing,
                     stop_tracing, trace_context, trace_session)
@@ -76,7 +74,6 @@ __all__ = [
     "memory",
     "metrics",
     "report",
-    "roofline",
     "server",
     "set_track",
     "slo",

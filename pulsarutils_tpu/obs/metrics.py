@@ -2,7 +2,7 @@
 
 The survey service's numeric telemetry lives here — candidate S/N and DM
 histograms from sift, dispatch/readback/retrace counters mirrored from
-the budget accountant, bytes moved over the host link, roofline gauges,
+the budget accountant, bytes moved over the host link,
 device-memory watermarks, chunks/s.  Two exporters:
 
 * JSONL (one metric per line) — artifact parsers, the perf gate;

@@ -331,12 +331,6 @@ METRIC_NAMES = {
         "unreadable ledger/candidate pairs skipped at resume",
     "putpu_retraces_total":
         "XLA compiles observed after a stream's first chunk",
-    "putpu_roofline_frac_of_ideal":
-        "last-dispatch achieved fraction of the roofline bound",
-    "putpu_roofline_gbytes_per_s":
-        "last-dispatch achieved memory bandwidth",
-    "putpu_roofline_gflops":
-        "last-dispatch achieved GFLOP/s",
     "putpu_sift_candidates_in_total":
         "candidates entering the sift",
     "putpu_sift_candidates_kept_total":

@@ -18,7 +18,6 @@ from (the CLI folds post-run sift telemetry in this way):
 * kernel autotuning: the per-geometry-key decision table (winner,
   source, measured speedup vs the static heuristic) when
   ``kernel="auto"`` resolved anything this run;
-* roofline: the per-kernel table when accounting ran;
 * sift + quarantine: telemetry counters and the manifest records.
 
 Every section is optional — pass what the run produced; the report says
@@ -43,16 +42,15 @@ def _fmt(v, nd=3):
     return str(v)
 
 
-def build_report(*, meta=None, budget=None, roofline=None, health=None,
-                 canary=None, quarantine=None, sift=None, metrics=None,
+def build_report(*, meta=None, budget=None, health=None, canary=None,
+                 quarantine=None, sift=None, metrics=None,
                  coincidence=None, fleet=None, periodicity=None,
                  slo=None, lineage=None, push=None, ingest=None,
                  capacity=None):
     """Assemble the structured report record (JSON-ready).
 
     ``meta``: run header dict; ``budget``: ``BudgetAccountant.to_json()``;
-    ``roofline``: ``obs.roofline.table()`` rows; ``health``:
-    ``HealthEngine.snapshot()``; ``canary``:
+    ``health``: ``HealthEngine.snapshot()``; ``canary``:
     ``CanaryController.to_json()``; ``quarantine``:
     ``QuarantineManifest.records()``; ``sift``: the ``SIFT_JSON`` stats
     dict; ``metrics``: a registry snapshot list (key totals are pulled
@@ -76,7 +74,6 @@ def build_report(*, meta=None, budget=None, roofline=None, health=None,
         "generated": time.strftime("%Y-%m-%d %H:%M:%S"),
         "meta": dict(meta or {}),
         "budget": budget,
-        "roofline": roofline or [],
         "health": health,
         "canary": canary,
         "quarantine": quarantine or [],
@@ -267,22 +264,6 @@ def render_markdown(rec):
             lines.append("")
     else:
         lines += ["No budget ledger for this run.", ""]
-
-    lines.append("## Roofline")
-    lines.append("")
-    if rec.get("roofline"):
-        lines.append(_md_table(
-            ("kernel", "calls", "wall s", "GF/s", "GB/s", "ideal"),
-            [(r["kernel"], r["calls"], _fmt(r["wall_s"]),
-              _fmt(r["achieved_gflops"], 2),
-              _fmt(r["achieved_gbytes_per_s"], 2),
-              "-" if r["frac_of_ideal"] is None
-              else f"{100 * r['frac_of_ideal']:.1f}%")
-             for r in rec["roofline"]]))
-        lines.append("")
-    else:
-        lines += ["Roofline accounting did not run (enable with "
-                  "`--trace` or `PUTPU_ROOFLINE=1`).", ""]
 
     lines.append("## Kernel autotuning")
     lines.append("")

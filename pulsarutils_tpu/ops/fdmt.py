@@ -48,7 +48,6 @@ Implementation notes (TPU):
 from __future__ import annotations
 
 import functools
-import os
 
 import numpy as np
 
@@ -308,22 +307,11 @@ def _merge_xla(state, idx_low, idx_high, shift, shift_high=None):
 def _pick_fdmt_tile(t):
     """Largest power-of-two tile in [1024, 8192] dividing ``t`` (0 if none).
 
-    Env ``PUTPU_FDMT_TILE`` caps/overrides the preference (tuning knob:
-    the kernel accepts any power-of-two tile dividing ``t``, but VMEM
-    limits the (tile x MERGE_ROW_BLOCK) product).
+    The kernel accepts any power-of-two tile dividing ``t``; VMEM limits
+    the (tile x MERGE_ROW_BLOCK) product, and on the v5e the largest
+    tile won every sweep (8192 >> 4096 >> 2048).
     """
-    prefs = (8192, 4096, 2048, 1024)
-    try:
-        override = int(os.environ.get("PUTPU_FDMT_TILE") or 0)
-    except ValueError:
-        override = 0
-    # only a power-of-two >= 1024 is a legal tile; anything else would
-    # break the pad-guarantees-a-tile invariant of _transform_setup, so
-    # invalid overrides fall back to the defaults (which stay in prefs
-    # unconditionally for the same reason)
-    if override >= 1024 and (override & (override - 1)) == 0:
-        prefs = (override,) + prefs
-    for t_tile in prefs:
+    for t_tile in (8192, 4096, 2048, 1024):
         if t % t_tile == 0:
             return t_tile
     return 0
@@ -356,35 +344,12 @@ def _transform_setup(data, use_pallas):
             jax.default_backend() != "tpu", t)
 
 
-def _merge_row_block():
-    # guarded like PUTPU_FDMT_TILE: a malformed value must not crash the
-    # import (ValueError) or the padding math later (0/negative ->
-    # ZeroDivisionError in the (-rows) % row_block pads)
-    raw = os.environ.get("PUTPU_MERGE_ROW_BLOCK")
-    try:
-        value = int(raw or 0)
-    except ValueError:
-        value = 0
-    if raw and not 0 < value <= 256:
-        import warnings
-
-        warnings.warn(
-            f"PUTPU_MERGE_ROW_BLOCK={raw!r} ignored (needs an int in "
-            "[1, 256]); using 32", stacklevel=2)
-    return value if 0 < value <= 256 else 32
-
-
 #: output rows processed per merge-kernel grid step; amortises the
 #: per-step Pallas/DMA orchestration overhead (the kernel is otherwise
 #: grid-overhead-bound: one row per step = ~1.4M steps per transform).
-#: Re-swept on v5e at the 1024x1M headline with the DM-pruned plan
-#: (tools/fdmt_tune.py): 32 @ tile 8192 = 0.352 s (1454 tr/s) vs 8 =
-#: 0.394 s; 64 @ 8192 exhausts scoped VMEM; tile size still dominates
-#: (8192 >> 4096 >> 2048).  Compile is slower at 32 (~25 s cold) but the
-#: persistent compilation cache amortises it.  Overridable via env
-#: ``PUTPU_MERGE_ROW_BLOCK`` (an int in [1, 256]; anything else warns
-#: and falls back to 32) — tuning/bisection without code edits.
-MERGE_ROW_BLOCK = _merge_row_block()
+#: Chosen by the v5e sweep at 1024 x 1M with the DM-pruned plan: 32 @
+#: tile 8192 = 0.352 s vs 8 = 0.394 s; 64 @ 8192 exhausts scoped VMEM.
+MERGE_ROW_BLOCK = 32
 
 
 @functools.lru_cache(maxsize=64)
@@ -603,20 +568,6 @@ def _merge4_pallas(state, idx, shift, t_tile, interpret):
     return out[:rows_out] if pad else out
 
 
-def _deep_pair_enabled():
-    """PUTPU_FDMT_DEEP_PAIR: ''=auto (ON), 0, 1.
-
-    Default ON (round-5 A/B, v5e 1024x1M coarse sweep, min-of-4:
-    0.241 s -> 0.229 s on top of the one-pass scorer — the two
-    per-level passes it replaces write and re-read the largest deep
-    state).  Applies only where the Pallas merge path runs; the knob
-    bisects."""
-    from ..utils.knobs import tristate_env
-
-    knob = tristate_env("PUTPU_FDMT_DEEP_PAIR")
-    return True if knob is None else knob
-
-
 def merge_rows_traced(state, idx_low, idx_high, shift, shift_high, *,
                       k_tiles, k_tiles_h, t_tile, interpret):
     """One Pallas merge pass with *traced* (runtime) tables.
@@ -671,26 +622,6 @@ def _merge_pallas(state, it, t_tile, interpret):
     return out[:rows_out] if pad else out
 
 
-def _head_enabled(use_pallas):
-    """Resolve the fused-head knob (PUTPU_FDMT_HEAD: ''=auto, 0, 1).
-
-    Resolved at the call sites (not inside the cached transform
-    builders) so the choice is part of the compile-cache key.
-
-    Default ON for TPU (measured, v5e, 1024 x 1M benchmark): the head
-    is bit-identical, cuts the covered levels' HBM traffic ~4x, and
-    with the 8-row-unrolled row loop measures 0.323 s vs 0.365 s for
-    the per-level path (transform+score).  The win needed two tuning
-    rounds — 128-lane chunks measured 0.62 s and an un-unrolled row
-    loop 0.53 s (both scalar/instruction-bound, see
-    ops/fdmt_resident.py) — so the knob stays for bisection.
-    """
-    from ..utils.knobs import tristate_env
-
-    knob = tristate_env("PUTPU_FDMT_HEAD")
-    return bool(use_pallas) if knob is None else knob
-
-
 def head_active(nchan, start_freq, bandwidth, max_delay, n_lo, t):
     """True iff the fused head WILL run for this transform config.
 
@@ -715,27 +646,11 @@ def head_active(nchan, start_freq, bandwidth, max_delay, n_lo, t):
                           max_level_shift=max(hp.max_shift_per_level))
 
 
-def _score_kernel_choice(use_pallas, interpret):
-    """Resolve the one-pass-scorer choice at a call site.
-
-    Like ``_head_enabled``: the result must be passed into
-    ``_transform_fn``/``_build_transform`` so it keys their lru/compile
-    caches — an in-builder env read would serve a stale compiled
-    program after toggling ``PUTPU_PALLAS_SCORE`` in-process.  Auto
-    (knob unset) enables the kernel on the compiled TPU path only
-    (interpret-mode Pallas is minutes-slow; tests opt in explicitly).
-    """
-    from .score_pallas import score_enabled
-
-    knob = score_enabled()
-    return (bool(use_pallas) and not interpret) if knob is None else knob
-
-
 @functools.lru_cache(maxsize=16)
 def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
                   use_pallas, interpret, n_lo=0, with_scores=False,
-                  with_plane=True, t_orig=None, with_cert=False,
-                  use_head=False, use_score=False, deep_pair=False):
+                  with_plane=True, t_orig=None, with_cert=False, *,
+                  use_head=None, use_score=None, deep_pair=None):
     """The traceable (un-jitted) transform body: DM-pruned merges
     [+ scoring].  :func:`_build_transform` wraps it in ``jax.jit``;
     the hybrid search composes it with its fused seed-rescore program
@@ -747,17 +662,31 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
     program keeps the live set between calls near zero — returning the
     full state keeps gigabytes alive and OOMs back-to-back searches at
     the 1M-sample size.
+
+    THE place the sweep's shape is decided, from what it observes: the
+    fused head and the paired deep pass wherever the Pallas merges run
+    and the geometry fits, the one-pass scorer where those kernels are
+    compiled (interpret-mode Pallas is minutes-slow) and a tile divides
+    the time axis.  ``use_head``/``use_score``/``deep_pair`` are the
+    tests' seam — ``None`` resolves, a bool forces the variant so a
+    parity test can build both sides in one process; nothing outside
+    ``tests/`` passes one.
     """
     import jax.numpy as jnp
 
     plan = fdmt_plan(nchan, start_freq, bandwidth, max_delay, n_lo)
+    if use_head is None:
+        use_head = use_pallas
+    if deep_pair is None:
+        deep_pair = use_pallas
+    if use_score is None:
+        use_score = use_pallas and not interpret
 
     # VMEM-resident fused head (ops/fdmt_resident.py): the first
     # HEAD_LEVELS merges — ~75% of the per-level HBM traffic — run in
     # one Pallas program whose intermediate states never leave VMEM,
-    # bit-identical to the per-level path.  ``use_head`` is resolved by
-    # the caller via _head_enabled (auto on TPU; PUTPU_FDMT_HEAD
-    # overrides) so it keys the compile caches.
+    # bit-identical to the per-level path (v5e, 1024 x 1M: 0.323 s vs
+    # 0.365 s per-level, transform+score).
     head_run = None
     n_head = 0
     if use_head and head_active(nchan, start_freq, bandwidth, max_delay,
@@ -776,10 +705,10 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
             HEAD_LEVELS, t, pick_head_t_slice(hp, t), interpret)
         n_head = HEAD_LEVELS
 
-    # deep-level pairing (round 5, VERDICT r4 #3): fuse the LAST TWO
-    # per-level merges into one 4-parent pass — the intermediate state
-    # (the largest deep state) is never written or re-read.  Pallas
-    # path only; leaf merges (shift_high) cannot compose.
+    # deep-level pairing: fuse the LAST TWO per-level merges into one
+    # 4-parent pass — the intermediate state (the largest deep state)
+    # is never written or re-read (v5e, 1024 x 1M: 0.241 s -> 0.229 s).
+    # Pallas path only; leaf merges (shift_high) cannot compose.
     iters = plan.iterations[n_head:]
     paired = None
     if (deep_pair and use_pallas and len(iters) >= 2
@@ -816,19 +745,15 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
         from .score_pallas import pick_score_tile
         from .search import score_profiles_chunked
 
-        # one-pass Pallas scorer (round 5): reads the plane once and
-        # accumulates per-row partials in VMEM — the XLA chunked scorer
+        # one-pass Pallas scorer: reads the plane once and accumulates
+        # per-row partials in VMEM — the XLA chunked scorer
         # materialises ~9 GB of mean-sub/pyramid/sliding temps at the
         # 513 x 1M coarse plane and measured 0.17 s standalone against
-        # this kernel's ~0.02 s.  ``use_score`` is resolved by the
-        # caller via _score_kernel_choice (auto on compiled TPU;
-        # PUTPU_PALLAS_SCORE=0|1 bisects) so it keys the compile caches.
+        # this kernel's ~0.02 s.
         if use_score and not pick_score_tile(plane.shape[1]):
             import warnings
 
-            # trace-time, once per shape: a silent fall-through would
-            # make a PUTPU_PALLAS_SCORE A/B bisection measure the same
-            # XLA scorer twice (the _head_enabled lesson)
+            # trace-time, once per shape
             warnings.warn(
                 f"one-pass scorer unavailable: no supported tile "
                 f"divides T={plane.shape[1]}; falling back to the XLA "
@@ -853,8 +778,8 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
 @functools.lru_cache(maxsize=16)
 def _build_transform(nchan, start_freq, bandwidth, max_delay, t, t_tile,
                      use_pallas, interpret, n_lo=0, with_scores=False,
-                     with_plane=True, t_orig=None, with_cert=False,
-                     use_head=False, use_score=False, deep_pair=False):
+                     with_plane=True, t_orig=None, with_cert=False, *,
+                     use_head=None, use_score=None, deep_pair=None):
     """Jitted wrapper of :func:`_transform_fn` (same signature)."""
     import jax
 
@@ -906,9 +831,7 @@ def fdmt_transform(data, max_delay, start_freq, bandwidth, use_pallas=None,
     # consumer has read it.
     run = _build_transform(nchan, float(start_freq), float(bandwidth),
                            int(max_delay), t_run, t_tile, use_pallas,
-                           interpret, n_lo=int(min_delay), t_orig=t_orig,
-                           use_head=_head_enabled(use_pallas),
-                           deep_pair=_deep_pair_enabled())
+                           interpret, n_lo=int(min_delay), t_orig=t_orig)
     return run(data)
 
 

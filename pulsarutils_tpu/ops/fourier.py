@@ -75,9 +75,9 @@ def _fdd_hbm_budget():
     except (ValueError, OverflowError):  # "8GB", "inf", ...
         value = 0
     if raw and value <= (1 << 28):
-        # mirror the PUTPU_MERGE_ROW_BLOCK guard: a rejected override
-        # must not silently budget for the 12 GB default on a smaller
-        # chip (the compile-OOM this knob exists to prevent)
+        # a rejected override must not silently budget for the 12 GB
+        # default on a smaller chip (the compile-OOM this knob exists
+        # to prevent)
         warnings.warn(
             f"PUTPU_FDD_HBM={raw!r} ignored (needs a byte count "
             "> 2^28, e.g. 8589934592 for 8 GB); using the "

@@ -1,7 +1,7 @@
 """One-pass Pallas scorer for coarse (FDMT) planes (round 5).
 
 VERDICT r4 #3 named the fused one-pass scorer as the next FDMT lever:
-the stage probe (``tools/fdmt_stage_probe.py``, ``docs/performance.md``)
+round 4's stage probe (``docs/performance.md``)
 measured the XLA chunked scorer at ~0.17 s standalone on the 513 x 1M
 coarse plane — instruction/materialisation-bound, not traffic-bound
 (the mean-subtracted copy plus the boxcar pyramid and three sliding
@@ -264,15 +264,6 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret):
         name="score_rows",
     )
     return call
-
-
-def score_enabled():
-    """Resolve the one-pass-scorer knob (PUTPU_PALLAS_SCORE: ''=auto,
-    0, 1).  Mirrors ``fdmt._head_enabled``: resolved at call sites so a
-    toggle is never served a stale compiled program."""
-    from ..utils.knobs import tristate_env
-
-    return tristate_env("PUTPU_PALLAS_SCORE")
 
 
 def _kernel_scores(rows_p, t, t_blk, with_cert, interpret, sub):

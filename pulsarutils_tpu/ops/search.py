@@ -40,7 +40,6 @@ from .plan import (
     normalize_shifts,
 )
 from .rebin import block_sum_time
-from ..obs import roofline
 from ..tuning.geometry import PLAN_CACHE_SIZE, counted_plan_cache
 from ..utils.logging_utils import budget_bucket, budget_count
 from ..utils.table import ResultTable
@@ -549,9 +548,7 @@ def _search_jax_fdmt(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
     """
     import jax.numpy as jnp
 
-    from .fdmt import (_build_transform, _head_enabled,
-                       _score_kernel_choice, _transform_setup,
-                       fdmt_trial_dms)
+    from .fdmt import _build_transform, _transform_setup, fdmt_trial_dms
 
     nchan = data.shape[0]
     trial_dms, n_lo, n_hi = fdmt_trial_dms(nchan, dmmin, dmmax, start_freq,
@@ -562,18 +559,11 @@ def _search_jax_fdmt(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
     # scoring (and the row slice) run inside the transform's jit: only
     # the per-trial score vectors (and optionally the plane) leave the
     # device, keeping back-to-back searches within HBM
-    from .fdmt import _deep_pair_enabled
-
     run = _build_transform(nchan, float(start_freq), float(bandwidth),
                            n_hi, t_run, t_tile, use_pallas, interpret,
                            n_lo=n_lo, with_scores=True,
                            with_plane=capture_plane, t_orig=t_orig,
-                           with_cert=with_cert,
-                           use_head=_head_enabled(use_pallas),
-                           use_score=_score_kernel_choice(use_pallas,
-                                                          interpret),
-                           deep_pair=_deep_pair_enabled())
-    roof = roofline.begin()
+                           with_cert=with_cert)
     with budget_bucket("search/coarse"):
         out = run(data)
         budget_count("dispatches")
@@ -584,7 +574,6 @@ def _search_jax_fdmt(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
     with budget_bucket("search/coarse_readback"):
         scores = unstack_scores(stacked)
         budget_count("readbacks")
-    roofline.end(roof, "fdmt_coarse", run, (data,))
     (maxvalues, stds, best_snrs, best_windows, best_peaks) = scores[:5]
     out = (trial_dms, maxvalues, stds, best_snrs, best_windows, best_peaks,
            plane_out)
@@ -793,7 +782,6 @@ def _dispatch_direct(data, offset_blocks, capture_plane, chan_block,
     kernel_fn = _jax_search_kernel(capture_plane, chan_block, formulation,
                                    packed_meta, policy)
     if passes <= 1:
-        roof = roofline.begin()  # wall spans dispatch -> readback
         with budget_bucket("search/dispatch"):
             offs_dev = jnp.asarray(offset_blocks)  # attributed
             out = kernel_fn(data, offs_dev)
@@ -802,7 +790,6 @@ def _dispatch_direct(data, offset_blocks, capture_plane, chan_block,
         with budget_bucket("search/readback"):
             stacked = np.asarray(stacked)
             budget_count("readbacks")
-        roofline.end(roof, "gather_sweep", kernel_fn, (data, offs_dev))
         return stacked, (out[1] if capture_plane else None)
     parts = []
     planes = []
@@ -1183,8 +1170,7 @@ def fused_scores_to_host(scores, roll_k, nsamples):
 @functools.lru_cache(maxsize=8)
 def _fused_hybrid_seed_kernel(nchan, start_freq, bandwidth, n_hi, t_run,
                               t_tile, n_lo, t_orig, max_off, ndm_plan,
-                              bucket, use_head=False, bucket2=0,
-                              use_score=False, deep_pair=False):
+                              bucket, bucket2=0):
     """ONE jitted program for the hybrid's first round on TPU:
 
     FDMT coarse sweep -> plan-grid score mapping -> device-side top-k
@@ -1224,9 +1210,7 @@ def _fused_hybrid_seed_kernel(nchan, start_freq, bandwidth, n_hi, t_run,
     coarse_fn = _transform_fn(nchan, start_freq, bandwidth, n_hi, t_run,
                               t_tile, True, False, n_lo=n_lo,
                               with_scores=True, with_plane=False,
-                              t_orig=t_orig, with_cert=True,
-                              use_head=use_head, use_score=use_score,
-                              deep_pair=deep_pair)
+                              t_orig=t_orig, with_cert=True)
     k = min(HYBRID_SEED_TOPK, ndm_plan)  # top_k requires k <= axis size
 
     @jax.jit
@@ -1445,8 +1429,6 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
         assert bucket >= 3 * HYBRID_SEED_TOPK
         bucket2 = min(HYBRID_NEED_BUCKET, ndm)
         t_tile = _pick_fdmt_tile(nsamples)
-        from .fdmt import _head_enabled
-
         # the need stage wants the retention bound BEFORE the dispatch;
         # same lru-cached computation the gate performs, so no extra
         # cost — rho_cert=False (cert opt-out) sends +inf, which
@@ -1461,29 +1443,18 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
                                         rho_cert=rho_cert,
                                         cert_slack=cert_slack)
 
-        # the head flag is resolved HERE so it keys the builder's lru
-        # cache (an in-builder env read would serve a stale compiled
-        # program after toggling PUTPU_FDMT_HEAD in-process)
-        from .fdmt import _deep_pair_enabled, _score_kernel_choice
-
         rebased_full, roll_k, max_off = offsets_table()
         kernel = _fused_hybrid_seed_kernel(
             nchan, float(start_freq), float(bandwidth), n_hi, nsamples,
-            t_tile, n_lo, None, max_off, ndm, bucket,
-            use_head=_head_enabled(True), bucket2=bucket2,
-            use_score=_score_kernel_choice(True, False),
-            deep_pair=_deep_pair_enabled())
+            t_tile, n_lo, None, max_off, ndm, bucket, bucket2=bucket2)
         offs_dev = _device_offsets_cache(rebased_full.tobytes(),
                                          rebased_full.shape)
-        roof = roofline.begin()
         with budget_bucket("search/fused"):
             idx_dev = jnp.asarray(idx.astype(np.int32))
             cert_dev = jnp.asarray(cert_params)
             packed = np.asarray(kernel(data32, idx_dev, offs_dev, cert_dev))
             budget_count("dispatches")
             budget_count("readbacks")
-        roofline.end(roof, "fused_hybrid_seed", kernel,
-                     (data32, idx_dev, offs_dev, cert_dev))
         (coarse, sel, seed_scores, _, sel2, need_scores,
          n_need) = unpack_fused_hybrid(packed, ndm, bucket, bucket2)
         maxvalues, stds, snrs = coarse[0], coarse[1], coarse[2]

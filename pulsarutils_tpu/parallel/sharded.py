@@ -244,9 +244,6 @@ def sharded_dedispersion_search(data, dmmin, dmmax, start_freq, bandwidth,
 
     compiled = _sharded_kernel(mesh, capture_plane, chan_block, kernel,
                                max_off, policy_arg)
-    from ..obs import roofline
-
-    roof = roofline.begin()
     with budget_bucket("search/dispatch"):
         # host->device conversions stay INSIDE the bucket: on CPU the
         # asarray of a full chunk copies synchronously, and those
@@ -273,7 +270,6 @@ def sharded_dedispersion_search(data, dmmin, dmmax, start_freq, bandwidth,
     with budget_bucket("search/readback"):
         stacked_host = fetch(stacked)[:, :ndm]
         budget_count("readbacks")
-    roofline.end(roof, "sharded_sweep", compiled, sweep_args)
     maxvalues, stds, best_snrs, best_windows, best_peaks = unstack_scores(
         stacked_host)
 

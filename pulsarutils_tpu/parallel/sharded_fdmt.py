@@ -744,7 +744,6 @@ def sharded_hybrid_search(data, dmmin, dmmax, start_freq, bandwidth,
             flat += [jnp.asarray(it[k]) for k in
                      ("idx_low", "idx_high", "shift", "shift_high")]
         from ..faults import inject as fault_inject
-        from ..obs import roofline
 
         try:
             # the "mesh" fault site also fires HERE (not only in the
@@ -753,7 +752,6 @@ def sharded_hybrid_search(data, dmmin, dmmax, start_freq, bandwidth,
             # times=1 spec already consumed at the pipeline seam is
             # exhausted and no-ops here
             fault_inject.fire("mesh", chunk=None)
-            roof = roofline.begin()
             with budget_bucket("search/fused"):
                 # operand conversions stay inside the bucket
                 # (attributed); on the packed path the operand IS the
@@ -766,8 +764,6 @@ def sharded_hybrid_search(data, dmmin, dmmax, start_freq, bandwidth,
                 packed = np.asarray(kernel_fn(*fused_args))
                 budget_count("dispatches")
                 budget_count("readbacks")
-            roofline.end(roof, "sharded_fused_hybrid", kernel_fn,
-                         fused_args)
         except (ValueError, TypeError):
             raise  # deterministic configuration error, never OOM
         except Exception as exc:  # jax errors share no base class

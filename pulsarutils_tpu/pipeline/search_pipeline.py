@@ -41,7 +41,6 @@ from ..io.candidates import CandidateStore, config_fingerprint
 from ..io.sigproc import FilterbankReader
 from ..obs import memory as obs_memory
 from ..obs import metrics as obs_metrics
-from ..obs import roofline
 from ..obs.canary import CanaryController
 from ..obs.capacity import EwmaThroughput
 from ..obs.health import HealthEngine
@@ -718,8 +717,8 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
       CRITICAL verdict ``/healthz`` serves;
     * ``report_out`` writes the end-of-run survey report (markdown +
       single-file HTML, :mod:`~pulsarutils_tpu.obs.report`) stitching
-      budget, roofline, canary recall curve, health incidents, sift
-      counters and the quarantine manifest into one artifact.
+      budget, canary recall curve, health incidents, sift counters and
+      the quarantine manifest into one artifact.
 
     Fleet knobs (ISSUE 9; ``docs/fleet.md``) — default-off, byte-inert
     when unset:
@@ -1602,7 +1601,6 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             with with_timer("clean"):
                 if device_clean is not None:
                     try:
-                        roof = roofline.begin()
                         cleaned = device_clean(src, mask_dev)
                         timer.count("dispatches")
                         # wait here: dispatch is async, so a device
@@ -1615,8 +1613,6 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                         # a host wait on the device is one trip of the
                         # budget's trips x RTT floor, like a readback
                         timer.count("readbacks")
-                        roofline.end(roof, "device_clean", device_clean,
-                                     (src, mask_dev))
                         array = cleaned
                     except Exception as exc:
                         if compile_phase.failed_phase(exc):
@@ -2112,7 +2108,6 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                           "kernel": kernel,
                           "snr_threshold": snr_threshold},
                     budget=timer.to_json(max_per_chunk=0),
-                    roofline=roofline.table(),
                     health=health.snapshot() if health is not None else None,
                     canary=canary.to_json() if canary is not None else None,
                     quarantine=manifest.records(),

@@ -1,14 +1,11 @@
-"""Shared tri-state environment-knob parser.
+"""Tri-state environment-knob parser.
 
-Every Pallas-path bisection knob (``PUTPU_FDMT_HEAD``,
-``PUTPU_PALLAS_SCORE``, ``PUTPU_FDD_PALLAS``) follows the same
-contract: ``''``/unset means *auto* (platform default), ``'0'`` forces
-off, ``'1'`` forces on, and anything else WARNS and falls back to auto
-— a silently-ignored ``'true'``/``'off'`` would make an A/B bisection
-measure the same compiled program twice (the ``_head_enabled`` lesson,
-round 3).  Three hand-rolled copies of this parser had already drifted
-(``PUTPU_FDD_PALLAS`` silently ignored garbage — code-review r5); this
-helper pins the behaviour once.
+``PUTPU_FDD_PALLAS`` (``kernel="fourier"`` has no chip row yet, so
+neither side of it has won; ROADMAP D3) follows this contract:
+``''``/unset means *auto* (platform default), ``'0'`` forces off,
+``'1'`` forces on, and anything else WARNS and falls back to auto — a
+silently-ignored ``'true'``/``'off'`` would make an A/B bisection
+measure the same compiled program twice.
 """
 
 from __future__ import annotations

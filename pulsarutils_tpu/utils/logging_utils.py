@@ -27,9 +27,6 @@ import logging
 import threading
 import time
 
-from ..obs import metrics as _metrics
-from ..obs import trace as _trace
-
 logger = logging.getLogger("pulsarutils_tpu")
 if not logger.handlers:
     _h = logging.StreamHandler()
@@ -37,6 +34,11 @@ if not logger.handlers:
         "%(asctime)s %(name)s %(levelname)s: %(message)s", "%H:%M:%S"))
     logger.addHandler(_h)
     logger.setLevel(logging.INFO)
+
+# after ``logger``: importing the obs package runs its live surface,
+# which imports ``logger`` from this (then partially initialised) module
+from ..obs import metrics as _metrics  # noqa: E402
+from ..obs import trace as _trace  # noqa: E402
 
 
 class StageTimer:
@@ -531,9 +533,6 @@ class BudgetAccountant(StageTimer):
         if j["wall_s"]:
             _metrics.gauge("putpu_chunks_per_s").set(
                 round(j["chunks"] / j["wall_s"], 4))
-        from ..obs import roofline as _roofline
-
-        _roofline.log_table(log)  # no-op unless roofline accounting ran
 
 
 def current_budget():

@@ -319,7 +319,7 @@ def test_every_kernel_of_the_hybrid_path_is_named():
     coarse = fdmt._transform_fn(
         nchan, f0, bw, n_hi, t, fdmt._pick_fdmt_tile(t), True, True,
         n_lo=n_lo, with_scores=True, with_plane=False, t_orig=t,
-        with_cert=True, use_head=True, use_score=True, deep_pair=True)
+        with_cert=True, use_score=True)
     found = _pallas_call_names(
         jax.make_jaxpr(coarse)(jnp.zeros((nchan, t), jnp.float32)).jaxpr,
         [])

@@ -146,15 +146,10 @@ def test_fdmt_transform_as_resolved_on_tpu(as_tpu, one_chip, plan):
 
     from pulsarutils_tpu.ops import fdmt
 
-    use_head = fdmt._head_enabled(True)
-    use_score = fdmt._score_kernel_choice(True, False)
-    deep_pair = fdmt._deep_pair_enabled()
-    assert (use_head, use_score, deep_pair) == (True, True, True)
     run = fdmt._build_transform(
         NCHAN, F0, BW, plan["n_hi"], T, plan["t_tile"], True, False,
         n_lo=plan["n_lo"], with_scores=True, with_plane=False, t_orig=T,
-        with_cert=True, use_head=use_head, use_score=use_score,
-        deep_pair=deep_pair)
+        with_cert=True)
     compiled = run.lower(_sds((NCHAN, T), jnp.float32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
@@ -178,16 +173,13 @@ def test_fused_hybrid_seed_program(as_tpu, one_chip, plan):
     (``ops/search.py:_fused_hybrid_seed_kernel``)."""
     import jax.numpy as jnp
 
-    from pulsarutils_tpu.ops import fdmt, search
+    from pulsarutils_tpu.ops import search
 
     ndm = plan["ndm"]
     kernel = search._fused_hybrid_seed_kernel(
         NCHAN, F0, BW, plan["n_hi"], T, plan["t_tile"], plan["n_lo"], None,
         plan["max_off"], ndm, search.HYBRID_SEED_BUCKET,
-        use_head=fdmt._head_enabled(True),
-        bucket2=min(search.HYBRID_NEED_BUCKET, ndm),
-        use_score=fdmt._score_kernel_choice(True, False),
-        deep_pair=fdmt._deep_pair_enabled())
+        bucket2=min(search.HYBRID_NEED_BUCKET, ndm))
     compiled = kernel.lower(
         _sds((NCHAN, T), jnp.float32, one_chip),
         _sds((ndm,), jnp.int32, one_chip),

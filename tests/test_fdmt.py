@@ -25,6 +25,16 @@ from pulsarutils_tpu.ops.search import dedispersion_search
 GEOM = (1200.0, 200.0, 0.0005)  # start_freq, bandwidth, tsamp
 
 
+def _kernels(run, shape):
+    """The coarse sweep's Pallas kernels a program calls, by their
+    declared names (traced only: nothing is lowered or run)."""
+    import jax
+
+    text = str(jax.make_jaxpr(run)(jax.ShapeDtypeStruct(shape, np.float32)))
+    return {name for name in ("fdmt_head", "fdmt_merge", "fdmt_deep_pair",
+                              "score_rows") if name in text}
+
+
 def brute_force_tracks(data, plan, max_delay):
     """Recompute every row by walking the plan's merge tables on the host.
 
@@ -76,43 +86,58 @@ class TestTransform:
                                       use_pallas=True))
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-4)
 
-    def test_deep_pair_bit_identical(self, monkeypatch):
-        # the composed 4-parent pass (PUTPU_FDMT_DEEP_PAIR=1) must be
-        # BIT-identical to the two per-level merges it replaces: same
-        # floats, same pairwise add tree (ops/fdmt.py:_build_merge4_kernel)
+    @pytest.mark.parametrize("min_delay", [0, 17])
+    def test_deep_pair_bit_identical(self, min_delay):
+        # the composed 4-parent pass must be BIT-identical to the two
+        # per-level merges it replaces: same floats, same pairwise add
+        # tree (ops/fdmt.py:_build_merge4_kernel); 17 prunes the plan
         from pulsarutils_tpu.ops import fdmt
 
-        rng = np.random.default_rng(9)
         nchan, t = 16, 2048  # pallas path; >= 2 deep iterations
-        data = rng.normal(0, 1, (nchan, t)).astype(np.float32)
-        monkeypatch.delenv("PUTPU_FDMT_DEEP_PAIR", raising=False)
-        base = np.asarray(fdmt_transform(data, 40, GEOM[0], GEOM[1],
-                                         use_pallas=True))
-        monkeypatch.setenv("PUTPU_FDMT_DEEP_PAIR", "1")
-        fdmt._build_transform.cache_clear()
-        fdmt._transform_fn.cache_clear()
-        paired = np.asarray(fdmt_transform(data, 40, GEOM[0], GEOM[1],
-                                           use_pallas=True))
-        fdmt._build_transform.cache_clear()
-        fdmt._transform_fn.cache_clear()
-        np.testing.assert_array_equal(base, paired)
+        data = np.random.default_rng(9).normal(
+            0, 1, (nchan, t)).astype(np.float32)
 
-    def test_deep_pair_with_pruning_bit_identical(self, monkeypatch):
+        def side(deep_pair):
+            run = fdmt._build_transform(
+                nchan, GEOM[0], GEOM[1], 40, t, fdmt._pick_fdmt_tile(t),
+                True, True, n_lo=min_delay, t_orig=t, deep_pair=deep_pair)
+            return _kernels(run, (nchan, t)), np.asarray(run(data))
+
+        per_level_kernels, per_level = side(False)
+        paired_kernels, paired = side(True)
+        assert per_level_kernels == {"fdmt_merge"}
+        assert paired_kernels == {"fdmt_merge", "fdmt_deep_pair"}
+        np.testing.assert_array_equal(per_level, paired)
+
+    @pytest.mark.parametrize("use_pallas,interpret,scored", [
+        (True, False, {"score_rows"}),   # a TPU
+        (True, True, set()),             # Pallas forced on off the chip
+        (False, True, None),             # the CPU: XLA merges and scorer
+        (False, False, None),            # XLA merges forced on a TPU
+    ])
+    @pytest.mark.parametrize("nchan,t,n_lo,n_hi,merges", [
+        # ten levels, the head fits: head, one merge, the deep pair
+        (1024, 4096, 100, 250, {"fdmt_head", "fdmt_merge",
+                                "fdmt_deep_pair"}),
+        # four levels, below the head's size: two merges, the deep pair
+        (16, 2048, 0, 40, {"fdmt_merge", "fdmt_deep_pair"}),
+    ])
+    def test_sweep_decides_its_own_shape(self, use_pallas, interpret,
+                                         scored, nchan, t, n_lo, n_hi,
+                                         merges):
+        # what production builds (no variant keyword): the kernels of
+        # the traced program follow from use_pallas, interpret and the
+        # geometry alone
         from pulsarutils_tpu.ops import fdmt
 
-        rng = np.random.default_rng(10)
-        data = rng.normal(0, 1, (16, 2048)).astype(np.float32)
-        monkeypatch.delenv("PUTPU_FDMT_DEEP_PAIR", raising=False)
-        base = np.asarray(fdmt_transform(data, 40, GEOM[0], GEOM[1],
-                                         use_pallas=True, min_delay=17))
-        monkeypatch.setenv("PUTPU_FDMT_DEEP_PAIR", "1")
-        fdmt._build_transform.cache_clear()
-        fdmt._transform_fn.cache_clear()
-        paired = np.asarray(fdmt_transform(data, 40, GEOM[0], GEOM[1],
-                                           use_pallas=True, min_delay=17))
-        fdmt._build_transform.cache_clear()
-        fdmt._transform_fn.cache_clear()
-        np.testing.assert_array_equal(base, paired)
+        assert fdmt.head_active(nchan, GEOM[0], GEOM[1], n_hi, n_lo, t) == (
+            "fdmt_head" in merges)
+        run = fdmt._build_transform(
+            nchan, GEOM[0], GEOM[1], n_hi, t, fdmt._pick_fdmt_tile(t),
+            use_pallas, interpret, n_lo=n_lo, with_scores=True,
+            with_plane=False, t_orig=t, with_cert=True)
+        want = set() if scored is None else merges | scored
+        assert _kernels(run, (nchan, t)) == want
 
     def test_row_zero_is_plain_channel_sum(self):
         rng = np.random.default_rng(2)

@@ -1,10 +1,6 @@
 """The VMEM-resident fused FDMT head: bit-identity with the per-level
 merges, and the full transform/search with the head enabled."""
 
-import os
-import subprocess
-import sys
-
 import numpy as np
 import pytest
 
@@ -69,33 +65,21 @@ class TestHead:
         # halo equals the sum of per-level worst shifts
         assert hp.halo == sum(hp.max_shift_per_level)
 
-    def test_full_transform_with_head_matches(self, tmp_path):
-        """End-to-end: the full search with PUTPU_FDMT_HEAD=1 must equal
-        the head-off transform bit-for-bit (subprocess: the knob keys
-        compile caches at import-free call time, so each setting gets a
-        fresh interpreter)."""
-        code = """
-import jax
-jax.config.update("jax_platforms", "cpu")
-import numpy as np
-from pulsarutils_tpu.ops.fdmt import fdmt_transform
-rng = np.random.default_rng(3)
-data = rng.standard_normal((256, 4096)).astype(np.float32)
-out = np.asarray(fdmt_transform(data, 250, 1200., 200., min_delay=100))
-np.save(%r, out)
-"""
-        outs = []
-        for knob, path in (("0", str(tmp_path / "head_off.npy")),
-                           ("1", str(tmp_path / "head_on.npy"))):
-            env = dict(os.environ, PUTPU_FDMT_HEAD=knob)
-            r = subprocess.run([sys.executable, "-c", code % path],
-                               env=env, capture_output=True, text=True,
-                               cwd=os.path.dirname(os.path.dirname(
-                                   os.path.abspath(__file__))))
-            assert r.returncode == 0, r.stderr[-2000:]
-            outs.append(np.load(path))
-        assert np.array_equal(outs[0], outs[1]), float(
-            np.abs(outs[0] - outs[1]).max())
+    def test_full_transform_with_head_matches(self):
+        """End-to-end: the whole transform with the head forced on must
+        equal the per-level transform bit for bit."""
+        from pulsarutils_tpu.ops import fdmt
+
+        nchan, t, lo, hi = 256, 4096, 100, 250
+        assert fdmt.head_active(nchan, *GARGS, hi, lo, t)
+        data = np.random.default_rng(3).standard_normal(
+            (nchan, t)).astype(np.float32)
+        head, per_level = (np.asarray(fdmt._build_transform(
+            nchan, *GARGS, hi, t, fdmt._pick_fdmt_tile(t), False, True,
+            n_lo=lo, t_orig=t, use_head=use_head)(data))
+            for use_head in (True, False))
+        assert np.array_equal(head, per_level), float(
+            np.abs(head - per_level).max())
 
     def test_head_supported_gates(self):
         assert not head_supported(64, 10, 1 << 14)      # too few chans

@@ -1,6 +1,6 @@
 """Round-7 telemetry subsystem (ISSUE 3): span tracing, metrics
-registry, roofline accounting, the perf gate — and the byte-compat
-contract that the span refactor did NOT change ``BUDGET_JSON``.
+registry, the perf gate — and the byte-compat contract that the span
+refactor did NOT change ``BUDGET_JSON``.
 """
 import json
 import os
@@ -12,7 +12,7 @@ import time
 import numpy as np
 import pytest
 
-from pulsarutils_tpu.obs import gate, memory, metrics, roofline, trace
+from pulsarutils_tpu.obs import gate, memory, metrics, trace
 from pulsarutils_tpu.utils.logging_utils import (BudgetAccountant,
                                                  budget_bucket,
                                                  budget_count)
@@ -422,76 +422,6 @@ def test_memory_watermark_gauges():
     peak = g.value
     memory.record_watermark()
     assert metrics.REGISTRY.gauge("putpu_device_bytes_peak").value >= peak
-
-
-# ---------------------------------------------------------------------------
-# roofline
-# ---------------------------------------------------------------------------
-
-def test_roofline_fused_mesh_dispatch():
-    jax = pytest.importorskip("jax")
-    from pulsarutils_tpu.models.simulate import simulate_test_data
-    from pulsarutils_tpu.parallel.mesh import make_mesh
-    from pulsarutils_tpu.parallel.sharded_fdmt import sharded_hybrid_search
-
-    array, header = simulate_test_data(150, nchan=64, nsamples=4096,
-                                       signal=2.0, noise=0.4, rng=51)
-    mesh = make_mesh((1, 1), ("dm", "chan"))
-    roofline.reset()
-    roofline.enable()
-    try:
-        sharded_hybrid_search(array, 100, 200.0, header["fbottom"],
-                              header["bandwidth"], header["tsamp"],
-                              mesh=mesh)
-        rows = {r["kernel"]: r for r in roofline.table()}
-        assert "sharded_fused_hybrid" in rows
-        r = rows["sharded_fused_hybrid"]
-        assert r["calls"] >= 1 and r["wall_s"] > 0
-        assert r["gflops_total"] > 0 and r["gbytes_total"] > 0
-        assert r["uncosted_calls"] == 0
-        assert r["achieved_gflops"] > 0
-        # registry gauges mirror the per-kernel rates
-        g = metrics.REGISTRY.gauge("putpu_roofline_gflops",
-                                   kernel="sharded_fused_hybrid")
-        assert g.value > 0
-    finally:
-        roofline.disable()
-        roofline.reset()
-
-
-def test_roofline_disabled_is_free():
-    roofline.disable()
-    try:
-        assert roofline.begin() is None
-        roofline.end(None, "x", None, ())  # must not raise
-        assert roofline.table() == []
-    finally:
-        roofline.reset()
-        roofline.disable()
-
-
-def test_roofline_peaks_by_device_kind_unknown_gets_no_fraction(monkeypatch):
-    """ISSUE 22: peaks are looked up by the device's own ``device_kind``
-    in a sourced table; a device that is not in it (every CPU) yields
-    no fraction — never "anything called tpu is 9e13 FLOP/s"."""
-    flops, bw, source = roofline.DEVICE_PEAKS["TPU v5 lite"]
-    assert (flops, bw) == (1.97e14, 8.19e11) and "TPU v5e" in source
-    monkeypatch.delenv("PUTPU_PEAK_FLOPS", raising=False)
-    monkeypatch.delenv("PUTPU_PEAK_BYTES_PER_S", raising=False)
-    roofline.reset()
-    try:
-        assert roofline._peaks() == (None, None)     # device_kind "cpu"
-        assert roofline._fraction(1e12, 1e9, 0.5) is None
-        roofline.reset()
-        import jax
-
-        monkeypatch.setattr(type(jax.devices()[0]), "device_kind",
-                            property(lambda self: "TPU v5 lite"),
-                            raising=False)
-        assert roofline._peaks() == (flops, bw)
-        assert roofline._fraction(1.97e14, 0.0, 2.0) == pytest.approx(0.5)
-    finally:
-        roofline.reset()
 
 
 # ---------------------------------------------------------------------------

@@ -462,18 +462,13 @@ def test_report_renders_all_sections(tmp_path):
         str(tmp_path / "rep"),
         meta={"root": "survey", "fingerprint": "abc"},
         budget=budget, health=health, canary=canary,
-        roofline=[{"kernel": "gather_sweep", "calls": 3, "wall_s": 1.0,
-                   "gflops_total": 1.0, "gbytes_total": 1.0,
-                   "achieved_gflops": 1.0,
-                   "achieved_gbytes_per_s": 1.0,
-                   "frac_of_ideal": 0.5, "uncosted_calls": 0}],
         quarantine=[{"chunk": 0, "end": 8192, "reason": "read_error"}],
         sift={"in": 4, "kept": 2,
               "rejected": {"duplicate": 1, "width": 1}})
     md = open(md_path).read()
     assert "**DEGRADED**" in md and "candidate_storm" in md
     assert "recall 0.9167" in md
-    assert "gather_sweep" in md and "read_error" in md
+    assert "read_error" in md
     html = open(html_path).read()
     assert html.startswith("<!doctype html>")
     assert 'class="verdict-DEGRADED"' in html
@@ -485,8 +480,25 @@ def test_report_renders_all_sections(tmp_path):
     md2 = open(md2_path).read()
     assert "No health engine" in md2
     assert "NOT measured" in md2
-    assert "Roofline accounting did not run" in md2
     assert "No chunks were quarantined" in md2
+
+
+def test_report_has_no_roofline_section(tmp_path):
+    """The host-wall roofline table is gone (the benchmark's
+    ``fdmt_roofline`` reads device time): no section, stated or empty,
+    and no parameter to feed one."""
+    from pulsarutils_tpu.obs import report
+
+    md_path, html_path = report.write_report(
+        str(tmp_path / "rep"), meta={"root": "survey"},
+        budget={"schema_version": 1, "chunks": 1, "wall_s": 1.0,
+                "buckets_s": {"search": 1.0}, "unattributed_s": 0.0,
+                "attributed_pct": 100.0, "counters": {}, "async_s": {},
+                "per_chunk": []})
+    for path in (md_path, html_path):
+        assert "roofline" not in open(path).read().lower()
+    with pytest.raises(TypeError):
+        report.build_report(roofline=[])
 
 
 def test_canary_time_matching_rejects_coincident_real_pulse():

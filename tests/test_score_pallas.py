@@ -136,33 +136,37 @@ def test_unsupported_tile_raises():
         score_plane_pallas(jnp.asarray(plane), interpret=True)
 
 
-def test_wired_into_transform(monkeypatch):
-    # PUTPU_PALLAS_SCORE=1 routes the fdmt search's scoring through the
-    # kernel (interpret mode here); the coarse table must match the
-    # XLA-scored run on selection rows and to f32 order on floats
+def test_wired_into_transform():
+    # use_score routes the coarse sweep's scoring through the kernel
+    # (interpret mode here); the coarse scores must match the
+    # XLA-scored program on selection rows and to f32 order on floats
+    import jax
+
     from pulsarutils_tpu.ops import fdmt
-    from pulsarutils_tpu.ops.search import _search_jax_fdmt
+    from pulsarutils_tpu.ops.search import unstack_scores
 
     rng = np.random.default_rng(7)
     data = rng.standard_normal((64, 2048)).astype(np.float32)
     data[:, 700] += 3.0
-    args = (data, 20.0, 80.0, 1200.0, 200.0, 0.001, False)
+    nchan, t = data.shape
+    _, n_lo, n_hi = fdmt.fdmt_trial_dms(nchan, 20.0, 80.0, 1200.0, 200.0,
+                                        0.001)
 
-    monkeypatch.setenv("PUTPU_PALLAS_SCORE", "1")
-    fdmt._build_transform.cache_clear()
-    fdmt._transform_fn.cache_clear()
-    got = _search_jax_fdmt(*args, with_cert=True)
+    def scores(use_score):
+        run = fdmt._build_transform(
+            nchan, 1200.0, 200.0, n_hi, t, fdmt._pick_fdmt_tile(t), False,
+            True, n_lo=n_lo, with_scores=True, with_plane=False, t_orig=t,
+            with_cert=True, use_score=use_score)
+        assert ("score_rows" in str(jax.make_jaxpr(run)(data))) == use_score
+        return unstack_scores(run(jnp.asarray(data)))
 
-    monkeypatch.setenv("PUTPU_PALLAS_SCORE", "0")
-    want = _search_jax_fdmt(*args, with_cert=True)
-
-    np.testing.assert_array_equal(got[0], want[0])  # trial grid
-    for i in (1, 2, 3, 7):  # max, std, snr, cert
+    got, want = scores(True), scores(False)
+    for i in (0, 1, 2, 5):  # max, std, snr, cert
         np.testing.assert_allclose(np.asarray(got[i]),
                                    np.asarray(want[i]), rtol=2e-4,
                                    atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(want[3]))
     np.testing.assert_array_equal(np.asarray(got[4]), np.asarray(want[4]))
-    np.testing.assert_array_equal(np.asarray(got[5]), np.asarray(want[5]))
 
 
 def test_over_2pow24_series_warns_peak_inexact(monkeypatch):
