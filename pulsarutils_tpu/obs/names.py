@@ -308,6 +308,10 @@ METRIC_NAMES = {
         "by policy)",
     "putpu_persist_retries_total":
         "candidate persists re-attempted after OSError",
+    "putpu_prescan_packed_bytes_total":
+        "bytes of packed low-bit files the bad-channel pre-scan reduced "
+        "on the device (jit_prescan_moments); the float64 host loop "
+        "does not touch it",
     "putpu_push_dead_letter_total":
         "alert deliveries abandoned after retries and journaled to the "
         "push dead-letter file (labelled by subscriber)",
@@ -418,6 +422,9 @@ KERNEL_NAMES = {
         "program: the fused FDMT head run alone",
     "harmonic_sum":
         "kernel: harmonic sums and their peaks per spectrum row",
+    "prescan_moments":
+        "program: bit-unpack of one packed block of the bad-channel "
+        "pre-scan + per-channel sum and sum of squares in int32",
     "rescore_fused":
         "program: coarse sweep + seed selection + exact rescore in one "
         "dispatch",
