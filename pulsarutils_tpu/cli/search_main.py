@@ -72,6 +72,13 @@ def build_parser():
                              "tier searches the band delays of its own "
                              "sample time (default off: one sample time for "
                              "the whole range)")
+    parser.add_argument("--boxcar-max", type=int, default=None, metavar="N",
+                        help="widest boxcar of the scorer's ladder, a power "
+                             "of two in samples of the file (Heimdall's "
+                             "boxcar_max): 1, 2, 4, ..., N, in a tier at "
+                             "2^k samples 1 .. max(8, N / 2^k) of its own "
+                             "(default off: 1, 2, 4, 8 of whatever sample "
+                             "time a plan or tier works at)")
     parser.add_argument("--output-dir", default=None)
     parser.add_argument("--show-plots", action="store_true",
                         help="display each diagnostic figure interactively "
@@ -273,6 +280,7 @@ def main(args=None):
                 cut_outliers=opts.cut_outliers,
                 zero_dm=opts.zero_dm,
                 dm_tiers=opts.dm_tiers,
+                boxcar_max=opts.boxcar_max,
                 max_chunks=opts.max_chunks,
                 period_search=opts.period_search,
                 period_sigma_threshold=opts.period_sigma,

@@ -384,8 +384,9 @@ class CandidateStore:
 
         The window covers the dispersed track — ``[peak - pad,
         peak + span + pad]`` with ``span`` the band-crossing delay at
-        the candidate's DM — then block-sum decimates if still over
-        budget.  The passed ``info`` is untouched (a trimmed *copy* is
+        the candidate's DM and ``pad`` at least the hit's boxcar — then
+        block-sum decimates if still over budget.  The passed ``info``
+        is untouched (a trimmed *copy* is
         returned, or ``info`` itself when already under budget), with
         ``cutout_start``/``cutout_decim`` recording the window (see
         :class:`..pipeline.pulse_info.PulseInfo`).
@@ -422,7 +423,10 @@ class CandidateStore:
             span = int(delta_delay(float(best["DM"]), info.start_freq,
                                    info.start_freq + info.bandwidth)
                        / tsamp) + 1
-        pad = max(span // 2, 256)
+        # a hit matched at a boxcar wider than the pad starts a whole
+        # boxcar after ``peak`` at the latest: the window holds it
+        width = int(best["rebin"]) if "rebin" in table.colnames else 1
+        pad = max(span // 2, 256, width)
         lo = peak - pad
         hi = peak + span + pad
         if hi - lo >= nbin:  # window covers the whole chunk

@@ -52,6 +52,10 @@ METRIC_NAMES = {
     "putpu_beam_hits_total":
         "beam-chunks whose best S/N cleared the threshold (labelled by "
         "beam)",
+    "putpu_boxcar_windows_total":
+        "boxcar levels scored, one per level of the ladder per tier sweep "
+        "or flat sweep (4 with the default ladder; --boxcar-max adds "
+        "levels)",
     "putpu_bytes_readback_total":
         "bytes copied device -> host",
     "putpu_bytes_uploaded_total":

@@ -22,7 +22,28 @@ merge tables:
 3. the worst-case retention over pulse phase follows combinatorially from
    that scatter histogram and the scorer's block-boxcar geometry
    (widths 1, 2, 4, 8, non-sliding block sums — reference
-   ``pulsarutils/dedispersion.py:190-196``).
+   ``pulsarutils/dedispersion.py:190-196`` — or the longer ladder of
+   ``--boxcar-max``, ``ops/search.py:boxcar_ladder``).
+
+A ladder of any length (ISSUE 32)
+---------------------------------
+With the default ladder the exact score of a pulse wider than 8 samples
+decays like the sliding capture's, and the certificate's bound has its
+minimum at widths 1-3.  A longer ladder matches wide pulses at full
+strength, so the certificate's capture grows with it — windows of every
+scored level from 8 up at strides of half a window
+(:func:`~pulsarutils_tpu.ops.search.cert_wide_windows`, the one set the
+XLA scorer, the one-pass kernel and this module share) — and the bound
+is minimised over every pulse width up to twice the ladder's widest
+window (:func:`_cert_retention_from_offsets`: numerically to width 16, by
+a closed form beyond).  The guarantee is the one it was: under the
+signal model below, a pulse of any width up to the ladder's widest whose
+exact score reaches the floor shows a certificate score of at least
+``rho * floor - HYBRID_CERT_SLACK``.  At the HTRU plan the bound reads
+0.5554 (0.5728 in the last tier) for the default ladder and for
+``--boxcar-max 4096`` alike: the wide widths' minimum is 0.73
+(``docs/hybrid_calibration.md`` has the table), so the ``certifiable``
+floors do not move.
 
 Signal model (stated, not hidden): the bound covers **impulsive signals**
 — one coherent pulse per channel riding a dispersion track, width >=
@@ -98,13 +119,14 @@ import functools
 
 import numpy as np
 
-def _windows():
+def _windows(windows=None):
     """The detection scorer's boxcar widths — imported lazily from the
     single source of truth so the bounds can never silently diverge
-    from the scorer."""
-    from .search import SEARCH_WINDOWS
+    from the scorer.  ``None`` is the default ladder; a longer one is
+    checked by the scorer's own rule."""
+    from .search import check_windows
 
-    return SEARCH_WINDOWS
+    return check_windows(windows)
 
 #: absolute S/N slack in the certificate inequality
 #: ``coarse >= rho * exact - HYBRID_CERT_SLACK``: the allowance for the
@@ -236,41 +258,129 @@ def _retention_from_offsets(offsets, weights=None, min_width=1):
     return float(worst)
 
 
-@functools.lru_cache(maxsize=64)
-def _exact_best_phase(width):
+@functools.lru_cache(maxsize=256)
+def _exact_best_phase(width, windows=None):
     """Best block-boxcar score of a clean width-``width`` box (in total-
     mass units), over all windows AND phases — the soundness-relevant
-    denominator of the certificate ratio.  Depends on ``width`` alone,
-    so it is memoised (cert_retention evaluates it once per trial x
-    width otherwise — a multi-second host stall at multi-thousand-trial
-    configs)."""
-    box = np.full(width, 1.0 / width)
-    best = 0.0
-    for w in _windows():
-        # best phase: the box starts on a block boundary; blocks
-        # capture min(w, width)/width contiguously
-        for p in range(8):
-            bins = p + np.arange(width)
-            blocks = bins // w
-            cap = np.zeros(blocks[-1] + 1)
-            np.add.at(cap, blocks, box)
-            best = max(best, cap.max() / np.sqrt(w))
-    return best
+    denominator of the certificate ratio.  The best phase starts the box
+    on a boundary of every block: a window ``w`` then captures
+    ``min(w, width)`` of its ``width`` equal parts, and no window of
+    that width captures more at any phase.  Depends on ``width`` and
+    the ladder alone, so it is memoised (cert_retention evaluates it
+    once per trial x width otherwise — a multi-second host stall at
+    multi-thousand-trial configs)."""
+    parts = np.cumsum(np.full(width, 1.0 / width))
+    return max(parts[min(w, width) - 1] / np.sqrt(w)
+               for w in _windows(windows))
 
 
-def _cert_retention_from_offsets(offsets, max_width=16):
+def _wide_capture_worst_phase(mass, wide):
+    """Score of the half-stride captures (``search.cert_wide_windows``)
+    on ``mass`` at the pulse phase that serves them worst.
+
+    A window of width ``w`` starts at every multiple of ``w / 2``.  One
+    at least twice as long as the mass holds all of it whatever the
+    phase; a shorter one holds, at phase ``p``, the best of the sliding
+    sums whose start is ``-p`` modulo ``w / 2``.  The phases are those
+    of the widest such window's stride, which every narrower stride
+    divides.
+    """
+    n = len(mass)
+    total = float(mass.sum())
+    whole = [w for w in wide if w >= 2 * n]
+    floor = total / np.sqrt(whole[0]) if whole else 0.0
+    partial = [w for w in wide if w < 2 * n]
+    if not partial:
+        return floor
+    csum = np.concatenate([[0.0], np.cumsum(mass)])
+    phases = np.arange(partial[-1] // 2)
+    scores = np.full(len(phases), floor)
+    for w in partial:
+        half = w // 2
+        start = np.arange(-(w - 1), n)
+        sums = csum[np.clip(start + w, 0, n)] - csum[np.clip(start, 0, n)]
+        best = np.zeros(half)
+        np.maximum.at(best, start % half, sums)
+        scores = np.maximum(scores, best[(-phases) % half] / np.sqrt(w))
+    return float(scores.min())
+
+
+@functools.lru_cache(maxsize=32)
+def _wide_retention_table(windows, wide, first_width):
+    """What the closed-form bound of :func:`_cert_retention_from_offsets`
+    needs for the pulse widths ``first_width .. 2 x windows[-1]``, per
+    width ``W`` (rows) and capture window ``w`` (columns): the capture's
+    guaranteed score ``cap / sqrt(w)``, the score one sample of mean
+    track deviation costs it, ``1 / (W sqrt(w))``, and the exact
+    ladder's best-phase score of the box.
+
+    ``cap`` is the share of a width-``W`` box that SOME window of width
+    ``w`` holds at every phase: all of it from ``w >= 2W`` (starts every
+    ``w / 2``), ``w / W`` where a window fits inside the box at any
+    phase (``3w <= 2W``), and in between ``1/2 + w / (4W)`` — the two
+    windows that straddle the box hold ``W - u`` and ``u + w / 2`` of
+    its samples for an offset ``u``, and the larger of the two is least
+    where they are equal.  The sliding :data:`~.search.CERT_WINDOWS`
+    hold ``min(w, W) / W`` at any phase.
+    """
+    from .search import CERT_WINDOWS
+
+    widths = np.arange(first_width, 2 * windows[-1] + 1, dtype=np.float64)
+    W = widths[:, None]
+    w = np.asarray(wide, dtype=np.float64)[None, :]
+    cap = np.where(w >= 2 * W, 1.0,
+                   np.where(3 * w <= 2 * W, w / W, 0.5 + w / (4 * W)))
+    ws = np.asarray(CERT_WINDOWS, dtype=np.float64)[None, :]
+    cap = np.concatenate([cap, np.minimum(ws, W) / W], axis=1)
+    w_all = np.concatenate([w, ws], axis=1)
+    ladder = np.asarray(windows, dtype=np.float64)[None, :]
+    exact = (np.minimum(ladder, W) / W / np.sqrt(ladder)).max(axis=1)
+    table = (cap / np.sqrt(w_all), 1.0 / (W * np.sqrt(w_all)), exact)
+    for arr in table:  # shared cache objects: fail loudly
+        arr.setflags(write=False)
+    return table
+
+
+def _cert_retention_from_offsets(offsets, max_width=16, windows=None,
+                                 wide=()):
     """Worst-case ``cert_score / exact_snr`` ratio for one trial's track.
 
-    The certificate numerator is the *sliding* window-2/4 capture
-    (:func:`~pulsarutils_tpu.ops.search.cert_profile_scores`) — phase
-    invariant, so no worst-phase minimisation applies to it; the
-    denominator is the exact kernel's best detection score of the same
-    pulse, taken at the pulse's *best* phase (the soundness-relevant
-    worst case: the exact sweep scoring the pulse as well as it possibly
-    can while the coarse row still must flag it).  Minimised over pulse
-    widths 1..``max_width``; beyond the scorer's largest block (8) both
-    sides decay ~1/W and the ratio tends to a constant ~0.7, so the
-    minimum always sits at small widths.
+    The denominator is the exact kernel's best detection score of the
+    pulse over the ladder ``windows``, taken at the pulse's *best* phase
+    (the soundness-relevant worst case: the exact sweep scoring the
+    pulse as well as it possibly can while the coarse row still must
+    flag it).  The numerator is the certificate's capture
+    (:func:`~pulsarutils_tpu.ops.search.cert_profile_scores`) of the
+    same pulse scattered by the track's deviations: the *sliding*
+    window-2/3/4 capture — phase invariant, so no worst-phase
+    minimisation applies to it — and, for a ladder longer than the
+    default, the half-stride captures ``wide``
+    (:func:`~pulsarutils_tpu.ops.search.cert_wide_windows`) at the phase
+    that serves them worst.
+
+    **The default ladder** (no ``wide``): minimised over pulse widths
+    1..``max_width``.  Beyond the scorer's largest block (8) both sides
+    decay ~1/W and the ratio tends to a constant ~0.7, so the minimum
+    sits at small widths.
+
+    **A longer ladder**: the exact score no longer decays beyond 8, and
+    a sliding capture of at most 4 samples alone would keep ``2 /
+    sqrt(W)`` of a width-``W`` pulse (0.09 at 512); the half-stride
+    captures are what keeps it bounded.  Minimised over every pulse
+    width the ladder can match and twice beyond, ``1 .. 2 x
+    windows[-1]``: widths up to ``max_width`` numerically from the
+    track's own scatter as above; wider ones from the closed form of
+    :func:`_wide_retention_table`, which gives every capture window the
+    share of a clean box it holds at ANY phase, less what the scatter
+    can cost it.  A box of ``W`` equal parts moved by one sample changes
+    any window's share by at most ``1 / W``, and the scattered pulse is
+    a mixture of such boxes, so a window holds at least its clean share
+    less ``D / W``, ``D`` the mean absolute deviation of the track about
+    its median.  Without scatter the closed form's minimum is 0.75, at
+    widths that are powers of two (the box straddles two windows of its
+    own width and the next level holds all of it at ``1/sqrt(2)``);
+    with the tree's scatter (``D`` about a sample) the overall minimum
+    still sits at widths 1-3, where it sat before.
     """
     offsets = np.asarray(offsets, dtype=np.int64)
     offsets = offsets - offsets.min()
@@ -286,12 +396,23 @@ def _cert_retention_from_offsets(offsets, max_width=16):
         kernel = np.ones(w)
         return np.convolve(mass, kernel).max()
 
+    ladder = _windows(windows)
+    if wide:
+        max_width = min(max_width, 2 * ladder[-1])
     worst = np.inf
     for width in range(1, max_width + 1):
         mass = np.convolve(h, np.full(width, 1.0 / width))
         cert = max(sliding_capture(mass, w) / np.sqrt(w)
                    for w in CERT_WINDOWS)
-        worst = min(worst, cert / _exact_best_phase(width))
+        if wide:
+            cert = max(cert, _wide_capture_worst_phase(mass, wide))
+        worst = min(worst, cert / _exact_best_phase(width, windows))
+    if wide and max_width < 2 * ladder[-1]:
+        score, per_sample, exact = _wide_retention_table(
+            ladder, tuple(wide), max_width + 1)
+        deviation = float(np.abs(offsets - np.median(offsets)).mean())
+        cert = (score - deviation * per_sample).max(axis=1)
+        worst = min(worst, float((cert / exact).min()))
     return float(worst)
 
 
@@ -322,10 +443,20 @@ def _track_deviations(nchan, trial_dms, start_freq, bandwidth, sample_time,
 
 @functools.lru_cache(maxsize=32)
 def _retention_cached(nchan, dms_key, start_freq, bandwidth, sample_time,
-                      nsamples, min_width, cert):
+                      nsamples, min_width, cert, windows=None):
     trial_dms = np.frombuffer(dms_key, dtype=np.float64)
     dev = _track_deviations(nchan, trial_dms, start_freq, bandwidth,
                             sample_time, nsamples)
+    if cert and windows is not None:
+        from ..utils.logging_utils import budget_bucket
+        from .search import cert_wide_windows
+
+        wide = cert_wide_windows(windows, nsamples)
+        # the host's share of a longer ladder: its captures at the worst
+        # phase and the closed form beyond max_width
+        with budget_bucket("search/cert_wide"):
+            return np.asarray([_cert_retention_from_offsets(
+                d, windows=windows, wide=wide) for d in dev])
     rho = np.empty(len(trial_dms))
     for j in range(len(trial_dms)):
         if cert:
@@ -357,31 +488,40 @@ def coarse_retention(nchan, trial_dms, start_freq, bandwidth, sample_time,
 
 
 def cert_retention(nchan, trial_dms, start_freq, bandwidth, sample_time,
-                   nsamples):
+                   nsamples, windows=None):
     """Per-trial worst-case ``cert_score / exact_snr`` retention (the
     sliding certificate scorer as numerator — phase-invariant, so much
     tighter than :func:`coarse_retention` at the same track scatter:
-    ~0.6 vs ~0.44 at the benchmark config).  Returns ``(ndm,)``."""
+    ~0.6 vs ~0.44 at the benchmark config).  ``windows`` is the
+    detection ladder (``None``: the default four): the exact score in
+    the denominator, the half-stride captures in the numerator and the
+    pulse widths minimised over all follow it, cut off for ``nsamples``
+    as the scorer is.  Returns ``(ndm,)``."""
+    from .search import SEARCH_WINDOWS, scored_windows
+
     trial_dms = np.ascontiguousarray(trial_dms, dtype=np.float64)
+    ladder = scored_windows(windows, nsamples)
     return _retention_cached(int(nchan), trial_dms.tobytes(),
                              float(start_freq), float(bandwidth),
-                             float(sample_time), int(nsamples), 1, True)
+                             float(sample_time), int(nsamples), 1, True,
+                             None if ladder == SEARCH_WINDOWS else ladder)
 
 
 def retention_bound(nchan, trial_dms, start_freq, bandwidth, sample_time,
-                    nsamples, min_width=1, cert=False):
+                    nsamples, min_width=1, cert=False, windows=None):
     """``min`` over trials of :func:`coarse_retention` (or
-    :func:`cert_retention` with ``cert=True``) — the single per-config
-    constant the hybrid's margin and certificate use."""
-    fn = cert_retention if cert else functools.partial(coarse_retention,
-                                                       min_width=min_width)
+    :func:`cert_retention` with ``cert=True``, for the ladder
+    ``windows``) — the single per-config constant the hybrid's margin
+    and certificate use."""
+    fn = (functools.partial(cert_retention, windows=windows) if cert
+          else functools.partial(coarse_retention, min_width=min_width))
     return float(fn(nchan, trial_dms, start_freq, bandwidth, sample_time,
                     nsamples).min())
 
 
 def fused_cert_params(nchan, trial_dms, start_freq, bandwidth, sample_time,
                       nsamples, snr_floor=None, rho_cert=None,
-                      cert_slack=None):
+                      cert_slack=None, windows=None):
     """The ``(rho, slack, floor)`` float32 runtime operand of the fused
     hybrid programs — ONE place constructs it so the single-device
     (``ops/search.py:_fused_hybrid_seed_kernel``) and mesh
@@ -403,7 +543,7 @@ def fused_cert_params(nchan, trial_dms, start_freq, bandwidth, sample_time,
         with budget_bucket("search/cert_floor"):
             rho_val = retention_bound(nchan, trial_dms, start_freq,
                                       bandwidth, sample_time, nsamples,
-                                      cert=True)
+                                      cert=True, windows=windows)
     slack_val = (HYBRID_CERT_SLACK if cert_slack is None
                  else float(cert_slack))
     floor_val = np.inf if snr_floor is None else float(snr_floor)

@@ -650,7 +650,8 @@ def head_active(nchan, start_freq, bandwidth, max_delay, n_lo, t):
 def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
                   use_pallas, interpret, n_lo=0, with_scores=False,
                   with_plane=True, t_orig=None, with_cert=False, *,
-                  use_head=None, use_score=None, deep_pair=None):
+                  use_head=None, use_score=None, deep_pair=None,
+                  windows=None):
     """The traceable (un-jitted) transform body: DM-pruned merges
     [+ scoring].  :func:`_build_transform` wraps it in ``jax.jit``;
     the hybrid search composes it with its fused seed-rescore program
@@ -670,7 +671,9 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
     the time axis.  ``use_head``/``use_score``/``deep_pair`` are the
     tests' seam — ``None`` resolves, a bool forces the variant so a
     parity test can build both sides in one process; nothing outside
-    ``tests/`` passes one.
+    ``tests/`` passes one.  ``windows`` is the scorer's ladder (static;
+    ``None`` = the default four): the one-pass scorer runs where one of
+    its tiles is a multiple of the ladder's widest window.
     """
     import jax.numpy as jnp
 
@@ -743,14 +746,16 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
         if not with_scores:
             return plane
         from .score_pallas import pick_score_tile
-        from .search import score_profiles_chunked
+        from .search import score_profiles_chunked, scored_windows
 
         # one-pass Pallas scorer: reads the plane once and accumulates
         # per-row partials in VMEM — the XLA chunked scorer
         # materialises ~9 GB of mean-sub/pyramid/sliding temps at the
         # 513 x 1M coarse plane and measured 0.17 s standalone against
         # this kernel's ~0.02 s.
-        if use_score and not pick_score_tile(plane.shape[1]):
+        widest = scored_windows(windows, plane.shape[1])[-1]
+        score_tile = pick_score_tile(plane.shape[1], widest)
+        if use_score and not score_tile:
             import warnings
 
             # trace-time, once per shape
@@ -758,18 +763,20 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
                 f"one-pass scorer unavailable: no supported tile "
                 f"divides T={plane.shape[1]}; falling back to the XLA "
                 "chunked scorer", stacklevel=2)
-        if use_score and pick_score_tile(plane.shape[1]):
+        if use_score and score_tile:
             from .score_pallas import score_plane_pallas
 
             stacked = score_plane_pallas(plane, with_cert=with_cert,
-                                         interpret=interpret)
+                                         interpret=interpret,
+                                         windows=windows)
         else:
             # row-chunked scoring bounds the scorer's HBM temps (see
             # score_profiles_chunked) while still emitting ONE (5, ndm)
             # array ((6, ndm) with the hybrid's certificate row) -> one
             # host readback
             stacked = score_profiles_chunked(plane, jnp,
-                                             with_cert=with_cert)
+                                             with_cert=with_cert,
+                                             windows=windows)
         return (stacked, plane) if with_plane else stacked
 
     return fn
@@ -779,7 +786,8 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
 def _build_transform(nchan, start_freq, bandwidth, max_delay, t, t_tile,
                      use_pallas, interpret, n_lo=0, with_scores=False,
                      with_plane=True, t_orig=None, with_cert=False, *,
-                     use_head=None, use_score=None, deep_pair=None):
+                     use_head=None, use_score=None, deep_pair=None,
+                     windows=None):
     """Jitted wrapper of :func:`_transform_fn` (same signature)."""
     import jax
 
@@ -789,7 +797,7 @@ def _build_transform(nchan, start_freq, bandwidth, max_delay, t, t_tile,
                                  with_plane=with_plane, t_orig=t_orig,
                                  with_cert=with_cert, use_head=use_head,
                                  use_score=use_score,
-                                 deep_pair=deep_pair))
+                                 deep_pair=deep_pair, windows=windows))
 
 
 # ---------------------------------------------------------------------------

@@ -171,10 +171,12 @@ class DMTier:
     dm_lo: float           # DM interval this tier answers for
     dm_hi: float
     trial_dms: np.ndarray  # its trial grid (float64)
+    # its boxcar ladder, in its own samples (ops/search.py:boxcar_ladder)
+    windows: tuple = (1, 2, 4, 8)
 
 
 def dm_tier_plan(nchan, dmmin, dmmax, start_freq, bandwidth, sample_time,
-                 foff):
+                 foff, boxcar_max=None):
     """The tiers of a smearing-tiered search of ``dmmin..dmmax``.
 
     Tier ``k`` works at ``2^k * sample_time`` and **ends** at the DM
@@ -196,12 +198,23 @@ def dm_tier_plan(nchan, dmmin, dmmax, start_freq, bandwidth, sample_time,
     Tiers below ``dmmin`` are left out, so the first tier's
     ``downsample`` need not be 1.
 
+    Every tier carries its boxcar ladder, in its own samples
+    (:func:`~pulsarutils_tpu.ops.search.boxcar_ladder`): the default
+    four, or with ``boxcar_max`` (samples of the plan's sample time)
+    ``1 .. max(8, boxcar_max / 2^k)``, so that every tier reaches the
+    same width.
+
     >>> tiers = dm_tier_plan(1024, 0.0, 1000.0, 1182.0, 400.0, 64e-6, 0.390625)
     >>> [(t.downsample, len(t.trial_dms)) for t in tiers]
     [(1, 1069), (2, 534), (4, 534), (8, 534), (16, 534), (32, 107)]
     >>> round(tiers[0].dm_hi, 2)
     52.1
+    >>> [len(t.windows) for t in dm_tier_plan(
+    ...     1024, 0.0, 1000.0, 1182.0, 400.0, 64e-6, 0.390625, 4096)]
+    [13, 12, 11, 10, 9, 8]
     """
+    from .search import boxcar_ladder
+
     f0 = float(start_freq)
     f1 = f0 + float(bandwidth)
     dmmin, dmmax = float(dmmin), float(dmmax)
@@ -230,7 +243,8 @@ def dm_tier_plan(nchan, dmmin, dmmax, start_freq, bandwidth, sample_time,
         trial_dm = trial_n * tsamp_k / DM_DELAY_CONST / unit
         tiers.append(DMTier(downsample=2 ** k, sample_time=tsamp_k,
                             dm_lo=lo, dm_hi=hi,
-                            trial_dms=np.asarray(trial_dm, np.float64)))
+                            trial_dms=np.asarray(trial_dm, np.float64),
+                            windows=boxcar_ladder(boxcar_max, 2 ** k)))
         if last:
             return tiers
         lo = hi

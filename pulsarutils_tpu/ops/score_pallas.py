@@ -46,31 +46,56 @@ import functools
 
 import numpy as np
 
-#: scratch slot indices (each slot is one (8, 128) f32 tile per row block)
+#: fixed scratch slots (each slot is one (8, 128) f32 tile per row block);
+#: the per-level and per-capture slots follow, see :func:`_slots`
 _C, _SUM, _SSQ = 0, 1, 2
 _MAX1, _ARG1 = 3, 4
-_SQ2, _MAX2, _ARG2 = 5, 6, 7
-_SQ4, _MAX4, _ARG4 = 8, 9, 10
-_SQ8, _MAX8, _ARG8 = 11, 12, 13
-_CM2, _CM3, _CM4 = 14, 15, 16
-_FIRST3, _LAST3 = 17, 18
-_NSLOT = 19
 
 #: preferred time-tile widths (largest dividing T wins; all multiples of
-#: 8 so width-8 blocks never cross a tile boundary)
+#: 8 so width-8 blocks never cross a tile boundary — a longer ladder
+#: takes the tiles that are multiples of its widest window)
 _T_BLKS = (16384, 8192, 4096, 2048, 1024)
 
 
-def pick_score_tile(t):
-    """Largest supported time tile dividing ``t`` (0 if none)."""
+def pick_score_tile(t, widest=8):
+    """Largest supported time tile dividing ``t`` that is a multiple of
+    the ladder's widest window (0 if none)."""
     for t_blk in _T_BLKS:
-        if t % t_blk == 0:
+        if t % t_blk == 0 and t_blk % widest == 0:
             return t_blk
     return 0
 
 
+def _slots(n_levels, n_wide):
+    """The scratch layout of a ladder of ``n_levels`` windows with
+    ``n_wide`` half-stride certificate captures.
+
+    Returns ``(level, cert, wide, n_slots)``: ``level[j - 1]`` is the
+    ``(sumsq, max, argmax)`` triple of level ``j >= 1`` (width ``2^j``),
+    ``cert`` the sliding certificate's ``(cm2, cm3, cm4, first3,
+    last3)``, ``wide[i]`` the ``(max, last half block of the previous
+    tile)`` pair of the i-th capture.  For the default ladder the first
+    19 slots are the ones this kernel has always had.
+    """
+    base = _ARG1 + 1
+    level = [tuple(base + 3 * j + k for k in range(3))
+             for j in range(n_levels - 1)]
+    base += 3 * (n_levels - 1)
+    cert = tuple(range(base, base + 5))
+    base += 5
+    wide = [(base + 2 * i, base + 2 * i + 1) for i in range(n_wide)]
+    return level, cert, wide, base + 2 * n_wide
+
+
 @functools.lru_cache(maxsize=16)
-def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret):
+def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret,
+                        n_levels=4, wide_from=None):
+    """The one-pass kernel for a ladder of ``n_levels`` doubling windows.
+
+    ``wide_from`` (``None``: no such capture) is the level of the first
+    half-stride certificate capture; every level from it to the last has
+    one (:func:`..ops.search.cert_wide_windows`).
+    """
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -80,6 +105,12 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret):
     n_rb = rows_p // 8
     BIG = np.float32(1e18)
     NEG = np.float32(-1e30)
+    wide_levels = (list(range(wide_from, n_levels))
+                   if with_cert and wide_from is not None else [])
+    level_slots, cert_slots, wide_slots, n_slot = _slots(
+        n_levels, len(wide_levels))
+    _CM2, _CM3, _CM4, _FIRST3, _LAST3 = cert_slots
+    assert n_levels >= 4 and t_blk % (1 << (n_levels - 1)) == 0
 
     def lroll(v, s):
         # left-rotate by s lanes: result[i] = v[(i + s) mod L]
@@ -102,10 +133,13 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret):
             c = jnp.sum(raw, axis=1, keepdims=True) / jnp.float32(t_blk)
             st_ref[_C] = jnp.broadcast_to(c, (8, 128))
             zero = jnp.zeros((8, 128), jnp.float32)
-            for s in (_SUM, _SSQ, _ARG1, _ARG2, _ARG4, _ARG8,
-                      _SQ2, _SQ4, _SQ8):
+            for s in ([_SUM, _SSQ, _ARG1]
+                      + [sl[k] for sl in level_slots for k in (0, 2)]
+                      + [sl[1] for sl in wide_slots]):
                 st_ref[s] = zero
-            for s in (_MAX1, _MAX2, _MAX4, _MAX8, _CM2, _CM3, _CM4):
+            for s in ([_MAX1, _CM2, _CM3, _CM4]
+                      + [sl[1] for sl in level_slots]
+                      + [sl[0] for sl in wide_slots]):
                 st_ref[s] = jnp.full((8, 128), NEG)
 
         c = st_ref[_C][:, 0:1]
@@ -151,10 +185,6 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret):
         st_ref[_SUM] += jnp.sum(x, axis=1, keepdims=True)
         st_ref[_SSQ] += jnp.sum(x * x, axis=1, keepdims=True)
 
-        s2 = x + lroll(x, 1)
-        s4 = s2 + lroll(s2, 2)
-        s8 = s4 + lroll(s4, 4)
-
         def upd(vals, mask, max_slot, arg_slot, sq_slot):
             v = jnp.where(mask, vals, NEG)
             tile_max = jnp.max(v, axis=1, keepdims=True)
@@ -174,11 +204,53 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret):
                     jnp.where(mask, vals * vals, 0.0), axis=1,
                     keepdims=True)
 
+        def wide_capture(below, sums, w, max_slot, last_slot):
+            """Windows of width ``w`` at strides of ``w / 2``: the ones
+            inside this tile from ``sums`` (every lane's width-``w``
+            sum), the one across the tile's start from the previous
+            tile's last half block and this tile's first."""
+            half = w // 2
+            inside = (lane % half == 0) & (lane <= t_blk - w)
+            run = jnp.maximum(
+                st_ref[max_slot][:, 0:1],
+                jnp.max(jnp.where(inside, sums, NEG), axis=1,
+                        keepdims=True))
+            across = st_ref[last_slot][:, 0:1] + below[:, 0:1]
+            run = jnp.where(i_t > 0, jnp.maximum(run, across), run)
+            st_ref[max_slot] = jnp.broadcast_to(run, (8, 128))
+            # the last half block sits on a 128-lane boundary, or in the
+            # tile's last 128 lanes
+            if half >= 128:
+                last = below[:, t_blk - half:t_blk - half + 128][:, 0:1]
+            else:
+                last = jnp.max(
+                    jnp.where(lane128 == 128 - half,
+                              below[:, t_blk - 128:], NEG), axis=1,
+                    keepdims=True)
+            st_ref[last_slot] = jnp.broadcast_to(last, (8, 128))
+
+        # level j's sums of width 2^j at every lane: the sums of width
+        # 2^(j-1) plus themselves half a window on; at lanes that are
+        # multiples of 2^j these are the pyramid's block sums, same adds.
+        # The default ladder's three doublings come before any reduction,
+        # as this kernel has always had them: with a reduction between
+        # two of them the v5e compiler gives each its own pass over the
+        # tile (17.4 against 13.5 ms a call at 1,069 x 2^19, PR 32)
+        levels = [x]
+        for j in range(1, 4):
+            levels.append(levels[-1] + lroll(levels[-1], 1 << (j - 1)))
+        s2, s4 = levels[1], levels[2]
         true_mask = lane >= 0
         upd(x, true_mask, _MAX1, _ARG1, None)
-        upd(s2, lane % 2 == 0, _MAX2, _ARG2, _SQ2)
-        upd(s4, lane % 4 == 0, _MAX4, _ARG4, _SQ4)
-        upd(s8, lane % 8 == 0, _MAX8, _ARG8, _SQ8)
+        for j in range(1, n_levels):
+            w = 1 << j
+            if j == len(levels):
+                levels.append(levels[-1] + lroll(levels[-1], w // 2))
+            sq_slot, max_slot, arg_slot = level_slots[j - 1]
+            upd(levels[j], lane % w == 0, max_slot, arg_slot, sq_slot)
+            if j in wide_levels:
+                wide_capture(levels[j - 1], levels[j], w,
+                             *wide_slots[wide_levels.index(j)])
 
         if with_cert:
             # sliding cert maxima over windows fully inside this tile
@@ -216,19 +288,19 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret):
             best_snr = jnp.zeros((8, 1), jnp.float32)
             best_w = jnp.zeros((8, 1), jnp.float32)
             best_p = jnp.zeros((8, 1), jnp.float32)
-            for w, max_slot, arg_slot, sq_slot in (
-                    (1, _MAX1, _ARG1, None),
-                    (2, _MAX2, _ARG2, _SQ2),
-                    (4, _MAX4, _ARG4, _SQ4),
-                    (8, _MAX8, _ARG8, _SQ8)):
+            level_std = {}
+            for j in range(n_levels):
+                w = 1 << j
                 wm = jnp.float32(w) * m
-                if sq_slot is None:
-                    var_w, mx = var, maxv
+                if j == 0:
+                    var_w, mx, arg_slot = var, maxv, _ARG1
                 else:
+                    sq_slot, max_slot, arg_slot = level_slots[j - 1]
                     nb = tt / jnp.float32(w)
                     var_w = st_ref[sq_slot][:, 0:1] / nb - wm * wm
                     mx = st_ref[max_slot][:, 0:1] - wm
-                snr_w = mx / jnp.sqrt(jnp.maximum(var_w, 1e-30))
+                level_std[j] = jnp.sqrt(jnp.maximum(var_w, 1e-30))
+                snr_w = mx / level_std[j]
                 better = snr_w > best_snr
                 best_snr = jnp.where(better, snr_w, best_snr)
                 best_w = jnp.where(better, jnp.float32(w), best_w)
@@ -246,6 +318,10 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret):
                 cert = jnp.maximum(
                     cert, (st_ref[_CM4][:, 0:1] - 4.0 * m) / (
                         denom * jnp.float32(2.0)))
+                for j, (max_slot, _) in zip(wide_levels, wide_slots):
+                    cert = jnp.maximum(
+                        cert, (st_ref[max_slot][:, 0:1]
+                               - jnp.float32(1 << j) * m) / level_std[j])
                 cols.append(cert)
 
             out = jnp.zeros((8, 128), jnp.float32)
@@ -259,14 +335,15 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret):
         in_specs=[pl.BlockSpec((8, t_blk), lambda i_r, i_t: (i_r, i_t))],
         out_specs=pl.BlockSpec((8, 128), lambda i_r, i_t: (i_r, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, 128), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((_NSLOT, 8, 128), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n_slot, 8, 128), jnp.float32)],
         interpret=bool(interpret),
         name="score_rows",
     )
     return call
 
 
-def _kernel_scores(rows_p, t, t_blk, with_cert, interpret, sub):
+def _kernel_scores(rows_p, t, t_blk, with_cert, interpret, sub,
+                   n_levels=4, wide_from=None):
     """Run the one-pass kernel on the 8-aligned row block ``sub``.
 
     Split out of :func:`score_plane_pallas` so tests can stub the
@@ -275,18 +352,22 @@ def _kernel_scores(rows_p, t, t_blk, with_cert, interpret, sub):
     """
     import jax.numpy as jnp
 
-    return _build_score_kernel(rows_p, t, t_blk, with_cert, interpret)(
+    return _build_score_kernel(rows_p, t, t_blk, with_cert, interpret,
+                               n_levels, wide_from)(
         jnp.asarray(sub, jnp.float32))
 
 
-def score_plane_pallas(plane, with_cert=False, interpret=False):
+def score_plane_pallas(plane, with_cert=False, interpret=False,
+                       windows=None):
     """One-pass scores of ``plane`` — drop-in for
     :func:`..ops.search.score_profiles_chunked` on tile-friendly shapes.
 
     Returns the stacked ``(5, rows)`` float32 array (``(6, rows)`` with
     ``with_cert``: the sliding certificate row appended).  Raises
     ``ValueError`` when no supported tile divides the time axis — the
-    caller falls back to the XLA scorer.
+    caller falls back to the XLA scorer.  ``windows`` is the ladder
+    (static; ``None`` = the default four): the kernel is built for its
+    number of scored levels, on a tile that is a multiple of the widest.
 
     Peak indices are accumulated as float32 in the kernel (the global
     argmax slot is ``tile_arg + t_blk * i_t``), exact only below 2^24
@@ -303,12 +384,16 @@ def score_plane_pallas(plane, with_cert=False, interpret=False):
     """
     import jax.numpy as jnp
 
-    from .search import warn_peak_exactness
+    from .search import (cert_wide_windows, scored_windows,
+                         warn_peak_exactness)
 
     rows, t = plane.shape
-    t_blk = pick_score_tile(t)
+    scored = scored_windows(windows, t)
+    wide = cert_wide_windows(windows, t)
+    t_blk = pick_score_tile(t, scored[-1])
     if t_blk == 0:
-        raise ValueError(f"no supported score tile divides T={t}")
+        raise ValueError(f"no supported score tile divides T={t} in "
+                         f"multiples of the widest window {scored[-1]}")
     rows8 = (rows // 8) * 8
     if rows8 == rows:
         # remainder rows (below) route through the XLA stacked scorer,
@@ -317,12 +402,15 @@ def score_plane_pallas(plane, with_cert=False, interpret=False):
         warn_peak_exactness(t)
     parts = []
     if rows8:
-        out = _kernel_scores(rows8, t, t_blk, bool(with_cert),
-                             bool(interpret), plane[:rows8])
+        out = _kernel_scores(
+            rows8, t, t_blk, bool(with_cert), bool(interpret),
+            plane[:rows8], n_levels=len(scored),
+            wide_from=scored.index(wide[0]) if wide else None)
         parts.append(out[:, :6 if with_cert else 5].T)
     if rows8 != rows:
         from .search import score_profiles_chunked
 
         parts.append(score_profiles_chunked(plane[rows8:], jnp,
-                                            with_cert=with_cert))
+                                            with_cert=with_cert,
+                                            windows=windows))
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)

@@ -44,19 +44,95 @@ from ..tuning.geometry import PLAN_CACHE_SIZE, counted_plan_cache
 from ..utils.logging_utils import budget_bucket, budget_count
 from ..utils.table import ResultTable
 
-#: boxcar widths tried by the scorer (reference ``dedispersion.py:190-191``)
+#: boxcar widths tried by the scorer (reference ``dedispersion.py:190-191``):
+#: the default ladder.  ``--boxcar-max`` continues it in doubling steps
+#: (:func:`boxcar_ladder`); every scorer takes the ladder as a static
+#: tuple, ``None`` meaning this one.
 SEARCH_WINDOWS = (1, 2, 4, 8)
+
+#: a level above the default four is scored only where it has at least
+#: this many blocks: its ``std`` would otherwise be an estimate from too
+#: few values.  The default four are scored whatever the length is.
+MIN_WIDE_BLOCKS = 64
 
 #: sliding windows of the hybrid's certificate scorer.  SOUNDNESS
 #: COUPLING: :func:`cert_profile_scores` unrolls exactly these widths
-#: structurally, and ``certify._cert_retention_from_offsets`` computes
-#: the retention bound over the same set — change all three together or
-#: the noise certificate's bound no longer describes the scorer
-#: (``tests/test_certify.py`` pins the coupling).
+#: structurally (plus :func:`cert_wide_windows` of a longer ladder), the
+#: one-pass kernel (``ops/score_pallas.py``) captures the same set, and
+#: ``certify._cert_retention_from_offsets`` computes the retention bound
+#: over it — change all of them together or the noise certificate's bound
+#: no longer describes the scorer (``tests/test_certify.py`` pins the
+#: coupling).
 CERT_WINDOWS = (2, 3, 4)
 
 
-def score_profiles(plane, xp=np):
+def boxcar_ladder(boxcar_max=None, downsample=1):
+    """The detection ladder of a plan (or tier) that works at
+    ``downsample`` x the file's sample time.
+
+    ``boxcar_max`` (Heimdall's name and meaning: the widest boxcar, a
+    power of two in samples of the file) absent gives
+    :data:`SEARCH_WINDOWS`.  Given, the ladder is ``1, 2, 4, ...,
+    max(8, boxcar_max / downsample)`` samples of the working sample
+    time: every tier reaches the same width in samples of the file and
+    none loses a width of the default ladder.
+
+    >>> boxcar_ladder()
+    (1, 2, 4, 8)
+    >>> boxcar_ladder(64), boxcar_ladder(64, 4), boxcar_ladder(64, 32)
+    ((1, 2, 4, 8, 16, 32, 64), (1, 2, 4, 8, 16), (1, 2, 4, 8))
+    """
+    if boxcar_max is None:
+        return SEARCH_WINDOWS
+    n = int(boxcar_max)
+    if n != boxcar_max or n < SEARCH_WINDOWS[-1] or n & (n - 1):
+        raise ValueError(f"boxcar_max={boxcar_max!r}: expected a power of "
+                         f"two of at least {SEARCH_WINDOWS[-1]} samples")
+    widest = max(SEARCH_WINDOWS[-1], n // max(int(downsample), 1))
+    return tuple(1 << j for j in range(widest.bit_length()))
+
+
+def check_windows(windows):
+    """``windows`` as the scorers take it: ``None`` is the default
+    ladder; anything else must continue it in doubling steps (the
+    incremental pyramid, the one-pass kernel's tiles and the
+    certificate's bound all assume so)."""
+    if windows is None:
+        return SEARCH_WINDOWS
+    windows = tuple(int(w) for w in windows)
+    if (len(windows) < len(SEARCH_WINDOWS)
+            or windows != tuple(1 << j for j in range(len(windows)))):
+        raise ValueError(f"windows={windows!r}: expected 1, 2, 4, 8 "
+                         "continued in doubling steps")
+    return windows
+
+
+def scored_windows(windows, nsamples):
+    """The levels of ``windows`` a series of ``nsamples`` is scored at
+    (the :data:`MIN_WIDE_BLOCKS` cut-off) — one rule for the scorers,
+    the certificate's capture and its bound."""
+    return tuple(w for w in check_windows(windows)
+                 if w <= SEARCH_WINDOWS[-1]
+                 or int(nsamples) // w >= MIN_WIDE_BLOCKS)
+
+
+def cert_wide_windows(windows, nsamples):
+    """Widths of the certificate's half-stride captures for a ladder.
+
+    The default ladder has none (its certificate is the sliding
+    :data:`CERT_WINDOWS` alone, as ever).  A longer ladder adds one
+    capture per scored level from 8 up: windows of that width at
+    strides of half of it, i.e. adjacent pairs of the level below —
+    the set whose retention ``certify`` bounds below for every pulse
+    width the ladder can match.
+    """
+    scored = scored_windows(windows, nsamples)
+    if len(scored) <= len(SEARCH_WINDOWS):
+        return ()
+    return tuple(w for w in scored if w >= SEARCH_WINDOWS[-1])
+
+
+def score_profiles(plane, xp=np, windows=None):
     """Score a block of dedispersed series ``(ndm, T)``.
 
     Returns ``(maxvalues, stds, best_snrs, best_windows, best_peaks)`` per
@@ -77,10 +153,15 @@ def score_profiles(plane, xp=np):
     catastrophically-cancelling raw block sums on planes with a large
     DC offset (measured S/N errors of several units at baseline ~1e7
     in float32 — code-review r4).
+
+    ``windows`` is the ladder (static; ``None`` =
+    :data:`SEARCH_WINDOWS`): level ``j`` holds the block sums of width
+    ``2^j`` at offsets that are multiples of it, ``snr_j = max / std`` of
+    the level, the smallest ``j`` winning ties; levels cut off by
+    :func:`scored_windows` are not scored.
     """
-    assert SEARCH_WINDOWS == (1, 2, 4, 8), \
-        "the incremental pyramid assumes doubling windows"
     plane = xp.asarray(plane)
+    windows = scored_windows(windows, plane.shape[1])
     if not xp.issubdtype(plane.dtype, xp.floating):
         # integer-accumulated sweep plane (packed low-bit path): every
         # value is an exact integer below 2^24 (io/lowbit.accum_dtype's
@@ -95,7 +176,7 @@ def score_profiles(plane, xp=np):
     best_windows = xp.zeros(x.shape[0], dtype=xp.int32)
     best_peaks = xp.zeros(x.shape[0], dtype=xp.int32)
     reb = x
-    for window in SEARCH_WINDOWS:
+    for window in windows:
         if window > 1:
             reb = block_sum_time(reb, 2, xp=xp)
         snr = reb.max(axis=1) / reb.std(axis=1)
@@ -131,22 +212,23 @@ def warn_peak_exactness(nsamples, stacklevel=3):
             stacklevel=stacklevel)
 
 
-def score_profiles_stacked(plane, xp=np):
+def score_profiles_stacked(plane, xp=np, windows=None):
     """:func:`score_profiles` packed into ONE ``(5, ndm)`` float array.
 
     Every array fetched is a host sync with the device; stacking the
     per-trial score vectors device-side makes the whole search's host
     readback a single transfer.  Row order:
-    ``max, std, snr, window, peak`` (windows are 1..8 and peaks are
-    sample indices < 2^24 — both exact in float32).
+    ``max, std, snr, window, peak`` (windows are powers of two up to
+    the ladder's widest and peaks are sample indices < 2^24 — both
+    exact in float32).
     """
     warn_peak_exactness(plane.shape[1])
-    scores = score_profiles(plane, xp=xp)
+    scores = score_profiles(plane, xp=xp, windows=windows)
     dtype = scores[0].dtype
     return xp.stack([s.astype(dtype) for s in scores])
 
 
-def cert_profile_scores(plane, xp=np):
+def cert_profile_scores(plane, xp=np, windows=None):
     """Sliding-window certificate score per row of a (coarse) plane.
 
     ``max_t (x * box_w)(t) / (std * sqrt(w))`` for ``w`` in (2, 3, 4)
@@ -159,10 +241,20 @@ def cert_profile_scores(plane, xp=np):
     retention of ~0.6 and ~0.44 at the benchmark config — see
     :mod:`.certify`).  Used only on the hybrid's coarse plane; detection
     scores keep the reference's block convention.
+
+    A ladder longer than the default (``windows``) adds, per width ``w``
+    of :func:`cert_wide_windows`, the windows of width ``w`` at strides
+    of ``w / 2`` — adjacent pairs of the level below in the scorer's own
+    pyramid — over the ``std`` of the scorer's level ``w``: a superset
+    of that level's blocks over the same denominator, so on one series
+    this capture is never below the block score at ``w``, and a pulse of
+    any width up to twice the ladder's widest keeps a bounded share of
+    its exact score whatever its phase (:mod:`.certify`).
     """
     assert CERT_WINDOWS == (2, 3, 4), \
         "cert_profile_scores structurally unrolls widths 2/3/4"
     plane = xp.asarray(plane)
+    wide = cert_wide_windows(windows, plane.shape[1])
     # the mean subtraction is materialised (NOT folded into the maxima):
     # raw sliding sums cancel catastrophically at large DC offsets in
     # float32 — see score_profiles
@@ -173,10 +265,19 @@ def cert_profile_scores(plane, xp=np):
     s3 = s2 + xp.roll(x, -2, axis=1)
     best = xp.maximum(best, s3.max(axis=1) / (std * np.float32(np.sqrt(3.0))))
     s4 = s2 + xp.roll(s2, -2, axis=1)
-    return xp.maximum(best, s4.max(axis=1) / (std * np.float32(2.0)))
+    best = xp.maximum(best, s4.max(axis=1) / (std * np.float32(2.0)))
+    level, width = x, 1
+    while wide and width < wide[-1]:
+        below, width = level, 2 * width
+        level = block_sum_time(below, 2, xp=xp)
+        if width in wide:
+            pairs = below[:, :-1] + below[:, 1:]
+            best = xp.maximum(best, pairs.max(axis=1) / level.std(axis=1))
+    return best
 
 
-def score_profiles_chunked(plane, xp, chunk=512, with_cert=False):
+def score_profiles_chunked(plane, xp, chunk=512, with_cert=False,
+                           windows=None):
     """:func:`score_profiles_stacked` over row chunks of a large plane.
 
     Whole-plane scoring materialises the mean-subtracted copy plus four
@@ -195,10 +296,11 @@ def score_profiles_chunked(plane, xp, chunk=512, with_cert=False):
     rows = plane.shape[0]
 
     def one(sub):
-        stacked = score_profiles_stacked(sub, xp=xp)
+        stacked = score_profiles_stacked(sub, xp=xp, windows=windows)
         if with_cert:
             stacked = xp.concatenate(
-                [stacked, cert_profile_scores(sub, xp=xp)[None]])
+                [stacked,
+                 cert_profile_scores(sub, xp=xp, windows=windows)[None]])
         return stacked
 
     return xp.concatenate(
@@ -310,7 +412,7 @@ def block_offsets(offsets, dm_block):
 # ---------------------------------------------------------------------------
 
 def _search_numpy(data, trial_dms, start_freq, bandwidth, sample_time,
-                  capture_plane):
+                  capture_plane, windows=None):
     data = np.asarray(data, dtype=np.float64)
     nchan, nsamples = data.shape
     ndm = len(trial_dms)
@@ -340,7 +442,7 @@ def _search_numpy(data, trial_dms, start_freq, bandwidth, sample_time,
         dedisperse_batch_numpy(data, offsets[lo:hi], out=sub)
         if capture_plane:
             plane[lo:hi] = sub
-        m, s, b, w, p = score_profiles(sub)
+        m, s, b, w, p = score_profiles(sub, windows=windows)
         maxvalues[lo:hi] = m
         stds[lo:hi] = s
         best_snrs[lo:hi] = b
@@ -355,7 +457,8 @@ def _search_numpy(data, trial_dms, start_freq, bandwidth, sample_time,
 # ---------------------------------------------------------------------------
 
 def search_kernel_fn(data, offset_blocks, capture_plane=False,
-                     chan_block=None, formulation=None, policy=None):
+                     chan_block=None, formulation=None, policy=None,
+                     windows=None):
     """The pure, jittable forward step of the search (flagship kernel).
 
     ``data`` is ``(nchan, T)``; ``offset_blocks`` is
@@ -369,7 +472,8 @@ def search_kernel_fn(data, offset_blocks, capture_plane=False,
     backend-resolved) — the axis the autotuner measures.  ``policy``
     names a :mod:`..precision` accumulation strategy for the channel
     reduction (``None`` = the byte-identical ``f32`` default) — the
-    second axis the autotuner measures (ISSUE 17).
+    second axis the autotuner measures (ISSUE 17).  ``windows`` is the
+    scorer's ladder (static).
     """
     import jax
     import jax.numpy as jnp
@@ -378,7 +482,7 @@ def search_kernel_fn(data, offset_blocks, capture_plane=False,
         plane = dedisperse_block_chunked_jax(data, offs, chan_block,
                                              formulation=formulation,
                                              policy=policy)
-        scores = score_profiles_stacked(plane, xp=jnp)
+        scores = score_profiles_stacked(plane, xp=jnp, windows=windows)
         if capture_plane:
             return scores, plane
         return scores
@@ -388,7 +492,7 @@ def search_kernel_fn(data, offset_blocks, capture_plane=False,
 
 @functools.lru_cache(maxsize=32)
 def _jax_search_kernel(capture_plane, chan_block, formulation=None,
-                       packed=None, policy=None):
+                       packed=None, policy=None, windows=None):
     """The direct-sweep program.  ``packed`` (a
     :meth:`~pulsarutils_tpu.io.lowbit.PackedFrames.meta` tuple) makes
     ``data`` the RAW packed uint8 frames: the bit-unpack runs inside
@@ -408,10 +512,15 @@ def _jax_search_kernel(capture_plane, chan_block, formulation=None,
                                 capture_plane=capture_plane,
                                 chan_block=chan_block,
                                 formulation=formulation,
-                                policy=policy)
+                                policy=policy, windows=windows)
 
     return direct_sweep
 
+
+#: alignment of the exact kernels' rebase rotation
+#: (``pallas_dedisperse.rebase_offsets``): block sums of windows up to it
+#: are a rotation of the reference's
+REBASE_ALIGN = 128
 
 #: trials dedispersed per Pallas pass — bounds the live plane to
 #: superblock * nsamples floats (512 x 1M = 2 GB) regardless of ndm
@@ -470,25 +579,25 @@ def release_plane(plane):
 
 
 @functools.lru_cache(maxsize=8)
-def _jitted_scorer():
+def _jitted_scorer(windows=None):
     import jax
     import jax.numpy as jnp
 
     @jax.jit
     def score(plane):
-        return score_profiles_stacked(plane, xp=jnp)
+        return score_profiles_stacked(plane, xp=jnp, windows=windows)
 
     return score
 
 
 def _search_jax_pallas(data, offsets, capture_plane, dm_block=None,
-                       chan_block=None):
+                       chan_block=None, windows=None):
     """Pallas-kernel sweep: dedisperse in trial superblocks, score each."""
     from .pallas_dedisperse import dedisperse_plane_pallas
 
     ndm = offsets.shape[0]
     nsamples = int(np.shape(data)[1])
-    scorer = _jitted_scorer()
+    scorer = _jitted_scorer(windows)
     mm = plane_memmap(ndm, nsamples) if capture_plane == "memmap" else None
     outs, planes = [], []
     for lo in range(0, ndm, PALLAS_SUPERBLOCK):
@@ -538,7 +647,7 @@ def _search_jax_pallas(data, offsets, capture_plane, dm_block=None,
 
 
 def _search_jax_fdmt(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
-                     capture_plane, with_cert=False):
+                     capture_plane, with_cert=False, windows=None):
     """FDMT sweep: every integer-delay trial in one log-depth transform.
 
     Trial grid is the FDMT's natural (= the reference plan's) integer
@@ -563,7 +672,7 @@ def _search_jax_fdmt(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
                            n_hi, t_run, t_tile, use_pallas, interpret,
                            n_lo=n_lo, with_scores=True,
                            with_plane=capture_plane, t_orig=t_orig,
-                           with_cert=with_cert)
+                           with_cert=with_cert, windows=windows)
     with budget_bucket("search/coarse"):
         out = run(data)
         budget_count("dispatches")
@@ -584,7 +693,7 @@ def _search_jax_fdmt(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
 
 def _search_jax(data, trial_dms, start_freq, bandwidth, sample_time,
                 capture_plane, dm_block, chan_block, dtype, kernel="auto",
-                precision=None):
+                precision=None, windows=None):
     import jax
     import jax.numpy as jnp
 
@@ -605,6 +714,9 @@ def _search_jax(data, trial_dms, start_freq, bandwidth, sample_time,
     if kernel == "fourier":
         from .fourier import search_fourier
 
+        if windows is not None and tuple(windows) != SEARCH_WINDOWS:
+            raise ValueError("kernel='fourier' scores with the default "
+                             "boxcar ladder only")
         if eff_policy not in ("f32", "auto"):
             raise ValueError("precision policies apply to the gather/roll "
                              "channel reductions; kernel='fourier' is "
@@ -661,7 +773,7 @@ def _search_jax(data, trial_dms, start_freq, bandwidth, sample_time,
             data = packed.to_device()  # packed upload, unpack on HBM
         data = jnp.asarray(data, dtype=jnp.float32)
         return _search_jax_pallas(data, offsets, capture_plane, dm_block,
-                                  chan_block)
+                                  chan_block, windows=windows)
     packed_meta = None
     if packed is not None:
         # in-jit unpack for the traceable formulations: the RAW bytes
@@ -722,7 +834,7 @@ def _search_jax(data, trial_dms, start_freq, bandwidth, sample_time,
         try:
             stacked, plane_blocks = _dispatch_direct(
                 data, offset_blocks, capture_plane, chan_block, kernel,
-                packed_meta, passes, policy=policy_arg)
+                packed_meta, passes, policy=policy_arg, windows=windows)
             break
         except (ValueError, TypeError):
             raise  # deterministic configuration error, never OOM
@@ -763,7 +875,8 @@ def _search_jax(data, trial_dms, start_freq, bandwidth, sample_time,
 
 
 def _dispatch_direct(data, offset_blocks, capture_plane, chan_block,
-                     formulation, packed_meta, passes, policy=None):
+                     formulation, packed_meta, passes, policy=None,
+                     windows=None):
     """One direct-sweep dispatch at the given degradation level.
 
     ``passes == 1`` is the exact pre-resilience path (single dispatch,
@@ -780,7 +893,7 @@ def _dispatch_direct(data, offset_blocks, capture_plane, chan_block,
     import jax.numpy as jnp
 
     kernel_fn = _jax_search_kernel(capture_plane, chan_block, formulation,
-                                   packed_meta, policy)
+                                   packed_meta, policy, windows)
     if passes <= 1:
         with budget_bucket("search/dispatch"):
             offs_dev = jnp.asarray(offset_blocks)  # attributed
@@ -977,7 +1090,7 @@ def hybrid_certificate_gate(cert_scores, coarse_snrs, snrs, exact, rescore,
                             *, nchan, trial_dms, start_freq, bandwidth,
                             sample_time, nsamples, snr_floor,
                             noise_certificate, seed_done=False,
-                            rho_cert=None, cert_slack=None):
+                            rho_cert=None, cert_slack=None, windows=None):
     """The certificate check + guarantee loop, shared VERBATIM by the
     single-device and sharded hybrids (their docstrings promise an
     identical contract — this helper is what makes that true).
@@ -1008,7 +1121,8 @@ def hybrid_certificate_gate(cert_scores, coarse_snrs, snrs, exact, rescore,
     conservative margins (no certificate, no bound computation).
     ``cert_slack`` overrides the default
     :data:`~.certify.HYBRID_CERT_SLACK` in both the certificate
-    threshold and the skip criterion.
+    threshold and the skip criterion.  ``windows`` is the ladder the
+    scores came from: the bound is the one for that ladder.
     """
     import jax
 
@@ -1034,7 +1148,7 @@ def hybrid_certificate_gate(cert_scores, coarse_snrs, snrs, exact, rescore,
                 rho_cert_min = retention_bound(nchan, trial_dms,
                                                start_freq, bandwidth,
                                                sample_time, nsamples,
-                                               cert=True)
+                                               cert=True, windows=windows)
         certified = bool(noise_certificate
                          and certify_noise_only(cert_scores, snr_floor,
                                                 rho_cert_min,
@@ -1170,7 +1284,7 @@ def fused_scores_to_host(scores, roll_k, nsamples):
 @functools.lru_cache(maxsize=8)
 def _fused_hybrid_seed_kernel(nchan, start_freq, bandwidth, n_hi, t_run,
                               t_tile, n_lo, t_orig, max_off, ndm_plan,
-                              bucket, bucket2=0):
+                              bucket, bucket2=0, windows=None):
     """ONE jitted program for the hybrid's first round on TPU:
 
     FDMT coarse sweep -> plan-grid score mapping -> device-side top-k
@@ -1210,7 +1324,8 @@ def _fused_hybrid_seed_kernel(nchan, start_freq, bandwidth, n_hi, t_run,
     coarse_fn = _transform_fn(nchan, start_freq, bandwidth, n_hi, t_run,
                               t_tile, True, False, n_lo=n_lo,
                               with_scores=True, with_plane=False,
-                              t_orig=t_orig, with_cert=True)
+                              t_orig=t_orig, with_cert=True,
+                              windows=windows)
     k = min(HYBRID_SEED_TOPK, ndm_plan)  # top_k requires k <= axis size
 
     @jax.jit
@@ -1225,7 +1340,8 @@ def _fused_hybrid_seed_kernel(nchan, start_freq, bandwidth, n_hi, t_run,
         offs = offsets_rebased[sel]               # (bucket, nchan) rows
         plane = dedisperse_plane_pallas_traced(data, offs, max_off,
                                                dm_block=bucket)
-        exact = score_profiles_stacked(plane, xp=jnp)   # (5, bucket)
+        exact = score_profiles_stacked(plane, xp=jnp,
+                                       windows=windows)  # (5, bucket)
         parts = [coarse.reshape(-1), sel.astype(jnp.float32),
                  exact.reshape(-1),
                  jnp.full((1,), bucket, jnp.float32)]  # n_seed slot
@@ -1247,7 +1363,8 @@ def _fused_hybrid_seed_kernel(nchan, start_freq, bandwidth, n_hi, t_run,
                 plane2 = dedisperse_plane_pallas_traced(
                     data, offsets_rebased[rows], max_off,
                     dm_block=bucket2)
-                return score_profiles_stacked(plane2, xp=jnp)
+                return score_profiles_stacked(plane2, xp=jnp,
+                                              windows=windows)
 
             exact2 = jax.lax.cond(
                 n_need > 0, rescore2,
@@ -1275,7 +1392,7 @@ def _device_offsets_cache(offsets_bytes, shape):
 
 
 @functools.lru_cache(maxsize=16)
-def _fused_rescore_kernel(max_off, dm_block):
+def _fused_rescore_kernel(max_off, dm_block, windows=None, roll_k=0):
     """One jitted program: Pallas dedisperse (un-rebased output) + score.
 
     The hybrid's exact-rescore hot path on TPU.  ``max_off`` is the
@@ -1284,10 +1401,13 @@ def _fused_rescore_kernel(max_off, dm_block):
     one compiled program per row bucket.  The plane is scored WITHOUT
     undoing the rebase rotation: max/std/snr/window are
     rotation-invariant (the rebase constant is 128-aligned, a multiple
-    of every boxcar width, so block sums are a rotation of the reference
-    ones), and the peak index is corrected host-side
-    (``(peak - roll_k) mod T``) — saving a full-plane roll pass and two
-    dispatch round trips per call.
+    of every boxcar width of the default ladder, so block sums are a
+    rotation of the reference ones), and the peak index is corrected
+    host-side (``(peak - roll_k) mod T``) — saving a full-plane roll pass
+    and two dispatch round trips per call.  A ladder wider than the
+    alignment would score other blocks than the reference's, so with one
+    the rotation is undone on the device before scoring (``roll_k`` is
+    then static) and the peak needs no correction.
     """
     import jax
     import jax.numpy as jnp
@@ -1297,8 +1417,9 @@ def _fused_rescore_kernel(max_off, dm_block):
     @jax.jit
     def rescore_rows(data, offs):
         plane = dedisperse_plane_pallas_traced(data, offs, max_off,
-                                               dm_block=dm_block)
-        return score_profiles_stacked(plane, xp=jnp)
+                                               dm_block=dm_block,
+                                               roll_k=roll_k)
+        return score_profiles_stacked(plane, xp=jnp, windows=windows)
 
     return rescore_rows
 
@@ -1306,7 +1427,7 @@ def _fused_rescore_kernel(max_off, dm_block):
 def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
                        capture_plane, dm_block, chan_block,
                        snr_floor=None, noise_certificate=True,
-                       rho_cert=None, cert_slack=None):
+                       rho_cert=None, cert_slack=None, windows=None):
     """FDMT coarse sweep + exact rescore of the hit region.
 
     The throughput/exactness trade (VERDICT round 1): the FDMT computes
@@ -1375,6 +1496,11 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
     nchan, nsamples = np.shape(data)
     dmmin = float(np.min(trial_dms))
     dmmax = float(np.max(trial_dms))
+    ladder = check_windows(windows)  # ``windows`` below: the rows' best
+    # the exact kernels' rebase rotation is 128-aligned: blocks of a
+    # wider window are no rotation of the reference's, so such a ladder
+    # undoes the rotation on the device before it scores
+    rotation_free = ladder[-1] <= REBASE_ALIGN
 
     use_fused = jax.default_backend() == "tpu"
     # (the pad-free soundness guard — disabling certificate + cert-proof
@@ -1414,6 +1540,7 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
     fused_seed = (use_fused and not capture_plane
                   and ndm >= 3 * HYBRID_SEED_TOPK
                   and _pick_fdmt_tile(nsamples) > 0
+                  and rotation_free
                   and (snr_floor is None or not noise_certificate)
                   # OOM ladder "unfuse" rung (ISSUE 12): under memory
                   # pressure the one-dispatch program splits back into
@@ -1441,12 +1568,14 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
                                         bandwidth, sample_time, nsamples,
                                         snr_floor=snr_floor,
                                         rho_cert=rho_cert,
-                                        cert_slack=cert_slack)
+                                        cert_slack=cert_slack,
+                                        windows=ladder)
 
         rebased_full, roll_k, max_off = offsets_table()
         kernel = _fused_hybrid_seed_kernel(
             nchan, float(start_freq), float(bandwidth), n_hi, nsamples,
-            t_tile, n_lo, None, max_off, ndm, bucket, bucket2=bucket2)
+            t_tile, n_lo, None, max_off, ndm, bucket, bucket2=bucket2,
+            windows=ladder)
         offs_dev = _device_offsets_cache(rebased_full.tobytes(),
                                          rebased_full.shape)
         with budget_bucket("search/fused"):
@@ -1467,7 +1596,7 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
         (_, c_max, c_std, c_snr, c_win, c_peak, plane,
          c_cert) = _search_jax_fdmt(
             data, dmmin, dmmax, start_freq, bandwidth, sample_time,
-            capture_plane, with_cert=True)
+            capture_plane, with_cert=True, windows=ladder)
         if plane is not None and plane.shape[0] != ndm:
             # align the coarse plane with the plan grid (row gather —
             # cheap, and row-major on TPU unlike the scalarising lane
@@ -1530,21 +1659,24 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
             rebased_full, roll_k, max_off = offsets_table()
         for blk, padded in iter_rescore_buckets(rows):
             if use_fused:
-                run = _fused_rescore_kernel(max_off, len(padded))
+                run = _fused_rescore_kernel(
+                    max_off, len(padded), ladder,
+                    0 if rotation_free else roll_k)
                 with budget_bucket("search/rescore"):
                     stacked = run(data32,
                                   jnp.asarray(rebased_full[padded]))
                     budget_count("dispatches")
                     m, s, b_, w, p = unstack_scores(stacked)
                     budget_count("readbacks")
-                p = (p - roll_k) % nsamples  # undo the rebase rotation
+                if rotation_free:
+                    p = (p - roll_k) % nsamples  # undo the rebase rotation
                 _apply(blk, (m, s, b_, w, p))
             else:
                 m, s, b_, w, p, _ = _search_jax(
                     data, trial_dms[padded], start_freq, bandwidth,
                     sample_time, capture_plane=False, dm_block=dm_block,
                     chan_block=chan_block, dtype=None,
-                    kernel=rescore_kernel())
+                    kernel=rescore_kernel(), windows=ladder)
                 _apply(blk, (m, s, b_, w, p))
 
     # 2. seed (plausible-best rows + grid neighbours; the coarse grid
@@ -1579,7 +1711,7 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
         trial_dms=trial_dms, start_freq=start_freq, bandwidth=bandwidth,
         sample_time=sample_time, nsamples=nsamples, snr_floor=snr_floor,
         noise_certificate=noise_certificate, seed_done=fused_seed,
-        rho_cert=rho_cert, cert_slack=cert_slack)
+        rho_cert=rho_cert, cert_slack=cert_slack, windows=ladder)
     logger.debug("hybrid: %d/%d rows rescored exactly%s%s", exact.sum(), ndm,
                  f" (device need stage flagged {n_need})" if fused_seed
                  else "",
@@ -1598,7 +1730,7 @@ def dedispersion_search(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
                         trial_dms=None, dm_block=None, chan_block=None,
                         dtype=None, kernel="auto", snr_floor=None,
                         noise_certificate=True, rho_cert=None,
-                        cert_slack=None, precision=None):
+                        cert_slack=None, precision=None, windows=None):
     """Sweep trial DMs over ``data`` and score each dedispersed series.
 
     Parameters mirror the reference façade
@@ -1685,6 +1817,11 @@ def dedispersion_search(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
         only ever wins after the exact-hit-match equivalence harness
         passes at its documented error bound.  ``PUTPU_PRECISION``
         sets the default when the argument is omitted.
+    windows : the scorer's boxcar ladder, a tuple continuing
+        ``1, 2, 4, 8`` in doubling steps (:func:`boxcar_ladder`);
+        ``None`` (default) is :data:`SEARCH_WINDOWS`.  Every backend and
+        kernel takes it but ``"fourier"``; with ``kernel="hybrid"`` the
+        certificate's capture and retention bound follow it.
 
     Returns
     -------
@@ -1716,6 +1853,7 @@ def dedispersion_search(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
     nchan = data.shape[0]
     if capture_plane is None:
         capture_plane = bool(show)
+    windows = check_windows(windows)
 
     if kernel == "fdmt":
         # the FDMT computes its own trial grid: the plan's one-sample
@@ -1738,7 +1876,8 @@ def dedispersion_search(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
             dmmax = float(np.max(trial_dms))
         (trial_dms, maxvalues, stds, best_snrs, best_windows, best_peaks,
          plane) = _search_jax_fdmt(data, dmmin, dmmax, start_freq,
-                                   bandwidth, sample_time, capture_plane)
+                                   bandwidth, sample_time, capture_plane,
+                                   windows=windows)
         table = ResultTable({
             "DM": trial_dms,
             "max": maxvalues,
@@ -1777,7 +1916,8 @@ def dedispersion_search(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
                                        chan_block, snr_floor=snr_floor,
                                        noise_certificate=noise_certificate,
                                        rho_cert=rho_cert,
-                                       cert_slack=cert_slack)
+                                       cert_slack=cert_slack,
+                                       windows=windows)
         table = ResultTable({
             "DM": trial_dms,
             "max": maxvalues,
@@ -1797,13 +1937,13 @@ def dedispersion_search(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
     if backend == "numpy":
         (maxvalues, stds, best_snrs, best_windows, best_peaks,
          plane) = _search_numpy(data, trial_dms, start_freq, bandwidth,
-                                sample_time, capture_plane)
+                                sample_time, capture_plane, windows)
     elif backend == "jax":
         (maxvalues, stds, best_snrs, best_windows, best_peaks,
          plane) = _search_jax(data, trial_dms, start_freq, bandwidth,
                               sample_time, capture_plane, dm_block,
                               chan_block, dtype, kernel,
-                              precision=precision)
+                              precision=precision, windows=windows)
     else:
         raise ValueError(f"unknown backend {backend!r}")
 

@@ -63,7 +63,8 @@ from ..utils.table import ResultTable
 def _search_with_fallback(array, dmmin, dmmax, start_freq, bandwidth,
                           eff_tsamp, *, backend, kernel, capture_plane,
                           state=None, mesh=None, snr_floor=None,
-                          chunk=None, policy=None, trial_dms=None):
+                          chunk=None, policy=None, trial_dms=None,
+                          windows=None):
     """One chunk's search with failure containment.
 
     The reference has no failure handling at all (SURVEY §5).  Policy:
@@ -111,8 +112,9 @@ def _search_with_fallback(array, dmmin, dmmax, start_freq, bandwidth,
     chunk pays one coarse dispatch and no seed rescore — the same
     gating as the single-device fused path.
 
-    ``trial_dms`` is a tier's explicit trial grid (single-device routes
-    only: a tiered plan refuses a mesh before it gets here).
+    ``trial_dms`` is a tier's explicit trial grid and ``windows`` a
+    plan's or tier's boxcar ladder (single-device routes only: a tiered
+    plan and ``boxcar_max`` refuse a mesh before they get here).
     """
     from ..resilience import ladder as _ladder
 
@@ -172,6 +174,7 @@ def _search_with_fallback(array, dmmin, dmmax, start_freq, bandwidth,
             array, dmmin, dmmax, start_freq, bandwidth, eff_tsamp,
             backend=b, kernel=k, capture_plane=capture_plane,
             **({"trial_dms": trial_dms} if trial_dms is not None else {}),
+            **({"windows": windows} if windows is not None else {}),
             **({"snr_floor": snr_floor} if k == "hybrid" else {}))
 
     i = 0
@@ -245,6 +248,17 @@ def _search_with_fallback(array, dmmin, dmmax, start_freq, bandwidth,
             i += 1
             continue
     raise last
+
+
+def _count_windows(windows, nsamples):
+    """Counts one sweep's scored boxcar levels
+    (``putpu_boxcar_windows_total``: one per level per tier sweep or flat
+    sweep) and returns their number."""
+    from ..ops.search import scored_windows
+
+    n = len(scored_windows(windows, nsamples))
+    obs_metrics.counter("putpu_boxcar_windows_total").inc(n)
+    return n
 
 
 def _clean_block(block, m, xp, cut_outliers, zero_dm, fft_zap, resample):
@@ -337,9 +351,18 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 cut_outliers=False, zero_dm=False, mesh=None,
                 exact_floor="auto", quarantine_policy="sanitize",
                 period_search=False, period_sigma_threshold=8.0,
-                fingerprint_extra=None, dm_tiers=None):
+                fingerprint_extra=None, dm_tiers=None, boxcar_max=None):
     """Resolve a survey's geometry, threshold and resume fingerprint
     WITHOUT searching anything.
+
+    ``boxcar_max`` (a power of two in samples of the file, Heimdall's
+    name and meaning; default off) continues the scorer's boxcar ladder
+    (:func:`~pulsarutils_tpu.ops.search.boxcar_ladder`): the returned
+    dict's ``windows`` is the flat plan's ladder (``None``: the default
+    four), each entry of ``tiers`` carries its own under
+    ``tier.windows``, every threshold and certificate floor is resolved
+    for its ladder, and the fingerprint names ``boxcar_max``.  Absent,
+    nothing changes.
 
     ``dm_tiers="smearing"`` plans the DM range in tiers
     (:func:`~pulsarutils_tpu.ops.plan.dm_tier_plan`): the returned dict's
@@ -395,12 +418,21 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
     plan = plan_chunks(nsamples, sample_time, dmmin, dmmax, start_freq,
                        stop_freq, foff, chunk_length=chunk_length,
                        new_sample_time=new_sample_time)
+    flat_windows = None
+    if boxcar_max is not None:
+        from ..ops.search import boxcar_ladder
+
+        flat_windows = boxcar_ladder(boxcar_max, plan.resample)
     dm_plan = None
     if dm_tiers is not None:
         from ..ops.plan import dm_tier_plan
 
         dm_plan = dm_tier_plan(header["nchans"], dmmin, dmmax, start_freq,
-                               bandwidth, plan.sample_time, foff)
+                               bandwidth, plan.sample_time, foff,
+                               # the plan's samples are `resample` of the
+                               # file's
+                               boxcar_max=(None if boxcar_max is None else
+                                           flat_windows[-1]))
         if len(dm_plan) == 1 and dm_plan[0].downsample == 1:
             dm_plan = None  # the flat plan, to the bit
         else:
@@ -414,9 +446,10 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
     logger.info("chunk plan: step=%d hop=%d resample=%d -> tsamp=%g s",
                 plan.step, plan.hop, plan.resample, eff_tsamp)
 
-    def _resolve(threshold, tsamp, t_eff, grid):
+    def _resolve(threshold, tsamp, t_eff, grid, windows=None):
         """``(snr_threshold, search_snr_floor)`` of one searched geometry:
-        ``t_eff`` samples of ``tsamp`` over the trial DMs ``grid()``."""
+        ``t_eff`` samples of ``tsamp`` over the trial DMs ``grid()``,
+        scored with the ladder ``windows``."""
         from ..ops.certify import (certifiable_snr_floor, matched_snr_floor,
                                    retention_bound)
 
@@ -428,7 +461,8 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
             exact_floor='auto' comparison)."""
             trial_dms = grid()
             rho = retention_bound(header["nchans"], trial_dms, start_freq,
-                                  bandwidth, tsamp, t_eff, cert=True)
+                                  bandwidth, tsamp, t_eff, cert=True,
+                                  windows=windows)
             return certifiable_snr_floor(t_eff, len(trial_dms), rho)
 
         if isinstance(threshold, str):
@@ -483,7 +517,7 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
     tiers = None
     if dm_plan is None:
         snr_threshold, search_snr_floor = _resolve(
-            snr_threshold, eff_tsamp, t_flat, _flat_grid)
+            snr_threshold, eff_tsamp, t_flat, _flat_grid, flat_windows)
     else:
         tiers = []
         for tier in dm_plan:
@@ -493,7 +527,8 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
             thr, floor = _resolve(
                 snr_threshold, tier.sample_time,
                 max(t_flat // tier.downsample, 2),
-                lambda tier=tier: tier.trial_dms)
+                lambda tier=tier: tier.trial_dms,
+                None if boxcar_max is None else tier.windows)
             tiers.append({"tier": tier, "snr_threshold": thr,
                           "search_snr_floor": floor})
         # what the run reports as "the" threshold is the first tier's
@@ -526,6 +561,10 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
         **({"dm_tiers": [[t["tier"].downsample, len(t["tier"].trial_dms),
                           t["snr_threshold"]] for t in tiers]}
            if tiers else {}),
+        # another ladder scores other windows: a resume across a changed
+        # --boxcar-max is refused like any changed plan
+        **({"boxcar_max": int(boxcar_max)} if boxcar_max is not None
+           else {}),
         # workload-distinct ledgers (ISSUE 13): merged LAST so a
         # collision with a driver field fails loudly in review, and
         # absent entirely when unset — every pre-existing ledger
@@ -538,6 +577,7 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
         "snr_threshold": snr_threshold,
         "search_snr_floor": search_snr_floor,
         "tiers": tiers,
+        "windows": flat_windows,
         "fingerprint": fingerprint,
         "chunk_starts": list(iter_chunk_starts(nsamples, plan, tmin=tmin,
                                                sample_time=sample_time)),
@@ -559,7 +599,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                      health=None, report_out=None, chunks=None,
                      cancel_cb=None, plane_consumer=None,
                      fingerprint_extra=None, fence=None, lineage=None,
-                     push=None, dm_tiers=None):
+                     push=None, dm_tiers=None, boxcar_max=None):
     """Search a filterbank file for dispersed single pulses.
 
     Parameters follow the reference driver (``clean.py:276``) plus the
@@ -792,6 +832,17 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
     the canary, the period search and a ``plane_consumer`` are refused
     with it (``ValueError``).
 
+    ``boxcar_max`` (default off; ``PUsearchfrb --boxcar-max``) continues
+    the scorer's boxcar ladder beyond 8 samples, in doubling steps up to
+    that many samples of the file
+    (:func:`~pulsarutils_tpu.ops.search.boxcar_ladder`): a flat plan
+    scores ``1 .. boxcar_max``, tier ``k`` of a tiered plan ``1 ..
+    max(8, boxcar_max / 2^k)`` of its own samples.  The certificate's
+    capture and bound, the exact rescore, each tier's threshold and the
+    fingerprint follow the ladder; ``rebin`` and ``peak`` stay in the
+    row's own samples.  A mesh, the period search and a
+    ``plane_consumer`` are refused with it (``ValueError``).
+
     Returns ``(hits, store)`` where hits is a list of
     ``(istart, iend, PulseInfo, ResultTable)``.  NOTE (round 6): when
     plotting is off, a hit's retained/persisted ``info.allprofs`` is the
@@ -816,6 +867,15 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             ("plane_consumer", plane_consumer is not None)) if on]
         if refused:
             raise ValueError(f"dm_tiers={dm_tiers!r} does not run with "
+                             + ", ".join(refused))
+    if boxcar_max is not None:
+        # the mesh kernels and the plane's period search score with the
+        # default ladder only
+        refused = [name for name, on in (
+            ("mesh", mesh is not None), ("period_search", period_search),
+            ("plane_consumer", plane_consumer is not None)) if on]
+        if refused:
+            raise ValueError(f"boxcar_max={boxcar_max!r} does not run with "
                              + ", ".join(refused))
     if mesh is not None:
         # fail fast: a missing axis would otherwise surface as a KeyError
@@ -893,7 +953,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                          period_search=period_search,
                          period_sigma_threshold=period_sigma_threshold,
                          fingerprint_extra=fingerprint_extra,
-                         dm_tiers=dm_tiers)
+                         dm_tiers=dm_tiers, boxcar_max=boxcar_max)
         reader = sp["reader"]
         root = sp["root"]
         header = reader.header
@@ -913,6 +973,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         snr_threshold = sp["snr_threshold"]
         search_snr_floor = sp["search_snr_floor"]
         tiers = sp["tiers"]  # None: the flat plan
+        flat_windows = sp["windows"]  # None: the default ladder
         fingerprint = sp["fingerprint"]
         # fence (ISSUE 15): the fleet worker's lease epoch — candidate
         # artifact writes stamped with a higher epoch are refused (see
@@ -1450,17 +1511,19 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                     tier.sample_time, backend=backend, kernel=kernel,
                     capture_plane=capture, state=fallback_state,
                     snr_floor=t["search_snr_floor"], chunk=istart_,
-                    policy=dispatch_policy, trial_dms=tier.trial_dms)
+                    policy=dispatch_policy, trial_dms=tier.trial_dms,
+                    windows=tier.windows if flat_windows else None)
                 ttable, tplane = result if capture else (result, None)
                 certified = bool(ttable.meta.get("certified"))
                 tspan.attrs["certified"] = certified
             obs_metrics.counter("putpu_tier_sweeps_total").inc()
             if certified:
                 obs_metrics.counter("putpu_tier_certified_total").inc()
+            nwindows = _count_windows(tier.windows, arr.shape[1])
             rec["tiers"].append({
                 "downsample": tier.downsample, "trials": ttable.nrows,
                 "coarse_s": round(coarse_s() - coarse0, 4),
-                "certified": certified})
+                "certified": certified, "windows": nwindows})
             snr = np.asarray(ttable["snr"], dtype=np.float64)
             n_above += int(np.count_nonzero(snr > t["snr_threshold"]))
             best = ttable.best_row()
@@ -1487,6 +1550,11 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         top["best"] = dict(top["best"],
                            downsample=tiers[top["k"]]["tier"].downsample)
         top["n_above"] = n_above
+        if top["detection"]:
+            # the hit's boxcar, in samples of the file
+            rec["tiers"][top["k"]]["best_window"] = int(
+                top["best"]["rebin"]) * top["best"]["downsample"] \
+                * plan.resample
         return ResultTable(cols, meta=meta), top
 
     reader_pool = ThreadPoolExecutor(max_workers=1)
@@ -1651,7 +1719,9 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                             eff_tsamp, backend=backend, kernel=kernel,
                             capture_plane=capture, state=fallback_state,
                             mesh=mesh, snr_floor=search_snr_floor,
-                            chunk=istart, policy=dispatch_policy)
+                            chunk=istart, policy=dispatch_policy,
+                            windows=flat_windows)
+                        _count_windows(flat_windows, array.shape[1])
             except _resilience_ladder.OOMFloorError as exc:
                 # the degradation ladder's floor itself OOMed: this
                 # chunk cannot be searched on this host at ANY geometry
@@ -1866,6 +1936,9 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 info.dm = float(best["DM"])
                 info.snr = float(best["snr"])
                 info.width = float(best["rebin"]) * hit_tsamp
+                # the boxcar the hit was matched at, in samples of the file
+                ck["rec"]["best_window_samples"] = int(round(
+                    info.width / sample_time))
                 with with_timer("hit_products"):
                     # readback counters only for DEVICE sources: after a
                     # fallback to the numpy backend these are host

@@ -465,3 +465,252 @@ class TestCertifyHelpers:
         from pulsarutils_tpu.ops.search import CERT_WINDOWS
 
         assert CERT_WINDOWS == (2, 3, 4)
+
+
+class TestLadderCertificate:
+    """ISSUE 32: a boxcar ladder beyond 8 samples.  The exact score of a
+    wide pulse no longer decays, so the certificate's capture and its
+    bound grow with the ladder: half-stride windows of every scored level
+    from 8 up, one set for the scorer, the kernel and the bound."""
+
+    TIERED = dict(nchan=64, dmmin=0.0, dmmax=160.0, foff=-3.125, t=1 << 14)
+
+    @staticmethod
+    def _ladder(length):
+        return tuple(1 << j for j in range(length))
+
+    def test_capture_windows_shared_with_the_bound(self, monkeypatch):
+        """The pinned coupling: the bound is computed over the very set of
+        capture windows the scorer unrolls (``cert_wide_windows``), cut
+        off for the series' length as the scorer is."""
+        from pulsarutils_tpu.ops import certify
+        from pulsarutils_tpu.ops.search import (SEARCH_WINDOWS,
+                                                cert_wide_windows,
+                                                scored_windows)
+
+        nchan, t = 64, 1 << 12
+        dms = dedispersion_plan(nchan, 100.0, 120.0, *GARGS)
+        seen = []
+        real = certify._cert_retention_from_offsets
+
+        def spy(offsets, **kw):
+            seen.append(kw)
+            return real(offsets, **kw)
+
+        monkeypatch.setattr(certify, "_cert_retention_from_offsets", spy)
+        ladder = self._ladder(9)  # 256: cut off at 64 for 4,096 samples
+        cert_retention(nchan, dms, *GARGS, t, windows=ladder)
+        assert len(seen) == len(dms)
+        assert all(kw["windows"] == scored_windows(ladder, t)
+                   == self._ladder(7) for kw in seen)
+        assert all(kw["wide"] == cert_wide_windows(ladder, t)
+                   == (8, 16, 32, 64) for kw in seen)
+        # the default ladder, named or not, is the bound it always was
+        seen.clear()
+        a = cert_retention(nchan, dms, *GARGS, t)
+        b = cert_retention(nchan, dms, *GARGS, t, windows=SEARCH_WINDOWS)
+        assert np.array_equal(a, b) and not any(kw for kw in seen)
+
+    def test_exact_best_phase_is_the_aligned_box(self):
+        """The closed form against every phase by brute force."""
+        from pulsarutils_tpu.ops.certify import _exact_best_phase
+
+        ladder = self._ladder(7)
+        for width in (1, 3, 8, 11, 16, 24, 64, 100, 128):
+            best = 0.0
+            box = np.full(width, 1.0 / width)
+            for w in ladder:
+                for p in range(ladder[-1]):
+                    cap = np.zeros((p + width) // w + 1)
+                    np.add.at(cap, (p + np.arange(width)) // w, box)
+                    best = max(best, cap.max() / np.sqrt(w))
+            assert _exact_best_phase(width, ladder) == pytest.approx(
+                best, rel=1e-12)
+
+    @pytest.mark.parametrize("length", range(5, 14))
+    def test_closed_form_never_above_the_worst_phase(self, length):
+        """Widths past ``max_width`` use a closed form: it may not promise
+        more than the captures hold at their worst phase (here without
+        scatter, where both can be computed)."""
+        from pulsarutils_tpu.ops.certify import (_cert_retention_from_offsets,
+                                                 _exact_best_phase,
+                                                 _wide_capture_worst_phase)
+
+        ladder = self._ladder(length)
+        wide = tuple(w for w in ladder if w >= 8)
+        flat = np.zeros(64, dtype=np.int64)  # a track with no deviation
+        closed = _cert_retention_from_offsets(flat, windows=ladder,
+                                              wide=wide)
+        assert 0.70 <= closed <= 0.7501  # 0.75 at powers of two
+        rng = np.random.default_rng(length)
+        widths = set(rng.integers(17, 2 * ladder[-1] + 1, 12).tolist())
+        widths |= {ladder[-1], 2 * ladder[-1], ladder[-1] + 1}
+        for width in widths:
+            box = np.full(width, 1.0 / width)
+            true = (_wide_capture_worst_phase(box, wide)
+                    / _exact_best_phase(width, ladder))
+            assert closed - 1e-12 <= true <= 1.0 + 1e-12, width
+        # scatter costs the closed form what the text says: D / W a window
+        spread = np.repeat([0, 1, 2, 3], 16)
+        assert _cert_retention_from_offsets(spread, windows=ladder,
+                                            wide=wide) < closed
+
+    def test_a_sliding_capture_alone_would_lose_wide_pulses(self):
+        """What the ladder forces: without the half-stride captures a
+        width-512 pulse keeps 2 / sqrt(512) of its exact score."""
+        from pulsarutils_tpu.ops.certify import _exact_best_phase
+
+        ladder = self._ladder(13)
+        sliding = (4 / 512) / np.sqrt(4)
+        assert sliding / _exact_best_phase(512, ladder) == pytest.approx(
+            2 / np.sqrt(512))
+
+    @pytest.mark.parametrize("length", range(4, 14))
+    def test_scorer_keeps_the_bound_at_any_phase(self, length):
+        """Every ladder length, widths 1 .. 2 x the widest window, random
+        phase: the capture of the pulse where it is stays above the bound
+        times the exact score of the same pulse at its best phase, up to
+        the noise under the pulse (sd 1 on either side), which the slack
+        absorbs as often as the module states."""
+        from pulsarutils_tpu.ops.certify import _cert_retention_from_offsets
+        from pulsarutils_tpu.ops.search import (cert_profile_scores,
+                                                cert_wide_windows,
+                                                score_profiles)
+
+        ladder = self._ladder(length)
+        widest = ladder[-1]
+        t = 256 * widest
+        rho = _cert_retention_from_offsets(
+            np.zeros(8, dtype=np.int64), windows=ladder,
+            wide=cert_wide_windows(ladder, t))
+        rng = np.random.default_rng(40 + length)
+        noise = rng.standard_normal(t)
+        draws, short = 32, 0
+        for _ in range(draws):
+            width = int(rng.integers(1, 2 * widest + 1))
+            phase = int(rng.integers(0, widest))
+            amp = 9.0 / np.sqrt(width)
+            here, aligned = noise.copy(), noise.copy()
+            here[8 * widest + phase:8 * widest + phase + width] += amp
+            aligned[8 * widest:8 * widest + width] += amp
+            exact = score_profiles(aligned[None], windows=ladder)[2][0]
+            cert = cert_profile_scores(here[None], windows=ladder)[0]
+            short += bool(cert < rho * exact - HYBRID_CERT_SLACK)
+        assert short <= cert_miss_p_at_floor() * draws, short
+
+    def test_pulses_at_the_floor_are_not_certified_away(self):
+        """The property that guards the change: in every tier of a small
+        tiered plan, under short, middling and the longest scored ladder,
+        seeded pulses of widths 1 .. 2 x the widest window at random
+        phase and DM whose exact score reaches the floor are certified
+        away no more often than the module's stated miss probability."""
+        from pulsarutils_tpu.ops.plan import dm_tier_plan
+        from pulsarutils_tpu.ops.rebin import block_sum_time
+        from pulsarutils_tpu.ops.search import scored_windows
+
+        g = self.TIERED
+        rng = np.random.default_rng(32)
+        reached = missed = 0
+        for boxcar_max in (8, 64, 256):
+            tiers = dm_tier_plan(g["nchan"], g["dmmin"], g["dmmax"], *GARGS,
+                                 g["foff"], boxcar_max=boxcar_max)
+            assert [t.downsample for t in tiers] == [1, 2, 4]
+            for tier in tiers:
+                t_k = g["t"] // tier.downsample
+                geom = (GARGS[0], GARGS[1], tier.sample_time)
+                ladder = scored_windows(tier.windows, t_k)
+                rho = cert_retention(g["nchan"], tier.trial_dms, *geom, t_k,
+                                     windows=tier.windows).min()
+                floor = certifiable_snr_floor(t_k, len(tier.trial_dms), rho)
+                for case in range(5):
+                    width = int(rng.integers(1, 2 * ladder[-1] + 1))
+                    dm = float(rng.uniform(tier.dm_lo + 2, tier.dm_hi - 2))
+                    pos = int(rng.integers(t_k // 4, t_k // 2))
+                    noise = make_noise(g["nchan"], t_k, 500 + reached + case)
+                    # set 1.2 to 1.8 times the floor, so that what the
+                    # block phase and the pulse's own share of the std
+                    # leave is about the floor: the summed noise has sd
+                    # 0.3015 * sqrt(nchan) a sample
+                    amp = (float(rng.uniform(1.2, 1.8)) * floor * 0.3015
+                           * np.sqrt(width) / np.sqrt(g["nchan"]))
+                    sig = inject_pulse(noise, dm, amp=amp, width=width,
+                                       pos=pos, geom=geom)
+                    ref = dedispersion_search(
+                        sig, tier.dm_lo, tier.dm_hi, *geom, backend="numpy",
+                        trial_dms=tier.trial_dms, windows=tier.windows)
+                    if float(ref["snr"].max()) < floor:
+                        continue
+                    hyb = dedispersion_search(
+                        sig, tier.dm_lo, tier.dm_hi, *geom, backend="jax",
+                        kernel="hybrid", snr_floor=floor,
+                        trial_dms=tier.trial_dms, windows=tier.windows)
+                    reached += 1
+                    missed += bool(hyb.meta["certified"])
+                    if not hyb.meta["certified"]:
+                        assert hyb.argbest() == ref.argbest()
+        assert reached >= 15
+        assert missed <= cert_miss_p_at_floor() * reached, (missed, reached)
+
+
+class TestUncertifiedSweep:
+    """A signal-free sweep whose noise maximum sits a hair over the
+    certificate's threshold is allowed not to certify (about 1 sweep in
+    500 at the certifiable floor's margin).  The guarantee loop then keeps
+    the exact-argbest contract, with a floor as without one, and under a
+    longer ladder as under the default: it rescans toward a full exact
+    sweep, and the table's best row is the float64 backend's."""
+
+    nchan, t = 64, 1 << 12
+
+    def _grid(self, windows=None):
+        dms = dedispersion_plan(self.nchan, 100.0, 200.0, *GARGS)
+        return dms, float(cert_retention(self.nchan, dms, *GARGS,
+                                         self.t, windows=windows).min())
+
+    @pytest.mark.parametrize("windows", [None, (1, 2, 4, 8, 16, 32)])
+    def test_uncertified_noise_keeps_the_exact_argbest(self, windows):
+        dms, rho = self._grid(windows)
+        noise = make_noise(self.nchan, self.t, 4242)
+        free = dedispersion_search(noise, 100.0, 200.0, *GARGS,
+                                   backend="jax", kernel="hybrid",
+                                   windows=windows)
+        cert_max = float(np.max(free["cert"]))
+        # a floor whose certificate threshold sits just under this chunk's
+        # certificate maximum: not certifiable, and far above the noise
+        floor = (cert_max - 0.05 + HYBRID_CERT_SLACK) / rho
+        assert floor > float(np.max(free["snr"])) + 2.0
+        hyb = dedispersion_search(noise, 100.0, 200.0, *GARGS,
+                                  backend="jax", kernel="hybrid",
+                                  snr_floor=floor, windows=windows)
+        assert hyb.meta["certified"] is False
+        ref = dedispersion_search(noise, 100.0, 200.0, *GARGS,
+                                  backend="numpy", windows=windows)
+        j = ref.argbest()
+        assert hyb.argbest() == free.argbest() == j
+        assert bool(hyb["exact"][j])
+        assert int(hyb["rebin"][j]) == int(ref["rebin"][j])
+        # every row the floor's terms flag is exact, and the floor adds
+        # rows to the rescore, never takes any away
+        exact = np.asarray(hyb["exact"])
+        flagged = (np.asarray(hyb["cert"])
+                   >= rho * floor - HYBRID_CERT_SLACK)
+        assert flagged.any() and exact[flagged].all()
+        assert exact.sum() >= np.asarray(free["exact"]).sum()
+
+    def test_a_detection_is_rescored_as_ever(self):
+        dms, rho = self._grid()
+        floor = certifiable_snr_floor(self.t, len(dms), rho)
+        sig = inject_pulse(make_noise(self.nchan, self.t, 4243), 150.0,
+                           amp=6.0)
+        hyb = dedispersion_search(sig, 100.0, 200.0, *GARGS, backend="jax",
+                                  kernel="hybrid", snr_floor=floor)
+        ref = dedispersion_search(sig, 100.0, 200.0, *GARGS,
+                                  backend="numpy")
+        j = ref.argbest()
+        assert float(ref["snr"][j]) > floor
+        assert hyb.argbest() == j and bool(hyb["exact"][j])
+        # every row at or above the floor is exact, and equal to float64's
+        above = np.asarray(ref["snr"]) >= floor
+        assert np.asarray(hyb["exact"])[above].all()
+        assert np.array_equal(np.asarray(hyb["rebin"])[above],
+                              np.asarray(ref["rebin"])[above])

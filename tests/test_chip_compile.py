@@ -265,3 +265,45 @@ def test_fused_sharded_hybrid_on_four_devices(as_tpu, topo, plan):
              for k in ("idx_low", "idx_high", "shift", "shift_high")]
     text = fn.lower(*args).compile().as_text()
     assert "all-gather" in text and "tpu_custom_call" in text
+
+
+# HTRU's six tiers under --boxcar-max 4096 (ISSUE 32): samples and ladder
+# length of each (chipbench/configs/htru_bpsr_fulldm_boxcar4096.json)
+HTRU_TIERS = [(1 << 19, 13), (1 << 18, 12), (1 << 17, 11), (1 << 16, 10),
+              (1 << 15, 9), (1 << 14, 8)]
+
+
+@pytest.mark.parametrize("t,length", HTRU_TIERS)
+def test_score_plane_pallas_with_a_longer_ladder(as_tpu, one_chip, t, length):
+    """The one-pass scorer built for each HTRU tier's ladder, certificate
+    captures included: Mosaic takes 8 to 13 levels on the tile the ladder
+    asks for."""
+    import jax
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops.score_pallas import (pick_score_tile,
+                                                  score_plane_pallas)
+
+    ladder = tuple(1 << j for j in range(length))
+    assert pick_score_tile(t, ladder[-1]) == min(t, 16384)
+    compiled = jax.jit(
+        lambda p: score_plane_pallas(p, with_cert=True,
+                                     windows=ladder)).lower(
+        _sds((64, t), jnp.float32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_rescore_program_with_a_longer_ladder(as_tpu, one_chip):
+    """The exact rescore of the 16x tier's hit (1,024 x 2^15, nine levels):
+    a ladder wider than the rebase's alignment undoes the rotation on the
+    device before it scores."""
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops.search import (REBASE_ALIGN,
+                                            _fused_rescore_kernel)
+
+    ladder = tuple(1 << j for j in range(9))
+    assert ladder[-1] > REBASE_ALIGN
+    _fits(_fused_rescore_kernel(1152, 32, ladder, -640).lower(
+        _sds((NCHAN, 1 << 15), jnp.float32, one_chip),
+        _sds((32, NCHAN), jnp.int32, one_chip)).compile())

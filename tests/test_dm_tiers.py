@@ -222,8 +222,10 @@ def test_budget_spans_and_counters_say_what_each_tier_did(tmp_path, caplog):
         all(t["certified"] for t in c["tiers"]) for c in per_chunk) == 2
     for c in per_chunk:
         assert "search/tier_downsample" in c["buckets"]
-        assert all(set(t) == {"downsample", "trials", "coarse_s",
-                              "certified"} for t in c["tiers"])
+        assert all(set(t) - {"best_window"} == {
+            "downsample", "trials", "coarse_s", "certified", "windows"}
+            for t in c["tiers"])
+        assert all(t["windows"] == 4 for t in c["tiers"])
     events, _ = tracer.events_since(0)
     spans = [e for e in events if e.get("name") == "search/tier"]
     assert len(spans) == 9

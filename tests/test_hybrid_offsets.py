@@ -166,7 +166,7 @@ def test_a_miss_has_a_bucket_and_a_hit_has_none():
 # -- the chip branch ------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _interpreted_rescore_kernel(max_off, dm_block):
+def _interpreted_rescore_kernel(max_off, dm_block, windows=None, roll_k=0):
     """``ops/search.py:_fused_rescore_kernel`` with its Pallas kernel in
     interpret mode (one trace per row bucket for the whole module)."""
     import jax
@@ -179,8 +179,10 @@ def _interpreted_rescore_kernel(max_off, dm_block):
     @jax.jit
     def rescore_rows(data, offs):
         plane = dedisperse_plane_pallas_traced(
-            data, offs, max_off, dm_block=dm_block, interpret=True)
-        return search_mod.score_profiles_stacked(plane, xp=jnp)
+            data, offs, max_off, dm_block=dm_block, interpret=True,
+            roll_k=roll_k)
+        return search_mod.score_profiles_stacked(plane, xp=jnp,
+                                                 windows=windows)
 
     return rescore_rows
 
@@ -278,6 +280,52 @@ def test_chip_branch_equals_the_portable_rescore(chip_branch, floor,
     assert both.any()
     for name in ("rebin", "peak"):
         assert np.array_equal(fused[name][both], plain[name][both]), name
+    assert np.allclose(fused["snr"][both], plain["snr"][both], rtol=1e-5)
+
+
+@pytest.mark.parametrize("length", [7, 9])
+def test_chip_branch_with_a_longer_ladder_equals_the_portable_rescore(
+        chip_branch, monkeypatch, length):
+    """ISSUE 32: windows up to the rebase's 128-sample alignment (length 7:
+    64) are scored on the rotated plane and the peak corrected on the
+    host; wider ones (length 9: 256, on 2^15 samples) undo the rotation on
+    the device first.  Either way every exactly rescored row is the
+    portable path's, and the float64 backend's window and peak."""
+    import jax
+
+    from pulsarutils_tpu.ops.plan import dedispersion_shifts
+
+    ladder = tuple(1 << j for j in range(length))
+    width, t = ladder[-1], 128 * ladder[-1]
+    data = make_noise(NCHAN, t, 7200 + length)
+    shifts = np.rint(np.asarray(dedispersion_shifts(
+        NCHAN, 110.0, *GARGS))).astype(int)
+    # a pulse of the widest window, on a block of it
+    for k in range(width):
+        data[np.arange(NCHAN), (40 * width + k + shifts) % t] += \
+            0.45 / np.sqrt(width)  # S/N about 12 at the widest window
+
+    def hybrid():
+        return dedispersion_search(data, 100.0, 120.0, *GARGS,
+                                   backend="jax", kernel="hybrid",
+                                   snr_floor=9.0, windows=ladder)
+
+    fused = hybrid()
+    assert not fused.meta["certified"]
+    monkeypatch.undo()  # back on the CPU branch
+    assert jax.default_backend() == "cpu"
+    plain = hybrid()
+    ref = dedispersion_search(data, 100.0, 120.0, *GARGS, backend="numpy",
+                              windows=ladder)
+    j = ref.argbest()
+    assert fused.argbest() == plain.argbest() == j
+    assert int(ref["rebin"][j]) == width
+    both = np.asarray(fused["exact"]) & np.asarray(plain["exact"])
+    assert both.sum() >= 8
+    for name in ("rebin", "peak"):
+        assert np.array_equal(fused[name][both], plain[name][both]), name
+        assert np.array_equal(fused[name][both],
+                              np.asarray(ref[name])[both]), name
     assert np.allclose(fused["snr"][both], plain["snr"][both], rtol=1e-5)
 
 

@@ -180,7 +180,7 @@ def test_over_2pow24_series_warns_peak_inexact(monkeypatch):
     t = 1 << 25
     calls = []
 
-    def fake_kernel(rows_p, t_, t_blk, with_cert, interpret, sub):
+    def fake_kernel(rows_p, t_, t_blk, with_cert, interpret, sub, **ladder):
         calls.append((rows_p, t_, t_blk))
         return jnp.zeros((rows_p, 128), jnp.float32)
 
