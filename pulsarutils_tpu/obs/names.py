@@ -143,6 +143,10 @@ METRIC_NAMES = {
     "putpu_fdas_trials_total":
         "(DM, accel, jerk) trials scored by the fdas correlation "
         "backend",
+    "putpu_fdmt_head_tiles_total":
+        "(8, 256) tiles the FDMT's VMEM-resident head computes, halo "
+        "chunks and padded rows included, one count per coarse sweep "
+        "(static: plan, time axis, chosen slice; 0 where no head runs)",
     "putpu_fleet_drains_total":
         "graceful worker drains (in-flight chunk finished, ledger "
         "flushed, unstarted leases returned)",

@@ -317,6 +317,12 @@ def _pick_fdmt_tile(t):
     return 0
 
 
+def _padded_length(t):
+    """The time axis the Pallas merges run on: ``t`` where one of their
+    tiles divides it, else the next multiple of 1024."""
+    return t if _pick_fdmt_tile(t) else -(-t // 1024) * 1024
+
+
 def _transform_setup(data, use_pallas):
     """Resolve the Pallas/XLA choice and tile for a time axis of length T.
 
@@ -333,13 +339,13 @@ def _transform_setup(data, use_pallas):
 
     t = data.shape[1]
     t_run = t
-    t_tile = _pick_fdmt_tile(t)
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    if use_pallas and t_tile == 0:
-        t_run = -(-t // 1024) * 1024
-        data = jnp.pad(data, ((0, 0), (0, t_run - t)))
-        t_tile = _pick_fdmt_tile(t_run)
+    if use_pallas:
+        t_run = _padded_length(t)
+        if t_run != t:
+            data = jnp.pad(data, ((0, 0), (0, t_run - t)))
+    t_tile = _pick_fdmt_tile(t_run)
     return (data, t_run, t_tile, bool(use_pallas),
             jax.default_backend() != "tpu", t)
 
@@ -623,27 +629,61 @@ def _merge_pallas(state, it, t_tile, interpret):
 
 
 def head_active(nchan, start_freq, bandwidth, max_delay, n_lo, t):
-    """True iff the fused head WILL run for this transform config.
-
-    THE eligibility gate — `_transform_fn` consults it and so must any
-    A/B harness (a head-vs-per-level parity check on hardware): a
-    hand-replicated copy of these conditions could silently diverge and
-    turn the A/B vacuous.
+    """True iff the fused head WILL run for this transform config: what
+    any A/B harness (a head-vs-per-level parity check on hardware) must
+    ask — a hand-replicated copy of :func:`_head_choice`'s conditions
+    could silently diverge and turn the A/B vacuous.
     """
+    return _head_choice(nchan, start_freq, bandwidth, max_delay, n_lo,
+                        t) is not None
+
+
+def _head_choice(nchan, start_freq, bandwidth, max_delay, n_lo, t):
+    """``(head plan, time slice)`` of the fused head for this transform
+    config, or None where the geometry does not fit it.  THE eligibility
+    gate: `_transform_fn` builds the head from it, :func:`head_active`
+    and :func:`coarse_head_tiles` report it."""
     from .fdmt_resident import (
         HEAD_LEVELS,
         _head_plan_cached,
         head_supported,
+        pick_head_t_slice,
     )
 
     plan = fdmt_plan(nchan, start_freq, bandwidth, max_delay, n_lo)
     if not head_supported(plan.nchan_padded, len(plan.iterations), t):
-        return False
+        return None
     hp = _head_plan_cached(nchan, start_freq, bandwidth, max_delay, n_lo,
                            HEAD_LEVELS)
-    return head_supported(plan.nchan_padded, len(plan.iterations), t,
+    if not head_supported(plan.nchan_padded, len(plan.iterations), t,
                           halo=hp.halo,
-                          max_level_shift=max(hp.max_shift_per_level))
+                          max_level_shift=max(hp.max_shift_per_level)):
+        return None
+    return hp, pick_head_t_slice(hp, t)
+
+
+def coarse_head_tiles(nchan, nsamples, dmmin, dmmax, start_freq, bandwidth,
+                      sample_time):
+    """``(computed, useful)`` (8, 256) tiles of the fused head in ONE
+    coarse sweep of the ``fdmt``/``hybrid`` kernels over these arguments
+    on this backend — the geometry ``ops/search.py:_search_jax_fdmt``
+    resolves, through the same functions; ``(0, 0)`` where no head runs
+    (the Pallas merges are off, or the geometry does not fit it).
+    """
+    import jax
+
+    from .fdmt_resident import head_tile_counts
+
+    if jax.default_backend() != "tpu":
+        return 0, 0
+    _, n_lo, n_hi = fdmt_trial_dms(nchan, dmmin, dmmax, start_freq,
+                                   bandwidth, sample_time)
+    t_run = _padded_length(nsamples)
+    choice = _head_choice(nchan, float(start_freq), float(bandwidth), n_hi,
+                          n_lo, t_run)
+    if choice is None:
+        return 0, 0
+    return head_tile_counts(choice[0], t_run, choice[1])
 
 
 @functools.lru_cache(maxsize=16)
@@ -692,20 +732,14 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
     # 0.365 s per-level, transform+score).
     head_run = None
     n_head = 0
-    if use_head and head_active(nchan, start_freq, bandwidth, max_delay,
-                                n_lo, t):
-        from .fdmt_resident import (
-            HEAD_LEVELS,
-            _build_head_kernel,
-            _head_plan_cached,
-            pick_head_t_slice,
-        )
+    head_choice = use_head and _head_choice(nchan, start_freq, bandwidth,
+                                            max_delay, n_lo, t)
+    if head_choice:
+        from .fdmt_resident import HEAD_LEVELS, _build_head_kernel
 
-        hp = _head_plan_cached(nchan, start_freq, bandwidth, max_delay,
-                               n_lo, HEAD_LEVELS)
         head_run, _ = _build_head_kernel(
             nchan, start_freq, bandwidth, max_delay, n_lo,
-            HEAD_LEVELS, t, pick_head_t_slice(hp, t), interpret)
+            HEAD_LEVELS, t, head_choice[1], interpret)
         n_head = HEAD_LEVELS
 
     # deep-level pairing: fuse the LAST TWO per-level merges into one

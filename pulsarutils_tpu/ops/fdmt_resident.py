@@ -18,10 +18,18 @@ into independent 128-channel groups whose whole sub-tree state
   VMEM scratch buffers, and writes only the LAST head level's rows to
   HBM — one read of the input + one write of the head output instead of
   ~4 HBM passes per level;
-* per-row shifted reads reuse the aligned-load + lane-rotate + blend
-  primitive of the dedispersion kernel
-  (:func:`~pulsarutils_tpu.ops.pallas_dedisperse.shifted_row_tile`);
-  merge tables ride scalar prefetch exactly like the per-level kernel.
+* per-row shifted reads are the aligned-load + lane-rotate + blend of
+  the dedispersion kernel
+  (:func:`~pulsarutils_tpu.ops.pallas_dedisperse.shifted_row_tile`),
+  made once over a row's whole extent in the step; merge tables and
+  each group's own row count ride scalar prefetch;
+* the slice is the largest whose two buffers fit the VMEM the core has
+  (128 MiB on a v5e; Mosaic's *default scoped limit* is 16 MiB, so the
+  ``pallas_call`` asks for its share by ``vmem_limit_bytes``): every
+  non-final level computes the slice plus the remaining halo, one chunk
+  more than the slice needs, so a step of 2,048 samples computes two
+  chunks for one and a step of 16,384 nine for eight
+  (:func:`pick_head_t_slice`, :func:`head_tile_counts`).
 
 The deep levels (large shifts, few rows) stay on the existing
 per-level kernel: their halos are too wide for VMEM residency and they
@@ -44,10 +52,13 @@ import functools
 import numpy as np
 
 #: tree levels fused into the VMEM-resident head; 2^HEAD_LEVELS channels
-#: per independent group (128 -> ~260 live rows per group, ~5 MB VMEM)
+#: per independent group (128 in -> up to 256 live rows per group from
+#: DM 0, 128 when the plan is pruned; 6 to 20 MiB a buffer at the slice
+#: :func:`pick_head_t_slice` takes)
 HEAD_LEVELS = 7
 
-#: default time-slice (samples); must divide T and hold the head halo
+#: smallest time-slice (samples), the eligibility floor: must divide T
+#: and hold the head halo
 HEAD_T_SLICE = 2048
 
 #: lane width of the chunked-row layout (one (8, L) chunk = 2048 samples).
@@ -55,8 +66,8 @@ HEAD_T_SLICE = 2048
 #: measured SLOWER than the per-level kernel: 8x narrower ops than its
 #: (8, 1024) tiles drowned the HBM win in instruction overhead); it also
 #: lets every head-level shifted read take the static-base fast path —
-#: all head-level shifts are < L by eligibility, so the 16-row load base
-#: is static and no dynamic sublane rotate is ever issued.
+#: all head-level shifts are < L by eligibility, so a row's load starts
+#: at its line 0 and no dynamic sublane rotate is ever issued.
 _L = 256
 _CHUNK = 8 * _L
 
@@ -65,8 +76,12 @@ _CHUNK = 8 * _L
 #: dominated the un-unrolled kernel (~110 ns/row vs ~20 ns of vector
 #: work -> 0.53 s, SLOWER than the per-level path's 0.37 s); unrolling
 #: by 8 amortises it and flips the comparison (0.32 s measured, v5e
-#: 1024 x 1M benchmark); 16 regresses hard (4.2 s measured — register
-#: pressure/spill pathology), so 8 is pinned.
+#: 1024 x 1M benchmark); 16 regressed hard then (4.2 s — register
+#: pressure/spill pathology).  Re-measured with the whole-row body
+#: (PR 33, HTRU tier 0 at 16,384): 4 and 2 are slower (35.7 and 41.6
+#: against 23.4 ms a call) behind Mosaic compiles of 34 and 23 s, and 4
+#: never returned at the tiers-1-4 shape; 16 equals 8 and pads more
+#: rows.  8 stays pinned: the plan's row padding is to this number.
 _ROW_UNROLL = 8
 
 
@@ -150,6 +165,11 @@ class HeadPlan:
                 "leaf": it["shift_high"] is not None,
             })
             in_offsets = out_offsets[::bpg_out]
+        #: per (level, group): iterations of the unrolled row loop that
+        #: cover the group's own rows (a narrower group stops early)
+        self.row_blocks = np.asarray(
+            [-(-tab["counts"] // _ROW_UNROLL) for tab in self.tables],
+            np.int32)
         self.rows_valid = self.tables[-1]["counts"]  # real final counts
         self.row_starts = np.concatenate(
             [[0], np.cumsum(self.rows_valid)])[:-1]
@@ -177,64 +197,118 @@ def _head_plan_cached(nchan, start_freq, bandwidth, max_delay, min_delay,
                               min_delay), n_levels)
 
 
-#: VMEM budget (bytes) for the head's two ping-pong scratch buffers —
-#: the chip's ~16 MB VMEM minus headroom for the small DMA staging and
-#: compiler temporaries (t_slice = 8192 at the benchmark plan lands at
-#: 12.6 MB; 16384 would need 21 MB and is rejected)
-_VMEM_BUDGET = 14 << 20
+#: VMEM of one v5e TensorCore (``jax/_src/pallas/mosaic/tpu_info.py``),
+#: assumed where no TPU is attached to ask: a program compiled from a
+#: CPU host is compiled for a described v5e (``tests/test_chip_compile.py``)
+_V5E_VMEM_BYTES = 128 << 20
+
+#: share of the core's VMEM the head asks Mosaic for
+#: (``vmem_limit_bytes``; the compiler's default scoped limit is 16 MiB
+#: whatever the chip has), and what of it is left to Mosaic's own
+#: scratch, the DMA staging and spills — the rest is the budget of the
+#: two ping-pong buffers
+_VMEM_SHARE = 0.75
+_VMEM_HEADROOM = 8 << 20
+
+
+def head_vmem_limit():
+    """Bytes of VMEM the head's ``pallas_call`` may use on this device."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    try:
+        capacity = pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:  # no TPU attached
+        capacity = _V5E_VMEM_BYTES
+    return int(capacity * _VMEM_SHARE)
 
 
 def _head_geometry(head, t_slice):
-    """Derived sizes for one (plan, t_slice): chunks allocated per step
-    and the scratch rows — shared by the builder and the slice chooser."""
+    """Derived sizes for one (plan, t_slice): chunks allocated per step,
+    the scratch rows and the chunks each level computes — shared by the
+    builder, the slice chooser and the tile count."""
     # level-0 input must stay valid over t_slice + halo; +1 chunk so the
     # 16-row shifted loads (8 rows past a chunk's base) never run off
     chunks_alloc = -(-(t_slice + head.halo) // _CHUNK) + 1
     rows_buf = max([head.rows_in] + head.rows_out)
-    return chunks_alloc, rows_buf
+    n_chunks_out = [-(-(t_slice + head.remaining_halo(lev + 1)) // _CHUNK)
+                    for lev in range(head.n_levels)]
+    n_chunks_out[-1] = t_slice // _CHUNK  # the output is exactly the slice
+    return chunks_alloc, rows_buf, n_chunks_out
 
 
-def pick_head_t_slice(head, t):
+def head_scratch_bytes(head, t_slice):
+    """VMEM of the two ping-pong buffers of one grid step."""
+    chunks_alloc, rows_buf, _ = _head_geometry(head, t_slice)
+    return 2 * rows_buf * chunks_alloc * _CHUNK * 4
+
+
+def head_tile_counts(head, t, t_slice, row_extents=True):
+    """``(computed, useful)`` tiles of one head call: a tile is one
+    (8, L) chunk of one row at one level, the unit of the kernel's inner
+    loop.  Useful are the real rows over the slice itself; computed adds
+    each non-final level's halo chunks and the rows the row loop pads
+    to (a multiple of the unroll of the group's own count, or of the
+    widest group's with ``row_extents`` off).  Static: plan, T, slice.
+    """
+    n_chunks_out = _head_geometry(head, t_slice)[2]
+    computed = useful = 0
+    for lev, tab in enumerate(head.tables):
+        rows = (int(head.row_blocks[lev].sum()) * _ROW_UNROLL
+                if row_extents else head.n_groups * head.rows_out[lev])
+        computed += rows * n_chunks_out[lev]
+        useful += int(tab["counts"].sum()) * (t_slice // _CHUNK)
+    return computed * (t // t_slice), useful * (t // t_slice)
+
+
+def pick_head_t_slice(head, t, vmem_limit=None):
     """Largest power-of-two time slice whose scratch fits VMEM.
 
     Bigger slices amortise the head's halo recompute (every non-final
     level computes ``ceil((t_slice + halo)/CHUNK)`` chunks for
-    ``t_slice/CHUNK`` useful ones: 2-for-1 at 2048 with the benchmark's
-    148-sample halo, 5-for-4 at 8192) and cut the per-step grid
-    overhead — measured 0.232 s -> 0.146 s head-only at the 1024 x 1M
-    benchmark.  The ceiling is the two ping-pong buffers' VMEM
-    footprint (:data:`_VMEM_BUDGET`); the floor is the eligibility
-    t_slice (:data:`HEAD_T_SLICE`), which callers have already checked
-    divides T.
+    ``t_slice/CHUNK`` useful ones: 2-for-1 at 2048, 5-for-4 at 8192,
+    9-for-8 at 16384) and cut the per-step grid overhead; the head's
+    time follows its tile count (:func:`head_tile_counts`;
+    ``docs/performance.md`` has the chip's sweep).  The ceiling is the
+    two ping-pong buffers' footprint against ``vmem_limit`` (default:
+    :func:`head_vmem_limit`, what the builder asks Mosaic for) less
+    :data:`_VMEM_HEADROOM`; the floor is the eligibility t_slice
+    (:data:`HEAD_T_SLICE`), which callers have already checked divides T.
     """
-    for t_slice in (16384, 8192, 4096, 2048):
+    if vmem_limit is None:
+        vmem_limit = head_vmem_limit()
+    for t_slice in (32768, 16384, 8192, 4096, 2048):
         if t_slice < HEAD_T_SLICE or t % t_slice or t_slice % _CHUNK:
             continue
         if head.halo > (2 * t_slice) // 3:
             continue
-        chunks_alloc, rows_buf = _head_geometry(head, t_slice)
-        if 2 * rows_buf * chunks_alloc * _CHUNK * 4 <= _VMEM_BUDGET:
+        if head_scratch_bytes(head, t_slice) <= vmem_limit - _VMEM_HEADROOM:
             return t_slice
     return HEAD_T_SLICE
 
 
 @functools.lru_cache(maxsize=8)
 def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
-                       n_levels, t, t_slice, interpret):
+                       n_levels, t, t_slice, interpret, row_extents=True):
     """Compile the fused-head pallas program for one (plan, T) config.
 
     I/O is MANUAL DMA (``ANY``-space operands + ``make_async_copy``)
     rather than pipelined BlockSpecs: the pipelined form double-buffers
     ``k_in`` whole input slices in VMEM, which at t_slice > 2048 blew
-    the ~16 MB VMEM (measured: every (t_slice >= 4096 | levels >= 8)
-    combination failed to compile).  Manual copies stage exactly the
-    ``chunks_alloc`` chunks a step needs, un-double-buffered — the DMA
-    is ~microseconds against a ~200 us compute step, so the lost
-    overlap is noise and the freed VMEM buys the big-slice win
-    (:func:`pick_head_t_slice`).  The circular wrap is handled by
-    statically-unrolled per-step copy segments (DMA shapes must be
-    static; only the last few steps wrap and each split is a
-    compile-time constant).
+    Mosaic's default 16 MiB of scoped VMEM (measured, round 4: every
+    (t_slice >= 4096 | levels >= 8) combination failed to compile).
+    Manual copies stage exactly the ``chunks_alloc`` chunks a step
+    needs, un-double-buffered, and the call asks for its share of the
+    VMEM the core has (:func:`head_vmem_limit`), which buys the
+    big-slice win (:func:`pick_head_t_slice`).  The copies are NOT
+    noise any more: at 16,384 a step of an unpruned plan stages 10 MiB
+    in and 12.5 MiB out around its vector work, un-overlapped
+    (``docs/performance.md`` round 4 has the chip's reading; ROADMAP
+    S3).  The circular wrap is handled by statically-unrolled per-step
+    copy segments (DMA shapes must be static; only the last few steps
+    wrap and each split is a compile-time constant).
+
+    ``row_extents`` is the tests' seam: off, every group loops to the
+    widest group's row count, as the kernel did before PR 33.
     """
     import jax
     import jax.numpy as jnp
@@ -249,33 +323,29 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
     assert max(head.max_shift_per_level) < _L, head.max_shift_per_level
     n_slices = t // t_slice
     cpb = t_slice // _CHUNK          # (8, L) chunks per slice
-    chunks_alloc, rows_buf = _head_geometry(head, t_slice)
+    chunks_alloc, rows_buf, n_chunks_out = _head_geometry(head, t_slice)
     r_alloc = chunks_alloc * 8
     c8 = n_slices * cpb * 8          # time axis in 8-row units
     rows_final = head.rows_out[-1]
 
     grid = (head.n_groups, n_slices)
 
-    n_chunks_out = [-(-(t_slice + head.remaining_halo(lev + 1)) // _CHUNK)
-                    for lev in range(n_levels)]
-    n_chunks_out[-1] = cpb  # the head's output is exactly the slice
-
     def kernel(*args):
-        # scalar prefetch: 4 tables per level, each (n_groups, rows_max)
+        # scalar prefetch: 4 tables per level, each (n_groups, rows_max),
+        # then the row loops' trip counts, (n_levels, n_groups)
         tabs = args[:4 * n_levels]
-        data_hbm = args[4 * n_levels]       # (rows, c8, L) in ANY space
-        out_hbm = args[4 * n_levels + 1]    # (G*rows_final, c8, L) in ANY
-        buf_a, buf_b, sem_in, sem_out = args[4 * n_levels + 2:]
+        blocks_t = args[4 * n_levels]
+        data_hbm = args[4 * n_levels + 1]   # (rows, c8, L) in ANY space
+        out_hbm = args[4 * n_levels + 2]    # (G*rows_final, c8, L) in ANY
+        buf_a, buf_b, sem_in, sem_out = args[4 * n_levels + 3:]
 
         g = pl.program_id(0)
         i_s = pl.program_id(1)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (8, _L), 1)
 
         # stage this step's input window straight into the level-0
-        # buffer; un-overlapped: the copies are ~us against a ~200 us
-        # compute step.  DMA shapes must be static, so the circular
-        # wrap is handled by per-step static segment lists: only the
-        # last few steps wrap, and each such step's (dst, src, size)
+        # buffer, un-overlapped.  DMA shapes must be static, so the
+        # circular wrap is handled by per-step static segment lists: only
+        # the last few steps wrap, and each such step's (dst, src, size)
         # split is a compile-time constant — no padded copy of the
         # 4 GB input (a device-side pad doubled input HBM and OOMed
         # the 1M benchmark).
@@ -321,17 +391,23 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
                 c.start()
                 c.wait()
 
-        def shifted_chunk(src, row, c, s):
-            """``src[row, c*CHUNK + s : +CHUNK]`` as an (8, L) tile.
-
-            Every head shift is < L (eligibility), so the 16-row load
-            base ``c*8`` is STATIC — one aligned load, one dynamic
-            lane-rotate, one two-row blend; no dynamic sublane rotate
-            (the same q0 specialisation as the dedispersion kernel).
+        def shifted_row(src, row, s, nco):
+            """``src[row, s : s + nco*CHUNK]`` as an (8*nco, L) tile: the
+            row's whole extent in ONE aligned load of ``nco + 1`` chunks,
+            one dynamic lane-rotate and one blend of each L-sample line
+            with the line after it.  Every head shift is < L
+            (eligibility), so the load starts at line 0 — no dynamic
+            sublane rotate (the same q0 specialisation as the
+            dedispersion kernel).  Chunk by chunk (a 16-line load each)
+            every chunk but the first was loaded and rotated twice and
+            the traced body grew with the slice: 32.3 -> 23.6 ms a call
+            at 16,384 on the chip (``docs/performance.md`` round 4).
             """
-            rows16 = src[row, pl.ds(c * 8, 16), :]
-            rolled = pltpu.roll(rows16, (_L - s) % _L, 1)
-            return jnp.where(lane < _L - s, rolled[0:8], rolled[1:9])
+            lines = src[row, pl.ds(0, 8 * nco + 8), :]
+            rolled = pltpu.roll(lines, (_L - s) % _L, 1)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (8 * nco, _L), 1)
+            return jnp.where(lane < _L - s, rolled[0:8 * nco],
+                             rolled[1:8 * nco + 1])
 
         src, dst = buf_a, buf_b
         for lev in range(n_levels):
@@ -346,20 +422,20 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
                 # over _ROW_UNROLL rows of vector work
                 for dr in range(_ROW_UNROLL):
                     r = rb * _ROW_UNROLL + dr
-                    il = il_t[g, r]
-                    ih = ih_t[g, r]
-                    s = s_t[g, r]
-                    for c in range(nco):
-                        low = shifted_chunk(src, il, c, s)
-                        if leaf:
-                            high = shifted_chunk(src, ih, c, sh_t[g, r])
-                        else:
-                            high = src[ih, pl.ds(c * 8, 8), :]
-                        dst[r, pl.ds(c * 8, 8), :] = low + high
+                    low = shifted_row(src, il_t[g, r], s_t[g, r], nco)
+                    if leaf:
+                        high = shifted_row(src, ih_t[g, r], sh_t[g, r], nco)
+                    else:
+                        high = src[ih_t[g, r], pl.ds(0, 8 * nco), :]
+                    dst[r, pl.ds(0, 8 * nco), :] = low + high
                 return 0
 
-            jax.lax.fori_loop(0, head.rows_out[lev] // _ROW_UNROLL,
-                              row_body, 0)
+            # each group loops over its own rows (a dynamic trip count
+            # from scalar prefetch): the padding to the widest group's
+            # count was 27 % of an unpruned plan's tiles
+            n_blocks = (blocks_t[lev, g] if row_extents
+                        else head.rows_out[lev] // _ROW_UNROLL)
+            jax.lax.fori_loop(0, n_blocks, row_body, 0)
             src, dst = dst, src
 
         # the final level landed in `src` (post-swap): one DMA out
@@ -372,7 +448,7 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
         copy_out.wait()
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 * n_levels,
+        num_scalar_prefetch=4 * n_levels + 1,
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -387,12 +463,15 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (head.n_groups * rows_final, c8, _L), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=head_vmem_limit()),
         interpret=bool(interpret), name="fdmt_head")
 
     flat_tabs = []
     for tab in head.tables:
         flat_tabs += [jnp.asarray(tab[k]) for k in
                       ("idx_low", "idx_high", "shift", "shift_high")]
+    flat_tabs.append(jnp.asarray(head.row_blocks))
 
     # host-side reassembly index: global level-n row -> (group, local row)
     gather_g = np.concatenate(
@@ -412,7 +491,8 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
 
 
 def head_transform(data, max_delay, start_freq, bandwidth, min_delay=0,
-                   n_levels=HEAD_LEVELS, t_slice=None, interpret=None):
+                   n_levels=HEAD_LEVELS, t_slice=None, interpret=None,
+                   row_extents=True):
     """Run the fused head: raw (nchan, T) -> level-``n_levels`` state.
 
     Returns the same float32 rows the first ``n_levels`` per-level
@@ -434,7 +514,7 @@ def head_transform(data, max_delay, start_freq, bandwidth, min_delay=0,
     run, head = _build_head_kernel(
         nchan, float(start_freq), float(bandwidth), int(max_delay),
         int(min_delay), int(n_levels), int(t), int(t_slice),
-        bool(interpret))
+        bool(interpret), bool(row_extents))
     if nchan < head.rows_in * head.n_groups:
         data = jnp.concatenate(
             [data, jnp.zeros((head.rows_in * head.n_groups - nchan, t),

@@ -261,6 +261,25 @@ def _count_windows(windows, nsamples):
     return n
 
 
+def _count_head_tiles(state, route, shape, dmmin, dmmax, start_freq,
+                      bandwidth, tsamp):
+    """Counts the (8, 256) tiles the FDMT's fused head computes in one
+    sweep (``putpu_fdmt_head_tiles_total``: halo chunks and padded rows
+    included; ``ops/fdmt.py:coarse_head_tiles``) and returns their
+    number: 0 where the sweep runs no head.  ``route`` is the call's
+    ``(backend, kernel, mesh)``, ``state`` what a fall-back made of it."""
+    backend, kernel, mesh = route
+    if (mesh is not None or state.get("backend", backend) != "jax"
+            or state.get("kernel", kernel) not in ("hybrid", "fdmt")):
+        return 0
+    from ..ops.fdmt import coarse_head_tiles
+
+    n = coarse_head_tiles(shape[0], shape[1], dmmin, dmmax, start_freq,
+                          bandwidth, tsamp)[0]
+    obs_metrics.counter("putpu_fdmt_head_tiles_total").inc(n)
+    return n
+
+
 def _clean_block(block, m, xp, cut_outliers, zero_dm, fft_zap, resample):
     """The conditioning of one chunk, parameterised by array namespace:
     the device (jitted) and host (fallback) paths call this one
@@ -1520,6 +1539,9 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             if certified:
                 obs_metrics.counter("putpu_tier_certified_total").inc()
             nwindows = _count_windows(tier.windows, arr.shape[1])
+            _count_head_tiles(fallback_state, (backend, kernel, None),
+                              arr.shape, tier.dm_lo, tier.dm_hi, start_freq,
+                              bandwidth, tier.sample_time)
             rec["tiers"].append({
                 "downsample": tier.downsample, "trials": ttable.nrows,
                 "coarse_s": round(coarse_s() - coarse0, 4),
@@ -1722,6 +1744,10 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                             chunk=istart, policy=dispatch_policy,
                             windows=flat_windows)
                         _count_windows(flat_windows, array.shape[1])
+                        _count_head_tiles(
+                            fallback_state, (backend, kernel, mesh),
+                            array.shape, dmmin, dmmax, start_freq, bandwidth,
+                            eff_tsamp)
             except _resilience_ladder.OOMFloorError as exc:
                 # the degradation ladder's floor itself OOMed: this
                 # chunk cannot be searched on this host at ANY geometry

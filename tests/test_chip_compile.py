@@ -155,6 +155,32 @@ def test_fdmt_transform_as_resolved_on_tpu(as_tpu, one_chip, plan):
     _fits(compiled)
 
 
+@pytest.mark.parametrize("hi,lo,t", [
+    (1068, 0, 1 << 19),    # tier 0: unpruned, 256 live rows, 72 MiB
+    (641, 535, 1 << 14),   # tier 5: one slice laps the whole axis
+], ids=["htru_tier0", "htru_tier5"])
+def test_fdmt_head_on_the_survey_plans(as_tpu, one_chip, hi, lo, t):
+    """The head alone at HTRU's tiers (1,182-1,582 MHz; ISSUE 33): the
+    slice its chooser takes needs more scoped VMEM than Mosaic's default
+    16 MiB, so this compile holds the ``vmem_limit_bytes`` it asks for
+    to what the v5e's compiler grants."""
+    import jax
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops import fdmt_resident as fr
+
+    hp = fr._head_plan_cached(NCHAN, 1182.0, 400.0, hi, lo, fr.HEAD_LEVELS)
+    t_slice = fr.pick_head_t_slice(hp, t)
+    assert t_slice == min(t, 32768)
+    if lo == 0:
+        assert fr.head_scratch_bytes(hp, t_slice) > 16 << 20
+    run, _ = fr._build_head_kernel(NCHAN, 1182.0, 400.0, hi, lo,
+                                   fr.HEAD_LEVELS, t, t_slice, False)
+    compiled = jax.jit(run).lower(
+        _sds((NCHAN, t), jnp.float32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("with_cert", [False, True])
 def test_score_plane_pallas(as_tpu, one_chip, with_cert):
     import jax

@@ -6,13 +6,33 @@ import pytest
 
 from pulsarutils_tpu.ops.fdmt import _merge_xla, fdmt_plan
 from pulsarutils_tpu.ops.fdmt_resident import (
+    _VMEM_HEADROOM,
     HEAD_LEVELS,
     HeadPlan,
+    head_scratch_bytes,
     head_supported,
+    head_tile_counts,
     head_transform,
+    head_vmem_limit,
+    pick_head_t_slice,
 )
 
 GARGS = (1200.0, 200.0)
+#: HTRU/BPSR's band (chipbench/configs/htru_bpsr_lowdm.json)
+HTRU = (1182.0, 400.0)
+#: the sweeps of the benchmark's cells, (band, max_delay, min_delay, T):
+#: HTRU's six tiers (1-4 share a plan) and the rehearsal (PERF.md 4)
+SURVEY_PLANS = {
+    "htru_tier0": (HTRU, 1068, 0, 1 << 19),
+    "htru_tier1": (HTRU, 1068, 535, 1 << 18),
+    "htru_tier5": (HTRU, 641, 535, 1 << 14),
+    "rehearsal": (GARGS, 612, 458, 1 << 20),
+}
+
+
+def _survey_head(name):
+    band, hi, lo, t = SURVEY_PLANS[name]
+    return HeadPlan(fdmt_plan(1024, *band, hi, lo)), t
 
 
 def _unfused_head(plan, data, n_levels):
@@ -87,3 +107,133 @@ class TestHead:
         assert not head_supported(1024, 10, 1000)       # t not divisible
         assert head_supported(1024, 10, 1 << 14)
         assert not head_supported(1024, 10, 1 << 14, halo=2000)
+
+
+class TestSliceAndExtents:
+    """ISSUE 33: the slice sized to the core's VMEM, the row loop to each
+    group's own rows."""
+
+    NCHAN, T, HI = 256, 1 << 14, 267   # HTRU's band, unpruned from DM 0
+
+    @pytest.fixture(scope="class")
+    def case(self):
+        plan = fdmt_plan(self.NCHAN, *HTRU, self.HI, 0)
+        hp = HeadPlan(plan)
+        # the groups differ in row count at every level past the first
+        assert len(set(hp.tables[-1]["counts"])) == hp.n_groups > 1
+        assert hp.row_blocks[-1].min() < hp.row_blocks[-1].max()
+        data = np.random.default_rng(5).standard_normal(
+            (self.NCHAN, self.T)).astype(np.float32)
+        return data, _unfused_head(plan, data, HEAD_LEVELS)
+
+    @pytest.mark.parametrize("row_extents", [True, False])
+    @pytest.mark.parametrize("t_slice", [2048, 4096, 8192, 1 << 14],
+                             ids=["2048", "4096", "8192", "T"])
+    def test_bit_identical_at_every_slice(self, case, t_slice, row_extents):
+        """``t_slice == T`` is tier 5's shape: one slice whose window
+        laps the whole axis, every copy a wrap segment."""
+        data, ref = case
+        out = np.asarray(head_transform(
+            data, self.HI, *HTRU, t_slice=t_slice, interpret=True,
+            row_extents=row_extents))
+        assert out.shape == ref.shape
+        assert np.array_equal(out, ref), float(np.abs(out - ref).max())
+
+    @pytest.mark.parametrize("name", sorted(SURVEY_PLANS))
+    def test_slice_leaves_the_floor_on_the_survey_plans(self, name):
+        hp, t = _survey_head(name)
+        assert head_vmem_limit() == 96 << 20  # no TPU here: a v5e's share
+        # 72 / 45 / 20 / 36 MiB of scratch: the largest slice dividing T
+        assert pick_head_t_slice(hp, t) == min(t, 32768)
+        assert pick_head_t_slice(hp, 8192) == 8192  # T allows no more
+
+    @pytest.mark.parametrize("limit_mib", [21, 24, 32, 48, 64, 96])
+    @pytest.mark.parametrize("name", sorted(SURVEY_PLANS))
+    def test_slice_never_exceeds_the_budget_given(self, name, limit_mib):
+        hp, t = _survey_head(name)
+        t_slice = pick_head_t_slice(hp, t, vmem_limit=limit_mib << 20)
+        assert (head_scratch_bytes(hp, t_slice)
+                <= (limit_mib << 20) - _VMEM_HEADROOM)
+        # and it is the largest that fits
+        if 2 * t_slice <= min(t, 32768):
+            assert (head_scratch_bytes(hp, 2 * t_slice)
+                    > (limit_mib << 20) - _VMEM_HEADROOM)
+
+    def test_scratch_of_the_tier0_plan(self):
+        hp, _ = _survey_head("htru_tier0")
+        assert [head_scratch_bytes(hp, ts) >> 20 for ts in
+                (2048, 4096, 8192, 16384, 32768)] == [12, 16, 24, 40, 72]
+
+    @pytest.mark.parametrize("t_slice,computed,extents", [
+        (2048, 5685248, 4171776),
+        (4096, 4366336, 3200000),
+        (8192, 3706880, 2714112),
+        (16384, 3377152, 2471168),
+        (32768, 3212288, 2349696),
+    ])
+    def test_tile_count_of_the_tier0_plan(self, t_slice, computed, extents):
+        """PERF.md 6 (PR 33): 2.615 tiles computed per useful one at the
+        2,048 floor, 1.553 at 16,384 and 1.478 at 32,768; 1.137 and 1.081
+        with the groups' own row extents."""
+        hp, t = _survey_head("htru_tier0")
+        assert head_tile_counts(hp, t, t_slice, row_extents=False) == (
+            computed, 2173952)
+        assert head_tile_counts(hp, t, t_slice) == (extents, 2173952)
+
+    @pytest.mark.parametrize("name,ratio", [
+        ("htru_tier1", 1.975), ("htru_tier5", 1.611), ("rehearsal", 1.429)])
+    def test_tile_ratio_at_the_parents_slice(self, name, ratio):
+        hp, t = _survey_head(name)
+        parents = {"htru_tier1": 4096, "htru_tier5": 8192,
+                   "rehearsal": 8192}[name]
+        computed, useful = head_tile_counts(hp, t, parents,
+                                            row_extents=False)
+        assert round(computed / useful, 3) == ratio
+        now, _ = head_tile_counts(hp, t, pick_head_t_slice(hp, t))
+        assert now < computed
+
+
+class TestSweepCounter:
+    """``putpu_fdmt_head_tiles_total``: one count per coarse sweep."""
+
+    #: cell 2's sweep: 1,024 x 2^19 at 64 us, DM 0-52
+    SWEEP = ((1024, 1 << 19), 0.0, 52.0, 1182.0, 400.0, 6.4e-05)
+
+    @staticmethod
+    def _total():
+        from pulsarutils_tpu.obs.metrics import REGISTRY
+
+        return sum(s["value"] for s in REGISTRY.snapshot()
+                   if s["name"] == "putpu_fdmt_head_tiles_total")
+
+    def test_counts_the_sweeps_that_run_the_head(self, monkeypatch):
+        import jax
+
+        from pulsarutils_tpu.pipeline.search_pipeline import (
+            _count_head_tiles,
+        )
+
+        n0 = self._total()
+        # off the TPU the Pallas merges are off: no head, nothing counted
+        assert _count_head_tiles({}, ("jax", "hybrid", None),
+                                 *self.SWEEP) == 0
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        from pulsarutils_tpu.ops.fdmt import fdmt_trial_dms
+
+        (nchan, t), dmmin, dmmax, f0, bw, tsamp = self.SWEEP
+        _, n_lo, n_hi = fdmt_trial_dms(nchan, dmmin, dmmax, f0, bw, tsamp)
+        hp = HeadPlan(fdmt_plan(nchan, f0, bw, n_hi, n_lo))
+        tiles = head_tile_counts(hp, t, pick_head_t_slice(hp, t))[0]
+        assert 2.3e6 < tiles < 2.4e6  # tier 0's 2,350 k, but for a trial
+        assert _count_head_tiles({}, ("jax", "hybrid", None),
+                                 *self.SWEEP) == tiles
+        assert _count_head_tiles({}, ("jax", "fdmt", None),
+                                 *self.SWEEP) == tiles
+        # another kernel, a mesh, a run that fell back to the host: none
+        assert _count_head_tiles({}, ("jax", "auto", None),
+                                 *self.SWEEP) == 0
+        assert _count_head_tiles({}, ("jax", "hybrid", object()),
+                                 *self.SWEEP) == 0
+        assert _count_head_tiles({"backend": "numpy", "kernel": "auto"},
+                                 ("jax", "hybrid", None), *self.SWEEP) == 0
+        assert self._total() - n0 == 2 * tiles
