@@ -1,8 +1,9 @@
 """The plain reference: what the pulse chunk's best row has to be.
 
 NumPy and SciPy only; imports nothing of the program and reads nothing the
-program made (its own header parser, its own bad-channel mask from its own
-pass over the file, its own dispersion arithmetic in ``dispersion.py``).
+program made (its own header parser, its own reader of 1-, 2-, 4- and 8-bit
+samples, its own bad-channel mask from its own pass over the file, its own
+dispersion arithmetic in ``dispersion.py``).
 
 It restates the upstream semantics (SURVEY.md: ``clean.py:70-111``,
 ``dedispersion.py:173-201``, ``stats.py:63-90``) in float64:
@@ -72,23 +73,29 @@ def read_header(path):
                 raise ValueError(f"unknown SIGPROC key {key!r}")
 
 
-def load_packed_T(path):
-    """The file's 2-bit samples as ``(nchan/4, nsamples)`` packed bytes,
-    FILE channel order (byte row j holds file channels 4j..4j+3, lowest in
-    the low bits), plus the header."""
+def read_packed(path):
+    """The file's 1-, 2-, 4- or 8-bit samples as ``(nchan * nbits / 8,
+    nsamples)`` packed bytes, FILE channel order (at ``per = 8 / nbits``
+    channels a byte, byte row j holds file channels ``per * j .. per * j +
+    per - 1``, the lowest in the low bits), plus the header, whose
+    ``nbits`` says how to take a row apart."""
     hdr, off = read_header(path)
-    if hdr["nbits"] != 2 or hdr.get("nifs", 1) != 1:
-        raise ValueError("the reference reads single-IF 2-bit files")
-    nchan = hdr["nchans"]
+    if hdr["nbits"] not in (1, 2, 4, 8) or hdr.get("nifs", 1) != 1:
+        raise ValueError("the reference reads single-IF 1-, 2-, 4- and "
+                         "8-bit files")
+    nrow = hdr["nchans"] * hdr["nbits"] // 8
     raw = np.fromfile(path, dtype=np.uint8, offset=off)
-    nsamples = raw.size // (nchan // 4)
-    raw = raw[: nsamples * (nchan // 4)].reshape(nsamples, nchan // 4)
+    nsamples = raw.size // nrow
+    raw = raw[: nsamples * nrow].reshape(nsamples, nrow)
     return np.ascontiguousarray(raw.T), hdr
 
 
-def _file_channel(packed_T, fc, lo=None, hi=None):
-    row = packed_T[fc // 4, lo:hi]
-    return (row >> np.uint8(2 * (fc % 4))) & np.uint8(3)
+def _file_channel(packed_T, nbits, fc, lo=None, hi=None):
+    per = 8 // nbits
+    row = packed_T[fc // per, lo:hi]
+    if per == 1:
+        return row
+    return (row >> np.uint8(nbits * (fc % per))) & np.uint8((1 << nbits) - 1)
 
 
 def medfilt_zero_padded(x, size):
@@ -102,24 +109,25 @@ def mad(x):
     return np.median(np.abs(x - np.median(x))) / 0.6744897501960817
 
 
-def bad_channels(packed_T):
+def bad_channels(packed_T, nbits):
     """Boolean mask in FILE channel order, from whole-file statistics.
     The per-channel moments are exact: they come from the counts of each
     byte value in each packed row."""
     nrow, nsamples = packed_T.shape
+    per = 8 // nbits
     b = np.arange(256)
-    mean = np.empty(nrow * 4)
-    std = np.empty(nrow * 4)
+    mean = np.empty(nrow * per)
+    std = np.empty(nrow * per)
     for j in range(nrow):
         hist = np.bincount(packed_T[j], minlength=256).astype(np.float64)
-        for k in range(4):
-            lv = (b >> (2 * k)) & 3
+        for k in range(per):
+            lv = (b >> (nbits * k)) & ((1 << nbits) - 1)
             s1 = float((hist * lv).sum())
             s2 = float((hist * lv * lv).sum())
             m = s1 / nsamples
-            mean[4 * j + k] = m
-            std[4 * j + k] = np.sqrt(max(s2 / nsamples - m * m, 0.0))
-    bad = np.zeros(nrow * 4, dtype=bool)
+            mean[per * j + k] = m
+            std[per * j + k] = np.sqrt(max(s2 / nsamples - m * m, 0.0))
+    bad = np.zeros(nrow * per, dtype=bool)
     for spec in (mean, std):
         sigma = mad(np.diff(spec)) / np.sqrt(2)
         bad |= spec > medfilt_zero_padded(spec, 11) + 4.0 * sigma
@@ -154,8 +162,8 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=2, control=False,
     ``near_dm``.  Returns a dict; with ``control`` it also holds the best
     row of the bfloat16-stored computation under ``"control"``."""
     t0 = time.perf_counter()
-    packed_T, hdr = load_packed_T(path)
-    nchan, tsamp = hdr["nchans"], hdr["tsamp"]
+    packed_T, hdr = read_packed(path)
+    nchan, nbits, tsamp = hdr["nchans"], hdr["nbits"], hdr["tsamp"]
     descending = hdr["foff"] < 0
     fbottom, bandwidth = dispersion.band_edges(hdr["fch1"], hdr["foff"],
                                                nchan)
@@ -163,7 +171,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=2, control=False,
     lo, hi = chunk_start, chunk_start + T
     if hi > packed_T.shape[1]:
         raise ValueError("the chunk leaves the file")
-    bad_file = bad_channels(packed_T)
+    bad_file = bad_channels(packed_T, nbits)
 
     def fchan(c):  # ascending-band channel -> file channel
         return nchan - 1 - c if descending else c
@@ -174,7 +182,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=2, control=False,
     # mean light curve of the good channels (exact integer sums)
     total = np.zeros(T, dtype=np.uint32)
     for c in good:
-        total += _file_channel(packed_T, fchan(c), lo, hi)
+        total += _file_channel(packed_T, nbits, fchan(c), lo, hi)
     lc = total.astype(np.float64) / max(ngood, 1)
     from scipy.ndimage import gaussian_filter1d
 
@@ -200,7 +208,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=2, control=False,
         part's share of the per-sample sum of normalised channels."""
         spec, msum = {}, np.zeros(T) if zero_dm else None
         for c in chans:
-            u = _file_channel(packed_T, fchan(c), lo, hi) * factor
+            u = _file_channel(packed_T, nbits, fchan(c), lo, hi) * factor
             s = float(u.mean())
             spec[c] = s if s != 0 else 1.0
             if zero_dm:
@@ -217,7 +225,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=2, control=False,
             acc = np.zeros((len(rows), T))
             ctl = np.zeros((len(rows), T)) if control else None
             for c in chans:
-                u = _file_channel(packed_T, fchan(c), lo, hi) * factor
+                u = _file_channel(packed_T, nbits, fchan(c), lo, hi) * factor
                 v = (u - spec[c]) / spec[c]
                 if zero_dm:
                     v -= mean_t

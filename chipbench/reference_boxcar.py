@@ -4,7 +4,7 @@ chunk's best row has to be.
 
 NumPy and SciPy only; imports nothing of the program and reads nothing the
 program made.  From ``reference.py`` it takes the header parser, the packed
-loader, the bad-channel mask and the bfloat16 rounding; from
+reader, the bad-channel mask and the bfloat16 rounding; from
 ``reference_tiered.py`` the tier rule (``tier_table``; a range that stays
 in the first tier is the flat grid); from ``dispersion.py`` the delays.  The
 clean and the roll-and-sum are ``reference_tiered.best_row``'s, restated
@@ -81,8 +81,8 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=None, control=False,
     t0 = time.perf_counter()
     if half_rows is None:
         half_rows = int(cfg["reference_half_rows"])
-    packed_T, hdr = reference.load_packed_T(path)
-    nchan, tsamp = hdr["nchans"], hdr["tsamp"]
+    packed_T, hdr = reference.read_packed(path)
+    nchan, nbits, tsamp = hdr["nchans"], hdr["nbits"], hdr["tsamp"]
     descending = hdr["foff"] < 0
     fbottom, bandwidth = dispersion.band_edges(hdr["fch1"], hdr["foff"],
                                                nchan)
@@ -90,7 +90,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=None, control=False,
     lo, hi = chunk_start, chunk_start + T
     if hi > packed_T.shape[1]:
         raise ValueError("the chunk leaves the file")
-    bad_file = reference.bad_channels(packed_T)
+    bad_file = reference.bad_channels(packed_T, nbits)
 
     tiers = reference_tiered.tier_table(cfg["dmmin"], cfg["dmmax"], fbottom,
                                         bandwidth, tsamp, hdr["foff"])
@@ -114,7 +114,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=None, control=False,
     # the clean of reference.best_row, at the file's own resolution
     total = np.zeros(T, dtype=np.uint32)
     for c in good:
-        total += reference._file_channel(packed_T, fchan(c), lo, hi)
+        total += reference._file_channel(packed_T, nbits, fchan(c), lo, hi)
     lc = total.astype(np.float64) / max(ngood, 1)
     from scipy.ndimage import gaussian_filter1d
 
@@ -136,7 +136,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=None, control=False,
     def spectrum(chans):
         spec, msum = {}, np.zeros(T) if zero_dm else None
         for c in chans:
-            u = reference._file_channel(packed_T, fchan(c), lo, hi) * flat
+            u = reference._file_channel(packed_T, nbits, fchan(c), lo, hi) * flat
             s = float(u.mean())
             spec[c] = s if s != 0 else 1.0
             if zero_dm:
@@ -156,7 +156,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=None, control=False,
             acc = np.zeros((len(rows), Tk))
             ctl = np.zeros((len(rows), Tk)) if control else None
             for c in chans:
-                u = reference._file_channel(packed_T, fchan(c), lo, hi) * flat
+                u = reference._file_channel(packed_T, nbits, fchan(c), lo, hi) * flat
                 v = (u - spec[c]) / spec[c]
                 if zero_dm:
                     v -= mean_t

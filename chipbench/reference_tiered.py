@@ -2,9 +2,9 @@
 best row has to be when the DM range is searched in tiers.
 
 NumPy and SciPy only; imports nothing of the program and reads nothing the
-program made.  From ``reference.py`` (unchanged) it takes the header
-parser, the packed loader, the bad-channel mask, ``score_row`` and the
-bfloat16 rounding; from ``dispersion.py`` the delays.  The clean is
+program made.  From ``reference.py`` it takes the header parser, the
+packed reader (1, 2, 4 and 8 bits), the bad-channel mask, ``score_row`` and
+the bfloat16 rounding; from ``dispersion.py`` the delays.  The clean is
 ``reference.best_row``'s, restated here because that function does not
 expose it.
 
@@ -82,8 +82,8 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=2, control=False,
     ``2 * half_rows + 1`` trials nearest ``near_dm`` on the grid of the
     tier that holds it, ``row`` counted in the concatenated table."""
     t0 = time.perf_counter()
-    packed_T, hdr = reference.load_packed_T(path)
-    nchan, tsamp = hdr["nchans"], hdr["tsamp"]
+    packed_T, hdr = reference.read_packed(path)
+    nchan, nbits, tsamp = hdr["nchans"], hdr["nbits"], hdr["tsamp"]
     descending = hdr["foff"] < 0
     fbottom, bandwidth = dispersion.band_edges(hdr["fch1"], hdr["foff"],
                                                nchan)
@@ -91,7 +91,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=2, control=False,
     lo, hi = chunk_start, chunk_start + T
     if hi > packed_T.shape[1]:
         raise ValueError("the chunk leaves the file")
-    bad_file = reference.bad_channels(packed_T)
+    bad_file = reference.bad_channels(packed_T, nbits)
 
     tiers = tier_table(cfg["dmmin"], cfg["dmmax"], fbottom, bandwidth, tsamp,
                        hdr["foff"])
@@ -114,7 +114,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=2, control=False,
     # the clean of reference.best_row, at the file's own resolution
     total = np.zeros(T, dtype=np.uint32)
     for c in good:
-        total += reference._file_channel(packed_T, fchan(c), lo, hi)
+        total += reference._file_channel(packed_T, nbits, fchan(c), lo, hi)
     lc = total.astype(np.float64) / max(ngood, 1)
     from scipy.ndimage import gaussian_filter1d
 
@@ -136,7 +136,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=2, control=False,
     def spectrum(chans):
         spec, msum = {}, np.zeros(T) if zero_dm else None
         for c in chans:
-            u = reference._file_channel(packed_T, fchan(c), lo, hi) * flat
+            u = reference._file_channel(packed_T, nbits, fchan(c), lo, hi) * flat
             s = float(u.mean())
             spec[c] = s if s != 0 else 1.0
             if zero_dm:
@@ -156,7 +156,7 @@ def best_row(path, cfg, chunk_start, near_dm, half_rows=2, control=False,
             acc = np.zeros((len(rows), Tk))
             ctl = np.zeros((len(rows), Tk)) if control else None
             for c in chans:
-                u = reference._file_channel(packed_T, fchan(c), lo, hi) * flat
+                u = reference._file_channel(packed_T, nbits, fchan(c), lo, hi) * flat
                 v = (u - spec[c]) / spec[c]
                 if zero_dm:
                     v -= mean_t

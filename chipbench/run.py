@@ -10,7 +10,7 @@ One process, no children: whoever imports JAX holds the chip.  A run
 2. writes the cell's SIGPROC file from the seed (``generate.py``);
 3. cold pass: ``PUsearchfrb``'s own ``main()`` on the new file (no
    ``.badchans`` beside it, an empty output directory): set-up, and what
-   ``correct`` is decided on;
+   ``correct`` is decided on; then everything set-up wrote is flushed;
 4. timed window: the same ``main()`` on the same file again and again,
    each pass into a fresh output directory, no pass started after
    ``--seconds``; rates divide by the true length of the window;
@@ -355,7 +355,8 @@ def _run(opts, manifest, entry, cfg, traffic, dev, peaks, watch, work):
     info = generate.generate(path, cfg, traffic, opts.seed)
     say(f"generated {info['bytes'] / 2**20:.0f} MiB, {info['nsamples']} "
         f"samples ({info['duration_s']:.3f} s of sky) in "
-        f"{info['seconds']:.2f} s; seed {opts.seed}; pulses "
+        f"{info['seconds']:.2f} s; seed {opts.seed}, hit seed "
+        f"{info['hit_seed']}; pulses "
         f"{json.dumps(info['pulses'])}")
     hop = info["hop"]
     chunk_starts = list(range(0, info["nsamples"] - hop, hop))
@@ -371,6 +372,13 @@ def _run(opts, manifest, entry, cfg, traffic, dev, peaks, watch, work):
     compare("cold_exit_status", cold["rc"], 0, results)
 
     # -- the window --------------------------------------------------------
+    # what set-up wrote (the file, the cold pass's products, compiled
+    # programs) goes to disk now, inside setup_s: every pass that stood
+    # still for 3-4 s did so some 30 s after set-up's last large write,
+    # the kernel's age limit for dirty pages (PERF.md section 7)
+    t_sync = time.perf_counter()
+    os.sync()
+    say(f"set-up's writes flushed in {time.perf_counter() - t_sync:.3f} s")
     passes = []
     miss0 = watch.misses()
     trace_dir = os.path.join(work, "trace")

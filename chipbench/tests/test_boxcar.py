@@ -135,8 +135,8 @@ def test_manifest_entries_of_the_new_cell():
     manifest = _load("BENCHMARK.json")
     cfg = _load("chipbench", "configs", CONFIG + ".json")
     full = _load("chipbench", "configs", "htru_bpsr_fulldm.json")
-    entry = manifest["configs"][-1]
-    assert entry["name"] == CONFIG and entry["file"].endswith(CONFIG + ".json")
+    entry = next(c for c in manifest["configs"] if c["name"] == CONFIG)
+    assert entry["file"].endswith(CONFIG + ".json")
     assert entry["source"] == cfg["source"] and len(cfg["source"]) <= 200
     assert entry["reduced"] == cfg["reduced"] == ["beams", "chunk_samples"]
     # cell 3's geometry, clean, DM range, chunk and tier table to the letter
@@ -163,15 +163,16 @@ def test_manifest_entries_of_the_new_cell():
         "pulse_widths"}
     assert (wide["hops_per_file"], wide["pulse_hops"],
             wide["pulse_widths"]) == (16, [15], [512])
-    cell = manifest["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
-        == (CELL, CONFIG, "backlog_pointing_wide", 1)
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) \
+        == (CONFIG, "backlog_pointing_wide", 1)
     assert len(cell["why"]) <= 200
-    new = manifest["per_layer"][-6:]
-    assert [m["name"] for m in new] == NEW_METRICS
-    for m in new:
-        spec = _load("chipbench", "layer_metrics", m["name"] + ".json")
-        assert m["workloads"] == [CELL] and m["moves"] == "sky_s_per_s"
+    # looked up by name: later PRs append metrics, and cells to these lists
+    per_layer = {m["name"]: m for m in manifest["per_layer"]}
+    for name in NEW_METRICS:
+        m = per_layer[name]
+        spec = _load("chipbench", "layer_metrics", name + ".json")
+        assert CELL in m["workloads"] and m["moves"] == "sky_s_per_s"
         assert (spec["unit"], spec["better"], spec["layer"]) == (
             m["unit"], m["better"], m["layer"])
         assert spec["origin"] == m["source"]

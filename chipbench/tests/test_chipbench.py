@@ -41,14 +41,15 @@ def tiny():
 
 def _levels(path, nchan):
     """(nsamples, nchan) levels in ascending band order."""
-    packed_T, hdr = reference.load_packed_T(path)
-    lv = np.stack([(packed_T >> np.uint8(2 * k)) & np.uint8(3)
-                   for k in range(4)], axis=1).reshape(nchan, -1)
+    packed_T, hdr = reference.read_packed(path)
+    lv = np.stack([reference._file_channel(packed_T, hdr["nbits"], fc)
+                   for fc in range(nchan)])
     return (lv[::-1] if hdr["foff"] < 0 else lv).T
 
 
 def test_generator_is_deterministic(tiny, tmp_path):
     cfg, traffic = tiny
+    traffic = {k: v for k, v in traffic.items() if k != "hit_seed"}
     a, b, c = (str(tmp_path / n) for n in "abc")
     ia = generate.generate(a, cfg, traffic, 2**31 + 11, threads=1)
     ib = generate.generate(b, cfg, traffic, 2**31 + 11, threads=4)
@@ -60,6 +61,40 @@ def test_generator_is_deterministic(tiny, tmp_path):
     hop = cfg["chunk_samples"] // 2
     assert pulse["sample"] // hop == 3  # whole track in the last hop
     assert ia["nsamples"] == 4 * hop
+
+
+def test_a_hit_seed_gives_every_seed_the_same_pulse_chunk(tiny, tmp_path,
+                                                        monkeypatch):
+    """Where a traffic file names a ``hit_seed`` the pulse and the hops its
+    chunk covers are one seed's for every run, the other hops the run's;
+    a run whose seed is the hit seed writes that seed's plain file."""
+    cfg, traffic = tiny
+    assert traffic["pulse_hops"] == [3]
+    monkeypatch.setattr(generate, "BLOCK", 1 << 11)  # four blocks a hop
+    hit = traffic["hit_seed"]
+    plain = {k: v for k, v in traffic.items() if k != "hit_seed"}
+    hop = cfg["chunk_samples"] // 2
+    head = len(generate.sigproc_header(cfg))
+    files, infos = {}, {}
+    for name, tr, seed in (("a", traffic, 2**31 + 11),
+                           ("b", traffic, 2**31 + 12),
+                           ("h", traffic, hit), ("p", plain, hit)):
+        path = str(tmp_path / name)
+        infos[name] = generate.generate(path, cfg, tr, seed)
+        files[name] = np.frombuffer(open(path, "rb").read()[head:],
+                                    np.uint8).reshape(4 * hop, -1)
+    assert generate.hit_hops(traffic) == [2, 3]
+    assert infos["a"]["pulses"] == infos["b"]["pulses"] == \
+        infos["p"]["pulses"]
+    assert infos["a"]["hit_seed"] == hit and infos["p"]["hit_seed"] == hit
+    assert np.array_equal(files["h"], files["p"])
+    for h in range(4):
+        same = np.array_equal(files["a"][h * hop:(h + 1) * hop],
+                              files["b"][h * hop:(h + 1) * hop])
+        assert same == (h in (2, 3))
+        assert np.array_equal(files["a"][h * hop:(h + 1) * hop],
+                              files["p"][h * hop:(h + 1) * hop]) == same
+    assert generate.hit_seed(plain, 9) == 9
 
 
 def test_generator_tracks_are_exact(tiny, tmp_path):

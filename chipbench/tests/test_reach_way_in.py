@@ -84,10 +84,19 @@ def test_a_configuration_names_its_reference(capsys, monkeypatch, named):
                if k != "peak_sample_gap")
 
 
-def test_no_cell_names_a_reference():
+def test_a_cells_reference_is_a_module_with_best_row():
+    """The hybrid cells name none (``reference.py`` is the default); a
+    configuration that names one names a module of ``chipbench/``."""
+    import importlib
+
     manifest = _load("BENCHMARK.json")
-    for c in manifest["configs"]:
-        assert "reference" not in _load(c["file"])
+    by_name = {c["name"]: _load(c["file"]) for c in manifest["configs"]}
+    for cell in HYBRID_CELLS:
+        assert "reference" not in by_name[cell.partition(".")[0]]
+    for cfg in by_name.values():
+        module = importlib.import_module(
+            "chipbench." + cfg.get("reference", "reference"))
+        assert callable(module.best_row)
 
 
 @pytest.mark.parametrize("counts,factor", [
@@ -156,7 +165,9 @@ def test_backlog_dense_is_backlog_sparse_with_a_pulse_in_every_chunk(
         tmp_path):
     sparse = _load("chipbench", "traffic", "backlog_sparse.json")
     dense = _load("chipbench", "traffic", "backlog_dense.json")
-    assert _differing(sparse, dense) == {"name", "why", "pulse_hops"}
+    # (and a file in no cell keeps the run's seed's hit)
+    assert _differing(sparse, dense) == {"name", "why", "pulse_hops",
+                                         "hit_seed", "hit_why"}
     assert dense["pulse_hops"] == [1, 3]
     cfg = _load("chipbench", "configs", TINY + ".json")
     info = generate.generate(str(tmp_path / "d.fil"), cfg, dense,
@@ -233,14 +244,15 @@ def test_rehearsal_of_the_defaults_cell(capsys, monkeypatch):
 def test_per_layer_workloads_name_cells():
     manifest = _load("BENCHMARK.json")
     cells = {w["name"] for w in manifest["workloads"]}
-    assert cells == HYBRID_CELLS
+    assert HYBRID_CELLS <= cells
     names = {m["name"] for m in manifest["per_layer"]}
     assert HYBRID_ONLY <= names and not SWEEP & names
     for m in manifest["per_layer"]:
         if m["name"] in HYBRID_ONLY:
             assert set(m["workloads"]) == HYBRID_CELLS
-        else:
-            assert "workloads" not in m
+        # a list names cells, and never a cell twice
+        assert set(m.get("workloads", [])) <= cells
+        assert len(set(m.get("workloads", []))) == len(m.get("workloads", []))
         spec = _load("chipbench", "layer_metrics", m["name"] + ".json")
         assert (spec["unit"], spec["layer"], spec["moves"]) == (
             m["unit"], m["layer"], m["moves"])

@@ -188,19 +188,18 @@ def test_manifest_entries_of_the_new_cell():
     smeared = _load("chipbench", "traffic", "backlog_sparse_smeared.json")
     assert {k for k in set(sparse) | set(smeared)
             if sparse.get(k) != smeared.get(k)} == {
-        "name", "why", "pulse_why", "pulse_widths"}
+        "name", "why", "pulse_why", "pulse_widths", "hit_seed", "hit_why"}
     assert smeared["pulse_widths"] == [16]
-    cell = manifest["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
-        == (CELL, "htru_bpsr_fulldm", "backlog_sparse_smeared", 1)
-    new = manifest["per_layer"][-9:]
-    assert [m["name"] for m in new] == NEW_METRICS
-    for m in new:
-        spec = _load("chipbench", "layer_metrics", m["name"] + ".json")
-        assert m["workloads"] == [CELL] and m["moves"] == "sky_s_per_s"
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) \
+        == ("htru_bpsr_fulldm", "backlog_sparse_smeared", 1)
+    # looked up by name: later PRs append metrics, and cells to these lists
+    per_layer = {m["name"]: m for m in manifest["per_layer"]}
+    for name in NEW_METRICS:
+        m = per_layer[name]
+        spec = _load("chipbench", "layer_metrics", name + ".json")
+        assert CELL in m["workloads"] and m["moves"] == "sky_s_per_s"
         assert (m["unit"], m["better"], m["layer"], m["source"]) == (
             spec["unit"], spec["better"], spec["layer"], spec["origin"])
-    # no accepted list names the new cell: fdmt_roofline counts one
-    # full-size call a chunk
-    for m in manifest["per_layer"][:-9]:
-        assert CELL not in m.get("workloads", [])
+    # fdmt_roofline counts one full-size call a chunk: not this cell's
+    assert CELL not in per_layer["fdmt_roofline"]["workloads"]
