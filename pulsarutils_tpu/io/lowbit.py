@@ -1,4 +1,5 @@
-"""1/2/4-bit sample packing/unpacking for SIGPROC filterbanks.
+"""1/2/4-bit sample packing/unpacking for SIGPROC filterbanks, and the
+raw-byte carrier of unsigned 8-bit ones.
 
 The reference delegates filterbank decoding to the third-party
 ``sigpyproc`` (``clean.py:18``, ``stats.py:6``), which supports 1-32 bit
@@ -39,8 +40,9 @@ logger = logging.getLogger("pulsarutils_tpu")
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native", "unpack.cpp")
 
-#: values per byte for each supported width
-_PER_BYTE = {1: 8, 2: 4, 4: 2}
+#: values per byte for each width whose raw frames go to the device as
+#: they are stored (8: one unsigned byte a sample, nothing to shift)
+_PER_BYTE = {1: 8, 2: 4, 4: 2, 8: 1}
 
 _lib = None
 _lib_tried = False
@@ -172,8 +174,8 @@ def device_unpack_block(frames, nbits, nchan, band_descending=False,
     """Jittable device unpack: packed frames -> ``(nchan, n)`` float32.
 
     ``frames`` is the raw ``(nsamps, nbytes_per_frame)`` uint8 block a
-    low-bit filterbank stores (``FilterbankReader.read_block_packed``),
-    single-IF.  Same LSB-first convention as :func:`unpack_numpy`; the
+    low-bit or unsigned 8-bit filterbank stores
+    (``FilterbankReader.read_block_packed``), single-IF.  Same LSB-first convention as :func:`unpack_numpy`; the
     returned block is ASCENDING-band (``band_descending=True`` flips
     the file's channel order, mirroring ``read_block(band_ascending=
     True)``).
@@ -191,9 +193,18 @@ def device_unpack_block(frames, nbits, nchan, band_descending=False,
     """
     if xp is None:
         import jax.numpy as xp
+    frames = xp.asarray(frames)
+    if nbits == 8:
+        # nothing to shift: the BYTES are transposed (and flipped), the
+        # widening comes after — a quarter of the traffic of widening
+        # first, and none of the shift-and-mask's (frames, bytes, per)
+        # temporaries
+        block = frames[:, :nchan].T
+        if band_descending:
+            block = block[::-1]
+        return block.astype(dtype if dtype is not None else xp.float32)
     per = _PER_BYTE[nbits]
     mask = (1 << nbits) - 1
-    frames = xp.asarray(frames)
     shifts = xp.arange(per, dtype=xp.uint8) * np.uint8(nbits)
     vals = (frames[:, :, None] >> shifts[None, None, :]) & np.uint8(mask)
     block = vals.reshape(frames.shape[0], -1)[:, :nchan]
@@ -228,6 +239,8 @@ def sample_codes(frames, nbits, nchan, max_rows=4096):
     """
     frames = np.asarray(frames)
     stride = max(1, frames.shape[0] // int(max_rows))
+    if nbits == 8:  # the bytes are the codes: a view, nothing decoded
+        return frames[::stride, :int(nchan)].T
     per_frame = frames.shape[1] * _PER_BYTE[nbits]
     return unpack_numpy(frames[::stride], nbits).reshape(
         -1, per_frame)[:, :int(nchan)].T
@@ -370,7 +383,7 @@ def unpack(packed, nbits):
     """Packed uint8 buffer -> float32 values (native path when available)."""
     if nbits not in _PER_BYTE:
         raise ValueError(f"unsupported nbits={nbits}")
-    lib = _load()
+    lib = _load() if nbits < 8 else None  # a byte a sample: a plain cast
     if lib is None:
         return unpack_numpy(packed, nbits)
     packed = np.ascontiguousarray(packed, dtype=np.uint8).ravel()
@@ -384,7 +397,7 @@ def pack(values, nbits):
     """Float values -> packed uint8 (native path when available)."""
     if nbits not in _PER_BYTE:
         raise ValueError(f"unsupported nbits={nbits}")
-    lib = _load()
+    lib = _load() if nbits < 8 else None
     if lib is None:
         return pack_numpy(values, nbits)
     per = _PER_BYTE[nbits]

@@ -213,6 +213,16 @@ class FilterbankReader:
         return self.header["foff"] < 0
 
     @property
+    def packed_bits(self):
+        """Bit depth of a file whose raw frames :meth:`read_block_packed`
+        serves — 1, 2 or 4 bits packed, or 8 bits unsigned, one IF — and
+        0 for every other (signed 8-bit, 16- and 32-bit, multi-IF), which
+        :meth:`read_block` decodes on the host.  THE rule of the packed
+        path: the search's upload and the bad-channel pre-scan ask it."""
+        raw_bytes = self._mmap.dtype == np.uint8
+        return self._nbits if raw_bytes and self.nifs == 1 else 0
+
+    @property
     def nbeams(self):
         """Total beams of the observation this file belongs to (sigproc
         ``nbeams`` header key; ``None`` when the header omits it)."""
@@ -239,21 +249,24 @@ class FilterbankReader:
     def read_block_packed(self, istart, nsamps):
         """Raw packed frames ``(nsamps, bytes_per_frame)`` uint8 — the
         low-bit fast path: callers ship THESE over the host->device
-        link (1/16th the bytes of float32 at 2 bits) and unpack in the
-        device-clean jit (:func:`..io.lowbit.device_unpack_block`);
+        link (1/16th the bytes of float32 at 2 bits, a quarter at 8) and
+        unpack in the device-clean jit
+        (:func:`..io.lowbit.device_unpack_block`);
         :meth:`unpack_frames` is the matching host-side decode for
-        fallback paths.  Low-bit single-IF files only: the device-side
-        unpack takes the first ``nchans`` values of each frame, which on
-        a multi-IF file would silently decode IF 0 instead of honouring
-        ``if_mode`` the way :meth:`read_block` does."""
-        if self._nbits not in (1, 2, 4):
-            raise ValueError(
-                f"read_block_packed needs a packed low-bit file "
-                f"(nbits={self._nbits})")
+        fallback paths.  Files with :attr:`packed_bits` only — 1/2/4-bit
+        and unsigned 8-bit, single-IF: the device-side unpack takes the
+        first ``nchans`` values of each frame, which on a multi-IF file
+        would silently decode IF 0 instead of honouring ``if_mode`` the
+        way :meth:`read_block` does."""
         if self.nifs != 1:
             raise ValueError(
                 f"read_block_packed is single-IF only (nifs={self.nifs}); "
                 "use read_block, which honours if_mode")
+        if not self.packed_bits:
+            raise ValueError(
+                f"read_block_packed needs a packed low-bit or unsigned "
+                f"8-bit file (nbits={self._nbits}, "
+                f"{self._mmap.dtype.name} samples)")
         istart = int(istart)
         fault_inject.fire("read", chunk=istart)
         nsamps = int(min(nsamps, self.nsamples - istart))
@@ -269,6 +282,17 @@ class FilterbankReader:
 
             frames = unpack(raw, self._nbits).reshape(
                 nsamps, self.nifs, self.nchans).astype(float)
+        elif self._nbits == 8 and (self.nifs == 1 or self.if_mode != "sum"):
+            # one plane of bytes: transposed as stored, widened after (to
+            # float32, which holds every 8-bit value) — a chunk of
+            # 131,072 x 4,096 takes 6 s so, 18 + 18 s widened to float64
+            # first and copied transposed later (PERF.md section 6, PR 34)
+            plane = 0 if self.nifs == 1 else int(self.if_mode)
+            block = np.ascontiguousarray(
+                raw.reshape(nsamps, self.nifs, self.nchans)[:, plane].T)
+            if band_ascending and self.band_descending:
+                block = block[::-1]
+            return block.astype(np.float32)
         else:
             frames = raw.reshape(nsamps, self.nifs,
                                  self.nchans).astype(float)

@@ -143,6 +143,14 @@ METRIC_NAMES = {
     "putpu_fdas_trials_total":
         "(DM, accel, jerk) trials scored by the fdas correlation "
         "backend",
+    "putpu_fdmt_head_declined_total":
+        "coarse sweeps whose geometry declined the FDMT's fused head and "
+        "ran the per-level merges (labelled by reason: shape, halo, "
+        "shift, smem)",
+    "putpu_fdmt_head_smem_bytes":
+        "SMEM the fused head's merge tables take in the last coarse "
+        "sweep's plan, as the compiler pads them (one group's slice, "
+        "twice; 0 where the shape rules a head out)",
     "putpu_fdmt_head_tiles_total":
         "(8, 256) tiles the FDMT's VMEM-resident head computes, halo "
         "chunks and padded rows included, one count per coarse sweep "

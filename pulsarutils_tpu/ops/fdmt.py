@@ -643,47 +643,66 @@ def _head_choice(nchan, start_freq, bandwidth, max_delay, n_lo, t):
     config, or None where the geometry does not fit it.  THE eligibility
     gate: `_transform_fn` builds the head from it, :func:`head_active`
     and :func:`coarse_head_tiles` report it."""
+    return _head_verdict(nchan, start_freq, bandwidth, max_delay, n_lo,
+                         t)[0]
+
+
+def _head_verdict(nchan, start_freq, bandwidth, max_delay, n_lo, t):
+    """``(choice, reason, smem bytes)``: :func:`_head_choice`'s answer,
+    why it is None where it is (``"shape"``: channels, levels or time
+    axis; ``"halo"``; ``"shift"``; ``"smem"``: the tables outgrow the
+    core's scalar memory) and the SMEM the head's tables need (0 where
+    the shape rules a head out).  Declining leaves the sweep to the
+    per-level merges: no geometry ends in the compiler's refusal."""
     from .fdmt_resident import (
         HEAD_LEVELS,
         _head_plan_cached,
+        head_smem_bytes,
+        head_smem_limit,
         head_supported,
         pick_head_t_slice,
     )
 
     plan = fdmt_plan(nchan, start_freq, bandwidth, max_delay, n_lo)
-    if not head_supported(plan.nchan_padded, len(plan.iterations), t):
-        return None
+    shape = (plan.nchan_padded, len(plan.iterations), t)
+    if not head_supported(*shape):
+        return None, "shape", 0
     hp = _head_plan_cached(nchan, start_freq, bandwidth, max_delay, n_lo,
                            HEAD_LEVELS)
-    if not head_supported(plan.nchan_padded, len(plan.iterations), t,
-                          halo=hp.halo,
+    smem = head_smem_bytes(hp)
+    if not head_supported(*shape, halo=hp.halo):
+        return None, "halo", smem
+    if not head_supported(*shape,
                           max_level_shift=max(hp.max_shift_per_level)):
-        return None
-    return hp, pick_head_t_slice(hp, t)
+        return None, "shift", smem
+    if smem > head_smem_limit():
+        return None, "smem", smem
+    return (hp, pick_head_t_slice(hp, t)), None, smem
 
 
 def coarse_head_tiles(nchan, nsamples, dmmin, dmmax, start_freq, bandwidth,
                       sample_time):
-    """``(computed, useful)`` (8, 256) tiles of the fused head in ONE
-    coarse sweep of the ``fdmt``/``hybrid`` kernels over these arguments
-    on this backend — the geometry ``ops/search.py:_search_jax_fdmt``
-    resolves, through the same functions; ``(0, 0)`` where no head runs
-    (the Pallas merges are off, or the geometry does not fit it).
+    """``(computed, useful, declined, smem bytes)`` of the fused head in
+    ONE coarse sweep of the ``fdmt``/``hybrid`` kernels over these
+    arguments on this backend — the geometry
+    ``ops/search.py:_search_jax_fdmt`` resolves, through the same
+    functions: the (8, 256) tiles, why :func:`_head_choice` declined
+    (None where the head runs) and the SMEM its tables take;
+    ``(0, 0, None, 0)`` where the Pallas merges are off.
     """
     import jax
 
     from .fdmt_resident import head_tile_counts
 
     if jax.default_backend() != "tpu":
-        return 0, 0
+        return 0, 0, None, 0
     _, n_lo, n_hi = fdmt_trial_dms(nchan, dmmin, dmmax, start_freq,
                                    bandwidth, sample_time)
     t_run = _padded_length(nsamples)
-    choice = _head_choice(nchan, float(start_freq), float(bandwidth), n_hi,
-                          n_lo, t_run)
-    if choice is None:
-        return 0, 0
-    return head_tile_counts(choice[0], t_run, choice[1])
+    choice, declined, smem = _head_verdict(
+        nchan, float(start_freq), float(bandwidth), n_hi, n_lo, t_run)
+    tiles = head_tile_counts(choice[0], t_run, choice[1]) if choice else (0, 0)
+    return tiles + (declined, smem)
 
 
 @functools.lru_cache(maxsize=16)

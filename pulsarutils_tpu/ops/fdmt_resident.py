@@ -21,8 +21,9 @@ into independent 128-channel groups whose whole sub-tree state
 * per-row shifted reads are the aligned-load + lane-rotate + blend of
   the dedispersion kernel
   (:func:`~pulsarutils_tpu.ops.pallas_dedisperse.shifted_row_tile`),
-  made once over a row's whole extent in the step; merge tables and
-  each group's own row count ride scalar prefetch;
+  made once over a row's whole extent in the step; a step's merge
+  tables and row counts are its own group's, a block in SMEM
+  (:func:`head_tables`);
 * the slice is the largest whose two buffers fit the VMEM the core has
   (128 MiB on a v5e; Mosaic's *default scoped limit* is 16 MiB, so the
   ``pallas_call`` asks for its share by ``vmem_limit_bytes``): every
@@ -174,6 +175,14 @@ class HeadPlan:
         self.row_starts = np.concatenate(
             [[0], np.cumsum(self.rows_valid)])[:-1]
         self.rows_total = int(self.rows_valid.sum())
+        #: the kernel's output plane: each group's own final rows, in
+        #: whole blocks of the row loop, one group after the other (the
+        #: real rows plus at most 7 a group; the widest group's count
+        #: for every group was 2.53 x the real rows at 32 groups)
+        plane_rows = self.row_blocks[-1].astype(np.int64) * _ROW_UNROLL
+        self.plane_starts = np.concatenate(
+            [[0], np.cumsum(plane_rows)])[:-1]
+        self.rows_plane = int(plane_rows.sum())
         #: cumulative worst-case shift a sample travels through the head
         self.max_shift_per_level = [
             int(t["shift"].max(initial=0)) for t in self.tables]
@@ -211,15 +220,67 @@ _VMEM_SHARE = 0.75
 _VMEM_HEADROOM = 8 << 20
 
 
-def head_vmem_limit():
-    """Bytes of VMEM the head's ``pallas_call`` may use on this device."""
+#: SMEM of one v5e TensorCore (the same table), and the share of it the
+#: head's tables may take: the compiler keeps scalars of its own there
+_V5E_SMEM_BYTES = 1 << 20
+_SMEM_SHARE = 0.5
+
+
+def _core_bytes(field, assumed):
+    """One TensorCore's capacity of a memory, asked of the device."""
     from jax.experimental.pallas import tpu as pltpu
 
     try:
-        capacity = pltpu.get_tpu_info().vmem_capacity_bytes
+        return getattr(pltpu.get_tpu_info(), field)
     except ValueError:  # no TPU attached
-        capacity = _V5E_VMEM_BYTES
-    return int(capacity * _VMEM_SHARE)
+        return assumed
+
+
+def head_vmem_limit():
+    """Bytes of VMEM the head's ``pallas_call`` may use on this device."""
+    return int(_core_bytes("vmem_capacity_bytes", _V5E_VMEM_BYTES)
+               * _VMEM_SHARE)
+
+
+def head_smem_limit():
+    """Bytes of SMEM the head's tables may take on this device."""
+    return int(_core_bytes("smem_capacity_bytes", _V5E_SMEM_BYTES)
+               * _SMEM_SHARE)
+
+
+def _tables_block(head):
+    """``(rows, width)`` of one group's slice of :func:`head_tables`."""
+    return (4 * head.n_levels + 1,
+            max(head.rows_out + [head.n_levels + 1]))
+
+
+def head_tables(head):
+    """The kernel's merge tables, ``int32 (n_groups, 4 * n_levels + 1,
+    width)``: a group's slice is everything one grid step reads.  Row
+    ``4 * lev + k`` is level ``lev``'s ``idx_low`` / ``idx_high`` /
+    ``shift`` / ``shift_high`` over the rows the level pads to; the last
+    row holds the row loops' trip counts, one a level, and then the
+    group's first row in the output plane."""
+    n_levels = head.n_levels
+    out = np.zeros((head.n_groups,) + _tables_block(head), np.int32)
+    for lev, tab in enumerate(head.tables):
+        for k, key in enumerate(("idx_low", "idx_high", "shift",
+                                 "shift_high")):
+            out[:, 4 * lev + k, :head.rows_out[lev]] = tab[key]
+    out[:, 4 * n_levels, :n_levels] = head.row_blocks.T
+    out[:, 4 * n_levels, n_levels] = head.plane_starts
+    return out
+
+
+def head_smem_bytes(head):
+    """SMEM the head's tables take as the compiler pads them: one
+    group's slice of :func:`head_tables`, in (8, 128)-word tiles, twice
+    (the pipeline fetches the next group's while this one computes).
+    Whole in SMEM, as 29 scalar-prefetched tables of every group's rows,
+    they took ``n_groups`` times a buffer: 1.75 MiB of the 1 MiB a v5e
+    has at MeerTRAP's 32 groups from DM 0 (PR 35)."""
+    rows, width = _tables_block(head)
+    return 2 * (-(-rows // 8) * 8) * (-(-width // 128) * 128) * 4
 
 
 def _head_geometry(head, t_slice):
@@ -326,18 +387,12 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
     chunks_alloc, rows_buf, n_chunks_out = _head_geometry(head, t_slice)
     r_alloc = chunks_alloc * 8
     c8 = n_slices * cpb * 8          # time axis in 8-row units
-    rows_final = head.rows_out[-1]
-
     grid = (head.n_groups, n_slices)
 
-    def kernel(*args):
-        # scalar prefetch: 4 tables per level, each (n_groups, rows_max),
-        # then the row loops' trip counts, (n_levels, n_groups)
-        tabs = args[:4 * n_levels]
-        blocks_t = args[4 * n_levels]
-        data_hbm = args[4 * n_levels + 1]   # (rows, c8, L) in ANY space
-        out_hbm = args[4 * n_levels + 2]    # (G*rows_final, c8, L) in ANY
-        buf_a, buf_b, sem_in, sem_out = args[4 * n_levels + 3:]
+    def kernel(tab, data_hbm, out_hbm, buf_a, buf_b, sem_in, sem_out):
+        # tab: this step's OWN group's tables in SMEM, (4 * n_levels + 1,
+        # table width) — see :func:`head_tables`; data_hbm (rows, c8, L)
+        # and out_hbm (rows_plane, c8, L) in ANY space
 
         g = pl.program_id(0)
         i_s = pl.program_id(1)
@@ -411,46 +466,69 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
 
         src, dst = buf_a, buf_b
         for lev in range(n_levels):
-            il_t, ih_t, s_t, sh_t = tabs[4 * lev:4 * lev + 4]
+            il, ih, sl, sh = range(4 * lev, 4 * lev + 4)
             leaf = head.tables[lev]["leaf"]
             nco = n_chunks_out[lev]
 
-            def row_body(rb, _, il_t=il_t, ih_t=ih_t, s_t=s_t, sh_t=sh_t,
+            def row_body(rb, _, il=il, ih=ih, sl=sl, sh=sh,
                          leaf=leaf, nco=nco, src=src, dst=dst):
                 # row unroll: one loop iteration's scalar overhead
                 # (control flow + dynamic address formation) amortised
                 # over _ROW_UNROLL rows of vector work
                 for dr in range(_ROW_UNROLL):
                     r = rb * _ROW_UNROLL + dr
-                    low = shifted_row(src, il_t[g, r], s_t[g, r], nco)
+                    low = shifted_row(src, tab[il, r], tab[sl, r], nco)
                     if leaf:
-                        high = shifted_row(src, ih_t[g, r], sh_t[g, r], nco)
+                        high = shifted_row(src, tab[ih, r], tab[sh, r], nco)
                     else:
-                        high = src[ih_t[g, r], pl.ds(0, 8 * nco), :]
+                        high = src[tab[ih, r], pl.ds(0, 8 * nco), :]
                     dst[r, pl.ds(0, 8 * nco), :] = low + high
                 return 0
 
             # each group loops over its own rows (a dynamic trip count
-            # from scalar prefetch): the padding to the widest group's
-            # count was 27 % of an unpruned plan's tiles
-            n_blocks = (blocks_t[lev, g] if row_extents
+            # from its tables' last row): the padding to the widest
+            # group's count was 27 % of an unpruned plan's tiles
+            n_blocks = (tab[4 * n_levels, lev] if row_extents
                         else head.rows_out[lev] // _ROW_UNROLL)
             jax.lax.fori_loop(0, n_blocks, row_body, 0)
             src, dst = dst, src
 
-        # the final level landed in `src` (post-swap): one DMA out
-        copy_out = pltpu.make_async_copy(
-            src.at[pl.ds(0, rows_final), pl.ds(0, cpb * 8)],
-            out_hbm.at[pl.ds(g * rows_final, rows_final),
-                       pl.ds(i_s * cpb * 8, cpb * 8)],
-            sem_out)
-        copy_out.start()
-        copy_out.wait()
+        # the final level landed in `src` (post-swap): the group's own
+        # rows go to their place in the plane, a block of the row loop a
+        # DMA (shapes are static, the group's row count is not), all
+        # started before the first is waited for
+        out_row0 = tab[4 * n_levels, n_levels]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 * n_levels + 1,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        def copy_out(b):
+            return pltpu.make_async_copy(
+                src.at[pl.ds(b * _ROW_UNROLL, _ROW_UNROLL),
+                       pl.ds(0, cpb * 8)],
+                out_hbm.at[pl.ds(out_row0 + b * _ROW_UNROLL, _ROW_UNROLL),
+                           pl.ds(i_s * cpb * 8, cpb * 8)],
+                sem_out)
+
+        def start_out(b, _):
+            copy_out(b).start()
+            return 0
+
+        def wait_out(b, _):
+            copy_out(b).wait()
+            return 0
+
+        n_out = tab[4 * n_levels, n_levels - 1]
+        jax.lax.fori_loop(0, n_out, start_out, 0)
+        jax.lax.fori_loop(0, n_out, wait_out, 0)
+
+    tables = head_tables(head)
+    call = pl.pallas_call(
+        kernel, grid=grid,
+        # a step's tables are its own group's, a block of SMEM indexed
+        # by the grid's group axis: whole in SMEM (scalar prefetch) they
+        # outgrew it at 32 groups (:func:`head_smem_bytes`)
+        in_specs=[pl.BlockSpec((None,) + tables.shape[1:],
+                               lambda g, i_s: (g, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((rows_buf, r_alloc, _L), jnp.float32),
@@ -458,34 +536,27 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
-    )
-    call = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (head.n_groups * rows_final, c8, _L), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((head.rows_plane, c8, _L),
+                                       jnp.float32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=head_vmem_limit()),
         interpret=bool(interpret), name="fdmt_head")
+    tables = jnp.asarray(tables)
 
-    flat_tabs = []
-    for tab in head.tables:
-        flat_tabs += [jnp.asarray(tab[k]) for k in
-                      ("idx_low", "idx_high", "shift", "shift_high")]
-    flat_tabs.append(jnp.asarray(head.row_blocks))
-
-    # host-side reassembly index: global level-n row -> (group, local row)
-    gather_g = np.concatenate(
-        [np.full(c, g) for g, c in enumerate(head.rows_valid)])
-    gather_r = np.concatenate(
-        [np.arange(c) for c in head.rows_valid])
+    # host-side reassembly index: global level-n row -> its plane row
+    gather = np.concatenate(
+        [start + np.arange(c)
+         for start, c in zip(head.plane_starts, head.rows_valid)])
 
     def run(data):
         # traceable (un-jitted) so the whole-transform jit can inline it
         data3 = data.reshape(data.shape[0], c8, _L)
-        out = call(*flat_tabs, data3)
-        # (G*rows_max, c8, L) -> (rows_total, t)
-        out = out.reshape(head.n_groups, rows_final, t)
-        return out[jnp.asarray(gather_g), jnp.asarray(gather_r)]
+        out = call(tables, data3)
+        # (rows_plane, c8, L) -> (rows_total, t)
+        out = out.reshape(head.rows_plane, t)
+        if head.rows_plane == head.rows_total:
+            return out
+        return out[jnp.asarray(gather)]
 
     return run, head
 

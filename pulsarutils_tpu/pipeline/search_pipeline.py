@@ -266,7 +266,10 @@ def _count_head_tiles(state, route, shape, dmmin, dmmax, start_freq,
     """Counts the (8, 256) tiles the FDMT's fused head computes in one
     sweep (``putpu_fdmt_head_tiles_total``: halo chunks and padded rows
     included; ``ops/fdmt.py:coarse_head_tiles``) and returns their
-    number: 0 where the sweep runs no head.  ``route`` is the call's
+    number: 0 where the sweep runs no head.  Beside it the SMEM the
+    head's tables take (gauge ``putpu_fdmt_head_smem_bytes``) and, where
+    the geometry declined the head, why
+    (``putpu_fdmt_head_declined_total``).  ``route`` is the call's
     ``(backend, kernel, mesh)``, ``state`` what a fall-back made of it."""
     backend, kernel, mesh = route
     if (mesh is not None or state.get("backend", backend) != "jax"
@@ -274,9 +277,13 @@ def _count_head_tiles(state, route, shape, dmmin, dmmax, start_freq,
         return 0
     from ..ops.fdmt import coarse_head_tiles
 
-    n = coarse_head_tiles(shape[0], shape[1], dmmin, dmmax, start_freq,
-                          bandwidth, tsamp)[0]
+    n, _, declined, smem = coarse_head_tiles(
+        shape[0], shape[1], dmmin, dmmax, start_freq, bandwidth, tsamp)
     obs_metrics.counter("putpu_fdmt_head_tiles_total").inc(n)
+    obs_metrics.gauge("putpu_fdmt_head_smem_bytes").set(smem)
+    if declined:
+        obs_metrics.counter("putpu_fdmt_head_declined_total",
+                            reason=declined).inc()
     return n
 
 
@@ -1045,13 +1052,13 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
     # and conditioned on the accelerator (one jitted program reused for
     # every chunk) — the host, often a single core, only reads/decodes,
     # and the cleaned chunk is already device-resident for the search.
-    # Low-bit single-IF files go further (round 4): the PACKED bytes are
-    # uploaded and the bit-unpack runs inside the same jit — 1/16th the
-    # link traffic at 2 bits, which is the survey bottleneck on thin
-    # links (the C++ host unpacker stays as the fallback decode).
-    packed_bits = (reader._nbits
-                   if (backend == "jax" and reader.nifs == 1
-                       and reader._nbits in (1, 2, 4)) else 0)
+    # Low-bit and unsigned 8-bit single-IF files go further (round 4;
+    # 8 bits PR 35): the bytes are uploaded as the file stores them and
+    # the unpack runs inside the same jit — 1/16th the link traffic at 2
+    # bits, a quarter at 8, and no float block on the host at all (the
+    # host decoders stay as the fallback).  ``reader.packed_bits`` is
+    # the rule.
+    packed_bits = reader.packed_bits if backend == "jax" else 0
     if canary is not None:
         # the packed fast path injects too (round 11): the bump is
         # quantized into the low-bit codes and re-packed on the reader

@@ -179,8 +179,8 @@ def get_spectral_stats(source, chunksize=10000):
     """One-pass mean & std bandpass spectra of a filterbank.
 
     Reference ``stats.py:35-60`` (diagnostic plotting lives in
-    :mod:`..pipeline.diagnostics`, not here).  A packed 1/2/4-bit
-    single-IF file (what ``FilterbankReader.read_block_packed`` serves)
+    :mod:`..pipeline.diagnostics`, not here).  A packed 1/2/4-bit or
+    unsigned 8-bit single-IF file (``FilterbankReader.packed_bits``)
     is reduced on the device in blocks sized from its header;
     ``chunksize`` is the block of the host's float64 loop, which every
     other source takes.
@@ -190,7 +190,7 @@ def get_spectral_stats(source, chunksize=10000):
         data = np.asarray(source, dtype=float)
         return data.mean(axis=1), data.std(axis=1)
 
-    if reader._nbits in (1, 2, 4) and reader.nifs == 1:
+    if reader.packed_bits:
         return moments_to_spectra(*_packed_moments(reader))
     return moments_to_spectra(*_float_moments(reader, chunksize))
 

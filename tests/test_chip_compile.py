@@ -155,30 +155,68 @@ def test_fdmt_transform_as_resolved_on_tpu(as_tpu, one_chip, plan):
     _fits(compiled)
 
 
-@pytest.mark.parametrize("hi,lo,t", [
-    (1068, 0, 1 << 19),    # tier 0: unpruned, 256 live rows, 72 MiB
-    (641, 535, 1 << 14),   # tier 5: one slice laps the whole axis
-], ids=["htru_tier0", "htru_tier5"])
-def test_fdmt_head_on_the_survey_plans(as_tpu, one_chip, hi, lo, t):
-    """The head alone at HTRU's tiers (1,182-1,582 MHz; ISSUE 33): the
-    slice its chooser takes needs more scoped VMEM than Mosaic's default
-    16 MiB, so this compile holds the ``vmem_limit_bytes`` it asks for
-    to what the v5e's compiler grants."""
+#: HTRU's band in 1,024 channels and MeerKAT's L band in 4,096
+HTRU = (1024, 1182.0, 400.0)
+MEERTRAP = (4096, 856.0, 856.0)
+
+
+@pytest.mark.parametrize("band,hi,lo,t,t_slice", [
+    (HTRU, 1068, 0, 1 << 19, 32768),  # tier 0: unpruned, 256 rows, 72 MiB
+    (HTRU, 641, 535, 1 << 14, 1 << 14),  # tier 5: one slice laps the axis
+    # MeerTRAP's tier 0 (ISSUE 35): 32 groups of up to 480 rows, whose 28
+    # whole tables were "1.75M of 1.00M smem"; halo 412, 75 MiB of scratch
+    (MEERTRAP, 5182, 0, 1 << 17, 16384),
+], ids=["htru_tier0", "htru_tier5", "meertrap_tier0"])
+def test_fdmt_head_on_the_survey_plans(as_tpu, one_chip, band, hi, lo, t,
+                                       t_slice):
+    """The head alone at HTRU's tiers (ISSUE 33) and at MeerTRAP's tier 0
+    (ISSUE 35): the slice its chooser takes needs more scoped VMEM than
+    Mosaic's default 16 MiB, and a step's tables are a block of SMEM, so
+    this compile holds the ``vmem_limit_bytes`` it asks for and the SMEM
+    it plans to what the v5e's compiler grants."""
     import jax
     import jax.numpy as jnp
 
     from pulsarutils_tpu.ops import fdmt_resident as fr
 
-    hp = fr._head_plan_cached(NCHAN, 1182.0, 400.0, hi, lo, fr.HEAD_LEVELS)
-    t_slice = fr.pick_head_t_slice(hp, t)
-    assert t_slice == min(t, 32768)
+    nchan = band[0]
+    hp = fr._head_plan_cached(*band, hi, lo, fr.HEAD_LEVELS)
+    assert fr.pick_head_t_slice(hp, t) == t_slice
+    assert fr.head_smem_bytes(hp) <= fr.head_smem_limit()
     if lo == 0:
         assert fr.head_scratch_bytes(hp, t_slice) > 16 << 20
-    run, _ = fr._build_head_kernel(NCHAN, 1182.0, 400.0, hi, lo,
-                                   fr.HEAD_LEVELS, t, t_slice, False)
+    run, _ = fr._build_head_kernel(*band, hi, lo, fr.HEAD_LEVELS, t,
+                                   t_slice, False)
     compiled = jax.jit(run).lower(
-        _sds((NCHAN, t), jnp.float32, one_chip)).compile()
+        _sds((nchan, t), jnp.float32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_meertrap_tier0_sweep_beside_its_chunk(as_tpu, one_chip):
+    """Tier 0's whole sweep of ``meertrap_lband_8bit`` as
+    ``_search_jax_fdmt`` builds it on a TPU (4,096 x 2^17, band delays
+    0-5,182, the 12-window ladder, the certificate row), with room for
+    what the chunk loop holds beside it: the three downsampled copies
+    (1.75 GiB) and two raw 8-bit chunks in flight (0.5 GiB each).  With
+    the widest group's rows for every group the head's plane alone was
+    6.5 GiB, twice (this compile read 15.0 GiB; ISSUE 35)."""
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops import fdmt
+    from pulsarutils_tpu.ops.search import boxcar_ladder
+
+    nchan, t = MEERTRAP[0], 1 << 17
+    assert fdmt.head_active(*MEERTRAP, 5182, 0, t)
+    run = fdmt._build_transform(
+        *MEERTRAP, 5182, t, fdmt._pick_fdmt_tile(t), True, False, n_lo=0,
+        with_scores=True, with_plane=False, t_orig=t, with_cert=True,
+        windows=boxcar_ladder(2048))
+    compiled = run.lower(_sds((nchan, t), jnp.float32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    total = (m.temp_size_in_bytes + m.argument_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert total + 2.75 * 2**30 < HBM_BYTES, m
 
 
 @pytest.mark.parametrize("with_cert", [False, True])
@@ -227,11 +265,16 @@ def test_fused_rescore_program(as_tpu, one_chip, plan, bucket):
         _sds((bucket, NCHAN), jnp.int32, one_chip)).compile())
 
 
-def test_packed_unpack_and_clean_with_donation(as_tpu, one_chip):
-    """The driver's first device program: packed 2-bit frames in, the
-    cleaned ascending-band float chunk out, the raw buffer donated
-    (``search_pipeline.py``; reduced time axis — see the module
-    docstring)."""
+@pytest.mark.parametrize("nbits,nchan", [(2, NCHAN), (8, 4096)],
+                         ids=["2bit", "8bit_4096ch"])
+def test_packed_unpack_and_clean_with_donation(as_tpu, one_chip, nbits,
+                                               nchan):
+    """The driver's first device program: packed 2-bit frames (or
+    MeerTRAP's 8-bit bytes) in, the cleaned ascending-band float chunk
+    out, the raw buffer donated (``search_pipeline.py``; reduced time
+    axis — see the module docstring.  At 4,096 x 2^17 the 8-bit program
+    holds 0.52 GiB of temporaries, the transposed bytes, beside its
+    2 GiB output; compiled by hand, PR 35)."""
     import jax
     import jax.numpy as jnp
 
@@ -240,15 +283,17 @@ def test_packed_unpack_and_clean_with_donation(as_tpu, one_chip):
 
     def unpack_clean(raw, mask):
         return renormalize_data(
-            device_unpack_block(raw, 2, NCHAN, band_descending=True,
+            device_unpack_block(raw, nbits, nchan, band_descending=True,
                                 xp=jnp),
             badchans_mask=mask, xp=jnp)
 
     compiled = jax.jit(unpack_clean, donate_argnums=(0,)).lower(
-        _sds((T_SMALL, NCHAN // 4), jnp.uint8, one_chip),
-        _sds((NCHAN,), jnp.bool_, one_chip)).compile()
-    assert compiled.memory_analysis().output_size_in_bytes \
-        == NCHAN * T_SMALL * 4
+        _sds((T_SMALL, nchan * nbits // 8), jnp.uint8, one_chip),
+        _sds((nchan,), jnp.bool_, one_chip)).compile()
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes == nchan * T_SMALL * 4
+    if nbits == 8:  # bytes are transposed, floats are not
+        assert m.temp_size_in_bytes < 1.5 * nchan * T_SMALL
 
 
 def test_fused_sharded_hybrid_on_four_devices(as_tpu, topo, plan):

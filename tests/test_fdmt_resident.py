@@ -10,7 +10,10 @@ from pulsarutils_tpu.ops.fdmt_resident import (
     HEAD_LEVELS,
     HeadPlan,
     head_scratch_bytes,
+    head_smem_bytes,
+    head_smem_limit,
     head_supported,
+    head_tables,
     head_tile_counts,
     head_transform,
     head_vmem_limit,
@@ -18,6 +21,9 @@ from pulsarutils_tpu.ops.fdmt_resident import (
 )
 
 GARGS = (1200.0, 200.0)
+#: MeerKAT's L band in 4,096 channels (chipbench/configs/
+#: meertrap_lband_8bit.json): 32 groups of 128
+MEERTRAP = (856.0, 856.0)
 #: HTRU/BPSR's band (chipbench/configs/htru_bpsr_lowdm.json)
 HTRU = (1182.0, 400.0)
 #: the sweeps of the benchmark's cells, (band, max_delay, min_delay, T):
@@ -237,3 +243,99 @@ class TestSweepCounter:
         assert _count_head_tiles({"backend": "numpy", "kernel": "auto"},
                                  ("jax", "hybrid", None), *self.SWEEP) == 0
         assert self._total() - n0 == 2 * tiles
+
+
+class TestTablesAndPlane:
+    """ISSUE 35: a grid step holds its own group's tables, not every
+    group's, and the head writes each group's own rows."""
+
+    #: (max_delay, min_delay) of MeerTRAP's tier 0 and of its pruned tiers
+    #: 1-2, and what 28 whole tables of 32 groups took of a v5e's 1 MiB
+    #: (each ``s32[32, rows]`` padded to 128-word lines; the compiler's
+    #: "Used 1.75M of 1.00M smem", PERF.md section 6, PR 34)
+    PLANS = {"tier0": (5182, 0, 1.75, 512),
+             "tier1": (5182, 2592, 0.9375, 384)}
+
+    @staticmethod
+    def _whole_tables_bytes(hp):
+        return sum(4 * hp.n_groups * (-(-rows // 128) * 128) * 4
+                   for rows in hp.rows_out)
+
+    @pytest.mark.parametrize("name", sorted(PLANS))
+    def test_smem_need_of_the_meertrap_plans(self, name):
+        hi, lo, old_mib, width = self.PLANS[name]
+        hp = HeadPlan(fdmt_plan(4096, *MEERTRAP, hi, lo))
+        assert hp.n_groups == 32
+        assert self._whole_tables_bytes(hp) == old_mib * 2 ** 20
+        assert head_smem_limit() == 512 << 10  # no TPU here: half a v5e's
+        # one group's slice, 29 rows in (8, 128)-word tiles, twice
+        assert head_smem_bytes(hp) == 2 * 32 * width * 4 <= head_smem_limit()
+        tables = head_tables(hp)
+        assert tables.shape == (32, 4 * HEAD_LEVELS + 1, max(hp.rows_out))
+        assert width - 128 < tables.shape[2] <= width
+        # indices <= 475, shifts <= 201: only a plane start is larger
+        assert tables[:, :-1].max() < 512
+        assert tables[:, -1, HEAD_LEVELS].max() == hp.plane_starts[-1]
+
+    @pytest.mark.parametrize("name", sorted(SURVEY_PLANS))
+    def test_smem_need_of_the_survey_plans(self, name):
+        hp, _ = _survey_head(name)
+        assert head_smem_bytes(hp) <= 64 << 10
+
+    def test_plane_is_the_groups_own_rows(self):
+        hp = HeadPlan(fdmt_plan(4096, *MEERTRAP, 5182, 0))
+        assert hp.rows_out[-1] * hp.n_groups == 13312  # the old plane
+        assert hp.rows_total <= hp.rows_plane <= hp.rows_total + 7 * 32
+        assert hp.rows_plane < 0.42 * 13312
+        assert (np.diff(hp.plane_starts) % 8 == 0).all()
+        assert (np.diff(hp.plane_starts) >= hp.rows_valid[:-1]).all()
+
+    def test_bit_identical_on_the_meertrap_band_from_dm0(self):
+        """4,096 channels, 32 groups of 56 to 413 rows, band delays
+        0-5,182: the plan the v5e compiler refused (interpret mode, one
+        slice of 2,048 samples)."""
+        t = 2048
+        plan = fdmt_plan(4096, *MEERTRAP, 5182, 0)
+        hp = HeadPlan(plan)
+        assert hp.rows_valid.min() < 64 and hp.rows_valid.max() > 400
+        data = np.random.default_rng(35).standard_normal(
+            (4096, t)).astype(np.float32)
+        ref = _unfused_head(plan, data, HEAD_LEVELS)
+        out = np.asarray(head_transform(data, 5182, *MEERTRAP, t_slice=t,
+                                        interpret=True))
+        assert out.shape == ref.shape == (hp.rows_total, t)
+        assert np.array_equal(out, ref), float(np.abs(out - ref).max())
+
+    def test_head_declines_where_the_tables_do_not_fit(self, monkeypatch):
+        """A plan too wide for the core's SMEM takes the per-level
+        merges: `_head_choice` says so, nothing raises."""
+        from pulsarutils_tpu.ops import fdmt, fdmt_resident
+
+        nchan, t, lo, hi = 256, 4096, 100, 250
+        args = (nchan, *GARGS, hi, lo, t)
+        choice, declined, smem = fdmt._head_verdict(*args)
+        assert choice is not None and declined is None and smem > 0
+        monkeypatch.setattr(fdmt_resident, "head_smem_limit",
+                            lambda: smem - 1)
+        assert fdmt._head_verdict(*args) == (None, "smem", smem)
+        assert fdmt._head_choice(*args) is None
+        assert not fdmt.head_active(*args)
+        fdmt._transform_fn.cache_clear()
+        data = np.random.default_rng(4).standard_normal(
+            (nchan, t)).astype(np.float32)
+        declined_out, per_level = (np.asarray(fdmt._build_transform(
+            nchan, *GARGS, hi, t, fdmt._pick_fdmt_tile(t), False, True,
+            n_lo=lo, t_orig=t, use_head=use_head)(data))
+            for use_head in (True, False))
+        fdmt._transform_fn.cache_clear()
+        assert np.array_equal(declined_out, per_level)
+
+    def test_verdict_names_the_other_reasons(self):
+        from pulsarutils_tpu.ops import fdmt
+
+        assert fdmt._head_verdict(64, *GARGS, 40, 0, 4096)[1:] == (
+            "shape", 0)
+        # a band whose early levels shift a row by more than one lane row
+        verdicts = {fdmt._head_verdict(1024, 300.0, 100.0, hi, 0, 1 << 15)[1]
+                    for hi in (3000, 30000)}
+        assert verdicts <= {"halo", "shift"} and verdicts

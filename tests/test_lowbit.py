@@ -83,3 +83,87 @@ def test_native_pack_half_values_match_numpy():
     assert np.array_equal(lowbit.pack(vals, 2), lowbit.pack_numpy(vals, 2))
     assert np.array_equal(lowbit.pack(vals, 4), lowbit.pack_numpy(vals, 4))
     assert np.array_equal(lowbit.pack(vals, 1), lowbit.pack_numpy(vals, 1))
+
+
+# ---------------------------------------------------------------------------
+# 8-bit files: the raw bytes are the packed frames (PR 35)
+# ---------------------------------------------------------------------------
+
+def _write_8bit(path, rng, nchan=16, nsamp=96, descending=True, **extra):
+    data = rng.integers(0, 256, size=(nchan, nsamp)).astype(float)
+    write_filterbank(str(path), data, tsamp=1e-3,
+                     fch1=1400.0 if descending else 1200.0,
+                     foff=-1.0 if descending else 1.0, nbits=8, **extra)
+    return data
+
+
+@pytest.mark.parametrize("descending", [True, False])
+def test_8bit_read_block_packed_equals_read_block(tmp_path, rng, descending):
+    data = _write_8bit(tmp_path / "u8.fil", rng, descending=descending)
+    r = FilterbankReader(str(tmp_path / "u8.fil"))
+    assert r.packed_bits == 8
+    frames = r.read_block_packed(10, 50)
+    assert frames.dtype == np.uint8 and frames.shape == (50, 16)
+    assert np.array_equal(frames.T, r.read_block(10, 50))
+    assert np.array_equal(frames.T, data[:, 10:60])
+
+
+@pytest.mark.parametrize("descending", [True, False])
+def test_8bit_device_decode_equals_host_exactly(tmp_path, rng, descending):
+    _write_8bit(tmp_path / "u8.fil", rng, descending=descending)
+    r = FilterbankReader(str(tmp_path / "u8.fil"))
+    host = r.read_block(0, 96, band_ascending=True)
+    packed = lowbit.PackedFrames.read(r, 0, 96)
+    assert packed.nbytes * 4 == packed.float_nbytes
+    dev = np.asarray(lowbit.device_unpack_block(
+        packed.frames, 8, r.nchans, band_descending=r.band_descending))
+    assert dev.dtype == np.float32 and host.dtype == np.float32
+    assert np.array_equal(dev, host)
+    assert np.array_equal(packed.to_host(), host)
+    assert np.array_equal(np.asarray(packed.to_device()), host)
+
+
+@pytest.mark.parametrize("nbits,nifs,extra", [
+    (8, 1, {"signed": 1}), (8, 2, {}), (16, 1, {}), (32, 1, {})],
+    ids=["signed8", "8bit_2if", "16bit", "float32"])
+def test_other_files_keep_the_host_path(tmp_path, rng, nbits, nifs, extra):
+    from pulsarutils_tpu.io.sigproc import FilterbankWriter
+
+    header = {"nchans": 8, "nbits": nbits, "nifs": nifs, "tsamp": 1e-3,
+              "fch1": 1400.0, "foff": -1.0, "tstart": 0.0, **extra}
+    data = rng.integers(0, 100, size=(nifs, 8, 32))
+    with FilterbankWriter(str(tmp_path / "o.fil"), header) as w:
+        w.write_block(data if nifs > 1 else data[0])
+    r = FilterbankReader(str(tmp_path / "o.fil"))
+    assert r.packed_bits == 0
+    with pytest.raises(ValueError, match="read_block_packed"):
+        r.read_block_packed(0, 8)
+    assert np.array_equal(r.read_block(0, 32), data.sum(axis=0))
+
+
+@pytest.mark.parametrize("signed,nifs,if_mode", [
+    (False, 1, "sum"), (True, 1, "sum"), (False, 2, 1), (False, 2, "sum")],
+    ids=["unsigned", "signed", "plane1_of_2", "sum_of_2"])
+def test_8bit_host_fallback_values(tmp_path, rng, signed, nifs, if_mode):
+    """``unpack_frames`` transposes the bytes and widens after: the values
+    are those of the float64 block it used to make first."""
+    from pulsarutils_tpu.io.sigproc import FilterbankWriter
+
+    nchan, nsamp = 8, 40
+    header = {"nchans": nchan, "nbits": 8, "nifs": nifs, "tsamp": 1e-3,
+              "fch1": 1400.0, "foff": -1.0, "tstart": 0.0}
+    if signed:
+        header["signed"] = 1
+    data = rng.integers(-128 if signed else 0, 128 if signed else 256,
+                        size=(nifs, nchan, nsamp))
+    with FilterbankWriter(str(tmp_path / "f.fil"), header) as w:
+        w.write_block(data if nifs > 1 else data[0])
+    r = FilterbankReader(str(tmp_path / "f.fil"), if_mode=if_mode)
+    raw = np.asarray(r._mmap[:])
+    old = raw.reshape(nsamp, nifs, nchan).astype(float)
+    old = (old.sum(axis=1) if if_mode == "sum" else old[:, if_mode]).T
+    for ascending in (False, True):
+        block = r.unpack_frames(raw, band_ascending=ascending)
+        assert np.array_equal(block, old[::-1] if ascending else old)
+        if if_mode != "sum" or nifs == 1:
+            assert block.dtype == np.float32

@@ -424,3 +424,111 @@ def test_packed_gate_quarantines_in_pipeline(tmp_path):
         snr_threshold=6.0, progress=False)
     assert hits == []
     assert len(store.quarantined_chunks) > 0
+
+
+# ---------------------------------------------------------------------------
+# 8-bit files through search_by_chunks (PR 35)
+# ---------------------------------------------------------------------------
+
+def _survey_8bit(tmp_path, arm, codes, **kw):
+    from pulsarutils_tpu.pipeline.search_pipeline import search_by_chunks
+
+    path = str(tmp_path / f"{arm}.fil")
+    write_lowbit(path, codes, 8, True)
+    hits, store = search_by_chunks(
+        path, dmmin=100, dmmax=200, backend="jax",
+        output_dir=str(tmp_path / f"out_{arm}"), make_plots=False,
+        snr_threshold=6.0, progress=False, **kw)
+    return path, hits, store
+
+
+def test_8bit_raw_upload_equals_host_decode(tmp_path, monkeypatch):
+    """An 8-bit file searched from its raw bytes (device decode, packed
+    pre-scan, code-domain gate) persists the tables of the host-decoded
+    run bit for bit, with the same ``.badchans``; the packed path's
+    counters move for it as for a 2-bit file."""
+    from pulsarutils_tpu.obs import metrics as m
+
+    from pulsarutils_tpu.models.simulate import disperse_array
+
+    nchan, nsamps = 64, 3 * 4096
+    rng = np.random.default_rng(8)
+    pulse = np.zeros((nchan, nsamps))
+    pulse[:, 9000] = 40.0
+    codes = 96 + 16 * rng.standard_normal((nchan, nsamps)) \
+        + disperse_array(pulse, 150.0, *GEOM)
+    codes[5] += 48  # a hot channel for the pre-scan to flag
+    codes = np.clip(np.rint(codes), 0, 255).astype(np.float32)
+
+    names = ("putpu_lowbit_packed_chunks_total",
+             "putpu_lowbit_bytes_saved_total",
+             "putpu_prescan_packed_bytes_total")
+    before = [m.counter(n).value for n in names]
+    path_p, hits_p, _ = _survey_8bit(tmp_path, "raw", codes)
+    moved = [m.counter(n).value - b for n, b in zip(names, before)]
+    assert moved[0] > 0             # chunks uploaded as raw bytes,
+    assert moved[1] > 3 * nchan * nsamps  # each a quarter of its floats
+    assert moved[2] == nchan * nsamps     # the pre-scan read every byte
+
+    monkeypatch.setattr(FilterbankReader, "packed_bits",
+                        property(lambda self: 0))
+    before = [m.counter(n).value for n in names]
+    path_h, hits_h, _ = _survey_8bit(tmp_path, "host", codes)
+    assert [m.counter(n).value - b for n, b in zip(names, before)] \
+        == [0, 0, 0]
+
+    assert len(hits_p) == len(hits_h) > 0
+    for h_p, h_h in zip(sorted(hits_p), sorted(hits_h)):
+        assert h_p[:2] == h_h[:2]
+        assert_tables_equal(h_p[3], h_h[3], msg=f"chunk {h_p[0]}")
+    with open(path_p + ".badchans", "rb") as f, \
+            open(path_h + ".badchans", "rb") as g:
+        assert f.read() == g.read()
+
+
+def test_8bit_gate_quarantines_a_railed_chunk(tmp_path):
+    """The code-domain gate reads the 8-bit rails off the raw bytes: a
+    file pinned at 255 is quarantined, a healthy one is not."""
+    from pulsarutils_tpu.faults.policy import (
+        IntegrityPolicy,
+        gate_chunk_packed,
+    )
+
+    nchan = 32
+    rng = np.random.default_rng(9)
+    healthy = np.clip(np.rint(96 + 16 * rng.standard_normal(
+        (2048, nchan))), 0, 255).astype(np.uint8)
+    _, info = gate_chunk_packed(healthy, 8, nchan, IntegrityPolicy())
+    assert info["verdict"] == "clean" and info["stats"]["nbits"] == 8
+    # the gate's sample is a view of the bytes, equal to their decode
+    from pulsarutils_tpu.io.lowbit import sample_codes, unpack_numpy
+
+    codes = sample_codes(healthy, 8, nchan, max_rows=512)
+    assert codes.dtype == np.uint8 and np.shares_memory(codes, healthy)
+    assert np.array_equal(codes, unpack_numpy(healthy[::4], 8).reshape(
+        -1, nchan).T)
+
+    railed = np.full((nchan, 2 * 4096), 255, dtype=np.float32)
+    _, hits, store = _survey_8bit(tmp_path, "railed", railed)
+    assert hits == []
+    assert len(store.quarantined_chunks) > 0
+
+
+def test_8bit_canary_quantizes_into_the_bytes():
+    """The packed canary needs no 8-bit branch: a byte a channel, the
+    bump rounded and clipped onto 0..255."""
+    from pulsarutils_tpu.obs.canary import CanaryController
+
+    nchan, nsamps = 32, 4096
+    rng = np.random.default_rng(10)
+    frames = np.clip(np.rint(96 + 16 * rng.standard_normal(
+        (nsamps, nchan))), 0, 255).astype(np.uint8)
+    c = CanaryController(rate=1.0, snr=20.0, seed=1)
+    c.bind(nchan=nchan, start_freq=GEOM[0], bandwidth=GEOM[1],
+           tsamp=GEOM[2], dmmin=100, dmmax=200)
+    out = c.maybe_inject_packed(frames, 0, nbits=8, nchan=nchan,
+                                band_descending=True)
+    diff = out.astype(int) - frames
+    assert np.any(diff > 0) and np.all(diff >= 0)
+    # one lit sample per channel and sample of the pulse's width
+    assert (diff > 0).sum(axis=0).max() <= c._width

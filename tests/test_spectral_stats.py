@@ -85,12 +85,12 @@ NCHAN, NSAMP, HOT = 64, 1000, (5, 30, 31)
 COUNTER = "putpu_prescan_packed_bytes_total"
 
 
-def lowbit_file(path, nbits, nifs=1, seed=0):
+def lowbit_file(path, nbits, nifs=1, seed=0, signed=False):
     """A file of ``nbits``-wide codes with hot channels: noise in the
     lower half of the code range, the hot channels spread over all of it
     (a higher mean and a larger scatter)."""
     rng = np.random.default_rng(seed)
-    top = (1 << nbits) - 1 if nbits < 8 else 200
+    top = (1 << nbits) - 1 if nbits <= 8 and not signed else 120
     data = rng.integers(0, top // 2 + 1, size=(nifs, NCHAN, NSAMP))
     data[:, HOT, :] = rng.integers(0, top + 1, size=(nifs, len(HOT), NSAMP))
     data[:, HOT, ::2] = top
@@ -98,6 +98,8 @@ def lowbit_file(path, nbits, nifs=1, seed=0):
               "tsamp": 1e-3, "fch1": 1400.0, "foff": -1.0, "tstart": 0.0,
               "source_name": "t", "machine_id": 0, "telescope_id": 0,
               "data_type": 1}
+    if signed:
+        header["signed"] = 1
     with FilterbankWriter(str(path), header) as w:
         w.write_block(data if nifs > 1 else data[0])
     return str(path)
@@ -117,7 +119,7 @@ def tiny_blocks(monkeypatch):
     return force
 
 
-@pytest.mark.parametrize("nbits", [1, 2, 4])
+@pytest.mark.parametrize("nbits", [1, 2, 4, 8])
 def test_packed_prescan_spectra_equal_float_loop(tmp_path, tiny_blocks,
                                                  nbits):
     path = lowbit_file(tmp_path / "p.fil", nbits)
@@ -134,7 +136,7 @@ def test_packed_prescan_spectra_equal_float_loop(tmp_path, tiny_blocks,
     assert std_p[list(HOT)].min() > np.delete(std_p, HOT).max()
 
 
-@pytest.mark.parametrize("nbits", [1, 2, 4])
+@pytest.mark.parametrize("nbits", [1, 2, 4, 8])
 def test_packed_prescan_sidecar_bytes_equal_float_loop(tmp_path, tiny_blocks,
                                                        nbits):
     path = lowbit_file(tmp_path / "p.fil", nbits)
@@ -150,9 +152,13 @@ def test_packed_prescan_sidecar_bytes_equal_float_loop(tmp_path, tiny_blocks,
         assert f.read() == g.read()
 
 
-@pytest.mark.parametrize("nbits,nifs", [(8, 1), (32, 1), (2, 2)])
-def test_other_sources_keep_float_loop(tmp_path, nbits, nifs):
-    path = lowbit_file(tmp_path / "f.fil", nbits, nifs=nifs)
+@pytest.mark.parametrize("nbits,nifs,signed", [
+    (8, 1, True), (32, 1, False), (2, 2, False), (8, 2, False),
+    (16, 1, False)], ids=["signed8", "float32", "2bit_2if", "8bit_2if",
+                          "16bit"])
+def test_other_sources_keep_float_loop(tmp_path, nbits, nifs, signed):
+    path = lowbit_file(tmp_path / "f.fil", nbits, nifs=nifs, signed=signed)
+    assert not FilterbankReader(path).packed_bits
     before = packed_bytes()
     mean_s, std_s = get_spectral_stats(path, chunksize=300)
     assert packed_bytes() == before
@@ -189,3 +195,8 @@ def test_packed_block_frames_keeps_int32_exact():
     assert spectral_stats._packed_block_frames(2, 256, 2 ** 30) \
         == spectral_stats._PACKED_BLOCK_BYTES // 256
     assert spectral_stats._packed_block_frames(2, 256, 1000) == 1024
+    # MeerTRAP's 4,096-byte frames of 8-bit samples: 64 MiB blocks of
+    # 16,384, under the 33,025 frames that keep a sum of 255^2 exact
+    assert spectral_stats._packed_block_frames(8, 4096, 2 ** 18) == 16384
+    assert spectral_stats._packed_block_frames(8, 64, 2 ** 30) \
+        == (2 ** 31 - 1) // 255 ** 2 == 33025
