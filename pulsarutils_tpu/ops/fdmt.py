@@ -358,8 +358,18 @@ def _transform_setup(data, use_pallas):
 MERGE_ROW_BLOCK = 32
 
 
+def _state_tiles(state, t_tile):
+    """A state in the layout the merge kernels read and write,
+    ``(rows, t / t_tile, 8, t_tile / 8)``: from the flat ``(rows, t)``,
+    or from the head's ``(rows, t / 256, 256)`` plane — a fixed
+    permutation of 128-sample blocks inside each ``t_tile``, ONE
+    relayout on the chip either way (``tests/test_chip_compile.py``
+    holds the compiled sweep to it)."""
+    return state.reshape(state.shape[0], -1, 8, t_tile // 8)
+
+
 @functools.lru_cache(maxsize=64)
-def _build_merge_kernel(rows_out, rows_in, t, t_tile, k_tiles, k_tiles_h,
+def _build_merge_kernel(rows_out, t, t_tile, k_tiles, k_tiles_h,
                         row_block, interpret):
     """Fused FDMT merge: ``out[r] = roll(high[ih[r]], sh[r]) +
     roll(low[il[r]], s[r])``, ``row_block`` rows per grid step.
@@ -367,7 +377,10 @@ def _build_merge_kernel(rows_out, rows_in, t, t_tile, k_tiles, k_tiles_h,
     ``k_tiles_h = 0`` compiles the common asymmetric form (high parent
     read aligned, no rotation) used by every iteration except the leaf
     merge.  ``rows_out`` must be a multiple of ``row_block`` (callers pad
-    the tables; padded rows write junk rows that are sliced off).
+    the tables; padded rows write copies of the last real row, which no
+    later table indexes).  State in and out are :func:`_state_tiles`
+    arrays, of any row count in: consecutive stages hand the state on
+    with no relayout and no row slice between them.
     """
     import jax
     import jax.numpy as jnp
@@ -453,19 +466,15 @@ def _build_merge_kernel(rows_out, rows_in, t, t_tile, k_tiles, k_tiles_h,
                               (rows_out, n_t, 8, L), jnp.float32),
                           interpret=bool(interpret), name="fdmt_merge")
 
-    @jax.jit
-    def fdmt_merge(state, idx_low, idx_high, shift, shift_high):
-        s4 = state.reshape(rows_in, n_t, 8, L)
+    def fdmt_merge(s4, idx_low, idx_high, shift, shift_high):
         n_in = row_block * (k_tiles + kh)
-        out = call(idx_low, idx_high, shift, shift_high,
-                   *([s4] * n_in))
-        return out.reshape(rows_out, t)
+        return call(idx_low, idx_high, shift, shift_high, *([s4] * n_in))
 
     return fdmt_merge
 
 
 @functools.lru_cache(maxsize=16)
-def _build_merge4_kernel(rows_out, rows_in, t, t_tile, k_tiles, row_block,
+def _build_merge4_kernel(rows_out, t, t_tile, k_tiles, row_block,
                          interpret):
     """Fused two-level FDMT merge: ``out[r] = sum_p roll(state[idx_p[r]],
     shift_p[r])`` over 4 parents (:func:`compose_iterations`).
@@ -473,7 +482,8 @@ def _build_merge4_kernel(rows_out, rows_in, t, t_tile, k_tiles, row_block,
     Same scalar-prefetch scheme as :func:`_build_merge_kernel`, with one
     shared ``k_tiles`` bound covering every composed shift (parent 0's
     shift is 0; the rotate machinery handles it without a special
-    case).  ``rows_out`` must be a multiple of ``row_block``.
+    case).  ``rows_out`` must be a multiple of ``row_block``; state in
+    and out are :func:`_state_tiles` arrays.
     """
     import jax
     import jax.numpy as jnp
@@ -539,21 +549,20 @@ def _build_merge4_kernel(rows_out, rows_in, t, t_tile, k_tiles, row_block,
                           interpret=bool(interpret),
                           name="fdmt_deep_pair")
 
-    @jax.jit
-    def fdmt_deep_pair(state, idx, shift):
-        s4 = state.reshape(rows_in, n_t, 8, L)
+    def fdmt_deep_pair(s4, idx, shift):
         n_in = row_block * P * k_tiles
-        out = call(*idx, *shift, *([s4] * n_in))
-        return out.reshape(rows_out, t)
+        return call(*idx, *shift, *([s4] * n_in))
 
     return fdmt_deep_pair
 
 
-def _merge4_pallas(state, idx, shift, t_tile, interpret):
-    """Run one composed 4-parent merge pass (host-side table prep)."""
+def _merge4_pallas(s4, idx, shift, t_tile, interpret):
+    """Run one composed 4-parent merge pass (host-side table prep) on a
+    :func:`_state_tiles` state; the output keeps the rows the row block
+    pads to, after the real ones."""
     import jax.numpy as jnp
 
-    rows_in, t = state.shape
+    t = s4.shape[1] * t_tile
     rows_out = len(idx[0])
     L = t_tile // 8
     max_shift = max(int(s.max(initial=0))  # putpu-lint: disable=device-trip — host plan tables
@@ -567,11 +576,21 @@ def _merge4_pallas(state, idx, shift, t_tile, interpret):
     pad = (-rows_out) % row_block
     idx_p = [np.concatenate([i, i[-1:].repeat(pad)]) for i in idx]
     shift_p = [np.concatenate([s, s[-1:].repeat(pad)]) for s in shift]
-    run = _build_merge4_kernel(rows_out + pad, rows_in, t, t_tile,
-                               k_tiles, row_block, interpret)
-    out = run(state, tuple(jnp.asarray(i) for i in idx_p),
-              tuple(jnp.asarray(s) for s in shift_p))
-    return out[:rows_out] if pad else out
+    run = _build_merge4_kernel(rows_out + pad, t, t_tile, k_tiles,
+                               row_block, interpret)
+    return run(s4, tuple(jnp.asarray(i) for i in idx_p),
+               tuple(jnp.asarray(s) for s in shift_p))
+
+
+def _merge_tiles(s4, idx_low, idx_high, shift, shift_high, k_tiles,
+                 k_tiles_h, t_tile, interpret):
+    """One merge pass, :func:`_state_tiles` state in and out, tables
+    already padded to the row block."""
+    rows_out = idx_low.shape[0]
+    run = _build_merge_kernel(rows_out, s4.shape[1] * t_tile, t_tile,
+                              k_tiles, k_tiles_h,
+                              min(MERGE_ROW_BLOCK, rows_out), interpret)
+    return run(s4, idx_low, idx_high, shift, shift_high)
 
 
 def merge_rows_traced(state, idx_low, idx_high, shift, shift_high, *,
@@ -583,20 +602,24 @@ def merge_rows_traced(state, idx_low, idx_high, shift, shift_high, *,
     schedules of identical shape (the sharded FDMT ships each device its
     own tables through ``shard_map``).  ``k_tiles``/``k_tiles_h`` must be
     static bounds covering every shift value; row count must already be
-    a multiple of :data:`MERGE_ROW_BLOCK` (or smaller than it).
+    a multiple of :data:`MERGE_ROW_BLOCK` (or smaller than it).  Flat
+    ``(rows, t)`` states in and out: a relayout either side of the
+    kernel, which the single-device sweep's chain of
+    :func:`_state_tiles` states does not pay.
     """
-    rows_in, t = state.shape
+    t = state.shape[1]
     rows_out = idx_low.shape[0]
-    row_block = min(MERGE_ROW_BLOCK, rows_out)
-    run = _build_merge_kernel(rows_out, rows_in, t, t_tile, k_tiles,
-                              k_tiles_h, row_block, interpret)
-    return run(state, idx_low, idx_high, shift, shift_high)
+    out = _merge_tiles(_state_tiles(state, t_tile), idx_low, idx_high,
+                       shift, shift_high, k_tiles, k_tiles_h, t_tile,
+                       interpret)
+    return out.reshape(rows_out, t)
 
 
-def _merge_pallas(state, it, t_tile, interpret):
+def _merge_pallas(s4, it, t_tile, interpret):
+    """One per-level merge pass on a :func:`_state_tiles` state; the
+    output keeps the rows the row block pads to, after the real ones."""
     import jax.numpy as jnp
 
-    rows_in, t = state.shape
     rows_out = len(it["idx_low"])
     L = t_tile // 8
     max_shift = int(  # putpu-lint: disable=device-trip — host plan tables
@@ -620,12 +643,9 @@ def _merge_pallas(state, it, t_tile, interpret):
     else:
         k_tiles_h = 0
         shift_high = np.zeros(rows_out + pad, np.int32)
-    out = merge_rows_traced(state, jnp.asarray(idx_low),
-                            jnp.asarray(idx_high), jnp.asarray(shift),
-                            jnp.asarray(shift_high), k_tiles=k_tiles,
-                            k_tiles_h=k_tiles_h, t_tile=t_tile,
-                            interpret=interpret)
-    return out[:rows_out] if pad else out
+    return _merge_tiles(s4, jnp.asarray(idx_low), jnp.asarray(idx_high),
+                        jnp.asarray(shift), jnp.asarray(shift_high),
+                        k_tiles, k_tiles_h, t_tile, interpret)
 
 
 def head_active(nchan, start_freq, bandwidth, max_delay, n_lo, t):
@@ -749,14 +769,15 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
     # one Pallas program whose intermediate states never leave VMEM,
     # bit-identical to the per-level path (v5e, 1024 x 1M: 0.323 s vs
     # 0.365 s per-level, transform+score).
-    head_run = None
+    head_run = head = None
     n_head = 0
     head_choice = use_head and _head_choice(nchan, start_freq, bandwidth,
                                             max_delay, n_lo, t)
     if head_choice:
-        from .fdmt_resident import HEAD_LEVELS, _build_head_kernel
+        from .fdmt_resident import (HEAD_LEVELS, _build_head_kernel,
+                                    head_flat_rows, head_plane_rows)
 
-        head_run, _ = _build_head_kernel(
+        head_run, head = _build_head_kernel(
             nchan, start_freq, bandwidth, max_delay, n_lo,
             HEAD_LEVELS, t, head_choice[1], interpret)
         n_head = HEAD_LEVELS
@@ -773,31 +794,56 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
         paired = compose_iterations(iters[-2], iters[-1])
         iters = iters[:-2]
 
+    # the Pallas stage after the head reads the head's plane as the head
+    # leaves it, each group's rows padded to the row loop's block: its
+    # parent tables are rebased onto plane rows here, on the host, so no
+    # gather and no row slice of the state runs on the device
+    if head and use_pallas:
+        plane_rows = head_plane_rows(head)
+        if iters:
+            iters = [dict(iters[0],
+                          idx_low=plane_rows[iters[0]["idx_low"]],
+                          idx_high=plane_rows[iters[0]["idx_high"]])
+                     ] + iters[1:]
+        else:
+            paired = ([plane_rows[i] for i in paired[0]], paired[1])
+    rows = max_delay - n_lo + 1
+
     def fn(data):
         state = data
         if nchan < plan.nchan_padded:
             state = jnp.concatenate(
                 [state,
                  jnp.zeros((plan.nchan_padded - nchan, t), state.dtype)])
-        if head_run is not None:
-            state = head_run(state)
-        for it in iters:
-            if use_pallas:
+        if use_pallas:
+            # from the head (or the chunk) to the scorer the state keeps
+            # the layout the kernels read and write, the rows each pads
+            # to left in place after the real ones: one relayout in, one
+            # out, none between two kernels
+            if head_run is not None:
+                state = head_run(state)
+            state = _state_tiles(state, t_tile)
+            for it in iters:
                 state = _merge_pallas(state, it, t_tile, interpret)
-            else:
+            if paired is not None:
+                state = _merge4_pallas(state, paired[0], paired[1], t_tile,
+                                       interpret)
+            state = state.reshape(state.shape[0], t)
+        else:
+            if head_run is not None:  # the tests' seam: head, XLA merges
+                state = head_flat_rows(head, head_run(state))
+            for it in iters:
                 sh = (jnp.asarray(it["shift_high"])
                       if it["shift_high"] is not None else None)
                 state = _merge_xla(state, jnp.asarray(it["idx_low"]),
                                    jnp.asarray(it["idx_high"]),
                                    jnp.asarray(it["shift"]), sh)
-        if paired is not None:
-            state = _merge4_pallas(state, paired[0], paired[1], t_tile,
-                                   interpret)
-        plane = state  # rows n_lo..max_delay by construction
+        # the first `rows` rows are n_lo..max_delay by construction
+        plane = state
         if t_orig is not None and t_orig != t:
             plane = plane[:, :t_orig]
         if not with_scores:
-            return plane
+            return plane[:rows]
         from .score_pallas import pick_score_tile
         from .search import score_profiles_chunked, scored_windows
 
@@ -821,16 +867,16 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
 
             stacked = score_plane_pallas(plane, with_cert=with_cert,
                                          interpret=interpret,
-                                         windows=windows)
+                                         windows=windows, rows=rows)
         else:
             # row-chunked scoring bounds the scorer's HBM temps (see
             # score_profiles_chunked) while still emitting ONE (5, ndm)
             # array ((6, ndm) with the hybrid's certificate row) -> one
             # host readback
-            stacked = score_profiles_chunked(plane, jnp,
+            stacked = score_profiles_chunked(plane[:rows], jnp,
                                              with_cert=with_cert,
                                              windows=windows)
-        return (stacked, plane) if with_plane else stacked
+        return (stacked, plane[:rows]) if with_plane else stacked
 
     return fn
 

@@ -543,22 +543,37 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
         interpret=bool(interpret), name="fdmt_head")
     tables = jnp.asarray(tables)
 
-    # host-side reassembly index: global level-n row -> its plane row
-    gather = np.concatenate(
-        [start + np.arange(c)
-         for start, c in zip(head.plane_starts, head.rows_valid)])
-
     def run(data):
-        # traceable (un-jitted) so the whole-transform jit can inline it
-        data3 = data.reshape(data.shape[0], c8, _L)
-        out = call(tables, data3)
-        # (rows_plane, c8, L) -> (rows_total, t)
-        out = out.reshape(head.rows_plane, t)
-        if head.rows_plane == head.rows_total:
-            return out
-        return out[jnp.asarray(gather)]
+        # traceable (un-jitted) so the whole-transform jit can inline
+        # it; returns the plane as the kernel writes it, (rows_plane,
+        # t / L, L): the sweep's next kernel reads it through
+        # :func:`head_plane_rows`, :func:`head_flat_rows` relays it flat
+        return call(tables, data.reshape(data.shape[0], c8, _L))
 
     return run, head
+
+
+def head_plane_rows(head):
+    """The plane row of every row of the head's output state: global
+    level-``n_levels`` row (band-major, as the next merge's tables index
+    it) -> its row in the kernel's plane, where each group's rows start
+    on a block of the row loop."""
+    return np.concatenate(
+        [start + np.arange(c, dtype=np.int32)
+         for start, c in zip(head.plane_starts, head.rows_valid)])
+
+
+def head_flat_rows(head, plane):
+    """The head's plane as the flat ``(rows_total, t)`` state of the
+    per-level path: a relayout of the whole plane and, where a group's
+    rows are padded, a gather of it — what the sweep's chain of kernels
+    does not pay (``ops/fdmt.py:_transform_fn``)."""
+    import jax.numpy as jnp
+
+    out = plane.reshape(head.rows_plane, -1)
+    if head.rows_plane == head.rows_total:
+        return out
+    return out[jnp.asarray(head_plane_rows(head))]
 
 
 def head_transform(data, max_delay, start_freq, bandwidth, min_delay=0,
@@ -591,7 +606,7 @@ def head_transform(data, max_delay, start_freq, bandwidth, min_delay=0,
             [data, jnp.zeros((head.rows_in * head.n_groups - nchan, t),
                              jnp.float32)])
     def fdmt_resident(data):  # the program's name in a device trace
-        return run(data)
+        return head_flat_rows(head, run(data))
 
     return jax.jit(fdmt_resident)(data)
 

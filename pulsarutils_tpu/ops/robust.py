@@ -128,6 +128,36 @@ def h_test(profile, nmax=20, xp=np):
     return h_candidates[best], best + 1
 
 
+def z_n_and_h(profile, harmonics, nmax=20):
+    """``Z^2_n`` for every ``n`` of ``harmonics`` and the H-test up to
+    ``nmax`` of one binned profile, from ONE ``rfft``: the floats
+    :func:`z_n_test` and :func:`h_test` return, bit for bit (the same
+    powers summed in the same order).  A candidate's record asks for four
+    ``Z^2_n`` and ``H`` of a profile as long as its chunk; five
+    transforms of 2^20 points, each building its own plan and buffers,
+    were 44 to 178 ms of a hit chunk's host time (``PERF.md`` section 6,
+    PR 37).
+
+    Returns ``({n: Z^2_n}, H, m_best)``.
+    """
+    profile = np.asarray(profile, dtype=float)
+    nbin = profile.shape[0]
+    harmonics = [int(n) for n in harmonics]
+    if harmonics and max(harmonics) > nbin // 2:
+        raise ValueError(
+            f"n_harmonics={max(harmonics)} exceeds the {nbin // 2} "
+            f"harmonics resolvable in a {nbin}-bin profile")
+    nmax = int(max(1, min(nmax, nbin // 2 if nbin >= 4 else 1)))
+    total = profile.sum()
+    spec = np.fft.rfft(profile)
+    z = {n: 2.0 / total * (np.abs(spec[1:n + 1]) ** 2).sum()
+         for n in harmonics}
+    z2 = 2.0 / total * np.cumsum(np.abs(spec[1:nmax + 1]) ** 2)
+    h_candidates = z2 - 4.0 * np.arange(1, nmax + 1) + 4.0
+    best = np.argmax(h_candidates)
+    return z, h_candidates[best], best + 1
+
+
 def h_test_batch(profiles, nmax=20, xp=np, total=None):
     """Vectorised H-test over a batch of profiles ``(nprof, nbin)``.
 

@@ -344,7 +344,8 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret,
 
 def _kernel_scores(rows_p, t, t_blk, with_cert, interpret, sub,
                    n_levels=4, wide_from=None):
-    """Run the one-pass kernel on the 8-aligned row block ``sub``.
+    """Run the one-pass kernel on the first ``rows_p`` (8-aligned) rows
+    of ``sub``: the grid visits them, rows past them are never read.
 
     Split out of :func:`score_plane_pallas` so tests can stub the
     (expensive) kernel invocation while exercising the wrapper's
@@ -358,9 +359,12 @@ def _kernel_scores(rows_p, t, t_blk, with_cert, interpret, sub,
 
 
 def score_plane_pallas(plane, with_cert=False, interpret=False,
-                       windows=None):
+                       windows=None, rows=None):
     """One-pass scores of ``plane`` — drop-in for
     :func:`..ops.search.score_profiles_chunked` on tile-friendly shapes.
+    ``rows`` (default: all) scores the first ``rows`` rows alone: the
+    FDMT sweep hands its last kernel's output with the padded rows left
+    in place after the real ones.
 
     Returns the stacked ``(5, rows)`` float32 array (``(6, rows)`` with
     ``with_cert``: the sliding certificate row appended).  Raises
@@ -379,15 +383,18 @@ def score_plane_pallas(plane, with_cert=False, interpret=False,
     Row counts are handled without any plane-sized copy (the motivating
     coarse plane is 513 x 1M — an odd row count; padding it would
     re-materialise ~2 GB per search, code-review r5): the 8-aligned
-    row prefix goes through the kernel and the <= 7 remainder rows
-    through the XLA scorer (same per-row semantics, independent rows).
+    row prefix goes through the kernel, by its grid, and the <= 7
+    remainder rows through the XLA scorer (same per-row semantics,
+    independent rows).
     """
     import jax.numpy as jnp
 
     from .search import (cert_wide_windows, scored_windows,
                          warn_peak_exactness)
 
-    rows, t = plane.shape
+    t = plane.shape[1]
+    if rows is None:
+        rows = plane.shape[0]
     scored = scored_windows(windows, t)
     wide = cert_wide_windows(windows, t)
     t_blk = pick_score_tile(t, scored[-1])
@@ -404,13 +411,13 @@ def score_plane_pallas(plane, with_cert=False, interpret=False,
     if rows8:
         out = _kernel_scores(
             rows8, t, t_blk, bool(with_cert), bool(interpret),
-            plane[:rows8], n_levels=len(scored),
+            plane, n_levels=len(scored),
             wide_from=scored.index(wide[0]) if wide else None)
         parts.append(out[:, :6 if with_cert else 5].T)
     if rows8 != rows:
         from .search import score_profiles_chunked
 
-        parts.append(score_profiles_chunked(plane[rows8:], jnp,
+        parts.append(score_profiles_chunked(plane[rows8:rows], jnp,
                                             with_cert=with_cert,
                                             windows=windows))
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)

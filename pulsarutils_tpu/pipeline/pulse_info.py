@@ -19,7 +19,7 @@ import os
 
 import numpy as np
 
-from ..ops.robust import digitize, h_test, z_n_test
+from ..ops.robust import digitize, z_n_and_h
 
 _ARRAY_FIELDS = ("allprofs", "dedisp_profile", "disp_profile",
                  "fold_profile")
@@ -99,11 +99,11 @@ class PulseInfo:
                 continue
             counts = np.maximum(digitize(np.asarray(profile)), 0)
             nmax = counts.size // 2
-            for n in (2, 6, 12, 20):
-                if n <= nmax:
-                    setattr(self, f"{prefix}_z{n}",
-                            float(z_n_test(counts, n)))
-            h, m = h_test(counts, nmax=min(20, max(nmax, 1)))
+            z, h, m = z_n_and_h(
+                counts, [n for n in (2, 6, 12, 20) if n <= nmax],
+                nmax=min(20, max(nmax, 1)))
+            for n, z_n in z.items():
+                setattr(self, f"{prefix}_z{n}", float(z_n))
             setattr(self, f"{prefix}_H", float(h))
             setattr(self, f"{prefix}_M", int(m))
         return self

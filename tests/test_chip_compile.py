@@ -26,6 +26,8 @@ full-size compiles were made by hand and are recorded in CHANGES.md
 (PR 22).
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -217,6 +219,67 @@ def test_meertrap_tier0_sweep_beside_its_chunk(as_tpu, one_chip):
     total = (m.temp_size_in_bytes + m.argument_size_in_bytes
              + m.output_size_in_bytes - m.alias_size_in_bytes)
     assert total + 2.75 * 2**30 < HBM_BYTES, m
+
+
+def _plane_sized_passes(text, plane_bytes):
+    """Instructions of the compiled program's entry computation that are
+    no kernel (``custom-call``), ``bitcast`` or ``parameter`` and whose
+    result holds at least half of ``plane_bytes``: each is one pass over
+    an FDMT state at memory speed that computes nothing."""
+    width = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1,
+             "u8": 1, "pred": 1}
+    found = []
+    for line in text[text.index("ENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if not m or m.group(3) in ("custom-call", "bitcast", "parameter"):
+            continue
+        size = sum(width[dtype] * int(np.prod([int(d) for d in
+                                               dims.split(",") if d]))
+                   for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                                 m.group(2))
+                   if dtype in width)
+        if 2 * size >= plane_bytes:
+            found.append((m.group(1), m.group(3), size))
+    return found
+
+
+@pytest.mark.parametrize("band,hi,lo,t,ladder,parent_temp_gib", [
+    (HTRU, 1068, 0, 1 << 19, None, 6.38),    # tier 0 (cells 2-4)
+    (HTRU, 1068, 535, 1 << 18, None, 1.61),  # a pruned tier (tiers 1-4)
+    (MEERTRAP, 5182, 0, 1 << 17, 2048, 7.60),  # three merges, then the pair
+], ids=["htru_tier0", "htru_tier1", "meertrap_tier0"])
+def test_sweep_keeps_the_kernels_layout(as_tpu, one_chip, band, hi, lo, t,
+                                        ladder, parent_temp_gib):
+    """The coarse sweep as ``_search_jax_fdmt`` builds it on a TPU
+    (ISSUE 37): between the head and the scorer the FDMT state goes from
+    kernel to kernel in the layout the kernels read and write.  Three
+    plane-sized passes are left that are no kernel: the cleaned chunk to
+    the head's lines, the head's plane to the merges' tiles, the last
+    stage's tiles to the flat plane the scorer reads.  The parent
+    compiled to 11 / 11 / 13 (a relayout either side of every kernel, a
+    gather of the head's rows, a slice of each stage's padding, a slice +
+    pad + relayout of the whole plane for the scorer's <= 7 remainder
+    rows), with the temporaries given here."""
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops import fdmt
+    from pulsarutils_tpu.ops.search import boxcar_ladder
+
+    run = fdmt._build_transform(
+        *band, hi, t, fdmt._pick_fdmt_tile(t), True, False, n_lo=lo,
+        with_scores=True, with_plane=False, t_orig=t, with_cert=True,
+        windows=boxcar_ladder(ladder) if ladder else None)
+    compiled = run.lower(_sds((band[0], t), jnp.float32, one_chip)).compile()
+    text = compiled.as_text()
+    # the names every roofline and *_device_ms_* metric matches
+    assert text.startswith("HloModule jit_fn")
+    assert set(re.findall(r"%(\w+?)\.\d+ = \S+ custom-call\(", text)) == {
+        "fdmt_head", "fdmt_merge", "fdmt_deep_pair", "score_rows"}
+    passes = _plane_sized_passes(text, (hi - lo + 1) * t * 4)
+    assert len(passes) <= 3, passes
+    assert {op for _, op, _ in passes} <= {"copy", "reshape"}, passes
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes <= parent_temp_gib * 2**30, m
 
 
 @pytest.mark.parametrize("with_cert", [False, True])

@@ -139,6 +139,98 @@ class TestTransform:
         want = set() if scored is None else merges | scored
         assert _kernels(run, (nchan, t)) == want
 
+    #: plans that meet the edges of the chained sweep (ISSUE 37): the
+    #: state goes from kernel to kernel in the merges' own tiles, the
+    #: rows each kernel pads to left in place.  (nchan, hi, lo, forced
+    #: variant, rows of every level)
+    CHAINS = {
+        # four merges, each padded to its 32-row block (48 -> 64, 44 ->
+        # 64, ...): every stage reads a state of more rows than are real
+        "padded_rows_carried": (16, 40, 0, {"deep_pair": False},
+                                [48, 44, 42, 41]),
+        # the pair pads 41 rows to its 16-row block; 41 = 5 x 8 + 1
+        "pair_pads_its_rows": (16, 40, 0, {}, [48, 44, 42, 41]),
+        # MeerTRAP tier 0's shape in small: three merges, then the pair
+        "three_merges_then_pair": (32, 45, 0, {}, [61, 53, 49, 47, 46]),
+        # a pruned plan, fewer rows than a block at every level
+        "pruned": (16, 40, 17, {}, [31, 27, 25, 24]),
+    }
+
+    @staticmethod
+    def _chain(name, t=2048, builder="_build_transform", **kw):
+        from pulsarutils_tpu.ops import fdmt
+
+        nchan, hi, lo, variant, rows = TestTransform.CHAINS[name]
+        plan = fdmt_plan(nchan, GEOM[0], GEOM[1], hi, lo)
+        assert [len(it["idx_low"]) for it in plan.iterations] == rows
+
+        def build(use_pallas, **more):
+            return getattr(fdmt, builder)(
+                nchan, GEOM[0], GEOM[1], hi, t, fdmt._pick_fdmt_tile(t),
+                use_pallas, True, n_lo=lo, **dict(kw, **more))
+
+        data = np.random.default_rng(37).normal(
+            0, 1, (nchan, t)).astype(np.float32)
+        return build(True, **variant), build(False), data, rows
+
+    @pytest.mark.parametrize("name", sorted(CHAINS))
+    def test_chained_sweep_bit_identical_to_flat_path(self, name):
+        chained, flat, data, rows = self._chain(name, t_orig=2048)
+        got, want = np.asarray(chained(data)), np.asarray(flat(data))
+        assert got.shape == want.shape == (rows[-1], 2048)
+        np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("name", sorted(CHAINS))
+    def test_nothing_between_two_kernels(self, name):
+        """From the first kernel to the last the traced program holds
+        kernels alone: no reshape, slice or gather of the state."""
+        import jax
+
+        chained, _, data, rows = self._chain(name, builder="_transform_fn",
+                                             t_orig=2048)
+        prims = [e.primitive.name for e in
+                 jax.make_jaxpr(chained)(data).jaxpr.eqns]
+        calls = [i for i, p in enumerate(prims) if p == "pallas_call"]
+        paired = self.CHAINS[name][3].get("deep_pair", True)
+        assert len(calls) == len(rows) - paired  # the pair is two levels
+        assert prims[calls[0]:calls[-1] + 1] == ["pallas_call"] * len(calls)
+        # one relayout in, one out (and the cut to the true rows)
+        assert prims[:calls[0]].count("reshape") == 1
+        assert prims[calls[-1] + 1:].count("reshape") == 1
+
+    def test_chained_sweep_cuts_plane_to_true_rows_and_samples(self):
+        """``with_plane`` and ``t_orig < t``: the scorer's plane and the
+        captured one are the true rows and samples, whatever the last
+        kernel padded."""
+        chained, flat, data, rows = self._chain(
+            "pair_pads_its_rows", t_orig=1900, with_scores=True,
+            with_plane=True, with_cert=True)
+        (got_s, got_p), (want_s, want_p) = chained(data), flat(data)
+        assert got_p.shape == (rows[-1], 1900)
+        np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
+        np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+
+    def test_merge_rows_traced_keeps_its_flat_contract(self):
+        """``parallel/sharded_fdmt.py`` hands flat states and traced
+        tables: flat in, flat out, equal to the XLA merge."""
+        from pulsarutils_tpu.ops.fdmt import (MERGE_ROW_BLOCK, _merge_xla,
+                                              merge_rows_traced)
+
+        t, t_tile = 2048, 1024
+        it = fdmt_plan(32, GEOM[0], GEOM[1], 45, 0).iterations[1]
+        pad = (-len(it["idx_low"])) % MERGE_ROW_BLOCK
+        il, ih, sh = (jnp.asarray(np.concatenate([it[k], it[k][-1:].repeat(
+            pad)])) for k in ("idx_low", "idx_high", "shift"))
+        state = jnp.asarray(np.random.default_rng(2).normal(
+            0, 1, (61, t)).astype(np.float32))
+        out = merge_rows_traced(
+            state, il, ih, sh, jnp.zeros_like(sh),
+            k_tiles=(int(it["shift"].max()) // (t_tile // 8) + 23) // 8,
+            k_tiles_h=0, t_tile=t_tile, interpret=True)
+        assert out.shape == (53 + pad, t)
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(_merge_xla(state, il, ih, sh)))
+
     def test_row_zero_is_plain_channel_sum(self):
         rng = np.random.default_rng(2)
         data = rng.normal(0, 1, (8, 256)).astype(np.float32)

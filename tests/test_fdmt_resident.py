@@ -306,6 +306,55 @@ class TestTablesAndPlane:
         assert out.shape == ref.shape == (hp.rows_total, t)
         assert np.array_equal(out, ref), float(np.abs(out - ref).max())
 
+    #: the head's plane handed to the next kernel as the head leaves it
+    #: (ISSUE 37): (nchan, max_delay, the kernels after the head)
+    HANDED_ON = {
+        # 8 levels: the head, then ONE merge reading the padded plane
+        "head_then_merge": (256, 267, {"fdmt_merge"}),
+        # 9 levels: no single merge, the paired pass reads the plane
+        "head_then_pair": (512, 300, {"fdmt_deep_pair"}),
+    }
+
+    @pytest.mark.parametrize("name", sorted(HANDED_ON))
+    def test_next_kernel_reads_the_plane_through_rebased_tables(self, name):
+        """``rows_plane != rows_total``: the stage after the head indexes
+        plane rows (tables rebased on the host), no gather or slice of
+        the state in the program, and the sweep is the flat per-level
+        path's bit for bit."""
+        import jax
+
+        from pulsarutils_tpu.ops import fdmt
+        from pulsarutils_tpu.ops.fdmt_resident import head_plane_rows
+
+        nchan, hi, after = self.HANDED_ON[name]
+        t = 4096
+        hp = HeadPlan(fdmt_plan(nchan, *HTRU, hi, 0))
+        rows = head_plane_rows(hp)
+        assert hp.rows_plane > hp.rows_total == len(rows)
+        assert (np.diff(rows) >= 1).all() and rows[-1] < hp.rows_plane
+        assert (rows[hp.row_starts] == hp.plane_starts).all()
+
+        def build(builder, use_pallas):
+            return getattr(fdmt, builder)(
+                nchan, *HTRU, hi, t, fdmt._pick_fdmt_tile(t), use_pallas,
+                True, n_lo=0, t_orig=t)
+
+        data = np.random.default_rng(37).standard_normal(
+            (nchan, t)).astype(np.float32)
+        jaxpr = jax.make_jaxpr(build("_transform_fn", True))(data)
+        prims = [e.primitive.name for e in jaxpr.jaxpr.eqns]
+        calls = [i for i, p in enumerate(prims) if p == "pallas_call"]
+        assert len(calls) == 2 and "gather" not in prims
+        # the head's lines to the merges' tiles: one reshape, no slice
+        assert prims[calls[0] + 1:calls[1]] == ["reshape"]
+        text = str(jaxpr)
+        assert {k for k in ("fdmt_merge", "fdmt_deep_pair")
+                if k in text} == after
+        got = np.asarray(build("_build_transform", True)(data))
+        want = np.asarray(build("_build_transform", False)(data))
+        assert got.shape == want.shape == (hi + 1, t)
+        assert np.array_equal(got, want), float(np.abs(got - want).max())
+
     def test_head_declines_where_the_tables_do_not_fit(self, monkeypatch):
         """A plan too wide for the core's SMEM takes the per-level
         merges: `_head_choice` says so, nothing raises."""

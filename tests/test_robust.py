@@ -11,6 +11,7 @@ from pulsarutils_tpu.ops.robust import (
     mad,
     median_filter_1d,
     ref_mad,
+    z_n_and_h,
     z_n_test,
 )
 
@@ -156,3 +157,21 @@ def test_z_n_test_rejects_unresolvable_harmonics():
     prof = np.ones(16)
     with pytest.raises(ValueError, match="harmonics"):
         z_n_test(prof, 10)
+
+
+@pytest.mark.parametrize("nbin", [3, 4, 9, 40, 64, 1000, 1 << 14])
+def test_z_n_and_h_equals_the_separate_tests_bit_for_bit(nbin):
+    """One transform for a candidate's four Z^2_n and its H: the floats
+    of ``z_n_test`` and ``h_test`` exactly, whatever the profile's length
+    leaves of the harmonics (``PulseInfo.compute_stats``'s rule)."""
+    prof = np.random.default_rng(nbin).poisson(40, nbin)
+    nmax = nbin // 2
+    ns = [n for n in (2, 6, 12, 20) if n <= nmax]
+    z, h, m = z_n_and_h(prof, ns, nmax=min(20, max(nmax, 1)))
+    assert list(z) == ns
+    for n in ns:
+        assert z[n] == z_n_test(prof, n)
+    h_ref, m_ref = h_test(prof, nmax=min(20, max(nmax, 1)))
+    assert (h, m) == (h_ref, m_ref)
+    with pytest.raises(ValueError):
+        z_n_and_h(prof, [nmax + 1])

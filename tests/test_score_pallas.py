@@ -130,6 +130,53 @@ def test_injected_pulse_scores_and_peak():
     _check(plane)
 
 
+@pytest.mark.parametrize("with_cert", [False, True])
+@pytest.mark.parametrize("rows,padded", [(41, 48), (24, 32), (5, 16),
+                                         (46, 46)])
+def test_rows_scores_a_prefix_of_a_padded_plane(rows, padded, with_cert):
+    """The FDMT sweep hands its last kernel's output with the padded rows
+    in place (ISSUE 37): the first ``rows`` rows score exactly as the
+    plane cut to them does, 8-aligned prefix through the kernel, the
+    remainder through the XLA scorer; the rows after them are not read
+    (NaN there changes nothing)."""
+    rng = np.random.default_rng(rows)
+    plane = rng.standard_normal((padded, 2048)).astype(np.float32)
+    plane[rows:] = np.nan
+    want = _pallas(plane[:rows], with_cert)
+    got = np.asarray(score_plane_pallas(jnp.asarray(plane),
+                                        with_cert=with_cert,
+                                        interpret=True, rows=rows))
+    assert got.shape == (6 if with_cert else 5, rows)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_chained_sweep_scores_remainder_rows_as_the_flat_path():
+    """A sweep whose final row count is 5 x 8 + 1, its last kernel padded
+    to 48 rows: the coarse scores, remainder row included, and the
+    captured plane are those of the same scorer behind the flat per-level
+    merges, bit for bit (whole programs both: two programs compiled apart
+    may contract a multiply-add differently on the CPU)."""
+    from pulsarutils_tpu.ops import fdmt
+
+    nchan, t, hi = 16, 2048, 40
+    data = np.random.default_rng(41).standard_normal(
+        (nchan, t)).astype(np.float32)
+
+    def build(use_pallas, **kw):
+        return fdmt._build_transform(
+            nchan, 1200.0, 200.0, hi, t, fdmt._pick_fdmt_tile(t),
+            use_pallas, True, n_lo=0, t_orig=t, **kw)
+
+    kw = dict(with_scores=True, with_plane=True, with_cert=True,
+              use_score=True)
+    (got, got_plane), (want, want_plane) = build(True, **kw)(data), build(
+        False, **kw)(data)
+    assert got_plane.shape == (41, t) and got.shape == (6, 41)
+    np.testing.assert_array_equal(np.asarray(got_plane),
+                                  np.asarray(want_plane))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_unsupported_tile_raises():
     plane = np.zeros((8, 1000), np.float32)
     with pytest.raises(ValueError):
