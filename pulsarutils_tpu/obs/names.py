@@ -204,6 +204,10 @@ METRIC_NAMES = {
         "failure (flaky connect, reset socket)",
     "putpu_fleet_workers":
         "workers currently registered and alive",
+    "putpu_gc_pause_seconds_total":
+        "seconds the garbage collector paused the interpreter while the "
+        "process-wide span tracer was active (obs/trace.py; added when "
+        "tracing stops; untouched with no tracer)",
     "putpu_health_incidents_total":
         "health conditions raised (labelled by kind)",
     "putpu_health_status":

@@ -42,7 +42,16 @@ program's spans in the profiler's own host plane, on the device trace's
 clock.  With no tracer active none of this runs: no id is allocated and
 no annotation is constructed.
 
-The module is stdlib-only and never imports jax (the annotation class
+A cold start (ISSUE 38): where a program is built, the ``build/*``
+spans hang under whatever span was open (:func:`build_span_name`; the
+``jax.monitoring`` listener of ``utils/logging_utils.py`` opens one per
+build phase, the kernels' call sites one per Pallas kernel body), and
+while the process-wide tracer is on every pause of the garbage
+collector is counted, the long ones recorded as ``gc`` spans
+(:class:`_GcWatch`).
+
+The module imports nothing outside the standard library and this
+package, and never imports jax (the annotation class
 is looked up through ``sys.modules``: a process that never imported jax
 has no profiler to annotate); :func:`trace_session` drives
 ``jax.profiler`` lazily so one flag can emit both the span JSON and the
@@ -53,6 +62,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import itertools
 import json
 import logging
@@ -60,6 +70,8 @@ import sys
 import threading
 import time
 import uuid
+
+from . import metrics as _metrics
 
 logger = logging.getLogger("pulsarutils_tpu")
 
@@ -339,15 +351,20 @@ class Tracer:
         return ev
 
     def complete(self, s, track=None):
-        ev = {"name": s.name, "ph": "X", "pid": 1,
-              "tid": self._tid(track if track is not None
-                               else _TRACK.get()),
-              "ts": self._ts(s.t0), "dur": round(s.dur * 1e6, 3)}
-        if s.attrs:
-            ev["args"] = {k: _jsonable(v) for k, v in s.attrs.items()}
-        # the context bound on the recording thread, read at record time
-        self._append(self._stamp(ev, s.span_id, s.parent_id,
-                                 _TRACE_CTX.get()))
+        # track and context bound on the recording thread, read at
+        # record time
+        self.record(s.name, s.t0, s.t1, s.attrs, s.span_id, s.parent_id,
+                    track if track is not None else _TRACK.get(),
+                    _TRACE_CTX.get())
+
+    def record(self, name, t0, t1, attrs, span_id, parent_id, track, ctx):
+        """One ``X`` event from an interval measured elsewhere
+        (``perf_counter`` seconds), its identity and context given."""
+        ev = {"name": name, "ph": "X", "pid": 1, "tid": self._tid(track),
+              "ts": self._ts(t0), "dur": round((t1 - t0) * 1e6, 3)}
+        if attrs:
+            ev["args"] = {k: _jsonable(v) for k, v in attrs.items()}
+        self._append(self._stamp(ev, span_id, parent_id, ctx))
 
     def async_begin(self, a):
         ev = {"name": a.name, "ph": "b", "cat": "async", "id": a._id,
@@ -420,11 +437,80 @@ class Tracer:
         return n
 
 
+def build_span_name(kind, what):
+    """``build/<kind>:<what>``: the name of a span of a cold start.
+    ``kind`` is a build phase (``trace``, ``lower``, ``compile``; ``what``
+    the program as the device trace names it, ``jit_fn``), ``kernel``
+    (the Python that binds one Pallas kernel and traces its body, inside
+    its program's tracing) or ``plan`` (host work before ``jax.jit``)."""
+    return f"build/{kind}:{what}"
+
+
+#: a pause at least this long is recorded as a ``gc`` span
+GC_SPAN_MIN_S = 1e-3
+
+
+class _GcWatch:
+    """The ``gc.callbacks`` entry of the process-wide tracer.
+
+    A collection holds the interpreter lock, so its pause stalls the
+    main thread whichever thread set it off.  The callback runs inside
+    the collector, under whatever lock the interrupted code holds, so
+    it takes none: pauses are kept here and handed to the tracer and
+    the counter by :meth:`flush`, when tracing stops.
+    """
+
+    def __init__(self, tracer):
+        self.tracer = tracer
+        self.total_s = 0.0
+        self._t0 = None
+        self._long = []
+
+    def __call__(self, phase, info):
+        now = time.perf_counter()
+        if phase == "start":
+            self._t0 = now
+        elif self._t0 is not None:  # None: installed inside a collection
+            self.total_s += now - self._t0
+            if now - self._t0 >= GC_SPAN_MIN_S:
+                self._long.append(
+                    (self._t0, now, {"generation": info["generation"],
+                                     "collected": info["collected"]},
+                     self.tracer.next_id(), _OPEN_SPAN.get(), _TRACK.get(),
+                     _TRACE_CTX.get()))
+            self._t0 = None
+
+    def flush(self):
+        for pause in self._long:
+            self.tracer.record("gc", *pause)
+        _metrics.counter("putpu_gc_pause_seconds_total").inc(self.total_s)
+
+
+_GC_WATCH = None
+
+
+def _swap_gc_watch(tracer):
+    """Remove the active tracer's collector callback, its pauses flushed,
+    and install one for ``tracer`` (``None``: none)."""
+    global _GC_WATCH
+    watch, _GC_WATCH = _GC_WATCH, None
+    if watch is not None:
+        gc.callbacks.remove(watch)
+        watch.flush()
+    if tracer is not None:
+        _GC_WATCH = _GcWatch(tracer)
+        gc.callbacks.append(_GC_WATCH)
+
+
 def start_tracing():
     """Install a fresh process-wide tracer and return it (replaces any
-    active one — the replaced tracer keeps its recorded events)."""
+    active one — the replaced tracer keeps its recorded events).  While
+    it is active the collector's pauses are counted
+    (``putpu_gc_pause_seconds_total``) and the long ones recorded as
+    ``gc`` spans."""
     global _TRACER
     tracer = Tracer()
+    _swap_gc_watch(tracer)
     _TRACER = tracer
     return tracer
 
@@ -435,6 +521,7 @@ def stop_tracing():
     global _TRACER
     tracer = _TRACER
     _TRACER = None
+    _swap_gc_watch(None)
     if tracer is not None:
         tracer.close()
     return tracer

@@ -119,6 +119,9 @@ import functools
 
 import numpy as np
 
+from ..obs.trace import build_span_name, span
+
+
 def _windows(windows=None):
     """The detection scorer's boxcar widths — imported lazily from the
     single source of truth so the bounds can never silently diverge
@@ -445,25 +448,30 @@ def _track_deviations(nchan, trial_dms, start_freq, bandwidth, sample_time,
 def _retention_cached(nchan, dms_key, start_freq, bandwidth, sample_time,
                       nsamples, min_width, cert, windows=None):
     trial_dms = np.frombuffer(dms_key, dtype=np.float64)
-    dev = _track_deviations(nchan, trial_dms, start_freq, bandwidth,
-                            sample_time, nsamples)
-    if cert and windows is not None:
-        from ..utils.logging_utils import budget_bucket
-        from .search import cert_wide_windows
+    # host work of a process's first call with this geometry (a Python
+    # loop over the trials: 57 s of MeerTRAP's cold pass, PERF.md PR 38)
+    with span(build_span_name("plan", "cert_retention"), nchan=nchan,
+              trials=len(trial_dms), t=nsamples):
+        dev = _track_deviations(nchan, trial_dms, start_freq, bandwidth,
+                                sample_time, nsamples)
+        if cert and windows is not None:
+            from ..utils.logging_utils import budget_bucket
+            from .search import cert_wide_windows
 
-        wide = cert_wide_windows(windows, nsamples)
-        # the host's share of a longer ladder: its captures at the worst
-        # phase and the closed form beyond max_width
-        with budget_bucket("search/cert_wide"):
-            return np.asarray([_cert_retention_from_offsets(
-                d, windows=windows, wide=wide) for d in dev])
-    rho = np.empty(len(trial_dms))
-    for j in range(len(trial_dms)):
-        if cert:
-            rho[j] = _cert_retention_from_offsets(dev[j])
-        else:
-            rho[j] = _retention_from_offsets(dev[j], min_width=min_width)
-    return rho
+            wide = cert_wide_windows(windows, nsamples)
+            # the host's share of a longer ladder: its captures at the
+            # worst phase and the closed form beyond max_width
+            with budget_bucket("search/cert_wide"):
+                return np.asarray([_cert_retention_from_offsets(
+                    d, windows=windows, wide=wide) for d in dev])
+        rho = np.empty(len(trial_dms))
+        for j in range(len(trial_dms)):
+            if cert:
+                rho[j] = _cert_retention_from_offsets(dev[j])
+            else:
+                rho[j] = _retention_from_offsets(dev[j],
+                                                 min_width=min_width)
+        return rho
 
 
 def coarse_retention(nchan, trial_dms, start_freq, bandwidth, sample_time,

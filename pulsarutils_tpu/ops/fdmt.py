@@ -51,6 +51,8 @@ import functools
 
 import numpy as np
 
+from ..obs.trace import build_span_name, span
+from ..utils.logging_utils import kernel_build_span
 from .plan import DM_DELAY_CONST, delta_delay
 
 
@@ -564,22 +566,24 @@ def _merge4_pallas(s4, idx, shift, t_tile, interpret):
 
     t = s4.shape[1] * t_tile
     rows_out = len(idx[0])
-    L = t_tile // 8
-    max_shift = max(int(s.max(initial=0))  # putpu-lint: disable=device-trip — host plan tables
-                    for s in shift)
-    k_tiles = (max_shift // L + 23) // 8
-
     # the 4-parent kernel carries 4x the BlockSpec operands per row, so
     # its row block is kept smaller than MERGE_ROW_BLOCK to bound both
     # operand count and per-step VMEM
     row_block = min(max(1, MERGE_ROW_BLOCK // 2), rows_out)
     pad = (-rows_out) % row_block
-    idx_p = [np.concatenate([i, i[-1:].repeat(pad)]) for i in idx]
-    shift_p = [np.concatenate([s, s[-1:].repeat(pad)]) for s in shift]
-    run = _build_merge4_kernel(rows_out + pad, t, t_tile, k_tiles,
-                               row_block, interpret)
-    return run(s4, tuple(jnp.asarray(i) for i in idx_p),
-               tuple(jnp.asarray(s) for s in shift_p))
+    with kernel_build_span("fdmt_deep_pair", rows=rows_out + pad, t=t,
+                           t_tile=t_tile):
+        L = t_tile // 8
+        max_shift = max(
+            int(s.max(initial=0))  # putpu-lint: disable=device-trip — host
+            for s in shift)
+        k_tiles = (max_shift // L + 23) // 8
+        idx_p = [np.concatenate([i, i[-1:].repeat(pad)]) for i in idx]
+        shift_p = [np.concatenate([s, s[-1:].repeat(pad)]) for s in shift]
+        run = _build_merge4_kernel(rows_out + pad, t, t_tile, k_tiles,
+                                   row_block, interpret)
+        return run(s4, tuple(jnp.asarray(i) for i in idx_p),
+                   tuple(jnp.asarray(s) for s in shift_p))
 
 
 def _merge_tiles(s4, idx_low, idx_high, shift, shift_high, k_tiles,
@@ -621,31 +625,33 @@ def _merge_pallas(s4, it, t_tile, interpret):
     import jax.numpy as jnp
 
     rows_out = len(it["idx_low"])
-    L = t_tile // 8
-    max_shift = int(  # putpu-lint: disable=device-trip — host plan tables
-        it["shift"].max(initial=0))
-    k_tiles = (max_shift // L + 23) // 8
-
     row_block = min(MERGE_ROW_BLOCK, rows_out)
     pad = (-rows_out) % row_block
-    idx_low = np.concatenate([it["idx_low"],
-                              it["idx_low"][-1:].repeat(pad)])
-    idx_high = np.concatenate([it["idx_high"],
-                               it["idx_high"][-1:].repeat(pad)])
-    shift = np.concatenate([it["shift"], it["shift"][-1:].repeat(pad)])
+    with kernel_build_span("fdmt_merge", rows=rows_out + pad,
+                           t=s4.shape[1] * t_tile, t_tile=t_tile):
+        L = t_tile // 8
+        max_shift = int(  # putpu-lint: disable=device-trip — host tables
+            it["shift"].max(initial=0))
+        k_tiles = (max_shift // L + 23) // 8
 
-    if it["shift_high"] is not None:
-        max_sh = int(  # putpu-lint: disable=device-trip — host plan tables
-            it["shift_high"].max(initial=0))
-        k_tiles_h = (max_sh // L + 23) // 8
-        shift_high = np.concatenate([it["shift_high"],
-                                     it["shift_high"][-1:].repeat(pad)])
-    else:
-        k_tiles_h = 0
-        shift_high = np.zeros(rows_out + pad, np.int32)
-    return _merge_tiles(s4, jnp.asarray(idx_low), jnp.asarray(idx_high),
-                        jnp.asarray(shift), jnp.asarray(shift_high),
-                        k_tiles, k_tiles_h, t_tile, interpret)
+        idx_low = np.concatenate([it["idx_low"],
+                                  it["idx_low"][-1:].repeat(pad)])
+        idx_high = np.concatenate([it["idx_high"],
+                                   it["idx_high"][-1:].repeat(pad)])
+        shift = np.concatenate([it["shift"], it["shift"][-1:].repeat(pad)])
+
+        if it["shift_high"] is not None:
+            max_sh = int(  # putpu-lint: disable=device-trip — host tables
+                it["shift_high"].max(initial=0))
+            k_tiles_h = (max_sh // L + 23) // 8
+            shift_high = np.concatenate([it["shift_high"],
+                                         it["shift_high"][-1:].repeat(pad)])
+        else:
+            k_tiles_h = 0
+            shift_high = np.zeros(rows_out + pad, np.int32)
+        return _merge_tiles(s4, jnp.asarray(idx_low), jnp.asarray(idx_high),
+                            jnp.asarray(shift), jnp.asarray(shift_high),
+                            k_tiles, k_tiles_h, t_tile, interpret)
 
 
 def head_active(nchan, start_freq, bandwidth, max_delay, n_lo, t):
@@ -756,57 +762,61 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
     """
     import jax.numpy as jnp
 
-    plan = fdmt_plan(nchan, start_freq, bandwidth, max_delay, n_lo)
-    if use_head is None:
-        use_head = use_pallas
-    if deep_pair is None:
-        deep_pair = use_pallas
-    if use_score is None:
-        use_score = use_pallas and not interpret
+    # host work that no build phase of JAX's holds: the merge tables, the
+    # head's choice, plan and kernel, the deep pair's composed tables
+    with span(build_span_name("plan", "fdmt"), nchan=nchan, t=t,
+              rows=max_delay - n_lo + 1):
+        plan = fdmt_plan(nchan, start_freq, bandwidth, max_delay, n_lo)
+        if use_head is None:
+            use_head = use_pallas
+        if deep_pair is None:
+            deep_pair = use_pallas
+        if use_score is None:
+            use_score = use_pallas and not interpret
 
-    # VMEM-resident fused head (ops/fdmt_resident.py): the first
-    # HEAD_LEVELS merges — ~75% of the per-level HBM traffic — run in
-    # one Pallas program whose intermediate states never leave VMEM,
-    # bit-identical to the per-level path (v5e, 1024 x 1M: 0.323 s vs
-    # 0.365 s per-level, transform+score).
-    head_run = head = None
-    n_head = 0
-    head_choice = use_head and _head_choice(nchan, start_freq, bandwidth,
-                                            max_delay, n_lo, t)
-    if head_choice:
-        from .fdmt_resident import (HEAD_LEVELS, _build_head_kernel,
-                                    head_flat_rows, head_plane_rows)
+        # VMEM-resident fused head (ops/fdmt_resident.py): the first
+        # HEAD_LEVELS merges — ~75% of the per-level HBM traffic — run in
+        # one Pallas program whose intermediate states never leave VMEM,
+        # bit-identical to the per-level path (v5e, 1024 x 1M: 0.323 s vs
+        # 0.365 s per-level, transform+score).
+        head_run = head = None
+        n_head = 0
+        head_choice = use_head and _head_choice(nchan, start_freq, bandwidth,
+                                                max_delay, n_lo, t)
+        if head_choice:
+            from .fdmt_resident import (HEAD_LEVELS, _build_head_kernel,
+                                        head_flat_rows, head_plane_rows)
 
-        head_run, head = _build_head_kernel(
-            nchan, start_freq, bandwidth, max_delay, n_lo,
-            HEAD_LEVELS, t, head_choice[1], interpret)
-        n_head = HEAD_LEVELS
+            head_run, head = _build_head_kernel(
+                nchan, start_freq, bandwidth, max_delay, n_lo,
+                HEAD_LEVELS, t, head_choice[1], interpret)
+            n_head = HEAD_LEVELS
 
-    # deep-level pairing: fuse the LAST TWO per-level merges into one
-    # 4-parent pass — the intermediate state (the largest deep state)
-    # is never written or re-read (v5e, 1024 x 1M: 0.241 s -> 0.229 s).
-    # Pallas path only; leaf merges (shift_high) cannot compose.
-    iters = plan.iterations[n_head:]
-    paired = None
-    if (deep_pair and use_pallas and len(iters) >= 2
-            and iters[-1]["shift_high"] is None
-            and iters[-2]["shift_high"] is None):
-        paired = compose_iterations(iters[-2], iters[-1])
-        iters = iters[:-2]
+        # deep-level pairing: fuse the LAST TWO per-level merges into one
+        # 4-parent pass — the intermediate state (the largest deep state)
+        # is never written or re-read (v5e, 1024 x 1M: 0.241 s -> 0.229 s).
+        # Pallas path only; leaf merges (shift_high) cannot compose.
+        iters = plan.iterations[n_head:]
+        paired = None
+        if (deep_pair and use_pallas and len(iters) >= 2
+                and iters[-1]["shift_high"] is None
+                and iters[-2]["shift_high"] is None):
+            paired = compose_iterations(iters[-2], iters[-1])
+            iters = iters[:-2]
 
-    # the Pallas stage after the head reads the head's plane as the head
-    # leaves it, each group's rows padded to the row loop's block: its
-    # parent tables are rebased onto plane rows here, on the host, so no
-    # gather and no row slice of the state runs on the device
-    if head and use_pallas:
-        plane_rows = head_plane_rows(head)
-        if iters:
-            iters = [dict(iters[0],
-                          idx_low=plane_rows[iters[0]["idx_low"]],
-                          idx_high=plane_rows[iters[0]["idx_high"]])
-                     ] + iters[1:]
-        else:
-            paired = ([plane_rows[i] for i in paired[0]], paired[1])
+        # the Pallas stage after the head reads the head's plane as the head
+        # leaves it, each group's rows padded to the row loop's block: its
+        # parent tables are rebased onto plane rows here, on the host, so no
+        # gather and no row slice of the state runs on the device
+        if head and use_pallas:
+            plane_rows = head_plane_rows(head)
+            if iters:
+                iters = [dict(iters[0],
+                              idx_low=plane_rows[iters[0]["idx_low"]],
+                              idx_high=plane_rows[iters[0]["idx_high"]])
+                         ] + iters[1:]
+            else:
+                paired = ([plane_rows[i] for i in paired[0]], paired[1])
     rows = max_delay - n_lo + 1
 
     def fn(data):

@@ -52,6 +52,8 @@ import functools
 
 import numpy as np
 
+from ..utils.logging_utils import kernel_build_span
+
 #: tree levels fused into the VMEM-resident head; 2^HEAD_LEVELS channels
 #: per independent group (128 in -> up to 256 live rows per group from
 #: DM 0, 128 when the plan is pruned; 6 to 20 MiB a buffer at the slice
@@ -548,7 +550,9 @@ def _build_head_kernel(nchan, start_freq, bandwidth, max_delay, min_delay,
         # it; returns the plane as the kernel writes it, (rows_plane,
         # t / L, L): the sweep's next kernel reads it through
         # :func:`head_plane_rows`, :func:`head_flat_rows` relays it flat
-        return call(tables, data.reshape(data.shape[0], c8, _L))
+        with kernel_build_span("fdmt_head", rows=head.rows_plane, t=t,
+                               t_tile=t_slice):
+            return call(tables, data.reshape(data.shape[0], c8, _L))
 
     return run, head
 

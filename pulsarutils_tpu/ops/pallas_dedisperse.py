@@ -51,6 +51,8 @@ import functools
 
 import numpy as np
 
+from ..utils.logging_utils import kernel_build_span
+
 
 def _pallas_modules():
     import jax
@@ -455,9 +457,11 @@ def dedisperse_plane_pallas_traced(data, offsets, max_off, dm_block=None,
             data_ext = jnp.concatenate([data] * reps, axis=1)[:, :text]
 
     build = _build_kernel_rows if layout == "rows" else _build_kernel
-    run = build(ndm_p, nchan_p, text, t_out, dm_block, chan_block,
-                t_tile, k_tiles, interpret)
-    plane = run(offsets, data_ext)[:ndm, :t]
+    with kernel_build_span(f"dedisperse_{layout}", rows=ndm_p, t=t_out,
+                           t_tile=t_tile):
+        run = build(ndm_p, nchan_p, text, t_out, dm_block, chan_block,
+                    t_tile, k_tiles, interpret)
+        plane = run(offsets, data_ext)[:ndm, :t]
     if roll_k:
         plane = jnp.roll(plane, -roll_k, axis=1)
     return plane

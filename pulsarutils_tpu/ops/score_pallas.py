@@ -46,6 +46,8 @@ import functools
 
 import numpy as np
 
+from ..utils.logging_utils import kernel_build_span
+
 #: fixed scratch slots (each slot is one (8, 128) f32 tile per row block);
 #: the per-level and per-capture slots follow, see :func:`_slots`
 _C, _SUM, _SSQ = 0, 1, 2
@@ -409,10 +411,11 @@ def score_plane_pallas(plane, with_cert=False, interpret=False,
         warn_peak_exactness(t)
     parts = []
     if rows8:
-        out = _kernel_scores(
-            rows8, t, t_blk, bool(with_cert), bool(interpret),
-            plane, n_levels=len(scored),
-            wide_from=scored.index(wide[0]) if wide else None)
+        with kernel_build_span("score_rows", rows=rows8, t=t, t_tile=t_blk):
+            out = _kernel_scores(
+                rows8, t, t_blk, bool(with_cert), bool(interpret),
+                plane, n_levels=len(scored),
+                wide_from=scored.index(wide[0]) if wide else None)
         parts.append(out[:, :6 if with_cert else 5].T)
     if rows8 != rows:
         from .search import score_profiles_chunked

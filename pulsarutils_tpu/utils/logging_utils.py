@@ -19,11 +19,11 @@ trace timeline; counters are mirrored into the process metrics registry
 
 from __future__ import annotations
 
-import collections
 import contextlib
 import contextvars
 import json
 import logging
+import re
 import threading
 import time
 
@@ -109,29 +109,76 @@ _COMPILE = {"count": 0, "secs": 0.0, "trace_s": 0.0, "lower_s": 0.0,
             "cache_load_s": 0.0, "installed": False}
 _COMPILE_LOCK = threading.Lock()
 
-#: jax.monitoring duration event (by its last path component) -> the
-#: cumulative ``_COMPILE`` key it adds to
-_COMPILE_PHASES = {"jaxpr_to_mlir_module_duration": "lower_s",
-                   "cache_retrieval_time_sec": "cache_load_s"}
+#: jax.monitoring build-phase event (by its last path component) -> the
+#: phase's name in a ``build/<phase>:<program>`` span.  JAX 0.9 fires the
+#: event twice: as a scalar when the phase begins, as a duration from the
+#: phase's ``__exit__`` (also when it raises), both with ``fun_name=``.
+_BUILD_PHASES = {"jaxpr_trace_duration": "trace",
+                 "jaxpr_to_mlir_module_duration": "lower",
+                 "backend_compile_duration": "compile"}
 
-#: ``[start, end)`` of the outermost tracing intervals seen lately, on
-#: the perf_counter clock.  JAX fires ``jaxpr_trace_duration`` for every
-#: nested ``jit`` inside the interval of the one that encloses it (each
-#: jitted ``jnp`` helper the clean program calls is one), so the
-#: durations cannot be summed: an event that arrives later and covers
-#: earlier ones replaces them.
-_TRACE_INTERVALS = collections.deque(maxlen=1024)
+class _BuildPhases(threading.local):
+    """``.open``: this thread's build phases that have begun and not
+    ended, innermost last, as ``(phase, span or None)``.  JAX fires
+    ``jaxpr_trace_duration`` for every nested ``jit`` inside the phase of
+    the one that encloses it (each jitted ``jnp`` helper the clean program
+    calls is one), so only a tracing phase that no other encloses adds to
+    ``trace_s``."""
+
+    def __init__(self):
+        self.open = []
 
 
-def _note_trace_interval(secs):
-    """Add one tracing event that ended now (``_COMPILE_LOCK`` held)."""
-    end = time.perf_counter()
-    start = end - secs
-    while _TRACE_INTERVALS and _TRACE_INTERVALS[-1][0] >= start:
-        inner = _TRACE_INTERVALS.pop()
-        _COMPILE["trace_s"] -= inner[1] - inner[0]
-    _TRACE_INTERVALS.append((start, end))
-    _COMPILE["trace_s"] += secs
+_BUILD = _BuildPhases()
+
+
+def _program_name(phase, fun_name):
+    """The program as the device trace names it: tracing is told ``fn``,
+    lowering and compiling ``jit(fn)``; all three become ``jit_fn``."""
+    name = f"jit_{fun_name}" if phase == "trace" else str(fun_name)
+    return re.sub(r"[^\w.-]+", "_", name).strip("_")
+
+
+def _on_build_phase_begin(event, _value, fun_name="", **_kw):
+    phase = _BUILD_PHASES.get(event.rsplit("/", 1)[-1])
+    if phase is None:
+        return
+    span = None
+    if _trace.is_tracing():
+        span = _trace.open_span(
+            _trace.build_span_name(phase, _program_name(phase, fun_name)),
+            {"cache": "miss"} if phase == "compile" else None)
+    _BUILD.open.append((phase, span))
+
+
+def _on_build_event(event, secs, **_kw):
+    name = event.rsplit("/", 1)[-1]
+    secs = float(secs)
+    open_phases = _BUILD.open
+    if name == "cache_retrieval_time_sec":
+        # fires inside the compile phase whose executable it read back
+        if open_phases and open_phases[-1][1] is not None:
+            open_phases[-1][1].attrs.update(cache="hit",
+                                            cache_load_s=round(secs, 6))
+        with _COMPILE_LOCK:
+            _COMPILE["cache_load_s"] += secs
+        return
+    phase = _BUILD_PHASES.get(name)
+    if phase is None:
+        return
+    # a phase that began before the listeners were installed is not here
+    if open_phases and open_phases[-1][0] == phase:
+        span = open_phases.pop()[1]
+        if span is not None:
+            _trace.close_span(span)
+    with _COMPILE_LOCK:
+        if phase == "compile":
+            _COMPILE["count"] += 1
+            _COMPILE["secs"] += secs
+        elif phase == "lower":
+            _COMPILE["lower_s"] += secs
+        elif all(p != "trace" for p, _ in open_phases):
+            _COMPILE["trace_s"] += secs
 
 
 def _install_compile_listener():
@@ -141,18 +188,22 @@ def _install_compile_listener():
         _COMPILE["installed"] = True
         from jax import monitoring
 
-        def _on_event(name, secs, **kw):
-            name = name.rsplit("/", 1)[-1]
-            with _COMPILE_LOCK:
-                if name == "backend_compile_duration":
-                    _COMPILE["count"] += 1
-                    _COMPILE["secs"] += float(secs)
-                elif name == "jaxpr_trace_duration":
-                    _note_trace_interval(float(secs))
-                elif name in _COMPILE_PHASES:
-                    _COMPILE[_COMPILE_PHASES[name]] += float(secs)
+        monitoring.register_scalar_listener(_on_build_phase_begin)
+        monitoring.register_event_duration_secs_listener(_on_build_event)
 
-        monitoring.register_event_duration_secs_listener(_on_event)
+
+@contextlib.contextmanager
+def kernel_build_span(kernel, **attrs):
+    """``build/kernel:<kernel>`` around the Python that binds a Pallas
+    kernel and so traces its body, with the geometry that keys it.
+    Recorded under a tracer, inside a program's tracing phase: a warm
+    call never reaches this Python, and an eager call of the same code
+    (no phase open) is no build."""
+    if not (_trace.is_tracing() and _BUILD.open):
+        yield
+        return
+    with _trace.span(_trace.build_span_name("kernel", kernel), **attrs):
+        yield
 
 
 def compile_snapshot():
