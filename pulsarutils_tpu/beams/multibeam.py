@@ -49,6 +49,7 @@ from ..ops.rebin import quick_resample
 from ..parallel.stream import iter_chunk_starts, plan_chunks
 from ..pipeline.pulse_info import PulseInfo
 from ..pipeline.sift import hit_fields
+from ..utils.frame_reserve import reserve_frames
 from ..utils.logging_utils import BudgetAccountant, logger
 from ..utils.table import ResultTable
 from .batcher import BeamBatcher, BeamGeometryError
@@ -102,6 +103,7 @@ def _clean_block(block, resample):
     return np.asarray(cleaned, dtype=np.float32)
 
 
+@reserve_frames
 def multibeam_search(fnames, dmmin=200, dmmax=800, *, snr_threshold=6.0,
                      output_dir=None, resume=True, max_chunks=None,
                      chunk_length=None, new_sample_time=None,

@@ -43,6 +43,7 @@ from ..io.sigproc import read_header
 from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..obs.health import HealthEngine
+from ..utils.frame_reserve import reserve_frames
 from ..utils.logging_utils import logger
 
 __all__ = ["SurveyService", "JobSpec", "validate_spec", "QUEUED",
@@ -453,6 +454,7 @@ class SurveyService:
                         fname=os.path.basename(job.spec["fname"]))
             return batch
 
+    @reserve_frames
     def _run(self):
         while True:
             self._wake.wait()

@@ -37,6 +37,7 @@ import warnings
 import numpy as np
 
 from ..obs import metrics as _metrics
+from ..utils.frame_reserve import reserve_frames
 
 
 class DispatchTimeoutError(RuntimeError):
@@ -85,6 +86,7 @@ def call_with_deadline(fn, timeout_s=None):
     box = {}
     ctx = contextvars.copy_context()
 
+    @reserve_frames
     def target():
         try:
             box["value"] = ctx.run(fn)

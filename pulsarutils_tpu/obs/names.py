@@ -204,6 +204,11 @@ METRIC_NAMES = {
         "failure (flaky connect, reset socket)",
     "putpu_fleet_workers":
         "workers currently registered and alive",
+    "putpu_frame_reserve_entries_total":
+        "times a thread entered a driver or a dispatch thread target "
+        "through the large-frame trampoline (utils/frame_reserve.py): "
+        "one reserved frame chunk mapped; a nested driver call passes "
+        "straight through and adds nothing",
     "putpu_gc_pause_seconds_total":
         "seconds the garbage collector paused the interpreter while the "
         "process-wide span tracer was active (obs/trace.py; added when "

@@ -30,6 +30,7 @@ import numpy as np
 from ..ops.plan import delta_delay, dm_broadening
 from ..ops.search import dedispersion_search
 from ..tuning.geometry import PLAN_CACHE_SIZE, counted_plan_cache
+from ..utils.frame_reserve import reserve_frames
 from ..utils.logging_utils import budget_bucket
 
 
@@ -127,6 +128,7 @@ def _iter_lookahead(chunks):
     yield pending
 
 
+@reserve_frames
 def stream_search(chunks, dmmin, dmmax, start_freq, bandwidth, sample_time,
                   *, backend="jax", snr_threshold=6.0, trial_dms=None,
                   dm_block=None, chan_block=None, budget=None, mesh=None,

@@ -31,6 +31,7 @@ import numpy as np
 
 from ..faults import inject as fault_inject
 from ..obs import metrics as _metrics
+from ..utils.frame_reserve import reserve_frames
 from ..utils.logging_utils import logger
 from .accel import accel_grid, accel_search, jerk_grid
 from .accumulate import DMTimeAccumulator
@@ -86,6 +87,7 @@ def _canary_is_recovered(cand, freq, freq_tol):
             or harmonic_ratio(freq, cand["freq"]) > 0)
 
 
+@reserve_frames
 def periodicity_search(fname, dmmin=200, dmmax=800, *, accel_max=0.0,
                        n_accel=None, jerk_max=0.0, n_jerk=None,
                        accel_backend="auto",

@@ -55,6 +55,7 @@ from ..parallel.stream import iter_chunk_starts, plan_chunks
 from ..pipeline.pulse_info import PulseInfo
 from ..pipeline.spectral_stats import get_bad_chans
 from ..resilience import ladder as _resilience_ladder
+from ..utils.frame_reserve import reserve_frames
 from ..utils.logging_utils import (BudgetAccountant, logger,
                                    measure_device_rtt)
 from ..utils.table import ResultTable
@@ -610,6 +611,7 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
     }
 
 
+@reserve_frames
 def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                      dmmin=200, dmmax=800, surelybad=(), *, backend="jax",
                      kernel="auto", snr_threshold=6.0, output_dir=None,
