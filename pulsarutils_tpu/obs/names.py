@@ -388,6 +388,16 @@ METRIC_NAMES = {
     "putpu_tier_sweeps_total":
         "tier sweeps of a tiered search (dm_tiers): one per tier per "
         "chunk",
+    "putpu_tile_halo_samples_total":
+        "samples swept a second time as a time tile's halo, in the "
+        "tier's own samples: the price of searching a chunk the device "
+        "cannot hold whole",
+    "putpu_time_tile_samples":
+        "own samples of one time tile of a tier, or the tier's whole "
+        "axis where it is swept whole (labelled by tier)",
+    "putpu_time_tiles_total":
+        "time tiles swept: one per tile of a tier (or flat plan) whose "
+        "axis is searched in more than one",
     "putpu_trace_clock_offset_seconds":
         "worker wall clock offset vs the coordinator, midpoint rule "
         "over the register/lease exchange (labelled by worker)",
@@ -426,6 +436,9 @@ BUDGET_COUNTERS = frozenset({
 #: closure, ``fn`` (``ops/fdmt.py:_transform_fn``): the accepted
 #: ``fdmt_roofline`` metric matches ``^jit_fn/``; not listed here.
 KERNEL_NAMES = {
+    "chunk_stats":
+        "program: a packed chunk's light-curve factor and per-channel "
+        "mean, from its resident bytes (a chunk searched in time tiles)",
     "clean":
         "program: device clean of an uploaded float chunk",
     "dedisperse_flat":
@@ -455,13 +468,25 @@ KERNEL_NAMES = {
         "dispatch",
     "rescore_rows":
         "program: exact rescore of one row bucket (dedisperse + score)",
+    "rescore_tile":
+        "program: exact dedispersion of one row bucket on one time tile "
+        "+ the partial scores of its own samples",
     "score_rows":
         "kernel: one-pass scorer of the coarse plane's rows",
     "tier_downsample":
         "program: a tiered search's downsample chain, each tier's array "
         "the previous one summed in pairs",
+    "tile_band_mean":
+        "program: the band average of a tiled tier's cleaned axis (a "
+        "hit's dispersed profile)",
+    "tile_clean":
+        "program: unpack + clean + downsample of one stretch of a "
+        "resident packed chunk (a time tile, or a deep tier whole)",
     "unpack_clean":
         "program: bit-unpack + clean of an uploaded packed chunk",
+    "wrap_rows":
+        "program: a resident packed chunk with its first frames once more "
+        "at its end, so that a time tile's blocks are contiguous slices",
 }
 
 

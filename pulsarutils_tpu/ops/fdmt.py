@@ -736,7 +736,7 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
                   use_pallas, interpret, n_lo=0, with_scores=False,
                   with_plane=True, t_orig=None, with_cert=False, *,
                   use_head=None, use_score=None, deep_pair=None,
-                  windows=None):
+                  windows=None, partial=None):
     """The traceable (un-jitted) transform body: DM-pruned merges
     [+ scoring].  :func:`_build_transform` wraps it in ``jax.jit``;
     the hybrid search composes it with its fused seed-rescore program
@@ -854,6 +854,9 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
             plane = plane[:, :t_orig]
         if not with_scores:
             return plane[:rows]
+        if partial is not None:
+            return _tile_partials(plane, rows, partial, windows, with_cert,
+                                  use_score, interpret)
         from .score_pallas import pick_score_tile
         from .search import score_profiles_chunked, scored_windows
 
@@ -891,12 +894,35 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
     return fn
 
 
+def _tile_partials(plane, rows, partial, windows, with_cert, use_score,
+                   interpret):
+    """One time tile's partials of the coarse plane's first ``rows`` rows:
+    the one-pass kernel where it is compiled and one of its tiles fits,
+    else the XLA scorer over row chunks (its temporaries are several times
+    its input's size)."""
+    import jax.numpy as jnp
+
+    from .score_pallas import pick_partial_tile, score_partials_pallas
+    from .score_partials import score_partials, tile_ladder
+
+    own, total = partial
+    widest = tile_ladder(windows, total)[0][-1]
+    if use_score and pick_partial_tile(own, plane.shape[1], widest):
+        return score_partials_pallas(plane, own, total, with_cert=with_cert,
+                                     interpret=interpret, windows=windows,
+                                     rows=rows)
+    return jnp.concatenate(
+        [score_partials(plane[lo:min(lo + 128, rows), :own], jnp, windows,
+                        total, with_cert=with_cert)
+         for lo in range(0, rows, 128)], axis=0)
+
+
 @functools.lru_cache(maxsize=16)
 def _build_transform(nchan, start_freq, bandwidth, max_delay, t, t_tile,
                      use_pallas, interpret, n_lo=0, with_scores=False,
                      with_plane=True, t_orig=None, with_cert=False, *,
                      use_head=None, use_score=None, deep_pair=None,
-                     windows=None):
+                     windows=None, partial=None):
     """Jitted wrapper of :func:`_transform_fn` (same signature)."""
     import jax
 
@@ -906,7 +932,8 @@ def _build_transform(nchan, start_freq, bandwidth, max_delay, t, t_tile,
                                  with_plane=with_plane, t_orig=t_orig,
                                  with_cert=with_cert, use_head=use_head,
                                  use_score=use_score,
-                                 deep_pair=deep_pair, windows=windows))
+                                 deep_pair=deep_pair, windows=windows,
+                                 partial=partial))
 
 
 # ---------------------------------------------------------------------------

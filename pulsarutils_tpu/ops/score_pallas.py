@@ -68,7 +68,7 @@ def pick_score_tile(t, widest=8):
     return 0
 
 
-def _slots(n_levels, n_wide):
+def _slots(n_levels, n_wide, partial=False):
     """The scratch layout of a ladder of ``n_levels`` windows with
     ``n_wide`` half-stride certificate captures.
 
@@ -76,7 +76,8 @@ def _slots(n_levels, n_wide):
     ``(sumsq, max, argmax)`` triple of level ``j >= 1`` (width ``2^j``),
     ``cert`` the sliding certificate's ``(cm2, cm3, cm4, first3,
     last3)``, ``wide[i]`` the ``(max, last half block of the previous
-    tile)`` pair of the i-th capture.  For the default ladder the first
+    tile)`` pair of the i-th capture — with ``partial`` a triple, the
+    row's first half block after them.  For the default ladder the first
     19 slots are the ones this kernel has always had.
     """
     base = _ARG1 + 1
@@ -85,18 +86,28 @@ def _slots(n_levels, n_wide):
     base += 3 * (n_levels - 1)
     cert = tuple(range(base, base + 5))
     base += 5
-    wide = [(base + 2 * i, base + 2 * i + 1) for i in range(n_wide)]
-    return level, cert, wide, base + 2 * n_wide
+    per = 3 if partial else 2
+    wide = [tuple(base + per * i + k for k in range(per))
+            for i in range(n_wide)]
+    return level, cert, wide, base + per * n_wide
 
 
 @functools.lru_cache(maxsize=16)
 def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret,
-                        n_levels=4, wide_from=None):
+                        n_levels=4, wide_from=None, partial=False):
     """The one-pass kernel for a ladder of ``n_levels`` doubling windows.
 
     ``wide_from`` (``None``: no such capture) is the level of the first
     half-stride certificate capture; every level from it to the last has
     one (:func:`..ops.search.cert_wide_windows`).
+
+    ``partial``: the row block's last tile emits what the scratch holds,
+    in :func:`..ops.score_partials.partial_layout`'s columns, in place of
+    the finished scores: ``t`` is then one time tile's own samples of a
+    longer row (the array may be longer still: its halo is never read),
+    and :func:`..ops.score_partials.combine_partials` finishes the row
+    over its tiles.  Nothing wraps inside such a call: the windows across
+    the row's end are the combiner's.
     """
     import jax
     import jax.numpy as jnp
@@ -110,7 +121,7 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret,
     wide_levels = (list(range(wide_from, n_levels))
                    if with_cert and wide_from is not None else [])
     level_slots, cert_slots, wide_slots, n_slot = _slots(
-        n_levels, len(wide_levels))
+        n_levels, len(wide_levels), partial)
     _CM2, _CM3, _CM4, _FIRST3, _LAST3 = cert_slots
     assert n_levels >= 4 and t_blk % (1 << (n_levels - 1)) == 0
 
@@ -206,12 +217,19 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret,
                     jnp.where(mask, vals * vals, 0.0), axis=1,
                     keepdims=True)
 
-        def wide_capture(below, sums, w, max_slot, last_slot):
+        def wide_capture(below, sums, w, max_slot, last_slot,
+                         first_slot=None):
             """Windows of width ``w`` at strides of ``w / 2``: the ones
             inside this tile from ``sums`` (every lane's width-``w``
             sum), the one across the tile's start from the previous
             tile's last half block and this tile's first."""
             half = w // 2
+            if first_slot is not None:
+                @pl.when(i_t == 0)
+                def _first_half():
+                    st_ref[first_slot] = jnp.broadcast_to(below[:, 0:1],
+                                                          (8, 128))
+
             inside = (lane % half == 0) & (lane <= t_blk - w)
             run = jnp.maximum(
                 st_ref[max_slot][:, 0:1],
@@ -274,7 +292,44 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret,
             st_ref[_LAST3] = lroll(x, t_blk - 3)[:, :128]
 
         # ---- finish the row block ------------------------------------
-        @pl.when(i_t == n_t - 1)
+        if partial:
+            @pl.when(i_t == n_t - 1)
+            def _emit_partials():
+                from .score_partials import partial_layout
+
+                cols, ncol = partial_layout(n_levels, len(wide_levels),
+                                            with_cert)
+                assert ncol <= 128
+                scalars = [(cols["c"], _C), (cols["sum"], _SUM),
+                           (cols["ssq"], _SSQ), (cols[("max", 0)], _MAX1),
+                           (cols[("arg", 0)], _ARG1)]
+                for j in range(1, n_levels):
+                    sq_slot, max_slot, arg_slot = level_slots[j - 1]
+                    scalars += [(cols[("ssq", j)], sq_slot),
+                                (cols[("max", j)], max_slot),
+                                (cols[("arg", j)], arg_slot)]
+                if with_cert:
+                    scalars += [(cols["cm2"], _CM2), (cols["cm3"], _CM3),
+                                (cols["cm4"], _CM4)]
+                    for i, (max_slot, last_slot, first_slot) in enumerate(
+                            wide_slots):
+                        scalars += [(cols[("wmax", i)], max_slot),
+                                    (cols[("wlast", i)], last_slot),
+                                    (cols[("wfirst", i)], first_slot)]
+                out = jnp.zeros((8, 128), jnp.float32)
+                for col, slot in scalars:
+                    out = out + jnp.where(lane128 == col,
+                                          st_ref[slot][:, 0:1], 0.0)
+                if with_cert:
+                    # first3 sits at lanes 3..5 of its slot, last3 at 0..2
+                    for col, slot, at in ((cols["first3"], _FIRST3, 3),
+                                          (cols["last3"], _LAST3, 0)):
+                        moved = rroll(st_ref[slot], col - at)
+                        out = out + jnp.where(
+                            (lane128 >= col) & (lane128 < col + 3), moved,
+                            0.0)
+                out_ref[:] = out
+
         def _emit():
             if with_cert:
                 # circular wrap: windows starting in the row's last 3
@@ -331,6 +386,9 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret,
                 out = out + jnp.where(lane128 == k, v, 0.0)
             out_ref[:] = out
 
+        if not partial:
+            pl.when(i_t == n_t - 1)(_emit)
+
     call = pl.pallas_call(
         kernel,
         grid=(n_rb, n_t),
@@ -345,7 +403,7 @@ def _build_score_kernel(rows_p, t, t_blk, with_cert, interpret,
 
 
 def _kernel_scores(rows_p, t, t_blk, with_cert, interpret, sub,
-                   n_levels=4, wide_from=None):
+                   n_levels=4, wide_from=None, partial=False):
     """Run the one-pass kernel on the first ``rows_p`` (8-aligned) rows
     of ``sub``: the grid visits them, rows past them are never read.
 
@@ -356,7 +414,7 @@ def _kernel_scores(rows_p, t, t_blk, with_cert, interpret, sub,
     import jax.numpy as jnp
 
     return _build_score_kernel(rows_p, t, t_blk, with_cert, interpret,
-                               n_levels, wide_from)(
+                               n_levels, wide_from, partial)(
         jnp.asarray(sub, jnp.float32))
 
 
@@ -424,3 +482,52 @@ def score_plane_pallas(plane, with_cert=False, interpret=False,
                                             with_cert=with_cert,
                                             windows=windows))
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def pick_partial_tile(own, length, widest=8):
+    """The scorer's time tile for one time tile of a row: the largest
+    supported one that divides both its ``own`` samples and the array's
+    ``length`` (own + halo: no block of the grid is cut short) and is a
+    multiple of the widest window; 0 if none."""
+    for t_blk in _T_BLKS:
+        if own % t_blk == 0 and length % t_blk == 0 and t_blk % widest == 0:
+            return t_blk
+    return 0
+
+
+def score_partials_pallas(plane, own, nsamples_total, with_cert=False,
+                          interpret=False, windows=None, rows=None):
+    """One time tile's partials (:mod:`.score_partials`) of ``plane``'s
+    first ``rows`` rows over its first ``own`` samples, through the
+    one-pass kernel: ``(rows, ncol)`` float32.  The ladder's levels follow
+    ``nsamples_total``, the whole row.  The rows past the last whole block
+    of eight go through the XLA :func:`.score_partials.score_partials`.
+    Raises ``ValueError`` where no tile fits (the caller takes the XLA
+    scorer for all rows)."""
+    import jax.numpy as jnp
+
+    from .score_partials import partial_layout, score_partials, tile_ladder
+
+    if rows is None:
+        rows = plane.shape[0]
+    scored, wide = tile_ladder(windows, nsamples_total)
+    t_blk = pick_partial_tile(own, plane.shape[1], scored[-1])
+    if t_blk == 0:
+        raise ValueError(f"no supported score tile divides a tile of {own} "
+                         f"of {plane.shape[1]} samples in multiples of the "
+                         f"widest window {scored[-1]}")
+    _, ncol = partial_layout(len(scored), len(wide), with_cert)
+    rows8 = (rows // 8) * 8
+    parts = []
+    if rows8:
+        with kernel_build_span("score_rows", rows=rows8, t=own, t_tile=t_blk):
+            out = _kernel_scores(
+                rows8, own, t_blk, bool(with_cert), bool(interpret), plane,
+                n_levels=len(scored),
+                wide_from=scored.index(wide[0]) if wide else None,
+                partial=True)
+        parts.append(out[:, :ncol])
+    if rows8 != rows:
+        parts.append(score_partials(plane[rows8:rows, :own], jnp, windows,
+                                    nsamples_total, with_cert=with_cert))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)

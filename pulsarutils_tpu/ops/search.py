@@ -691,6 +691,102 @@ def _search_jax_fdmt(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
     return out
 
 
+def time_tiles_of(data):
+    """How many time tiles ``data`` is searched in: 1 for an array, more
+    for a tier the device cannot hold whole
+    (:class:`~pulsarutils_tpu.pipeline.time_tiles.TiledTierArray`: a
+    ``shape``, its ``tier``, ``own`` samples a tile, a ``halo``, how many
+    cleaned tiles a rescore may ``keep``, and ``tile(i, shift)``, the
+    cleaned ``own + halo`` samples from ``i * own + shift`` on, circular
+    over the chunk)."""
+    return int(getattr(data, "time_tiles", 1))
+
+
+def _search_jax_fdmt_tiled(src, dmmin, dmmax, start_freq, bandwidth,
+                           sample_time, windows=None):
+    """:func:`_search_jax_fdmt` (``with_cert``, no plane) of a tier that
+    exists a time tile at a time: the unchanged transform on each tile's
+    axis of ``own + halo`` samples, the scorer's partials over the tile's
+    own outputs (the last ``halo`` are the circular transform's wrap), and
+    the rows' scores from the tiles' partials
+    (:func:`~.score_partials.combine_partials`).  The last tile's halo is
+    the chunk's start, so the row stays the circular series of the whole
+    chunk, which is what the certificate's bound and the reference
+    assume.  A tile's coarse values are the untiled sweep's bit for bit
+    (the same tree of adds); the scores agree to float32 summation order.
+    """
+    import jax
+
+    from ..obs.trace import span as trace_span
+    from .fdmt import _build_transform, _pick_fdmt_tile, fdmt_trial_dms
+    from .score_partials import combine_partials
+
+    nchan, total = src.shape
+    trial_dms, n_lo, n_hi = fdmt_trial_dms(nchan, dmmin, dmmax, start_freq,
+                                           bandwidth, sample_time)
+    length = src.own + src.halo
+    use_pallas = jax.default_backend() == "tpu"
+    t_tile = _pick_fdmt_tile(length)
+    if src.halo < n_hi or (use_pallas and not t_tile):
+        raise ValueError(f"a time tile of {src.own} + {src.halo} samples "
+                         f"cannot hold band delays to {n_hi} (or no FDMT "
+                         "tile divides it): the tile plan is not this "
+                         "tier's")
+    run = _build_transform(nchan, float(start_freq), float(bandwidth), n_hi,
+                           length, t_tile, use_pallas, not use_pallas,
+                           n_lo=n_lo, with_scores=True, with_plane=False,
+                           t_orig=length, with_cert=True, windows=windows,
+                           partial=(src.own, total))
+    parts = []
+    for i in range(src.time_tiles):
+        with trace_span("search/tile", tier=src.tier, tile=i,
+                        samples=src.own, halo=src.halo):
+            with budget_bucket("search/tile_clean"):
+                tile = src.tile(i)
+                budget_count("dispatches")
+            with budget_bucket("search/coarse"):
+                out = run(tile)
+                budget_count("dispatches")
+            del tile
+            with budget_bucket("search/coarse_readback"):
+                parts.append(np.asarray(out))
+                budget_count("readbacks")
+    scores = unstack_scores(combine_partials(parts, src.own, windows, total,
+                                             with_cert=True))
+    return (trial_dms,) + tuple(scores[:5]) + (None, scores[5])
+
+
+@functools.lru_cache(maxsize=16)
+def _tiled_rescore_kernel(max_off, dm_block, windows, own, total):
+    """One jitted program: exact dedispersion of a row bucket on one time
+    tile + the partials of its own samples.  The tile starts ``roll_k``
+    (the rebase's rotation, :func:`~.pallas_dedisperse.rebase_offsets`)
+    before its own first sample, so output ``t`` of the rebased kernel IS
+    sample ``t`` of the reference's row: no rotation is left to undo,
+    whatever the ladder."""
+    import jax
+    import jax.numpy as jnp
+
+    from .score_partials import score_partials
+
+    on_tpu = jax.default_backend() == "tpu"
+
+    @jax.jit
+    def rescore_tile(data, offs):
+        if on_tpu:
+            from .pallas_dedisperse import dedisperse_plane_pallas_traced
+
+            plane = dedisperse_plane_pallas_traced(data, offs, max_off,
+                                                   dm_block=dm_block)
+        else:
+            from .dedisperse import dedisperse_block_jax
+
+            plane = dedisperse_block_jax(data, offs)
+        return score_partials(plane[:, :own], jnp, windows, total)
+
+    return rescore_tile
+
+
 def _search_jax(data, trial_dms, start_freq, bandwidth, sample_time,
                 capture_plane, dm_block, chan_block, dtype, kernel="auto",
                 precision=None, windows=None):
@@ -1493,7 +1589,11 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
     from .fdmt import _pick_fdmt_tile, fdmt_trial_dms
 
     ndm = len(trial_dms)
-    nchan, nsamples = np.shape(data)
+    tiled = time_tiles_of(data) > 1
+    nchan, nsamples = data.shape if tiled else np.shape(data)
+    if tiled and capture_plane:
+        raise ValueError("a tier searched in time tiles has no plane to "
+                         "capture")
     dmmin = float(np.min(trial_dms))
     dmmax = float(np.max(trial_dms))
     ladder = check_windows(windows)  # ``windows`` below: the rows' best
@@ -1502,7 +1602,7 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
     # undoes the rotation on the device before it scores
     rotation_free = ladder[-1] <= REBASE_ALIGN
 
-    use_fused = jax.default_backend() == "tpu"
+    use_fused = jax.default_backend() == "tpu" and not tiled
     # (the pad-free soundness guard — disabling certificate + cert-proof
     # on zero-padded TPU time axes — lives in hybrid_certificate_gate;
     # the streaming driver sizes chunks so the post-resample axis is a
@@ -1591,12 +1691,14 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
         peaks = np.rint(coarse[4]).astype(np.int64)
         cert_scores = coarse[5]
     else:
-        # two-stage path (CPU, plane capture, or awkward time axes):
-        # coarse sweep first, scores mapped host-side
+        # two-stage path (CPU, plane capture, awkward time axes, or a
+        # tier in time tiles): coarse sweep first, scores mapped host-side
         (_, c_max, c_std, c_snr, c_win, c_peak, plane,
-         c_cert) = _search_jax_fdmt(
+         c_cert) = (_search_jax_fdmt_tiled(
             data, dmmin, dmmax, start_freq, bandwidth, sample_time,
-            capture_plane, with_cert=True, windows=ladder)
+            windows=ladder) if tiled else _search_jax_fdmt(
+            data, dmmin, dmmax, start_freq, bandwidth, sample_time,
+            capture_plane, with_cert=True, windows=ladder))
         if plane is not None and plane.shape[0] != ndm:
             # align the coarse plane with the plan grid (row gather —
             # cheap, and row-major on TPU unlike the scalarising lane
@@ -1648,6 +1750,42 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
                 chan_block=chan_block)
         return _rescore_kernel["k"]
 
+    kept_tiles = {}
+
+    def rescore_tiled(rows):
+        """:func:`rescore` of a tier in time tiles: each tile is cleaned
+        once more, ``roll_k`` early, every row bucket dedispersed on it
+        and scored into partials, and the buckets' scores combined over
+        the tiles.  As many cleaned tiles as the tile plan says fit
+        (``data.keep``) are held for the guarantee loop's next rounds."""
+        from .score_partials import combine_partials
+
+        rebased_full, roll_k, max_off = offsets_table()
+        if data.halo < max_off:
+            raise ValueError(f"a halo of {data.halo} samples is short of "
+                             f"the exact kernels' {max_off}")
+        buckets = list(iter_rescore_buckets(rows))
+        parts = [[] for _ in buckets]
+        with budget_bucket("search/rescore"):
+            for i in range(data.time_tiles):
+                tile = kept_tiles.get(i)
+                if tile is None:
+                    tile = data.tile(i, shift=roll_k)
+                    budget_count("dispatches")
+                    if len(kept_tiles) < data.keep:
+                        kept_tiles[i] = tile
+                for part, (_, padded) in zip(parts, buckets):
+                    run = _tiled_rescore_kernel(max_off, len(padded), ladder,
+                                                data.own, nsamples)
+                    part.append(np.asarray(
+                        run(tile, np.asarray(rebased_full[padded]))))
+                    budget_count("dispatches")
+                    budget_count("readbacks")
+                del tile
+        for part, (blk, _) in zip(parts, buckets):
+            _apply(blk, unstack_scores(combine_partials(
+                part, data.own, ladder, nsamples)))
+
     def rescore(rows):
         """Exact scores for ``rows`` — fused Pallas+score program on TPU
         (one dispatch + one readback per bucketed call), the portable
@@ -1655,6 +1793,8 @@ def _search_jax_hybrid(data, trial_dms, start_freq, bandwidth, sample_time,
         dispatch/readback time; here only the call/row counters)."""
         budget_count("rescore_calls")
         budget_count("rescore_rows", len(rows))
+        if tiled:
+            return rescore_tiled(rows)
         if use_fused:
             rebased_full, roll_k, max_off = offsets_table()
         for blk, padded in iter_rescore_buckets(rows):

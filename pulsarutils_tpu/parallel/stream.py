@@ -82,6 +82,118 @@ def plan_chunks(nsamples, sample_time, dmmin, dmmax, start_freq, stop_freq,
                      sample_time=resample * sample_time)
 
 
+@dataclasses.dataclass(frozen=True)
+class TierTiles:
+    """One tier's share of a tile plan (:func:`plan_time_tiles`)."""
+    tiles: int      # time tiles its axis is swept in (1: whole, as ever)
+    own: int        # its own samples a tile answers for
+    halo: int       # samples of the next tile swept again (0 when whole)
+    bytes: int      # device bytes reckoned while one of its tiles is swept
+    keep: int = 0   # cleaned tiles an exact rescore may hold across rounds
+
+
+def sweep_state_rows(nchan, start_freq, bandwidth, n_hi, n_lo):
+    """Rows of the two largest consecutive states of the FDMT's merge
+    schedule for band delays ``n_lo..n_hi``: what one sweep holds at once
+    beside its input, per sample of its time axis.  An upper bound on
+    the chip, where the fused head keeps the first levels in VMEM (the
+    v5e compiler: 5.51 GiB of temporaries for MeerTRAP's tier 0 on a tile
+    of 139,264 samples against 6.97 GiB reckoned here, PR 40)."""
+    from ..ops.fdmt import fdmt_plan
+
+    plan = fdmt_plan(int(nchan), float(start_freq), float(bandwidth),
+                     int(n_hi), int(n_lo))
+    rows = [plan.nchan_padded] + [int(sum(it["ndelay"]))
+                                  for it in plan.iterations]
+    return max(a + b for a, b in zip(rows, rows[1:]))
+
+
+def plan_time_tiles(nchan, nsamples, start_freq, bandwidth, tiers,
+                    budget_bytes, resident_bytes=0):
+    """How many time tiles each tier of a chunk is swept in, from the
+    device's memory.
+
+    ``tiers`` is one ``(downsample, sample_time, trial_dms, windows)`` per
+    tier (a flat plan is one tier at ``downsample`` 1) over a chunk of
+    ``nsamples`` samples of the plan; ``resident_bytes`` is what the chunk
+    loop holds beside a sweep (the packed chunk and the next one's
+    prefetch).  A tier's sweep is reckoned at its input array plus
+    :func:`sweep_state_rows` rows of float32 over its time axis, beside
+    the whole arrays of the tiers below it that exist by then: an upper
+    bound, not a footprint (the planner's job is a plan that cannot run
+    out of memory).  A tier that does not fit ``budget_bytes`` whole is
+    halved until a tile plus its halo does.  The halo holds the tier's
+    longest track (the FDMT's highest band delay and the exact kernels'
+    rebased offsets) and keeps a tile's axis divisible by the kernels'
+    time tiles.  A tile cannot be shorter than its halo or than the
+    ladder's widest window: a tier whose smallest tile still does not fit
+    raises ``ValueError`` naming the tier and the bytes, at plan time.
+
+    The tiers are planned from the deepest up.  The deepest tier that is
+    tiled lays the whole arrays of the tiers below it from its own tile
+    cleans (``pipeline/time_tiles.py``), so it is reckoned beside all of
+    them; a tiled tier above it is swept before they exist.  ``keep`` is
+    how many cleaned tiles fit beside that and one more tile: what an
+    exact rescore may hold across its rounds.
+
+    ``budget_bytes=None`` (no accelerator to ask) plans every tier whole.
+    Returns a list of :class:`TierTiles`, one per tier.
+    """
+    from ..ops.fdmt import fdmt_trial_dms
+    from ..ops.pallas_dedisperse import rebase_offsets
+    from ..ops.search import _offsets_for, scored_windows
+
+    out = [None] * len(tiers)
+    below = 0       # whole arrays of deeper tiers, resident by then
+    for k in reversed(range(len(tiers))):
+        downsample, sample_time, trial_dms, windows = tiers[k]
+        axis = int(nsamples) // int(downsample)
+        if budget_bytes is None:
+            out[k] = TierTiles(1, axis, 0, 0)
+            continue
+        dm_lo, dm_hi = float(np.min(trial_dms)), float(np.max(trial_dms))
+        _, n_lo, n_hi = fdmt_trial_dms(nchan, dm_lo, dm_hi, start_freq,
+                                       bandwidth, sample_time)
+        rows = int(nchan) + sweep_state_rows(nchan, start_freq, bandwidth,
+                                             n_hi, n_lo)
+        # the exact kernels' span is the highest trial's, rebased
+        _, _, max_off = rebase_offsets(_offsets_for(
+            [dm_lo, dm_hi], nchan, start_freq, bandwidth, sample_time, axis),
+            axis)
+        widest = scored_windows(windows, axis)[-1]
+        held = int(resident_bytes) + below
+        tiles = 1
+        while True:
+            own = axis // tiles
+            halo = 0
+            if tiles > 1:
+                quantum = min(8192, max(128, 1 << ((own // 8).bit_length()
+                                                   - 1)))
+                halo = -(-max(n_hi, max_off) // quantum) * quantum
+            need = held + rows * (own + halo) * 4
+            if need <= budget_bytes:
+                break
+            if (axis % (2 * tiles) or (own // 2) % widest
+                    or own // 2 < max(halo, n_hi, max_off)):
+                raise ValueError(
+                    f"DM tier {k} (x{downsample}, band delays {n_lo}-{n_hi}) "
+                    f"cannot be searched on this device: a time tile of "
+                    f"{own} + {halo} samples needs {need} bytes of "
+                    f"{budget_bytes}, and a shorter tile would not hold "
+                    "its own halo")
+            tiles *= 2
+        keep = 0
+        if tiles > 1:
+            tile_bytes = int(nchan) * (own + halo) * 4
+            keep = int(max(0, min(tiles, (budget_bytes - held)
+                                  // tile_bytes - 1)))
+            below = 0   # tiers above are swept before anything is laid
+        else:
+            below += int(nchan) * axis * 4
+        out[k] = TierTiles(tiles, own, halo, need, keep)
+    return out
+
+
 def iter_chunk_starts(nsamples, plan, tmin=0, sample_time=None):
     """Chunk start indices with 50% overlap, skipping a final fragment
     shorter than half a chunk (reference ``clean.py:318-325``) — and,

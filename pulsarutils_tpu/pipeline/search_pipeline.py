@@ -50,8 +50,9 @@ from ..obs.push import AlertBroker
 from ..obs.trace import begin_span, span as trace_span
 from ..ops.clean_ops import (fft_zap_time, renormalize_data, zero_dm_filter)
 from ..ops.rebin import downsample_chain, quick_resample
-from ..ops.search import dedispersion_search
-from ..parallel.stream import iter_chunk_starts, plan_chunks
+from ..ops.search import dedispersion_search, time_tiles_of
+from ..parallel.stream import (iter_chunk_starts, plan_chunks,
+                               plan_time_tiles)
 from ..pipeline.pulse_info import PulseInfo
 from ..pipeline.spectral_stats import get_bad_chans
 from ..resilience import ladder as _resilience_ladder
@@ -360,6 +361,25 @@ def _clean_on_host(what, exc):
     return None
 
 
+class _TiledChunk:
+    """A chunk searched in time tiles, between the clean stage and the
+    search: its packed bytes on the device and its chunk-wide moments
+    (``pipeline/time_tiles.py``)."""
+
+    __slots__ = ("raw", "stats")
+
+    def __init__(self, raw, stats):
+        self.raw, self.stats = raw, stats
+
+
+def _sweep_shape(arr):
+    """The shape one coarse sweep of ``arr`` runs on: a tiled tier's is
+    a tile's, halo included."""
+    if time_tiles_of(arr) > 1:
+        return (arr.shape[0], arr.own + arr.halo)
+    return arr.shape
+
+
 class _ReadFailure:
     """Sentinel from the reader thread: a chunk's read failed even after
     the bounded retries.  The chunk loop quarantines that one chunk
@@ -372,13 +392,59 @@ class _ReadFailure:
         self.exc = exc
 
 
+def _device_memory_bytes():
+    """What the first local accelerator can hold (``bytes_limit`` of its
+    ``memory_stats``); ``None`` where the backend does not say (the CPU):
+    nothing is then tiled."""
+    import jax
+
+    try:
+        stats = jax.local_devices()[0].memory_stats()
+    except (RuntimeError, NotImplementedError):  # a backend without them
+        return None
+    return (stats or {}).get("bytes_limit")
+
+
+def _tile_geometry(header, plan, tiers, flat, packed_bits):
+    """:func:`~..parallel.stream.plan_time_tiles`' arguments but the
+    budget, for a survey's plan: ``tiers`` is the tiered plan's list
+    (``None``: the flat plan, ``flat`` its ``(dmmin, dmmax, windows)``),
+    the last element what the chunk loop holds beside a sweep (the packed
+    chunk and the next one's prefetch)."""
+    from ..ops.plan import dedispersion_plan
+
+    nchan = header["nchans"]
+    if tiers:
+        geometry = [(t["tier"].downsample, t["tier"].sample_time,
+                     t["tier"].trial_dms, t["tier"].windows) for t in tiers]
+    else:
+        dmmin, dmmax, windows = flat
+        geometry = [(1, plan.sample_time, dedispersion_plan(
+            nchan, dmmin, dmmax, header["fbottom"], header["bandwidth"],
+            plan.sample_time), windows)]
+    return (nchan, plan.step // plan.resample, header["fbottom"],
+            header["bandwidth"], geometry,
+            2 * plan.step * (nchan * packed_bits // 8))
+
+
+def _plan_tiles(device_memory_bytes, *survey):
+    """The tile plan of a survey (:func:`_tile_geometry`'s arguments) on a
+    device of ``device_memory_bytes``, a sixteenth of it left to what the
+    reckoning does not see; ``None`` where every tier is swept whole."""
+    *args, resident = _tile_geometry(*survey)
+    tile_plan = plan_time_tiles(*args, int(device_memory_bytes) * 15 // 16,
+                                resident)
+    return tile_plan if any(t.tiles > 1 for t in tile_plan) else None
+
+
 def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 dmmin=200, dmmax=800, surelybad=(), *, backend="jax",
                 kernel="auto", snr_threshold=6.0, fft_zap=False,
                 cut_outliers=False, zero_dm=False, mesh=None,
                 exact_floor="auto", quarantine_policy="sanitize",
                 period_search=False, period_sigma_threshold=8.0,
-                fingerprint_extra=None, dm_tiers=None, boxcar_max=None):
+                fingerprint_extra=None, dm_tiers=None, boxcar_max=None,
+                device_memory_bytes=None):
     """Resolve a survey's geometry, threshold and resume fingerprint
     WITHOUT searching anything.
 
@@ -424,7 +490,18 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
     ``"certifiable"`` strings are resolved here), ``search_snr_floor``
     (the hybrid's forwarded floor, or ``None``), ``fingerprint`` (the
     resume-ledger key), ``root`` (the candidate filename stem) and
-    ``nsamples``/``sample_time``.
+    ``nsamples``/``sample_time``, and ``tile_plan``: ``None`` where every
+    tier is swept whole (every chunk that fits its device: today's
+    programs and bits), else one
+    :class:`~pulsarutils_tpu.parallel.stream.TierTiles` per tier (one for
+    a flat plan), chosen from the geometry and ``device_memory_bytes``
+    (:func:`_plan_tiles`).  Only the process that searches states its
+    device's memory (``search_by_chunks`` asks its first accelerator); a
+    planner that searches nothing (the fleet coordinator) leaves it
+    ``None``, touches no device and plans no tiles.  The fingerprint does
+    not name the tiles: they are the device's business, they change the
+    floats' summation order and not the search, and a coordinator and
+    its workers, or a resume on another device, must meet on one ledger.
     """
     logger.info("opening %s", fname)
     # strip only the final extension: "obs.day1.fil" and "obs.day2.fil"
@@ -562,6 +639,24 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
         snr_threshold = tiers[0]["snr_threshold"]
         search_snr_floor = tiers[0]["search_snr_floor"]
 
+    # a chunk the device cannot hold is searched in time tiles: only the
+    # packed single-device hybrid path knows how (the bytes stay resident
+    # and every tile is cleaned from them), and only the plain clean
+    tile_plan = None
+    if (device_memory_bytes and backend == "jax" and kernel == "hybrid"
+            and mesh is None and reader.packed_bits and plan.resample == 1
+            and not (fft_zap or cut_outliers)):
+        tile_plan = _plan_tiles(device_memory_bytes, header, plan, tiers,
+                                (dmmin, dmmax, flat_windows),
+                                reader.packed_bits)
+    if tile_plan:
+        logger.info("tile plan: %s", "; ".join(
+            f"tier {k}: {t.tiles} x ({t.own} + {t.halo} halo), "
+            f"{t.bytes / 2**30:.2f} GiB reckoned, keeps {t.keep}"
+            for k, t in enumerate(tile_plan)))
+    else:
+        logger.info("tile plan: every tier whole")
+
     fingerprint = config_fingerprint(
         fname=os.path.abspath(str(fname)), dmmin=dmmin, dmmax=dmmax,
         step=plan.step, resample=plan.resample, backend=backend,
@@ -605,6 +700,7 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
         "search_snr_floor": search_snr_floor,
         "tiers": tiers,
         "windows": flat_windows,
+        "tile_plan": tile_plan,
         "fingerprint": fingerprint,
         "chunk_starts": list(iter_chunk_starts(nsamples, plan, tmin=tmin,
                                                sample_time=sample_time)),
@@ -981,7 +1077,11 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                          period_search=period_search,
                          period_sigma_threshold=period_sigma_threshold,
                          fingerprint_extra=fingerprint_extra,
-                         dm_tiers=dm_tiers, boxcar_max=boxcar_max)
+                         dm_tiers=dm_tiers, boxcar_max=boxcar_max,
+                         device_memory_bytes=(
+                             _device_memory_bytes()
+                             if backend == "jax" and kernel == "hybrid"
+                             and mesh is None else None))
         reader = sp["reader"]
         root = sp["root"]
         header = reader.header
@@ -1002,6 +1102,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         search_snr_floor = sp["search_snr_floor"]
         tiers = sp["tiers"]  # None: the flat plan
         flat_windows = sp["windows"]  # None: the default ladder
+        tile_plan = sp["tile_plan"]  # None: every tier swept whole
         fingerprint = sp["fingerprint"]
         # fence (ISSUE 15): the fleet worker's lease epoch — candidate
         # artifact writes stamped with a higher epoch are refused (see
@@ -1040,6 +1141,15 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
     ncertified = 0
     capture = bool(make_plots) or bool(period_search) \
         or plane_consumer is not None
+    if tile_plan and capture:
+        raise ValueError(
+            "this chunk is searched in time tiles (it does not fit the "
+            "device whole): no plane exists to plot, fold or hand on; run "
+            "with make_plots=False")
+    if tile_plan:
+        for k, t in enumerate(tile_plan):
+            obs_metrics.gauge("putpu_time_tile_samples", tier=str(k)).set(
+                t.own)
     fallback_state = {}
 
     # one conditioning pipeline, parameterised by array namespace — the
@@ -1073,6 +1183,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                     resample=plan.resample)
     device_clean = None
     device_downsample = None
+    chain_factors = ()
     if tiers:
         # tier k's array is tier k-1's summed in pairs; a first tier at the
         # plan's own sample time searches the cleaned chunk itself
@@ -1102,6 +1213,12 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                                                  clean_options)
             if tiers:
                 device_downsample = _tier_downsample_program(chain_factors)
+            if tile_plan:
+                from .time_tiles import (TiledTierArray,
+                                         chunk_stats_program,
+                                         wrap_rows_program)
+
+                chunk_stats = chunk_stats_program(unpack, plan.step)
             if timer.rtt_s is None:  # keep a caller-calibrated RTT
                 timer.rtt_s = measure_device_rtt()
             if timer.rtt_s is not None:
@@ -1495,6 +1612,29 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             # candidate record for the cross-beam coincidence sift
             ibeam=reader.ibeam, nbeams=reader.nbeams)
 
+    # the deepest tier in tiles lays the whole arrays of the tiers below
+    # it from its own tile cleans (time_tiles.py): one clean of the chunk
+    # per tiled tier, none per whole one
+    laying_tier = max((k for k, t in enumerate(tile_plan or ())
+                       if t.tiles > 1), default=None)
+
+    def tier_source(chunk, k):
+        """Tier ``k``'s array of a chunk searched in time tiles, made
+        from the resident bytes on demand (``k`` 0 of a flat plan)."""
+        factor = tiers[k]["tier"].downsample if tiers else 1
+        return TiledTierArray(
+            chunk.raw, plan.step, chunk.stats, mask_dev, unpack, zero_dm,
+            tuple(f for f in chain_factors if f <= factor),
+            tile_plan[k].tiles, tile_plan[k].halo, tier=k,
+            keep=tile_plan[k].keep,
+            lay=(tuple(f for f in chain_factors if f > factor)
+                 if k == laying_tier else ()))
+
+    def _count_tiles(t):
+        obs_metrics.counter("putpu_time_tiles_total").inc(t.tiles)
+        obs_metrics.counter("putpu_tile_halo_samples_total").inc(
+            t.tiles * t.halo)
+
     def _search_tiers(cleaned, istart_, rec):
         """The tiered search of one cleaned chunk: downsample chain, then
         every tier through the flat path's own search call.
@@ -1507,18 +1647,24 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         tier array still referenced), ``best``, ``row`` (the best row's
         index in the concatenated table), ``detection`` and ``n_above``
         (rows of all tiers above their own tier's threshold)."""
-        with with_timer("search/tier_downsample"):
-            if isinstance(cleaned, np.ndarray):
-                rest = downsample_chain(cleaned, chain_factors)
-            else:
-                import jax as _jax
+        if isinstance(cleaned, _TiledChunk):
+            # nothing is made ahead: each tier's array, or its tiles,
+            # comes from the resident bytes at its turn
+            arrays = [None] * len(tiers)
+        else:
+            with with_timer("search/tier_downsample"):
+                if isinstance(cleaned, np.ndarray):
+                    rest = downsample_chain(cleaned, chain_factors)
+                else:
+                    import jax as _jax
 
-                rest = device_downsample(cleaned)
-                timer.count("dispatches")
-                _jax.block_until_ready(rest)
-                timer.count("readbacks")
-        arrays = [cleaned] * (len(tiers) - len(chain_factors)) + list(rest)
-        del rest  # a tier's array goes once searched, unless it is on top
+                    rest = device_downsample(cleaned)
+                    timer.count("dispatches")
+                    _jax.block_until_ready(rest)
+                    timer.count("readbacks")
+            arrays = ([cleaned] * (len(tiers) - len(chain_factors))
+                      + list(rest))
+            del rest  # a tier's array goes once searched, unless on top
         tables, top, n_above, nrows = [], None, 0, 0
         rec["tiers"] = []
 
@@ -1529,6 +1675,20 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
         for k, t in enumerate(tiers):
             tier = t["tier"]
             arr, arrays[k] = arrays[k], None
+            if isinstance(cleaned, _TiledChunk) and arr is None:
+                arr = tier_source(cleaned, k)
+                if arr.time_tiles == 1:
+                    # a whole tier above every tiled one: the untiled
+                    # search of its array, laid from the bytes
+                    with with_timer("search/tier_downsample"):
+                        import jax as _jax
+
+                        arr = arr.tile(0)
+                        timer.count("dispatches")
+                        _jax.block_until_ready(arr)
+                        timer.count("readbacks")
+            if time_tiles_of(arr) > 1:
+                _count_tiles(tile_plan[k])
             coarse0 = coarse_s()
             with trace_span("search/tier", tier=k,
                             downsample=tier.downsample,
@@ -1545,16 +1705,22 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                 certified = bool(ttable.meta.get("certified"))
                 tspan.attrs["certified"] = certified
             obs_metrics.counter("putpu_tier_sweeps_total").inc()
+            if getattr(arr, "lay", ()):
+                # the tiers below, whole, as this tier's tile cleans laid
+                # them: each is searched at its turn and goes like any
+                arrays[k + 1:] = arr.laid()
             if certified:
                 obs_metrics.counter("putpu_tier_certified_total").inc()
             nwindows = _count_windows(tier.windows, arr.shape[1])
-            _count_head_tiles(fallback_state, (backend, kernel, None),
-                              arr.shape, tier.dm_lo, tier.dm_hi, start_freq,
-                              bandwidth, tier.sample_time)
+            for _ in range(time_tiles_of(arr)):
+                _count_head_tiles(fallback_state, (backend, kernel, None),
+                                  _sweep_shape(arr), tier.dm_lo, tier.dm_hi,
+                                  start_freq, bandwidth, tier.sample_time)
             rec["tiers"].append({
                 "downsample": tier.downsample, "trials": ttable.nrows,
                 "coarse_s": round(coarse_s() - coarse0, 4),
-                "certified": certified, "windows": nwindows})
+                "certified": certified, "windows": nwindows,
+                **({"tiles": tile_plan[k].tiles} if tile_plan else {})})
             snr = np.asarray(ttable["snr"], dtype=np.float64)
             n_above += int(np.count_nonzero(snr > t["snr_threshold"]))
             best = ttable.best_row()
@@ -1698,7 +1864,22 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                     except Exception as exc:
                         device_clean = _clean_on_host("upload", exc)
             with with_timer("clean"):
-                if device_clean is not None:
+                if tile_plan and device_clean is not None:
+                    # the chunk does not fit cleaned: its bytes stay on
+                    # the device, the chunk-wide moments are taken from
+                    # them here, and every tile is cleaned from both where
+                    # it is swept.  Nothing falls back to the host: what
+                    # does not fit the device does not fit a float64 copy
+                    with trace_span("clean/chunk_stats"):
+                        stats = chunk_stats(src, mask_dev)
+                        # the bytes every tile is cut from: the chunk
+                        # with its start once more at its end
+                        src = wrap_rows_program()(src)
+                        timer.count("dispatches", 2)
+                        jax.block_until_ready((stats, src))
+                        timer.count("readbacks")
+                    array = _TiledChunk(src, stats)
+                elif device_clean is not None:
                     try:
                         cleaned = device_clean(src, mask_dev)
                         timer.count("dispatches")
@@ -1718,6 +1899,10 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                             raise  # the clean program cannot be built
                         device_clean = _clean_on_host("clean", exc)
                 if device_clean is None:
+                    if tile_plan:
+                        raise RuntimeError(
+                            "the upload of a chunk searched in time tiles "
+                            "failed, and it has no host path")
                     host_raw = np.asarray(array)
                     if packed_bits and host_raw.dtype == np.uint8:
                         # fallback decode of a packed chunk (C++/numpy
@@ -1745,6 +1930,10 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                                   else table)
                     else:
                         top, hit_tsamp = None, eff_tsamp
+                        if isinstance(array, _TiledChunk):
+                            array = tier_source(array, 0)
+                            ck["rec"]["tiles"] = array.time_tiles
+                            _count_tiles(tile_plan[0])
                         result = _search_with_fallback(
                             array, dmmin, dmmax, start_freq, bandwidth,
                             eff_tsamp, backend=backend, kernel=kernel,
@@ -1753,10 +1942,11 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                             chunk=istart, policy=dispatch_policy,
                             windows=flat_windows)
                         _count_windows(flat_windows, array.shape[1])
-                        _count_head_tiles(
-                            fallback_state, (backend, kernel, mesh),
-                            array.shape, dmmin, dmmax, start_freq, bandwidth,
-                            eff_tsamp)
+                        for _ in range(time_tiles_of(array)):
+                            _count_head_tiles(
+                                fallback_state, (backend, kernel, mesh),
+                                _sweep_shape(array), dmmin, dmmax,
+                                start_freq, bandwidth, eff_tsamp)
             except _resilience_ladder.OOMFloorError as exc:
                 # the degradation ladder's floor itself OOMed: this
                 # chunk cannot be searched on this host at ANY geometry

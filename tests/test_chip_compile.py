@@ -441,3 +441,74 @@ def test_fused_rescore_program_with_a_longer_ladder(as_tpu, one_chip):
     _fits(_fused_rescore_kernel(1152, 32, ladder, -640).lower(
         _sds((NCHAN, 1 << 15), jnp.float32, one_chip),
         _sds((32, NCHAN), jnp.int32, one_chip)).compile())
+
+
+def test_meertrap_whole_range_tile_sweep_fits_and_the_untiled_does_not(
+        as_tpu, one_chip):
+    """ISSUE 40: a 2^19-sample chunk of MeerTRAP's beam is searched from
+    its resident bytes in time tiles.  Tier 0's sweep of one tile (2^17
+    own samples + 8,192 of halo, band delays 0-5,182, the 12-window
+    ladder, partial scores) compiles with room for what the chunk loop
+    holds beside it: the packed chunk and the next one's prefetch (2 GiB
+    each).  So does the 2x tier's (band delays 2,592-5,182, 11 windows)
+    beside those and the three deeper tiers' whole arrays, which its tile
+    cleans lay (3.5 GiB, donated through the clean).  The chunk's moments
+    and a tile's clean from the bytes compile beside them too.  The same
+    sweep of the whole 2^19 axis is what the compiler refuses: it is why
+    the chunk is tiled."""
+    import jax
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.io.lowbit import device_unpack_block
+    from pulsarutils_tpu.ops import fdmt
+    from pulsarutils_tpu.ops.search import boxcar_ladder
+    from pulsarutils_tpu.pipeline import time_tiles
+
+    nchan, total, own, halo = MEERTRAP[0], 1 << 19, 1 << 17, 8192
+    length = own + halo
+    held = 4 * 2**30  # two packed chunks
+    deep = [(nchan, total // f) for f in (4, 8, 16)]
+    laid = sum(4 * rows * t for rows, t in deep)  # 3.5 GiB
+
+    def sweep(t, partial, n_hi=5182, n_lo=0, widest=2048):
+        return fdmt._build_transform(
+            *MEERTRAP, n_hi, t, fdmt._pick_fdmt_tile(t), True, False,
+            n_lo=n_lo, with_scores=True, with_plane=False, t_orig=t,
+            with_cert=True, windows=boxcar_ladder(widest), partial=partial)
+
+    def total_of(compiled):
+        m = compiled.memory_analysis()
+        return (m.temp_size_in_bytes + m.argument_size_in_bytes
+                + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+    assert fdmt.head_active(*MEERTRAP, 5182, 0, length)
+    tile = _sds((nchan, length), jnp.float32, one_chip)
+    compiled = sweep(length, (own, total)).lower(tile).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert total_of(compiled) + held < HBM_BYTES, compiled.memory_analysis()
+    compiled = sweep(length, (own, total // 2), 5182, 2592,
+                     1024).lower(tile).compile()
+    assert total_of(compiled) + held + laid < HBM_BYTES, \
+        compiled.memory_analysis()
+
+    unpack = (device_unpack_block, 8, nchan, True)
+    clean_args = (
+        _sds((total + time_tiles.MAX_BLOCK, nchan), jnp.uint8, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((total,), jnp.float32, one_chip),
+        _sds((nchan,), jnp.float32, one_chip),
+        _sds((nchan,), jnp.bool_, one_chip))
+    clean = time_tiles.tile_clean_program(unpack, True, total, length, ())
+    # its own argument is one of the two packed chunks held
+    assert total_of(clean.lower(*clean_args).compile()) + held // 2 \
+        < HBM_BYTES
+    laying = time_tiles.tile_clean_program(unpack, True, total, length,
+                                           (2,), lay=(4, 8, 16))
+    compiled = laying.lower(*clean_args, *(
+        _sds(shape, jnp.float32, one_chip) for shape in deep)).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= laid  # laid in place, not copied
+    assert total_of(compiled) + held // 2 < HBM_BYTES
+
+    with pytest.raises(Exception, match="(?i)memory|RESOURCE_EXHAUSTED"):
+        sweep(total, None).lower(
+            _sds((nchan, total), jnp.float32, one_chip)).compile()

@@ -71,3 +71,39 @@ def test_a_2bit_file_is_still_the_parents(tmp_path):
                       traffic, 1)
     with open(path, "rb") as f:
         assert hashlib.sha256(f.read()).hexdigest() == PARENT_2BIT_SEED_1
+
+
+def test_fulldm_rehearsal_in_time_tiles_is_correct(capsys, tmp_path,
+                                                   force_time_tiles):
+    """ISSUE 40: ``tiny_cpu_8bit_fulldm`` (five smearing tiers, DM 0-600)
+    under ``backlog_sparse_8bit_dm500`` with a planner budget that puts
+    the 2x tier, which holds the pulse, in two time tiles and the native
+    tier in more: ``correct`` against ``reference_boxcar``'s
+    untiled float64 search, the bfloat16 control not."""
+    cfg = _load("configs", "tiny_cpu_8bit_fulldm")
+    traffic = _load("traffic", "backlog_sparse_8bit_dm500")
+    path = str(tmp_path / "plan.fil")
+    info = generate.generate(path, cfg, traffic, 3400000401)
+    tiles = force_time_tiles(path, dict(
+        chunk_length=cfg["chunk_samples"] // 2 * cfg["tsamp_s"],
+        dmmin=cfg["dmmin"], dmmax=cfg["dmmax"], backend="jax",
+        kernel="hybrid", snr_threshold="certifiable", zero_dm=True,
+        dm_tiers="smearing", boxcar_max=cfg["boxcar_max"]), 2, tier=1)
+    assert len(tiles) == 5 and tiles[0].tiles > tiles[1].tiles > 1
+    assert 42.4 < info["pulses"][0]["dm"] < 84.7  # the 2x tier's
+    swept = ("putpu_time_tiles_total", "putpu_host_fallbacks_total")
+    before = _counters(*swept)
+    rc = harness.main([
+        "--workload", "tiny_cpu_8bit_fulldm.backlog_sparse_8bit_dm500",
+        "--seed", "3400000401", "--seconds", "1", "--trace", "0",
+        "--rehearsal", "--control", "1"])
+    out = capsys.readouterr().out.strip().splitlines()
+    line = json.loads(out[-1])
+    assert rc != 0  # a rehearsal never exits 0
+    assert line["correct"] is True and line["control_correct"] is False
+    assert all(c["ok"] for name, c in line["compared"].items()
+               if name != "snr_rel_gap_rms.control")
+    n_tiles, fallbacks = (a - b for a, b in zip(_counters(*swept), before))
+    per_chunk = sum(t.tiles for t in tiles if t.tiles > 1)
+    assert n_tiles >= 2 * 3 * per_chunk and n_tiles % per_chunk == 0
+    assert fallbacks == 0
