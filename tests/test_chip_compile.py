@@ -328,35 +328,59 @@ def test_fused_rescore_program(as_tpu, one_chip, plan, bucket):
         _sds((bucket, NCHAN), jnp.int32, one_chip)).compile())
 
 
-@pytest.mark.parametrize("nbits,nchan", [(2, NCHAN), (8, 4096)],
-                         ids=["2bit", "8bit_4096ch"])
+@pytest.mark.parametrize("nbits,nchan,zero_dm", [
+    (2, NCHAN, False),  # cell 1's clean
+    (2, NCHAN, True),   # cells 2-4's (``--zero-dm``)
+    (8, 4096, False),
+], ids=["2bit", "2bit_zero_dm", "8bit_4096ch"])
 def test_packed_unpack_and_clean_with_donation(as_tpu, one_chip, nbits,
-                                               nchan):
-    """The driver's first device program: packed 2-bit frames (or
-    MeerTRAP's 8-bit bytes) in, the cleaned ascending-band float chunk
-    out, the raw buffer donated (``search_pipeline.py``; reduced time
-    axis — see the module docstring.  At 4,096 x 2^17 the 8-bit program
-    holds 0.52 GiB of temporaries, the transposed bytes, beside its
-    2 GiB output; compiled by hand, PR 35)."""
-    import jax
+                                               nchan, zero_dm):
+    """The driver's first device program, as ``search_by_chunks`` builds
+    it: packed 2-bit frames (or MeerTRAP's 8-bit bytes) of a descending
+    band in, the cleaned ascending-band float chunk out, the raw buffer
+    donated (reduced time axis — see the module docstring.  At 4,096 x
+    2^17 the 8-bit program holds 0.52 GiB of temporaries, the transposed
+    bytes, beside its 2 GiB output; compiled by hand, PR 35).
+
+    The 2-bit program flips the band on the packed bytes (ISSUE 42): the
+    TPU compiler fuses a ``reverse`` into nothing, so one on the float
+    plane forced that plane to be written, reversed and read back by
+    each of the clean's passes (``convert_bitcast_fusion`` and ``rev.3``,
+    29.5 ms of cell 1's 75.7 ms clean).  With none there, the widening is
+    fused into the clean's readers and the only float32 plane of the
+    entry computation is its root.  At 1,024 x 2^19 the temporaries went
+    2.00 -> 0.52 GiB, at 2^20 4.00 -> 1.03 (compiled by hand, PR 42)."""
     import jax.numpy as jnp
 
     from pulsarutils_tpu.io.lowbit import device_unpack_block
-    from pulsarutils_tpu.ops.clean_ops import renormalize_data
+    from pulsarutils_tpu.pipeline.search_pipeline import (
+        _device_clean_program,
+    )
 
-    def unpack_clean(raw, mask):
-        return renormalize_data(
-            device_unpack_block(raw, nbits, nchan, band_descending=True,
-                                xp=jnp),
-            badchans_mask=mask, xp=jnp)
-
-    compiled = jax.jit(unpack_clean, donate_argnums=(0,)).lower(
+    # (cut_outliers, zero_dm, fft_zap, resample): the benchmark's cells
+    unpack_clean = _device_clean_program(
+        (device_unpack_block, nbits, nchan, True), (0,),
+        (False, zero_dm, False, 1))
+    compiled = unpack_clean.lower(
         _sds((T_SMALL, nchan * nbits // 8), jnp.uint8, one_chip),
         _sds((nchan,), jnp.bool_, one_chip)).compile()
+    text = compiled.as_text()
+    # the name ``clean_device_ms_per_chunk`` matches
+    assert text.startswith("HloModule jit_unpack_clean")
     m = compiled.memory_analysis()
     assert m.output_size_in_bytes == nchan * T_SMALL * 4
     if nbits == 8:  # bytes are transposed, floats are not
         assert m.temp_size_in_bytes < 1.5 * nchan * T_SMALL
+        return
+    passes = _plane_sized_passes(text, nchan * T_SMALL * 4)
+    root = re.search(r"ROOT %?([\w.\-]+) = ", text[text.index("ENTRY"):])
+    assert [name for name, _, _ in passes] == [root.group(1)], passes
+    # what is reversed is the frames' bytes, a sixteenth of the plane
+    packed_bytes = T_SMALL * nchan * nbits // 8
+    reverses = [size for _, op, size in
+                _plane_sized_passes(text, 2 * packed_bytes)
+                if op == "reverse"]
+    assert reverses == [packed_bytes]
 
 
 def test_fused_sharded_hybrid_on_four_devices(as_tpu, topo, plan):

@@ -14,6 +14,7 @@ import pytest
 jnp = pytest.importorskip("jax.numpy")
 
 from pulsarutils_tpu.io.lowbit import (  # noqa: E402
+    accum_dtype,
     device_unpack_block,
     unpack_numpy,
 )
@@ -71,6 +72,44 @@ def test_decode_triangle_bit_exact(tmp_path, nbits, descending, nchan_mult):
     np.testing.assert_array_equal(dev, host.astype(np.float32))
     np.testing.assert_array_equal(dev, oracle)
     np.testing.assert_array_equal(dev, data)  # and the ground truth
+
+
+@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jax"])
+@pytest.mark.parametrize("integer", [False, True],
+                         ids=["float32", "accum_int"])
+@pytest.mark.parametrize("part_filled", [False, True],
+                         ids=["whole_bytes", "part_filled_last_byte"])
+@pytest.mark.parametrize("descending", [False, True],
+                         ids=["ascending", "descending"])
+@pytest.mark.parametrize("nbits", [1, 2, 4])
+def test_device_unpack_equals_numpy_unpack_then_host_flip(
+        nbits, descending, part_filled, integer, xp):
+    """``device_unpack_block`` flips a descending band on the packed
+    bytes (ISSUE 42); what it returns is still ``unpack_numpy`` followed
+    by the host's flip, bit for bit and in the dtype asked for, in both
+    array namespaces.
+
+    ``read_block_packed`` cannot produce a frame whose last byte is
+    part-filled (the reader and the writer refuse an ``nchan * nbits``
+    that is no whole number of bytes:
+    ``test_misaligned_nchan_rejected``), so that case hands the function
+    such frames directly: every bit random, the padding codes included,
+    so that a slice taken from the wrong end shows."""
+    nchan = 5 * PER[nbits] - (1 if part_filled else 0)
+    nsamps = 37
+    raw = np.random.default_rng(100 * nbits + nchan).integers(
+        0, 256, (nsamps, 5), dtype=np.uint8)
+    name = accum_dtype(nbits, nchan) if integer else "float32"
+
+    got = device_unpack_block(xp.asarray(raw), nbits, nchan,
+                              band_descending=descending, xp=xp,
+                              dtype=getattr(xp, name))
+
+    want = unpack_numpy(raw, nbits).reshape(nsamps, -1)[:, :nchan].T
+    if descending:
+        want = want[::-1]
+    assert got.shape == (nchan, nsamps) and got.dtype == np.dtype(name)
+    np.testing.assert_array_equal(np.asarray(got), want.astype(name))
 
 
 def test_misaligned_nchan_rejected(tmp_path):
