@@ -155,6 +155,10 @@ METRIC_NAMES = {
         "(8, 256) tiles the FDMT's VMEM-resident head computes, halo "
         "chunks and padded rows included, one count per coarse sweep "
         "(static: plan, time axis, chosen slice; 0 where no head runs)",
+    "putpu_fdmt_pad_channels_total":
+        "all-zero channels the FDMT's coarse sweeps carried to reach a "
+        "power of two (the plan's nchan_padded less the band's channels, "
+        "one count per coarse sweep; 0 on a power-of-two band)",
     "putpu_fleet_drains_total":
         "graceful worker drains (in-flight chunk finished, ledger "
         "flushed, unstarted leases returned)",

@@ -442,7 +442,9 @@ def build_span_name(kind, what):
     ``kind`` is a build phase (``trace``, ``lower``, ``compile``; ``what``
     the program as the device trace names it, ``jit_fn``), ``kernel``
     (the Python that binds one Pallas kernel and traces its body, inside
-    its program's tracing) or ``plan`` (host work before ``jax.jit``)."""
+    its program's tracing), ``levels`` (the walk of a sweep's per-level
+    merges where no fused head runs, around their ``kernel`` spans) or
+    ``plan`` (host work before ``jax.jit``)."""
     return f"build/{kind}:{what}"
 
 

@@ -47,6 +47,7 @@ Implementation notes (TPU):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import numpy as np
@@ -62,6 +63,12 @@ from .plan import DM_DELAY_CONST, delta_delay
 
 def _lam(f):
     return f ** -2.0
+
+
+def pad_channels(nchan):
+    """All-zero channels a sweep carries above a band of ``nchan`` to
+    reach the tree's power of two (0 on a power-of-two band)."""
+    return (1 << max(int(nchan) - 1, 0).bit_length()) - int(nchan)
 
 
 class FdmtPlan:
@@ -95,9 +102,7 @@ class FdmtPlan:
         if not 0 <= self.min_delay <= self.max_delay:
             raise ValueError(
                 f"min_delay {min_delay} outside [0, {max_delay}]")
-        nch2 = 1
-        while nch2 < nchan:
-            nch2 *= 2
+        nch2 = nchan + pad_channels(nchan)
         self.nchan_padded = nch2
         # zero-padded channels sit ABOVE the real band: they must not
         # stretch the physical frequency span, so give them zero bandwidth
@@ -678,8 +683,12 @@ def _head_verdict(nchan, start_freq, bandwidth, max_delay, n_lo, t):
     why it is None where it is (``"shape"``: channels, levels or time
     axis; ``"halo"``; ``"shift"``; ``"smem"``: the tables outgrow the
     core's scalar memory) and the SMEM the head's tables need (0 where
-    the shape rules a head out).  Declining leaves the sweep to the
-    per-level merges: no geometry ends in the compiler's refusal."""
+    the shape rules a head out).  ``halo`` is judged at the slice the
+    kernel would run at (:func:`~.fdmt_resident.pick_head_t_slice`, which
+    divides the time axis by construction), not at the 2,048-sample floor:
+    the floor only says whether a head can exist at all (``shape``).
+    Declining leaves the sweep to the per-level merges: no geometry ends
+    in the compiler's refusal."""
     from .fdmt_resident import (
         HEAD_LEVELS,
         _head_plan_cached,
@@ -696,14 +705,15 @@ def _head_verdict(nchan, start_freq, bandwidth, max_delay, n_lo, t):
     hp = _head_plan_cached(nchan, start_freq, bandwidth, max_delay, n_lo,
                            HEAD_LEVELS)
     smem = head_smem_bytes(hp)
-    if not head_supported(*shape, halo=hp.halo):
+    t_slice = pick_head_t_slice(hp, t)
+    if not head_supported(*shape, t_slice=t_slice, halo=hp.halo):
         return None, "halo", smem
     if not head_supported(*shape,
                           max_level_shift=max(hp.max_shift_per_level)):
         return None, "shift", smem
     if smem > head_smem_limit():
         return None, "smem", smem
-    return (hp, pick_head_t_slice(hp, t)), None, smem
+    return (hp, t_slice), None, smem
 
 
 def coarse_head_tiles(nchan, nsamples, dmmin, dmmax, start_freq, bandwidth,
@@ -819,6 +829,16 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
                 paired = ([plane_rows[i] for i in paired[0]], paired[1])
     rows = max_delay - n_lo + 1
 
+    def levels_span():
+        # a sweep that runs no head walks every level from the channels up
+        # (ROADMAP C5b): its tracing is the cold start's largest item
+        if head_run is not None or not iters:
+            return contextlib.nullcontext()
+        return kernel_build_span(
+            "fdmt_merge", kind="levels", levels=len(iters), t=t,
+            rows=len(iters[0]["idx_low"]),
+            pad=plan.nchan_padded - nchan)
+
     def fn(data):
         state = data
         if nchan < plan.nchan_padded:
@@ -833,8 +853,9 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
             if head_run is not None:
                 state = head_run(state)
             state = _state_tiles(state, t_tile)
-            for it in iters:
-                state = _merge_pallas(state, it, t_tile, interpret)
+            with levels_span():
+                for it in iters:
+                    state = _merge_pallas(state, it, t_tile, interpret)
             if paired is not None:
                 state = _merge4_pallas(state, paired[0], paired[1], t_tile,
                                        interpret)
@@ -842,12 +863,13 @@ def _transform_fn(nchan, start_freq, bandwidth, max_delay, t, t_tile,
         else:
             if head_run is not None:  # the tests' seam: head, XLA merges
                 state = head_flat_rows(head, head_run(state))
-            for it in iters:
-                sh = (jnp.asarray(it["shift_high"])
-                      if it["shift_high"] is not None else None)
-                state = _merge_xla(state, jnp.asarray(it["idx_low"]),
-                                   jnp.asarray(it["idx_high"]),
-                                   jnp.asarray(it["shift"]), sh)
+            with levels_span():
+                for it in iters:
+                    sh = (jnp.asarray(it["shift_high"])
+                          if it["shift_high"] is not None else None)
+                    state = _merge_xla(state, jnp.asarray(it["idx_low"]),
+                                       jnp.asarray(it["idx_high"]),
+                                       jnp.asarray(it["shift"]), sh)
         # the first `rows` rows are n_lo..max_delay by construction
         plane = state
         if t_orig is not None and t_orig != t:

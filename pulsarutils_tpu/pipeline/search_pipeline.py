@@ -269,16 +269,21 @@ def _count_head_tiles(state, route, shape, dmmin, dmmax, start_freq,
     sweep (``putpu_fdmt_head_tiles_total``: halo chunks and padded rows
     included; ``ops/fdmt.py:coarse_head_tiles``) and returns their
     number: 0 where the sweep runs no head.  Beside it the SMEM the
-    head's tables take (gauge ``putpu_fdmt_head_smem_bytes``) and, where
+    head's tables take (gauge ``putpu_fdmt_head_smem_bytes``), where
     the geometry declined the head, why
-    (``putpu_fdmt_head_declined_total``).  ``route`` is the call's
-    ``(backend, kernel, mesh)``, ``state`` what a fall-back made of it."""
+    (``putpu_fdmt_head_declined_total``), and the all-zero channels the
+    sweep carries to a power of two (``putpu_fdmt_pad_channels_total``,
+    on any backend: the tree pads wherever it runs).  ``route`` is the
+    call's ``(backend, kernel, mesh)``, ``state`` what a fall-back made
+    of it."""
     backend, kernel, mesh = route
     if (mesh is not None or state.get("backend", backend) != "jax"
             or state.get("kernel", kernel) not in ("hybrid", "fdmt")):
         return 0
-    from ..ops.fdmt import coarse_head_tiles
+    from ..ops.fdmt import coarse_head_tiles, pad_channels
 
+    obs_metrics.counter("putpu_fdmt_pad_channels_total").inc(
+        pad_channels(shape[0]))
     n, _, declined, smem = coarse_head_tiles(
         shape[0], shape[1], dmmin, dmmax, start_freq, bandwidth, tsamp)
     obs_metrics.counter("putpu_fdmt_head_tiles_total").inc(n)

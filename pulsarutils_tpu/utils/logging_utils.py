@@ -193,16 +193,17 @@ def _install_compile_listener():
 
 
 @contextlib.contextmanager
-def kernel_build_span(kernel, **attrs):
+def kernel_build_span(kernel, kind="kernel", **attrs):
     """``build/kernel:<kernel>`` around the Python that binds a Pallas
-    kernel and so traces its body, with the geometry that keys it.
-    Recorded under a tracer, inside a program's tracing phase: a warm
-    call never reaches this Python, and an eager call of the same code
-    (no phase open) is no build."""
+    kernel and so traces its body, with the geometry that keys it
+    (``kind="levels"``: ``build/levels:<kernel>`` around a sweep's whole
+    walk of per-level merges).  Recorded under a tracer, inside a
+    program's tracing phase: a warm call never reaches this Python, and
+    an eager call of the same code (no phase open) is no build."""
     if not (_trace.is_tracing() and _BUILD.open):
         yield
         return
-    with _trace.span(_trace.build_span_name("kernel", kernel), **attrs):
+    with _trace.span(_trace.build_span_name(kind, kernel), **attrs):
         yield
 
 

@@ -345,10 +345,19 @@ def test_each_cold_metric_reads_what_the_program_emits(path, sweep_events):
     with open(path) as f:
         spec = json.load(f)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        (entry,) = [m for m in json.load(f)["per_layer"]
-                    if m["name"] == spec["name"]]
+        manifest = json.load(f)
+    (entry,) = [m for m in manifest["per_layer"]
+                if m["name"] == spec["name"]]
     assert entry["moves"] == spec["moves"] == "setup_s"
-    assert entry["layer"] == spec["layer"] and "workloads" not in entry
+    assert entry["layer"] == spec["layer"]
+    if spec["name"] == "cold_head_trace_s":
+        # read in every cell but the one whose sweeps all decline the head
+        # (ISSUE 44): no ``fdmt_head`` is bound there, so nothing to read
+        assert entry["workloads"] == [
+            w["name"] for w in manifest["workloads"]
+            if w["config"] != "parkes_uwl_2bit"]
+    else:
+        assert "workloads" not in entry
     source = spec["source"]
     assert source["pass"] == "cold" and source["per"] == "total"
     if source["kind"] == "registry_counter":

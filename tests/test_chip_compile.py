@@ -536,3 +536,44 @@ def test_meertrap_whole_range_tile_sweep_fits_and_the_untiled_does_not(
     with pytest.raises(Exception, match="(?i)memory|RESOURCE_EXHAUSTED"):
         sweep(total, None).lower(
             _sds((nchan, total), jnp.float32, one_chip)).compile()
+
+
+def test_uwl_2bit_tile_clean_and_moments_at_832_byte_frames(as_tpu,
+                                                            one_chip):
+    """ISSUE 44: Parkes' ultra-wideband chunk (3,328 channels of 2 bits,
+    frames of 832 bytes, 2^17 samples) is cleaned from its resident bytes:
+    the chunk's moments and the native tier's tile clean (65,536 + 16,384
+    samples, laying the 2x and 4x tiers' whole arrays in place) compile
+    beside the two packed chunks held, with no float reverse of the
+    band."""
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.io.lowbit import device_unpack_block
+    from pulsarutils_tpu.pipeline import time_tiles
+
+    nchan, total, length = 3328, 1 << 17, 65536 + 16384
+    held = 2 * total * nchan // 4
+    unpack = (device_unpack_block, 2, nchan, True)
+
+    def total_of(compiled):
+        m = compiled.memory_analysis()
+        return (m.temp_size_in_bytes + m.argument_size_in_bytes
+                + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+    stats = time_tiles.chunk_stats_program(unpack, total).lower(
+        _sds((total, nchan // 4), jnp.uint8, one_chip),
+        _sds((nchan,), jnp.bool_, one_chip)).compile()
+    assert total_of(stats) + held < HBM_BYTES
+    deep = [(nchan, total // f) for f in (2, 4)]
+    laying = time_tiles.tile_clean_program(unpack, True, total, length, (),
+                                           lay=(2, 4))
+    compiled = laying.lower(
+        _sds((total + time_tiles.MAX_BLOCK, nchan // 4), jnp.uint8, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((total,), jnp.float32, one_chip),
+        _sds((nchan,), jnp.float32, one_chip),
+        _sds((nchan,), jnp.bool_, one_chip),
+        *(_sds(shape, jnp.float32, one_chip) for shape in deep)).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(4 * r * t for r, t in deep)
+    assert total_of(compiled) + held < HBM_BYTES
+    assert not re.search(r"f32\[3328,\d+\]\S* reverse\(", compiled.as_text())
