@@ -269,9 +269,8 @@ def _exact_best_phase(width, windows=None):
     on a boundary of every block: a window ``w`` then captures
     ``min(w, width)`` of its ``width`` equal parts, and no window of
     that width captures more at any phase.  Depends on ``width`` and
-    the ladder alone, so it is memoised (cert_retention evaluates it
-    once per trial x width otherwise — a multi-second host stall at
-    multi-thousand-trial configs)."""
+    the ladder alone, so it is memoised (the bound asks for each width
+    once a tier, the tests and tools once a trial)."""
     parts = np.cumsum(np.full(width, 1.0 / width))
     return max(parts[min(w, width) - 1] / np.sqrt(w)
                for w in _windows(windows))
@@ -279,7 +278,9 @@ def _exact_best_phase(width, windows=None):
 
 def _wide_capture_worst_phase(mass, wide):
     """Score of the half-stride captures (``search.cert_wide_windows``)
-    on ``mass`` at the pulse phase that serves them worst.
+    on ``mass`` at the pulse phase that serves them worst; ``mass`` is
+    one trial's ``(n,)`` or, for trials whose mass spans the same ``n``
+    bins, ``(trials, n)``.
 
     A window of width ``w`` starts at every multiple of ``w / 2``.  One
     at least twice as long as the mass holds all of it whatever the
@@ -288,24 +289,30 @@ def _wide_capture_worst_phase(mass, wide):
     of the widest such window's stride, which every narrower stride
     divides.
     """
-    n = len(mass)
-    total = float(mass.sum())
+    mass = np.asarray(mass, dtype=np.float64)
+    n = mass.shape[-1]
+    total = mass.sum(axis=-1)
     whole = [w for w in wide if w >= 2 * n]
-    floor = total / np.sqrt(whole[0]) if whole else 0.0
+    floor = total / np.sqrt(whole[0]) if whole else np.zeros_like(total)
     partial = [w for w in wide if w < 2 * n]
     if not partial:
         return floor
-    csum = np.concatenate([[0.0], np.cumsum(mass)])
+    csum = np.concatenate([np.zeros(mass.shape[:-1] + (1,)),
+                           np.cumsum(mass, axis=-1)], axis=-1)
     phases = np.arange(partial[-1] // 2)
-    scores = np.full(len(phases), floor)
+    scores = np.asarray(floor)[..., None]
     for w in partial:
         half = w // 2
-        start = np.arange(-(w - 1), n)
-        sums = csum[np.clip(start + w, 0, n)] - csum[np.clip(start, 0, n)]
-        best = np.zeros(half)
-        np.maximum.at(best, start % half, sums)
-        scores = np.maximum(scores, best[(-phases) % half] / np.sqrt(w))
-    return float(scores.min())
+        # whole strides of starts from -w on: the sums of one residue
+        # modulo the stride are a column, and the windows added at either
+        # end (before -(w - 1), from n on) hold nothing
+        strides = -(-(n + w) // half)
+        start = np.arange(-w, -w + strides * half)
+        sums = (csum[..., np.clip(start + w, 0, n)]
+                - csum[..., np.clip(start, 0, n)])
+        best = sums.reshape(sums.shape[:-1] + (strides, half)).max(axis=-2)
+        scores = np.maximum(scores, best[..., (-phases) % half] / np.sqrt(w))
+    return scores.min(axis=-1)
 
 
 @functools.lru_cache(maxsize=32)
@@ -346,7 +353,11 @@ def _wide_retention_table(windows, wide, first_width):
 
 def _cert_retention_from_offsets(offsets, max_width=16, windows=None,
                                  wide=()):
-    """Worst-case ``cert_score / exact_snr`` ratio for one trial's track.
+    """Worst-case ``cert_score / exact_snr`` ratio for one trial's track
+    (``offsets`` of ``(nchan,)``: a float), or for each of a tier's
+    trials at once (``(trials, nchan)``: an array, trial by trial the
+    same numbers; :func:`_cert_retention_from_histograms` is the
+    arithmetic of both).
 
     The denominator is the exact kernel's best detection score of the
     pulse over the ladder ``windows``, taken at the pulse's *best* phase
@@ -385,44 +396,108 @@ def _cert_retention_from_offsets(offsets, max_width=16, windows=None,
     with the tree's scatter (``D`` about a sample) the overall minimum
     still sits at widths 1-3, where it sat before.
     """
-    offsets = np.asarray(offsets, dtype=np.int64)
-    offsets = offsets - offsets.min()
-    span = int(offsets.max()) + 1
-    h = np.zeros(span)
-    np.add.at(h, offsets, 1.0 / len(offsets))
+    offsets = np.asarray(offsets)
+    rho = _cert_retention_from_histograms(
+        _offset_histograms([np.atleast_2d(offsets)]), max_width=max_width,
+        windows=windows, wide=wide)
+    return float(rho[0]) if offsets.ndim == 1 else rho
 
+
+def _cert_retention_from_histograms(hist, max_width=16, windows=None,
+                                    wide=()):
+    """:func:`_cert_retention_from_offsets` of every trial at once, from
+    the ``(trials, span)`` histograms of their offsets
+    (:func:`_offset_histograms`): each step of that bound as arithmetic
+    over the trials' axis.  Returns ``(trials,)``."""
     from .search import CERT_WINDOWS
 
-    def sliding_capture(mass, w):
-        if len(mass) <= w:
-            return mass.sum()
-        kernel = np.ones(w)
-        return np.convolve(mass, kernel).max()
+    spans = hist.shape[1] - (hist[:, ::-1] > 0).argmax(axis=1)
+    nchan = int(hist[0].sum())
+    # a bin's mass is 1 / nchan added once a channel, as np.add.at adds it
+    bin_mass = np.concatenate([[0.0],
+                               np.cumsum(np.full(nchan, 1.0 / nchan))])
 
     ladder = _windows(windows)
     if wide:
         max_width = min(max_width, 2 * ladder[-1])
-    worst = np.inf
-    for width in range(1, max_width + 1):
-        mass = np.convolve(h, np.full(width, 1.0 / width))
-        cert = max(sliding_capture(mass, w) / np.sqrt(w)
-                   for w in CERT_WINDOWS)
-        if wide:
-            cert = max(cert, _wide_capture_worst_phase(mass, wide))
-        worst = min(worst, cert / _exact_best_phase(width, windows))
+    worst = np.full(len(hist), np.inf)
+    # trials whose scatter spans as many bins share every shape below
+    # (and _wide_capture_worst_phase's split of the windows)
+    for span in np.unique(spans):
+        rows = np.flatnonzero(spans == span)
+        h = bin_mass[hist[rows, :span]]
+        low = np.full(len(rows), np.inf)
+        for width in range(1, max_width + 1):
+            n = span + width - 1
+            mass = np.zeros((len(rows), n))  # h convolved with the box
+            part = h * (1.0 / width)
+            for k in range(span):
+                mass[:, k:k + width] += part[:, k:k + 1]
+            cert = np.zeros(len(rows))
+            for w in CERT_WINDOWS:  # sliding: the best w adjacent bins
+                starts = max(n - w, 0) + 1
+                held = mass[:, :starts].copy()
+                for k in range(1, min(w, n)):
+                    held += mass[:, k:k + starts]
+                cert = np.maximum(cert, held.max(axis=1) / np.sqrt(w))
+            if wide:
+                cert = np.maximum(cert,
+                                  _wide_capture_worst_phase(mass, wide))
+            low = np.minimum(low, cert / _exact_best_phase(width, windows))
+        worst[rows] = low
     if wide and max_width < 2 * ladder[-1]:
         score, per_sample, exact = _wide_retention_table(
             ladder, tuple(wide), max_width + 1)
-        deviation = float(np.abs(offsets - np.median(offsets)).mean())
-        cert = (score - deviation * per_sample).max(axis=1)
-        worst = min(worst, float((cert / exact).min()))
-    return float(worst)
+        # the closed form is a function of the deviation alone: once a
+        # distinct value, a block at a time ((values, widths, windows))
+        deviation, trial = np.unique(_mean_abs_deviation(hist),
+                                     return_inverse=True)
+        closed = np.empty(len(deviation))
+        block = max(1, (1 << 22) // score.size)
+        for lo in range(0, len(deviation), block):
+            d = deviation[lo:lo + block, None, None]
+            cert = (score - d * per_sample).max(axis=2)
+            closed[lo:lo + block] = (cert / exact).min(axis=1)
+        worst = np.minimum(worst, closed[trial])
+    return worst
+
+
+def _offset_histograms(blocks):
+    """``(trials, span)`` counts of each trial's offsets above its
+    smallest, ``span`` the widest trial's; ``blocks`` yields the trials'
+    ``(rows, nchan)`` offsets some rows at a time."""
+    hists = []
+    for offsets in blocks:
+        offsets = offsets - offsets.min(axis=1, keepdims=True)
+        span = int(offsets.max()) + 1
+        rows = np.arange(len(offsets), dtype=np.int64)[:, None]
+        hists.append(np.bincount(
+            (rows * span + offsets).ravel(),
+            minlength=len(offsets) * span).reshape(-1, span))
+    span = max(h.shape[1] for h in hists)
+    return np.concatenate([np.pad(h, ((0, 0), (0, span - h.shape[1])))
+                           for h in hists])
+
+
+def _mean_abs_deviation(hist):
+    """Mean absolute deviation about the median (``np.median``'s: the
+    mean of the two middle values) of each trial's offsets, from their
+    histogram."""
+    n = int(hist[0].sum())
+    below = np.cumsum(hist, axis=1)
+    # the i-th smallest offset is the first bin whose running count passes i
+    median = ((below <= (n - 1) // 2).sum(axis=1)
+              + (below <= n // 2).sum(axis=1)) / 2.0
+    return (hist * np.abs(np.arange(hist.shape[1]) - median[:, None])
+            ).sum(axis=1) / n
 
 
 def _track_deviations(nchan, trial_dms, start_freq, bandwidth, sample_time,
                       nsamples):
     """Signed per-channel deviation of each plan trial's mapped coarse
-    row from the exact kernel's integer offsets: ``(ndm, nchan)``."""
+    row from the exact kernel's integer offsets: the rows of ``(ndm,
+    nchan)`` in trial order, about 2^20 elements at a time (the float64
+    shift arithmetic then works in buffers the allocator hands back)."""
     from .fdmt import fdmt_plan, fdmt_tracks, fdmt_trial_dms
     from .plan import dedispersion_shifts_batch, normalize_shifts
     from .search import nearest_rows
@@ -432,46 +507,50 @@ def _track_deviations(nchan, trial_dms, start_freq, bandwidth, sample_time,
         nchan, float(trial_dms.min()), float(trial_dms.max()), start_freq,
         bandwidth, sample_time)
     plan = fdmt_plan(nchan, float(start_freq), float(bandwidth), n_hi, n_lo)
-    tracks = fdmt_tracks(plan)[:, :nchan]
+    # a band delay and a chunk's sample index, with room to add two
+    dtype = np.int32 if max(n_hi, nsamples) < 2 ** 30 else np.int64
+    tracks = fdmt_tracks(plan, dtype)
     idx = nearest_rows(fdmt_dms, trial_dms)
-
-    shifts = dedispersion_shifts_batch(trial_dms, nchan, start_freq,
-                                       bandwidth, sample_time)
-    exact = normalize_shifts(shifts, nsamples).astype(np.int64)
-    dev = (tracks[idx] % nsamples) - exact
-    # wrap to signed: a track and an offset that agree mod T are the
-    # same gather; centre the deviation on the dominant branch
-    return (dev + nsamples // 2) % nsamples - nsamples // 2
+    period, half = dtype(nsamples), dtype(nsamples // 2)
+    block = max(1, (1 << 20) // nchan)
+    for lo in range(0, len(trial_dms), block):
+        shifts = dedispersion_shifts_batch(
+            trial_dms[lo:lo + block], nchan, start_freq, bandwidth,
+            sample_time)
+        exact = normalize_shifts(shifts, nsamples).astype(dtype, copy=False)
+        dev = tracks[idx[lo:lo + block], :nchan] % period - exact
+        # wrap to signed: a track and an offset that agree mod T are the
+        # same gather; centre the deviation on the dominant branch
+        yield (dev + half) % period - half
 
 
 @functools.lru_cache(maxsize=32)
 def _retention_cached(nchan, dms_key, start_freq, bandwidth, sample_time,
                       nsamples, min_width, cert, windows=None):
     trial_dms = np.frombuffer(dms_key, dtype=np.float64)
-    # host work of a process's first call with this geometry (a Python
-    # loop over the trials: 57 s of MeerTRAP's cold pass, PERF.md PR 38)
+    # host work of a process's first call with this geometry: array
+    # arithmetic over the tier's trials at once (a Python loop over them
+    # until PR 45: 57 s of MeerTRAP's cold pass, PERF.md PR 38)
     with span(build_span_name("plan", "cert_retention"), nchan=nchan,
               trials=len(trial_dms), t=nsamples):
-        dev = _track_deviations(nchan, trial_dms, start_freq, bandwidth,
-                                sample_time, nsamples)
-        if cert and windows is not None:
-            from ..utils.logging_utils import budget_bucket
-            from .search import cert_wide_windows
+        blocks = _track_deviations(nchan, trial_dms, start_freq, bandwidth,
+                                   sample_time, nsamples)
+        if not cert:
+            return np.asarray([
+                _retention_from_offsets(d, min_width=min_width)
+                for dev in blocks for d in dev])
+        hist = _offset_histograms(blocks)
+        if windows is None:
+            return _cert_retention_from_histograms(hist)
+        from ..utils.logging_utils import budget_bucket
+        from .search import cert_wide_windows
 
-            wide = cert_wide_windows(windows, nsamples)
-            # the host's share of a longer ladder: its captures at the
-            # worst phase and the closed form beyond max_width
-            with budget_bucket("search/cert_wide"):
-                return np.asarray([_cert_retention_from_offsets(
-                    d, windows=windows, wide=wide) for d in dev])
-        rho = np.empty(len(trial_dms))
-        for j in range(len(trial_dms)):
-            if cert:
-                rho[j] = _cert_retention_from_offsets(dev[j])
-            else:
-                rho[j] = _retention_from_offsets(dev[j],
-                                                 min_width=min_width)
-        return rho
+        # the host's share of a longer ladder: its captures at the
+        # worst phase and the closed form beyond max_width
+        with budget_bucket("search/cert_wide"):
+            return _cert_retention_from_histograms(
+                hist, windows=windows,
+                wide=cert_wide_windows(windows, nsamples))
 
 
 def coarse_retention(nchan, trial_dms, start_freq, bandwidth, sample_time,

@@ -251,7 +251,7 @@ def compose_iterations(it_a, it_b):
             [np.ascontiguousarray(s, np.int32) for s in shift])
 
 
-def fdmt_tracks(plan):
+def fdmt_tracks(plan, dtype=np.int64):
     """The effective dispersion track of every final transform row.
 
     Walks the plan's merge tables with an offset accumulator instead of
@@ -266,21 +266,27 @@ def fdmt_tracks(plan):
     Returns int64 ``(rows_final, nchan_padded)``; rows are the plan's
     ``min_delay..max_delay`` delay slice, columns ``>= plan.nchan`` belong
     to zero-padded channels (no data flows through them — slice them off
-    before comparing).
+    before comparing).  ``dtype=np.int32`` halves the array for a caller
+    that gathers from it (a track is a band delay, ``<= max_delay``).
     """
-    nchp = plan.nchan_padded
-    tracks = np.zeros((nchp, nchp), np.int64)
-    valid = np.eye(nchp, dtype=bool)
+    # a row's track is carried over the band it covers and nowhere else:
+    # the state entering a level is (rows, band width), and an output row
+    # is its low parent's track beside its high parent's (the low parent
+    # covers the lower half of the output band's channels)
+    tracks = np.zeros((plan.nchan_padded, 1), dtype)
     for it in plan.iterations:
-        tl = tracks[it["idx_low"]] + it["shift"][:, None]
-        th = tracks[it["idx_high"]]
-        if it["shift_high"] is not None:
-            th = th + it["shift_high"][:, None]
-        vl, vh = valid[it["idx_low"]], valid[it["idx_high"]]
-        # low/high parents cover disjoint channel halves of the output band
-        tracks = np.where(vl, tl, th) * (vl | vh)
-        valid = vl | vh
-    assert valid.all(), "final band must cover every channel"
+        width = tracks.shape[1]
+        out = np.empty((len(it["idx_low"]), 2 * width), dtype)
+        np.add(tracks[it["idx_low"]], it["shift"][:, None],
+               out=out[:, :width])
+        if it["shift_high"] is None:
+            out[:, width:] = tracks[it["idx_high"]]
+        else:
+            np.add(tracks[it["idx_high"]], it["shift_high"][:, None],
+                   out=out[:, width:])
+        tracks = out
+    assert tracks.shape[1] == plan.nchan_padded, \
+        "final band must cover every channel"
     return tracks
 
 

@@ -564,8 +564,9 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
 
         def cert_floor():
             """Certifiable floor for this geometry (lazy: the retention
-            bound is a multi-second host computation at multi-thousand-
-            trial configs and only two configurations need it —
+            bound walks the tier's merge tables and its trials' shift
+            table on the host, seconds at ten thousand trials of a few
+            thousand channels, and only two configurations need it —
             snr_threshold='certifiable', and the hybrid's
             exact_floor='auto' comparison)."""
             trial_dms = grid()

@@ -331,12 +331,16 @@ def test_span_union_reader(spans, seconds):
     assert got == (pytest.approx(seconds) if seconds is not None else None)
 
 
+#: the cold pass's metrics that read no build phase of a sweep: PR 24's
+#: host clock around the whole pass, and ISSUE 45's two of the plan
+NOT_OF_A_SWEEP = ("cold_pass_s", "cold_plan_s", "cold_cert_retention_s")
+
+
 def _cold_metric_files():
-    """The nine files ISSUE 38 adds (``cold_pass_s`` is PR 24's, the
-    host clock around the whole pass)."""
+    """The nine files ISSUE 38 adds."""
     return sorted(p for p in glob.glob(os.path.join(
         ROOT, "chipbench", "layer_metrics", "cold_*_s.json"))
-        if not p.endswith("cold_pass_s.json"))
+        if os.path.basename(p)[:-5] not in NOT_OF_A_SWEEP)
 
 
 @pytest.mark.parametrize("path", _cold_metric_files(),
@@ -408,3 +412,22 @@ def test_the_certificate_plan_is_a_span_where_it_is_computed(tracer):
     (ev,) = _named(_events(tracer), "build/plan:cert_retention")
     assert ev["args"]["parent_id"] == plan.span_id
     assert ev["args"]["trials"] == 7 and ev["args"]["nchan"] == 64
+    # ISSUE 45's two metrics read these spans of the cold pass: the plan
+    # through ``span_total``, the bound inside it through ``span_union``
+    from chipbench import run as harness
+
+    spans = [(e["ts"] / 1e6, (e["ts"] + e["dur"]) / 1e6, e["name"])
+             for e in _events(tracer)]
+    ctx = {"cold": {"spans": spans, "budget": None}, "passes": []}
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    only = dict(manifest, per_layer=[
+        m for m in manifest["per_layer"]
+        if m["name"] in ("cold_plan_s", "cold_cert_retention_s")])
+    assert len(only["per_layer"]) == 2
+    got = harness.read_layer_metrics(only, manifest["workloads"][0]["name"],
+                                     ctx)
+    assert got["cold_cert_retention_s"]["value"] == pytest.approx(
+        ev["dur"] / 1e6)
+    assert (0.0 < got["cold_cert_retention_s"]["value"]
+            <= got["cold_plan_s"]["value"])

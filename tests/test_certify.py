@@ -6,6 +6,11 @@ the coarse-trust bound).  The larger seeded sweep lives in
 ``tools/hybrid_calibrate.py``; the cases here are its CI-sized core.
 """
 
+import functools
+import json
+import os
+import tempfile
+
 import numpy as np
 import pytest
 
@@ -30,6 +35,8 @@ from pulsarutils_tpu.ops.plan import (
     dedispersion_shifts,
 )
 from pulsarutils_tpu.ops.search import dedispersion_search, nearest_rows
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 GEOM = dict(start_freq=1200.0, bandwidth=200.0, sample_time=0.0005)
 GARGS = (GEOM["start_freq"], GEOM["bandwidth"], GEOM["sample_time"])
@@ -79,7 +86,8 @@ class TestTracks:
 
         nchan, t = 256, 1 << 14
         dms = dedispersion_plan(nchan, 100.0, 200.0, *GARGS)
-        dev = _track_deviations(nchan, dms, *GARGS, t)
+        dev = np.concatenate(list(_track_deviations(nchan, dms, *GARGS, t)))
+        assert dev.shape == (len(dms), nchan)
         spread = dev.max(axis=1) - dev.min(axis=1)
         assert spread.max() <= 4, f"track spread up to {spread.max()}"
 
@@ -491,16 +499,16 @@ class TestLadderCertificate:
         nchan, t = 64, 1 << 12
         dms = dedispersion_plan(nchan, 100.0, 120.0, *GARGS)
         seen = []
-        real = certify._cert_retention_from_offsets
+        real = certify._cert_retention_from_histograms
 
-        def spy(offsets, **kw):
+        def spy(hist, **kw):
             seen.append(kw)
-            return real(offsets, **kw)
+            return real(hist, **kw)
 
-        monkeypatch.setattr(certify, "_cert_retention_from_offsets", spy)
+        monkeypatch.setattr(certify, "_cert_retention_from_histograms", spy)
         ladder = self._ladder(9)  # 256: cut off at 64 for 4,096 samples
         cert_retention(nchan, dms, *GARGS, t, windows=ladder)
-        assert len(seen) == len(dms)
+        assert len(seen) == 1  # every trial of the grid in one call
         assert all(kw["windows"] == scored_windows(ladder, t)
                    == self._ladder(7) for kw in seen)
         assert all(kw["wide"] == cert_wide_windows(ladder, t)
@@ -714,3 +722,329 @@ class TestUncertifiedSweep:
         assert np.asarray(hyb["exact"])[above].all()
         assert np.array_equal(np.asarray(hyb["rebin"])[above],
                               np.asarray(ref["rebin"])[above])
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 45: the bound as array arithmetic.  The plain references are the
+# statements the arithmetic replaced: the dense walk of the merge tables
+# and the bound of one trial at a time.
+# ---------------------------------------------------------------------------
+
+def dense_tracks(plan):
+    """The walk ``fdmt_tracks`` made until PR 45: every row over the whole
+    padded band, with a mask of the channels it covers."""
+    nchp = plan.nchan_padded
+    tracks = np.zeros((nchp, nchp), np.int64)
+    valid = np.eye(nchp, dtype=bool)
+    for it in plan.iterations:
+        tl = tracks[it["idx_low"]] + it["shift"][:, None]
+        th = tracks[it["idx_high"]]
+        if it["shift_high"] is not None:
+            th = th + it["shift_high"][:, None]
+        vl, vh = valid[it["idx_low"]], valid[it["idx_high"]]
+        tracks = np.where(vl, tl, th) * (vl | vh)
+        valid = vl | vh
+    assert valid.all()
+    return tracks
+
+
+def wide_capture_one_trial(mass, wide):
+    n = len(mass)
+    total = float(mass.sum())
+    whole = [w for w in wide if w >= 2 * n]
+    floor = total / np.sqrt(whole[0]) if whole else 0.0
+    partial = [w for w in wide if w < 2 * n]
+    if not partial:
+        return floor
+    csum = np.concatenate([[0.0], np.cumsum(mass)])
+    phases = np.arange(partial[-1] // 2)
+    scores = np.full(len(phases), floor)
+    for w in partial:
+        half = w // 2
+        start = np.arange(-(w - 1), n)
+        sums = csum[np.clip(start + w, 0, n)] - csum[np.clip(start, 0, n)]
+        best = np.zeros(half)
+        np.maximum.at(best, start % half, sums)
+        scores = np.maximum(scores, best[(-phases) % half] / np.sqrt(w))
+    return float(scores.min())
+
+
+def retention_one_trial(offsets, max_width=16, windows=None, wide=()):
+    """``certify._cert_retention_from_offsets`` as it stood until PR 45:
+    one trial's offsets, NumPy's own convolutions and median."""
+    from pulsarutils_tpu.ops.certify import (_exact_best_phase,
+                                             _wide_retention_table,
+                                             _windows)
+    from pulsarutils_tpu.ops.search import CERT_WINDOWS
+
+    offsets = np.asarray(offsets, dtype=np.int64)
+    offsets = offsets - offsets.min()
+    h = np.zeros(int(offsets.max()) + 1)
+    np.add.at(h, offsets, 1.0 / len(offsets))
+
+    def sliding_capture(mass, w):
+        if len(mass) <= w:
+            return mass.sum()
+        return np.convolve(mass, np.ones(w)).max()
+
+    ladder = _windows(windows)
+    if wide:
+        max_width = min(max_width, 2 * ladder[-1])
+    worst = np.inf
+    for width in range(1, max_width + 1):
+        mass = np.convolve(h, np.full(width, 1.0 / width))
+        cert = max(sliding_capture(mass, w) / np.sqrt(w)
+                   for w in CERT_WINDOWS)
+        if wide:
+            cert = max(cert, wide_capture_one_trial(mass, wide))
+        worst = min(worst, cert / _exact_best_phase(width, windows))
+    if wide and max_width < 2 * ladder[-1]:
+        score, per_sample, exact = _wide_retention_table(
+            ladder, tuple(wide), max_width + 1)
+        deviation = float(np.abs(offsets - np.median(offsets)).mean())
+        cert = (score - deviation * per_sample).max(axis=1)
+        worst = min(worst, float((cert / exact).min()))
+    return float(worst)
+
+
+#: what the parent commit (PR 44) resolves for every benchmark
+#: configuration, from one run of its ``plan_survey`` on a file of two
+#: chunks whose path reads ``/survey/<name>.fil``: the ledger fingerprint,
+#: and per tier (one for a flat plan) downsample, trials, the resolved
+#: ``snr_threshold`` = ``search_snr_floor``, and the retention bound
+PARENT_PLANS = {
+    "rehearsal_1024ch_2bit": ("b60734d25f7e7d96", [
+        (1, 154, 12.95, 0.5598718918997054)]),
+    "htru_bpsr_lowdm": ("a94c61c8db3a598b", [
+        (1, 1067, 13.41, 0.5553613429216615)]),
+    "htru_bpsr_fulldm": ("0bc8df4735b16b76", [
+        (1, 1069, 13.41, 0.5553613429216615),
+        (2, 534, 13.01, 0.5553613429216615),
+        (4, 534, 12.81, 0.5553613429216615),
+        (8, 534, 12.6, 0.5553613429216615),
+        (16, 534, 12.39, 0.5553613429216615),
+        (32, 107, 11.29, 0.5728397202115818)]),
+    "htru_bpsr_fulldm_boxcar4096": ("08c1a26e98448775", [
+        (1, 1069, 13.41, 0.5553613429216615),
+        (2, 534, 13.01, 0.5553613429216615),
+        (4, 534, 12.81, 0.5553613429216615),
+        (8, 534, 12.6, 0.5553613429216615),
+        (16, 534, 12.39, 0.5553613429216615),
+        (32, 107, 11.29, 0.5728397202115818)]),
+    "meertrap_lband_8bit": ("69f6b8bd90a25a47", [
+        (1, 5183, 13.89, 0.5383058295984329),
+        (2, 2591, 13.48, 0.5383058295984329),
+        (4, 2591, 13.27, 0.5383058295984329),
+        (8, 876, 12.37, 0.5532470230882034)]),
+    "meertrap_lband_8bit_fulldm": ("cba5e027161b7932", [
+        (1, 5183, 14.29, 0.5383058295984329),
+        (2, 2591, 13.89, 0.5383058295984329),
+        (4, 2591, 13.69, 0.5383058295984329),
+        (8, 2591, 13.48, 0.5383058295984329),
+        (16, 1846, 13.17, 0.5383058295984329)]),
+    "parkes_uwl_2bit": ("5c97f5269e1c9273", [
+        (1, 12985, 15.08, 0.5055284508469375),
+        (2, 6492, 14.65, 0.5055284508469375),
+        (4, 1, 8.67, 0.6552636157389882)]),
+}
+
+TINY_CONFIGS = ("tiny_cpu_rehearsal", "tiny_cpu_tiers", "tiny_cpu_boxcar",
+                "tiny_cpu_8bit", "tiny_cpu_8bit_fulldm", "tiny_cpu_uwl")
+
+
+@functools.lru_cache(maxsize=None)
+def survey_plan(name):
+    """``plan_survey`` of a ``chipbench`` configuration as ``run.py`` types
+    it, on a file of two chunks that holds a header and no data (the
+    planner reads nothing else), and what it asked the bound for, one
+    entry a searched geometry: ``(plan, [(nchan, trial_dms, fbottom,
+    bandwidth, tsamp, samples, scored ladder or None), ...])``."""
+    from chipbench import generate
+    from pulsarutils_tpu.ops import certify
+    from pulsarutils_tpu.pipeline import search_pipeline
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           name + ".json")) as f:
+        cfg = json.load(f)
+    flags = cfg["cli_flags"]
+    assert flags[:4] == ["--kernel", "hybrid", "--snr-threshold",
+                         "certifiable"]
+    header = generate.sigproc_header(cfg)
+    frames = 2 * cfg["chunk_samples"]
+    asked = []
+    bound = certify._retention_cached
+
+    def spy(nchan, dms_key, fbottom, bandwidth, tsamp, t, min_width, cert,
+            windows=None):
+        assert cert and min_width == 1
+        geometry = (nchan, dms_key, fbottom, bandwidth, tsamp, t, windows)
+        if geometry not in asked:  # asked twice a tier: the second is cached
+            asked.append(geometry)
+        return bound(nchan, dms_key, fbottom, bandwidth, tsamp, t,
+                     min_width, cert, windows)
+
+    with tempfile.TemporaryDirectory() as tmp, \
+            pytest.MonkeyPatch.context() as patch:
+        path = os.path.join(tmp, name + ".fil")
+        with open(path, "wb") as f:
+            f.write(header)
+            f.truncate(len(header)
+                       + frames * cfg["nchans"] * cfg["nbits"] // 8)
+        # the fingerprint names the file by its absolute path
+        patch.setattr(search_pipeline.os.path, "abspath",
+                      lambda p: f"/survey/{name}.fil")
+        patch.setattr(certify, "_retention_cached", spy)
+        plan = search_pipeline.plan_survey(
+            path, chunk_length=cfg["chunk_samples"] // 2 * cfg["tsamp_s"],
+            dmmin=cfg["dmmin"], dmmax=cfg["dmmax"], kernel="hybrid",
+            snr_threshold="certifiable", zero_dm="--zero-dm" in flags,
+            dm_tiers="smearing" if "--dm-tiers" in flags else None,
+            boxcar_max=(int(flags[flags.index("--boxcar-max") + 1])
+                        if "--boxcar-max" in flags else None))
+    return plan, [(nchan, np.frombuffer(key, np.float64), *rest)
+                  for nchan, key, *rest in asked]
+
+
+def coarse_plan_of(nchan, trial_dms, start_freq, bandwidth, sample_time):
+    _, n_lo, n_hi = fdmt_trial_dms(nchan, float(np.min(trial_dms)),
+                                   float(np.max(trial_dms)), start_freq,
+                                   bandwidth, sample_time)
+    return fdmt_plan(nchan, float(start_freq), float(bandwidth), n_hi, n_lo)
+
+
+class TestBandLocalWalk:
+    """``fdmt_tracks`` carries a row's track over the band it covers; the
+    array it returns is the dense walk's, element for element."""
+
+    @pytest.mark.parametrize("nchan,lo,hi,geom", [
+        pytest.param(100, 0, 300, GARGS[:2], id="odd_channel_count"),
+        pytest.param(84, 7, 90, GARGS[:2], id="not_a_multiple_of_8"),
+        # Parkes' UWL in 52 = 13 x 4 channels: 3,328 scaled by 64
+        pytest.param(52, 0, 202, (704.0, 3328.0), id="band_of_13_x_4"),
+        pytest.param(208, 0, 400, (704.0, 3328.0), id="band_of_13_x_16"),
+        pytest.param(128, 310, 640, GARGS[:2], id="pruned_range"),
+        pytest.param(64, 55, 55, GARGS[:2], id="one_row"),
+        pytest.param(1, 0, 0, GARGS[:2], id="one_channel"),
+    ])
+    def test_equal_to_the_dense_walk(self, nchan, lo, hi, geom):
+        plan = fdmt_plan(nchan, *geom, hi, lo)
+        tracks = fdmt_tracks(plan)
+        assert tracks.dtype == np.int64
+        assert tracks.shape == (hi - lo + 1, plan.nchan_padded)
+        assert np.array_equal(tracks, dense_tracks(plan))
+        narrow = fdmt_tracks(plan, np.int32)
+        assert narrow.dtype == np.int32 and np.array_equal(narrow, tracks)
+
+    @pytest.mark.parametrize("name", TINY_CONFIGS + (
+        "rehearsal_1024ch_2bit", "htru_bpsr_lowdm"))
+    def test_equal_on_a_configuration_s_tiers(self, name):
+        _, geometries = survey_plan(name)
+        for nchan, dms, fbottom, bandwidth, tsamp, _, _ in geometries:
+            plan = coarse_plan_of(nchan, dms, fbottom, bandwidth, tsamp)
+            assert np.array_equal(fdmt_tracks(plan), dense_tracks(plan))
+
+
+class TestBoundOfEveryTrialAtOnce:
+    """The bound of a tier's trials in one call equals the bound of each,
+    computed alone as the parent computed it, to 1e-12."""
+
+    @staticmethod
+    def _check(dev, windows=None, wide=()):
+        from pulsarutils_tpu.ops.certify import _cert_retention_from_offsets
+
+        together = _cert_retention_from_offsets(dev, windows=windows,
+                                                wide=wide)
+        assert together.shape == (len(dev),)
+        alone = np.asarray([retention_one_trial(d, windows=windows,
+                                                wide=wide) for d in dev])
+        np.testing.assert_allclose(together, alone, rtol=1e-12, atol=0)
+        # and one trial through the same function is a float, as ever
+        one = _cert_retention_from_offsets(dev[0], windows=windows,
+                                           wide=wide)
+        assert isinstance(one, float) and one == together[0]
+        return together
+
+    @pytest.mark.parametrize("name,tier", [
+        pytest.param(name, k, id=f"{name}-tier{k}")
+        for name, (_, tiers) in PARENT_PLANS.items()
+        for k in range(len(tiers))])
+    def test_on_a_benchmark_tier_s_own_tracks(self, name, tier):
+        """Every benchmark configuration's real geometry and ladder (the
+        default four, 4,096 / 2^k and 2,048 / 2^k), every 37th trial and
+        both ends."""
+        from pulsarutils_tpu.ops.certify import _track_deviations
+        from pulsarutils_tpu.ops.search import cert_wide_windows
+
+        _, geometries = survey_plan(name)
+        nchan, dms, fbottom, bandwidth, tsamp, t, windows = geometries[tier]
+        assert len(dms) == PARENT_PLANS[name][1][tier][1]
+        some = np.unique(np.concatenate([dms[::37], dms[-1:]]))
+        dev = np.concatenate(list(_track_deviations(
+            nchan, some, fbottom, bandwidth, tsamp, t)))
+        assert dev.shape == (len(some), nchan)
+        if windows is None:
+            self._check(dev)
+        else:
+            self._check(dev, windows=windows,
+                        wide=cert_wide_windows(windows, t))
+
+    @pytest.mark.parametrize("ladder", [
+        None, 6, 9, 12, 13], ids=lambda n: f"ladder_{n}")
+    def test_trials_of_several_spans_in_one_batch(self, ladder):
+        """Scatters of one to nine bins, with empty bins inside, an even
+        and an odd channel count (the median's two cases)."""
+        rng = np.random.default_rng(45)
+        windows = None if ladder is None else tuple(
+            1 << j for j in range(ladder))
+        wide = () if ladder is None else tuple(w for w in windows if w >= 8)
+        for nchan in (64, 51):
+            dev = np.stack([rng.integers(0, span, nchan) * step + shift
+                            for span, step, shift in
+                            [(1, 1, 0), (2, 1, -3), (3, 1, 5), (3, 2, 0),
+                             (5, 1, -1), (5, 2, 7), (2, 1, 0), (9, 1, 2),
+                             (4, 1, 0), (1, 1, 9)]])
+            rho = self._check(dev, windows=windows, wide=wide)
+            assert rho[0] == rho[-1]  # no scatter, wherever the track lies
+
+    def test_mean_abs_deviation_is_numpy_s(self):
+        from pulsarutils_tpu.ops.certify import (_mean_abs_deviation,
+                                                 _offset_histograms)
+
+        rng = np.random.default_rng(7)
+        for nchan in (8, 9, 100, 3328):
+            dev = rng.integers(-3, 4, (40, nchan))
+            dev[0] = 2  # one bin
+            dev[1, : nchan // 2] = 0  # the median between two bins
+            dev[1, nchan // 2:] = 3
+            hist = _offset_histograms(iter([dev[:25], dev[25:]]))
+            assert hist.shape[0] == 40 and (hist.sum(axis=1) == nchan).all()
+            want = [np.abs(d - np.median(d)).mean() for d in dev]
+            assert np.array_equal(_mean_abs_deviation(hist), want)
+
+
+class TestResolvedPlansAreTheParents:
+    """What a survey resolves from the bound is the parent's to the
+    letter: a resume ledger is not orphaned, and a fleet coordinator and
+    its workers still meet."""
+
+    @pytest.mark.parametrize("name", list(PARENT_PLANS))
+    def test_thresholds_floors_and_fingerprint(self, name):
+        fingerprint, parent = PARENT_PLANS[name]
+        plan, geometries = survey_plan(name)
+        assert plan["fingerprint"] == fingerprint
+        if plan["tiers"] is None:
+            got = [(1, len(geometries[0][1]), plan["snr_threshold"],
+                    plan["search_snr_floor"])]
+        else:
+            got = [(t["tier"].downsample, len(t["tier"].trial_dms),
+                    t["snr_threshold"], t["search_snr_floor"])
+                   for t in plan["tiers"]]
+        assert got == [(d, n, thr, thr) for d, n, thr, _ in parent]
+        assert (plan["snr_threshold"], plan["search_snr_floor"]) == (
+            parent[0][2], parent[0][2])
+        for (nchan, dms, fbottom, bandwidth, tsamp, t, windows), want in zip(
+                geometries, parent):
+            rho = cert_retention(nchan, dms, fbottom, bandwidth, tsamp, t,
+                                 windows=windows)
+            assert rho.shape == (len(dms),)
+            assert rho.min() == pytest.approx(want[3], rel=1e-12)
