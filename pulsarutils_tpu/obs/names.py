@@ -428,6 +428,7 @@ BUDGET_COUNTERS = frozenset({
     "readbacks",
     "rescore_calls",
     "rescore_rows",
+    "uploads_ready",
 })
 
 #: names the device side carries (ISSUE 25): the ``name=`` of every

@@ -222,6 +222,9 @@ class _NullAsync:
     def end(self, **attrs):
         pass
 
+    def follow(self, name, **attrs):
+        return self
+
 
 _NULL_ASYNC = _NullAsync()
 
@@ -234,7 +237,7 @@ class AsyncSpan:
     __slots__ = ("name", "attrs", "track", "t0", "_tracer", "_id",
                  "parent_id", "_ctx", "_done")
 
-    def __init__(self, name, attrs, track, tracer):
+    def __init__(self, name, attrs, track, tracer, after=None):
         self.name = name
         self.attrs = attrs
         self.track = track
@@ -242,12 +245,21 @@ class AsyncSpan:
         self._id = tracer.next_id()
         # caused by the span open where it begins; it may end on a
         # thread that inherits no context, so parent and trace context
-        # are taken here
-        self.parent_id = _OPEN_SPAN.get()
-        self._ctx = _TRACE_CTX.get()
+        # are taken here (or from the span it follows, see ``follow``)
+        self.parent_id = (_OPEN_SPAN.get() if after is None
+                          else after.parent_id)
+        self._ctx = _TRACE_CTX.get() if after is None else after._ctx
         self._done = False
         self.t0 = time.perf_counter()
         tracer.async_begin(self)
+
+    def follow(self, name, **attrs):
+        """Begin NOW the span of what a worker does next on this span's
+        track (a chunk's read, then its upload): same tracer, track,
+        parent and trace context, from whichever thread calls — a
+        worker thread has none of them to take."""
+        return AsyncSpan(name, attrs or None, self.track, self._tracer,
+                         after=self)
 
     def end(self, **attrs):
         """Complete the span (idempotent; safe after the tracer stopped)."""

@@ -1232,8 +1232,9 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                             "dispatch+readback trip", timer.rtt_s)
 
     # the chunk list is known upfront, so the NEXT chunk's read/decode
-    # overlaps the current chunk's device compute (single reader thread —
-    # the driver host is often one core doing nothing during the search)
+    # and then its upload overlap the current chunk's device compute
+    # (single reader thread, one chunk ahead — the driver host is often
+    # one core doing nothing during the search; see ``read_at``)
     todo = [s for s in sp["chunk_starts"]
             if not (resume and store.is_done(s))]
     if chunks is not None:
@@ -1330,7 +1331,7 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
 
     from concurrent.futures import ThreadPoolExecutor
 
-    def read_at(s, rspan):
+    def read_gated(s, rspan):
         """Read (and gate) one chunk on the reader thread.
 
         Returns ``(block, gate_info)`` — ``gate_info`` is ``None`` when
@@ -1426,45 +1427,67 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             timer.add_async("read_decode", time.perf_counter() - t0)
             rspan.end()
 
+    def upload(host):
+        """Start the (asynchronous) host->device transfer of a chunk."""
+        import jax
+
+        buf = jax.device_put(host)
+        obs_metrics.counter("putpu_bytes_uploaded_total").inc(
+            int(getattr(host, "nbytes", 0)))
+        return buf
+
+    def read_at(s, rspan):
+        """One chunk on the reader thread: read and gated
+        (:func:`read_gated`), then its upload started.
+
+        Returns ``(block, gate_info, buf)``.  The transfer of a chunk is
+        the continuation of its read: where there is a device clean and
+        the chunk may go up as it is, the device buffer ``buf`` comes
+        back with the block, so the link works under the chunk BEFORE's
+        search whenever the read ends, and the reader's next task waits
+        for the transfer (span ``upload`` on track ``reader``,
+        ``async_s.upload``) before it reads on — two transfers never
+        share the link.  A failed, sanitized or quarantined chunk never
+        goes up from here (the main path handles it, and must never
+        upload the un-sanitized bytes), and a put that fails is
+        non-fatal: ``buf`` is ``None`` and the main path uploads.  COST:
+        peak HBM carries one extra raw chunk.  The budget's counters are
+        taken on the main thread, where it takes the buffer.
+        """
+        block, gate_info = read_gated(s, rspan)
+        buf = None
+        if device_clean is not None \
+                and not isinstance(block, _ReadFailure) \
+                and (gate_info is None or gate_info["verdict"] == "clean"):
+            uspan = rspan.follow("upload", chunk=s)
+            t0 = time.perf_counter()
+            try:
+                buf = upload(block)
+                # queued before this read's future resolves, so ahead of
+                # the next read
+                reader_pool.submit(await_upload, buf, uspan, t0)
+            except Exception:
+                # the put failed, or the pool is already shut down (a
+                # cancel): the main path uploads, or nobody needs it
+                buf = None
+                uspan.end()
+        return block, gate_info, buf
+
+    def await_upload(buf, uspan, t0):
+        try:
+            buf.block_until_ready()
+        except Exception:
+            pass  # surfaces on the main thread's forced read-back
+        finally:
+            timer.add_async("upload", time.perf_counter() - t0)
+            uspan.end()
+
     def submit_read(s):
         # begun here, on the main thread: the pool's thread inherits
         # neither the trace id nor the open span
-        # putpu-lint: disable=span-leak — ends in read_at on the reader thread (cross-thread by design)
+        # putpu-lint: disable=span-leak — ends in read_gated on the reader thread (cross-thread by design)
         rspan = begin_span("read_decode", track="reader", chunk=s)
         return reader_pool.submit(read_at, s, rspan)
-
-    def prefetch_upload(read_future):
-        """Start the async device transfer of the NEXT chunk (main thread).
-
-        Called right before the current chunk's (blocking) search: by then
-        the reader thread has usually finished decoding chunk k+1, so its
-        host->device transfer proceeds while the device searches chunk k —
-        on slow links the transfer dominates the whole stream.  COST: peak
-        HBM briefly carries one extra raw chunk; a failure here is
-        non-fatal (the main path re-uploads).  All device ops stay on the
-        main thread.
-        """
-        if device_clean is None or read_future is None \
-                or not read_future.done():
-            return None
-        try:
-            import jax
-
-            host, gate_info = read_future.result()
-            if isinstance(host, _ReadFailure) or (
-                    gate_info is not None
-                    and gate_info["verdict"] != "clean"):
-                # failed/sanitized/quarantined chunks skip the prefetch:
-                # the main path handles them (and must never upload the
-                # un-sanitized bytes)
-                return None
-            buf = jax.device_put(host)
-            timer.count("prefetch_uploads")
-            obs_metrics.counter("putpu_bytes_uploaded_total").inc(
-                int(getattr(host, "nbytes", 0)))
-            return buf
-        except Exception:
-            return None
 
     # persist executor (round 6): one FIFO worker absorbs the per-chunk
     # candidate write + ledger write so it overlaps the NEXT
@@ -1762,7 +1785,6 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
 
     reader_pool = ThreadPoolExecutor(max_workers=1)
     next_read = submit_read(todo[0]) if todo else None
-    array_dev = None  # chunk's prefetched device buffer (if any)
     call_phase.close()  # call/setup ends where the first chunk starts
     try:
         for ichunk, istart in enumerate(todo):
@@ -1781,7 +1803,8 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             t0 = istart * sample_time
 
             with with_timer("read"):
-                array, gate_info = next_read.result()
+                # array_dev: the device buffer the reader started, if any
+                array, gate_info, array_dev = next_read.result()
             next_read = (submit_read(todo[ichunk + 1])
                          if ichunk + 1 < len(todo) else None)
 
@@ -1826,7 +1849,6 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                     with with_timer("persist"):
                         _persist_and_mark(None, istart, iend, ck,
                                           reason=quarantine_reason)
-                array_dev = None  # drop any prefetched device copy
                 nproc += 1
                 if canary is not None:
                     # the chunk never reaches the search: its pending
@@ -1851,20 +1873,22 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                             - array.nbytes))
                 with with_timer("upload_wait"):
                     try:
-                        import jax as _jax
-
                         if array_dev is None:
-                            src = _jax.device_put(array)
-                            obs_metrics.counter(
-                                "putpu_bytes_uploaded_total").inc(
-                                int(getattr(array, "nbytes", 0)))
+                            src = upload(array)
                         else:
-                            src = array_dev
+                            # no second name keeps the raw bytes alive
+                            # past the clean that consumes them
+                            src, array_dev = array_dev, None
+                            timer.count("prefetch_uploads")
+                            if src.is_ready():
+                                # hidden whole under the chunk before
+                                timer.count("uploads_ready")
                         # force the async host->device transfer HERE so
-                        # link time has its own bucket: un-forced, the
-                        # wait surfaces inside whatever device op blocks
-                        # next (the round-5 rehearsal's "search" stage
-                        # silently absorbed the next chunk's upload)
+                        # link time has its own bucket, which holds what
+                        # is left of it: un-forced, the wait surfaces
+                        # inside whatever device op blocks next (the
+                        # round-5 rehearsal's "search" stage silently
+                        # absorbed the next chunk's upload)
                         np.asarray(src[:1, :1])
                         timer.count("readbacks")
                     except Exception as exc:
@@ -1916,10 +1940,6 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                         host_raw = reader.unpack_frames(
                             host_raw, band_ascending=True)
                     array = _clean(host_raw, mask)
-
-            # overlap: start chunk k+1's async upload before chunk k's
-            # blocking search (see prefetch_upload)
-            array_dev = prefetch_upload(next_read)
 
             if lineage is not None:
                 lineage.mark(istart, "dispatch")
@@ -2276,12 +2296,6 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                                                    ck)
                     _lineage_finish(cl, istart, iend, payload,
                                     reason_out)
-            # second prefetch window: by the end of the iteration the
-            # reader has had the whole search/persist to finish decoding
-            # chunk k+1, so this attempt usually fires even when the
-            # pre-search one found the read still in flight
-            if array_dev is None:
-                array_dev = prefetch_upload(next_read)
             mem_snap = None
             if fallback_state.get("backend", backend) == "jax":
                 # per-chunk device-memory watermark: HBM headroom is a
