@@ -18,3 +18,14 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
+
+
+def pytest_configure(config):
+    # ``testpaths`` does not apply when a path is typed, and tier-1 types
+    # ``tests/``: the benchmark's own tests ride along with it, so the
+    # yardstick is guarded where the floor is kept
+    here = os.path.dirname(os.path.abspath(__file__))
+    given = {os.path.abspath(a.split("::")[0]) for a in config.args}
+    yardstick = os.path.join(here, "chipbench", "tests")
+    if os.path.join(here, "tests") in given and yardstick not in given:
+        config.args.append(yardstick)

@@ -14,7 +14,7 @@ only in reviewer memory; this package makes them machine-checked
                        only under it (PRs 3-5)
 ``metric-name-*``      every ``putpu_*`` literal resolves against the
                        ``obs/names.py`` manifest, and the manifest covers
-                       the docs + committed gate baseline (PR 3); every
+                       the docs (PR 3); every
                        ``pallas_call`` is named from its ``KERNEL_NAMES``
                        (``kernel-name-unknown``, ISSUE 25)
 ``broad-except``       broad handlers only in the reviewed containment-seam
@@ -23,7 +23,7 @@ only in reviewer memory; this package makes them machine-checked
 =====================  =====================================================
 
 Stdlib-only and jax-free by design: the linter runs on bare CI
-checkouts, inside ``tools/perf_gate.py`` and as a tier-1 test.  See
+checkouts and as a tier-1 test.  See
 ``docs/static_analysis.md`` for the workflow (inline waivers,
 committed baseline, adding a checker).
 """

@@ -9,9 +9,7 @@ Usage (the committed-tree invariant the test suite pins)::
 Exit codes: 0 clean (no new findings), 1 new findings, 2 usage errors.
 "New" means not inline-waived and not in the committed baseline
 (``.putpu-lint-baseline.json`` at the project root, ``--no-baseline``
-to see everything).  ``tools/perf_gate.py`` refuses to PASS unless this
-exits clean, and ``bench_suite.py --configs 11`` wraps it as the
-fast-config lint record.
+to see everything).
 """
 
 from __future__ import annotations
@@ -39,8 +37,8 @@ def default_root():
 
 def run_lint(paths=None, root=None, select=None, use_baseline=True,
              baseline_path=None):
-    """Programmatic entry (perf_gate, bench_suite, tests): lint and
-    return the :class:`~.core.LintProject`."""
+    """Programmatic entry (the tests'): lint and return the
+    :class:`~.core.LintProject`."""
     # root follows the SCANNED tree, not this package's checkout — under
     # pip install (or linting a different project) the baseline and the
     # names.py manifest must resolve against the tree being linted
@@ -86,8 +84,7 @@ def main(argv=None):
     parser.add_argument("--format", choices=("text", "json"),
                         default="text")
     parser.add_argument("--out", metavar="PATH",
-                        help="also write the JSON run report to PATH "
-                             "(the artifact tools/perf_gate.py checks)")
+                        help="also write the JSON run report to PATH")
     parser.add_argument("--baseline", metavar="PATH", default=None,
                         help=f"baseline file (default <root>/"
                              f"{BASELINE_NAME})")

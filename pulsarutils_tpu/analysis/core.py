@@ -2,8 +2,7 @@
 
 The framework is deliberately small and stdlib-only (``ast`` +
 ``tokenize``): it must be importable — and fast — with no JAX backend,
-because it runs in CI, inside ``tools/perf_gate.py`` and as a tier-1
-test over the whole tree.
+because it runs in CI and as a tier-1 test over the whole tree.
 
 Concepts
 --------
@@ -224,7 +223,7 @@ class LintProject:
     """One lint run: scan files, apply waivers, collect findings.
 
     ``root`` is the project root used by cross-file checkers to locate
-    artifacts (the manifest, docs, the committed gate baseline);
+    artifacts (the manifest, the docs);
     ``manifest_names``/``dynamic_names`` override the manifest for
     fixture tests.
     """
@@ -313,7 +312,7 @@ class LintProject:
                                sources=self.sources)
 
     def report(self):
-        """JSON-ready run report (the artifact the perf gate checks)."""
+        """JSON-ready run report (what ``--out`` writes)."""
         findings = sorted(self.findings,
                           key=lambda f: (f.path, f.line, f.checker))
         return {

@@ -1,8 +1,8 @@
 """Metric/span name-drift checker (``metric-name-*``).
 
-The ``putpu_*`` namespace is an external contract: the perf gate's
-committed baselines, the observability docs and any deployed Prometheus
-scrape configs all reference these names by string.  PR 3 grew them
+The ``putpu_*`` namespace is an external contract: the observability
+docs and any deployed Prometheus scrape configs reference these names
+by string.  PR 3 grew them
 organically as literals; :mod:`pulsarutils_tpu.obs.names` is now the
 single source of truth, and this checker enforces both directions:
 
@@ -17,8 +17,8 @@ single source of truth, and this checker enforces both directions:
   file emits: a stale entry, or a renamed metric whose manifest row was
   left behind.
 * ``metric-name-unknown-ref`` (finalize) — a ``putpu_*`` token in the
-  docs, README or the committed gate baseline that the manifest does
-  not declare: the doc (or baseline) references a series nothing emits.
+  docs or README that the manifest does not declare: the doc references
+  a series nothing emits.
 
 * ``kernel-name-unknown`` (per file) — a ``pallas_call(...)`` without a
   literal ``name=`` declared in the manifest's ``KERNEL_NAMES``: device
@@ -42,7 +42,7 @@ from .core import dotted_name, register
 _METRIC_CALLS = {"counter", "gauge", "histogram"}
 _NAME_RE = re.compile(r"putpu_[A-Za-z0-9_]+")
 #: project artifacts whose putpu_* references must resolve
-_REFERENCE_GLOBS = ("README.md", "BENCH_GATE_cpu.jsonl", "docs")
+_REFERENCE_GLOBS = ("README.md", "docs")
 #: non-metric putpu_ identifiers (contextvars, file prefixes) that may
 #: appear in prose — never emitted, never an error
 _PROSE_ALLOWED = {"putpu_budget", "putpu_trace_track", "putpu_plane_",
@@ -204,7 +204,7 @@ class NameDriftChecker:
                         project, "metric-name-unemitted",
                         f"manifest declares {name!r} but no scanned "
                         "file emits it — stale entry or renamed metric"))
-        # direction 2: docs/baseline references resolve
+        # direction 2: docs references resolve
         for path, line, name in self._references(project):
             if name in _PROSE_ALLOWED:
                 continue
@@ -226,8 +226,7 @@ class NameDriftChecker:
         return Finding(
             path=path, line=line, col=0, checker="metric-name-unknown-ref",
             message=f"{name!r} referenced here is not declared in "
-                    "obs/names.py — the doc/baseline names a series "
-                    "nothing emits")
+                    "obs/names.py — the doc names a series nothing emits")
 
     def _references(self, project):
         root = project.root
@@ -240,7 +239,7 @@ class NameDriftChecker:
                 targets.append(path)
             elif os.path.isdir(path):
                 for name in sorted(os.listdir(path)):
-                    if name.endswith((".md", ".jsonl")):
+                    if name.endswith(".md"):
                         targets.append(os.path.join(path, name))
         for path in targets:
             rel = os.path.relpath(path, root).replace(os.sep, "/")

@@ -16,7 +16,7 @@ DataParallel stacking discipline of SNIPPETS.md [2][3]):
   same shapes, same float association — pinned for both formulations
   in ``tests/test_beams.py``);
 * device dispatches per beam-chunk drop ~Nx (one program + one packed
-  readback per N-beam batch; bench_suite config 13 measures it);
+  readback per N-beam batch; ``tests/test_beams.py`` pins the count);
 * the dedisperse formulation is resolved by the kernel autotuner under
   a batch-specific geometry key (``…|b<N>`` —
   :func:`~pulsarutils_tpu.tuning.geometry.geometry_key`), so a batched

@@ -17,8 +17,7 @@ driver's contracts:
   and persisted candidates) are byte-identical between
   ``batched=True`` and the sequential arm (``batched=False`` searches
   beam-by-beam through the same single-beam compiled kernel) — the
-  PR 2 discipline, pinned in ``tests/test_beams.py`` and gated by
-  bench_suite config 13;
+  PR 2 discipline, pinned in ``tests/test_beams.py``;
 * **per-beam canary** — ``canary_rate`` arms one
   :class:`~pulsarutils_tpu.obs.canary.CanaryController` per beam with
   the beam's label, so each beam injects its own deterministic chunk

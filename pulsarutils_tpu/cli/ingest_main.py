@@ -11,8 +11,8 @@ Two subcommands, one per end of the wire:
   and runs the streaming search on them as they arrive.
 
 A loopback pair — ``PUingest listen`` in one shell, ``PUingest feed``
-in another — reproduces the disk search byte-for-byte (bench config
-23 pins that identity).
+in another — reproduces the disk search byte-for-byte
+(``tests/test_ingest.py`` pins that identity).
 """
 
 from __future__ import annotations

@@ -12,7 +12,7 @@ import os
 
 from ..obs import trace
 from ..pipeline.search_pipeline import search_by_chunks
-from ..utils.logging_utils import logger
+from ..utils.logging_utils import BUDGET_SCHEMA_VERSION, logger
 
 
 def build_parser():
@@ -324,7 +324,6 @@ def main(args=None):
     logger.info("total candidates: %d (%d raw detections)",
                 total_cands, total_raw)
     if opts.metrics_out:
-        from ..obs.gate import SCHEMA_VERSION
         from ..obs.metrics import REGISTRY
 
         if opts.metrics_out.endswith(".prom"):
@@ -333,7 +332,7 @@ def main(args=None):
             n = REGISTRY.write_prometheus(opts.metrics_out)
         else:
             n = REGISTRY.write_jsonl(opts.metrics_out,
-                                     schema_version=SCHEMA_VERSION)
+                                     schema_version=BUDGET_SCHEMA_VERSION)
         logger.info("metrics: %d lines -> %s", n, opts.metrics_out)
     if _degraded_counts() > degraded_before:
         # everything above was persisted as usual; the status says the

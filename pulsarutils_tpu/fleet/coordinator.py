@@ -221,7 +221,7 @@ class FleetCoordinator:
         #: and costs one fold per completion); the detector, gauges,
         #: scaling advice and ``fleet_saturated`` condition only run
         #: when ``capacity=True`` — and none of it touches science
-        #: bytes either way (pinned by tests + bench config 24).
+        #: bytes either way (pinned by tests/test_capacity.py).
         self.capacity_enabled = bool(capacity)
         self.health = health
         self.capacity_model = CapacityModel()

@@ -13,8 +13,8 @@ listening socket closes, the reader joins within a bounded timeout,
 and the assembler is flushed so the consumer's iterator ends.
 
 Send side — :func:`feed_tcp` / :func:`feed_udp` / :func:`feed_file`
-stream a list of encoded packets for the bench A/B arms, the chaos
-drill and the ``PUingest feed`` CLI.  The ``ingest`` fault site fires
+stream a list of encoded packets for the tests, the chaos drill and
+the ``PUingest feed`` CLI.  The ``ingest`` fault site fires
 here, per packet: ``drop`` loses it, ``reorder`` swaps it with its
 successor, ``duplicate`` sends it twice, ``corrupt`` flips payload
 bytes (the receiver's CRC rejects it — a gap, never poisoned data),
@@ -47,7 +47,7 @@ class _SourceBase:
     ``idle_timeout_s`` (optional) ends the session from the *feed*
     side: once at least one packet has arrived, a quiet wire for that
     long stops the reader and flushes the assembler, so a blocking
-    consumer (``PUingest listen``, the bench feed arm) terminates
+    consumer (``PUingest listen``) terminates
     without an operator ``close()``.  ``None`` (default) listens
     forever — the service posture."""
 

@@ -135,7 +135,7 @@ class CandidateStore:
           already completes chunks in ascending order, so its ledger
           bytes are unchanged, while N workers completing interleaved
           subsets of one file converge on the identical file (the
-          byte-identity contract bench config 14 gates);
+          byte-identity contract ``tests/test_fleet.py`` pins);
         * each write **merges with the on-disk ledger** first.  Two
           sessions share a ledger only in the work-stealing edge — a
           stalled worker's lease expires, its remaining chunks are

@@ -137,10 +137,9 @@ def simulate_accel_pulsar_data(freq=60.0, dm=150.0, accel=0.0,
     jerk)`` (sign convention pinned by
     ``tests/test_period_backend.py``).  ``floor`` adds a constant
     offset so unsigned-integer quantisation in a written filterbank
-    keeps the noise floor.  One generator serves the chaos drill,
-    bench configs 17/20 and the tests — the injection physics must
-    never fork (drifting ground truths between the drill and the perf
-    gate would gate different claims).
+    keeps the noise floor.  One generator serves the chaos drill and
+    the tests — the injection physics must never fork (drifting ground
+    truths between the two would hold them to different claims).
     """
     rng = np.random.default_rng(rng) \
         if not isinstance(rng, np.random.Generator) else rng

@@ -1,5 +1,4 @@
-"""Survey telemetry: span tracing, metrics registry, memory accounting,
-and the perf-regression gate's comparison logic.
+"""Survey telemetry: span tracing, metrics registry and memory accounting.
 
 Three pillars (ISSUE 3):
 
@@ -12,9 +11,6 @@ Three pillars (ISSUE 3):
 * :mod:`.metrics` — process-wide counters / gauges / histograms with
   JSONL and Prometheus-textfile exporters;
 * :mod:`.memory` — device-memory watermarks per chunk.
-
-:mod:`.gate` holds the perf-regression comparison consumed by
-``tools/perf_gate.py``.
 
 The **live surface** (ISSUE 5) builds on those pillars:
 
@@ -41,7 +37,7 @@ Everything here is dependency-light (stdlib + lazy jax) and safe to
 import before a JAX backend exists.
 """
 
-from . import gate, memory, metrics, trace
+from . import memory, metrics, trace
 from .metrics import REGISTRY
 from .trace import (begin_span, is_tracing, set_track, span, start_tracing,
                     stop_tracing, trace_context, trace_session)
@@ -68,7 +64,6 @@ __all__ = [
     "begin_span",
     "canary",
     "collector",
-    "gate",
     "health",
     "is_tracing",
     "memory",

@@ -24,7 +24,7 @@ Everything here is pure accounting over injected clocks/values — no
 threads, no IO — so tests drive it with a fake clock and synthetic load
 curves.  None of it touches science bytes: capacity-off fleet runs are
 byte-identical to pre-ISSUE-20 output (pinned by
-``tests/test_capacity.py`` and bench config 24).
+``tests/test_capacity.py``).
 """
 
 from __future__ import annotations

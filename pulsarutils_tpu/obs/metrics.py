@@ -5,7 +5,7 @@ histograms from sift, dispatch/readback/retrace counters mirrored from
 the budget accountant, bytes moved over the host link,
 device-memory watermarks, chunks/s.  Two exporters:
 
-* JSONL (one metric per line) — artifact parsers, the perf gate;
+* JSONL (one metric per line) — artifact parsers;
 * Prometheus textfile format — drop the file where a node-exporter
   textfile collector reads it and the survey host is scraped like any
   other service.

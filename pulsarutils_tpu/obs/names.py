@@ -2,8 +2,8 @@
 
 Five PRs of telemetry growth left ``putpu_*`` names scattered as string
 literals across ``obs/``, the drivers, the fault layer and the sift —
-and the only thing keeping the perf gate's baselines, the docs and the
-emitting call sites in agreement was reviewer memory.  This module is
+and the only thing keeping the docs and the emitting call sites in
+agreement was reviewer memory.  This module is
 the agreement, written down: **every metric name the framework emits is
 declared here**, with its one-line meaning, and the ``metric-name``
 checker of :mod:`pulsarutils_tpu.analysis` statically enforces both
@@ -12,8 +12,8 @@ directions —
 * a ``putpu_*`` literal passed to ``counter()``/``gauge()``/
   ``histogram()`` anywhere in the tree must appear in this manifest;
 * every manifest name must be emitted somewhere (or be a declared
-  dynamic budget counter), and every ``putpu_*`` token in the docs or
-  the committed gate baseline must resolve against it.
+  dynamic budget counter), and every ``putpu_*`` token in the docs
+  must resolve against it.
 
 The runtime facades cross-check too (:func:`warn_unknown`): an unknown
 name logs one warning instead of silently minting a new series.  Keep
@@ -24,7 +24,8 @@ importing the package.
 from __future__ import annotations
 
 __all__ = ["METRIC_NAMES", "BUDGET_COUNTERS", "KERNEL_NAMES",
-           "budget_counter_metric", "is_known", "warn_unknown"]
+           "budget_counter_metric", "is_known", "unknown_budget_counters",
+           "warn_unknown"]
 
 #: every statically-named metric: name -> one-line meaning.  Sorted.
 METRIC_NAMES = {
@@ -419,7 +420,7 @@ METRIC_NAMES = {
 #: ``BudgetAccountant.count(name)`` as ``putpu_<name>_total`` — the one
 #: sanctioned dynamic-name seam (waived at its call site).  Adding a new
 #: ``count()`` name means adding it here, or the runtime warns and the
-#: doc/baseline coverage check cannot vouch for it.
+#: docs' coverage check cannot vouch for it.
 BUDGET_COUNTERS = frozenset({
     "dispatches",
     "host_sweeps",
@@ -506,6 +507,14 @@ def is_known(name):
         return True
     return (name.startswith("putpu_") and name.endswith("_total")
             and name[len("putpu_"):-len("_total")] in BUDGET_COUNTERS)
+
+
+def unknown_budget_counters(counters):
+    """The keys of a ``BUDGET_JSON`` ``counters`` block that
+    :data:`BUDGET_COUNTERS` does not declare, sorted — a renamed counter
+    whose manifest row was left behind would otherwise drift out of the
+    docs' coverage in silence."""
+    return sorted(set(counters) - BUDGET_COUNTERS)
 
 
 _warned = set()

@@ -18,8 +18,8 @@ letting delivery touch the search loop's latency:
   torn-tail-safe journal the persist path uses — and counted
   ``putpu_push_dead_letter_total``;
 * subscribers carry min-S/N / DM-window filters; a filtered-out pair
-  counts ``putpu_push_filtered_total`` and is never delivered (bench
-  config 22 forces the score to 0.0 on any violation);
+  counts ``putpu_push_filtered_total`` and is never delivered
+  (``tests/test_obs_lineage.py`` pins it);
 * drops and dead letters raise a ``push`` DEGRADED condition on the
   run's :class:`~.health.HealthEngine`; :meth:`close` drains the queue
   within a bound, journals anything undeliverable, and resolves the

@@ -236,8 +236,7 @@ def fft_zap_time(array, nsigma=5.0, protect_dc=1, xp=np):
     flag Fourier bins whose log-power exceeds a running-median + MAD
     threshold, null those bins in every channel, inverse transform.
 
-    Returns ``(cleaned_array, zapped_bins_mask)``.  This is the "FFT mask"
-    stage of benchmark config 3 (``BASELINE.json``); the reference package
+    Returns ``(cleaned_array, zapped_bins_mask)``.  The reference package
     has no Fourier-domain excision — its cleaning is purely spectral-stats
     based — so this op is an extension, not a parity item.
 

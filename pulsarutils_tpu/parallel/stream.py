@@ -319,8 +319,8 @@ def stream_search(chunks, dmmin, dmmax, start_freq, bandwidth, sample_time,
     block — the RAW 1/2/4-bit bytes ship to the device and the
     bit-unpack runs inside the search jit (integer sweep accumulation
     where exact), cutting host->device traffic 8-16x with candidates
-    byte-identical to the host-unpacked run (bench config 15 gates the
-    identity and the ``putpu_bytes_uploaded_total`` ratio).  Canaries
+    byte-identical to the host-unpacked run (``tests/test_lowbit_e2e.py``
+    pins the identity and the ``putpu_bytes_uploaded_total`` ratio).  Canaries
     are quantized into the packed codes on the same seam
     (:meth:`~pulsarutils_tpu.obs.canary.CanaryController.
     maybe_inject_packed`), so recall is measured on packed runs too.
@@ -571,7 +571,7 @@ def stream_search(chunks, dmmin, dmmax, start_freq, bandwidth, sample_time,
             if backend == "jax":
                 # bytes shipped for this chunk's search: the packed
                 # fast path's 8-16x link win is a METRIC, not a claim
-                # (bench config 15 gates the ratio).  The float arm
+                # (tests/test_lowbit_e2e.py pins the ratio).  The float arm
                 # counts the float32 bytes the search actually uploads
                 # (not the host array's nbytes — a float64 producer
                 # would over-report 2x and inflate the ratio)

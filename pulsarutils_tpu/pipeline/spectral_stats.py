@@ -60,8 +60,8 @@ def _as_reader(source):
 def moment_accumulate(carry, block):
     """Fold one ``(nchans, n)`` block into running ``(sum, sumsq, count)``.
 
-    Pure function of its carry; the host's float64 loop (and
-    ``bench_suite.py``) folds every block through it.
+    Pure function of its carry; the host's float64 loop folds every
+    block through it.
     """
     s, sq, n = carry
     block_f = block.astype(s.dtype) if hasattr(block, "astype") else block

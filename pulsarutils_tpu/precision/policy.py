@@ -62,8 +62,8 @@ __all__ = [
 EPS_F32 = float(np.finfo(np.float32).eps)  # 2^-23
 # bfloat16 significand is 8 bits (incl. hidden), so machine epsilon is
 # 2^(1-8); the per-operand rounding bound below uses the unit roundoff
-# eps/2 = 2^-8.  (bench config 21 checks a real sweep against this
-# bound — a too-tight value fails there, not in production.)
+# eps/2 = 2^-8.  (``tests/test_precision.py`` checks a real sweep
+# against this bound — a too-tight value fails there, not in production.)
 EPS_BF16 = 2.0 ** -7
 
 # Largest contiguous integer range float32 represents exactly.  This is

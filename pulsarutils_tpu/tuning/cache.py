@@ -23,7 +23,7 @@ Durability contract (the PR 4 torn-ledger rules, applied verbatim):
   just written by another release.  Its entries are rejected (stale
   measurement schemas must never drive kernel selection) and the next
   :meth:`TuneCache.store` rewrites the file at the current version.
-  ``tools/perf_gate.py`` applies the same rule to the committed
+  :func:`check_artifact` applies the same rule to the committed
   ``TUNE_cpu.json`` artifact.
 """
 
@@ -40,7 +40,7 @@ from ..io.atomic import atomic_write_json
 logger = logging.getLogger("pulsarutils_tpu")
 
 #: bump when an entry's meaning changes (measurement discipline, key
-#: axes, winner semantics).  Mirrored by the perf gate's artifact check.
+#: axes, winner semantics).  :func:`check_artifact` holds files to it.
 TUNE_SCHEMA_VERSION = 1
 
 #: env override for the cache file location
@@ -63,11 +63,10 @@ def default_cache_path():
 def check_artifact(path, expect_version=TUNE_SCHEMA_VERSION):
     """``(ok, detail)`` for a committed tune-cache artifact.
 
-    Used by ``tools/perf_gate.py``: a missing, unreadable, corrupt or
-    version-mismatched artifact refuses the PASS, exactly like the
-    snapshot schema gate (PR 5) — a stale committed tune cache would
-    silently pin every future run's kernel choice to measurements whose
-    meaning drifted.
+    Used by ``tools/autotune.py verify`` and the tier-1 tests: a
+    missing, unreadable, corrupt or version-mismatched artifact is
+    refused — a stale committed tune cache would silently pin every
+    future run's kernel choice to measurements whose meaning drifted.
     """
     try:
         with open(path, encoding="utf-8") as f:

@@ -30,9 +30,8 @@ def enable_compile_cache():
     """Turn on JAX's persistent compilation cache for this process.
 
     THE one place the cache directory is chosen; every entry point that
-    compiles (the CLI mains, the fleet worker, ``bench.py``,
-    ``bench_suite.py``, ``chip_smoke.py``) calls it before its first
-    compile.  ``JAX_COMPILATION_CACHE_DIR`` wins: when it is set JAX has
+    compiles (the CLI mains, the fleet worker, ``chip_smoke.py``) calls
+    it before its first compile.  ``JAX_COMPILATION_CACHE_DIR`` wins: when it is set JAX has
     already read it and nothing is set in code; otherwise the cache goes
     to ``<checkout>/.pulsarutils_tpu_cache/jax``.  Returns the directory
     in use.

@@ -76,6 +76,14 @@ class StageTimer:
 #: never misattribute into the main thread's serial buckets.
 _ACTIVE_BUDGET = contextvars.ContextVar("putpu_budget", default=None)
 
+#: version of the ``BUDGET_JSON`` footer (its first key) and of the
+#: ``--metrics-out`` JSONL header; bumped whenever a record's meaning
+#: changes, so a parser fails loudly on a drifted schema.  v2 (ISSUE 14):
+#: the ``chunk_wall_s`` p50/p95/p99 block.  v3 (ISSUE 17): no footer
+#: change.  v4 (ISSUE 25): ``call_s``, per-chunk ``on_disk_lag_s``, the
+#: compile-phase counters, and ``async_s`` splits ``persist``.
+BUDGET_SCHEMA_VERSION = 4
+
 #: chunk-wall histogram edges: decade-ish coverage from sub-100ms CPU
 #: test chunks to multi-minute chunks
 _CHUNK_WALL_EDGES = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
@@ -461,8 +469,6 @@ class BudgetAccountant(StageTimer):
     # -- reporting -----------------------------------------------------------
 
     def to_json(self, max_per_chunk=32):
-        from ..obs.gate import SCHEMA_VERSION
-
         nchunks = len(self.chunks)
         wall = sum(c["wall_s"] for c in self.chunks)
         buckets = {}
@@ -473,11 +479,9 @@ class BudgetAccountant(StageTimer):
         unattributed = wall - top
         walls = sorted(c["wall_s"] for c in self.chunks)
         out = {
-            # versioned footer (ISSUE 5 satellite): parsers and the perf
-            # gate key off this instead of silently comparing records
-            # whose meaning drifted.  ISSUE 14 added chunk_wall_s
-            # percentiles, ISSUE 25 call_s — each a schema_version bump.
-            "schema_version": SCHEMA_VERSION,
+            # versioned footer (ISSUE 5 satellite): parsers key off this
+            # instead of silently reading records whose meaning drifted
+            "schema_version": BUDGET_SCHEMA_VERSION,
             "chunks": nchunks,
             "wall_s": round(wall, 3),
             "chunk_wall_s": ({

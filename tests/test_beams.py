@@ -6,7 +6,7 @@ The load-bearing pins:
   single-beam dispatches (kernel level and end-to-end: tables, ledgers,
   persisted candidate bytes) — the PR 2 discipline at the beam axis;
 * one device dispatch serves N beam-chunks (the counters prove the Nx
-  amortisation config 13 gates);
+  amortisation);
 * cross-beam coincidence verdicts: all-beam same-(DM, t) detections are
   RFI-vetoed, single/adjacent-beam detections confirmed;
 * beam provenance (sigproc ``ibeam``/``nbeams``) rides the reader, the

@@ -1,6 +1,6 @@
 """Live ingest frontend (ISSUE 19): wire codec, ring-buffer assembler,
 socket sources, ledger accounting — and the byte identity of a lossless
-local feed with the disk search (the tier-1 twin of bench config 23).
+local feed with the disk search.
 
 Everything here runs on localhost sockets and tiny arrays; no test
 needs more than a few hundred ms of JAX work.
@@ -311,9 +311,8 @@ def test_assembler_far_future_packet_forces_cuts():
 # -- socket sources -----------------------------------------------------------
 
 def test_tcp_feed_lossless_byte_identity_with_disk_search(tmp_path):
-    """The tier-1 twin of bench config 23: a lossless localhost feed
-    must reproduce the disk search byte for byte — delivered chunks,
-    per-chunk tables, and the hit list."""
+    """A lossless localhost feed must reproduce the disk search byte for
+    byte — delivered chunks, per-chunk tables, and the hit list."""
     from pulsarutils_tpu.io.sigproc import (FilterbankReader,
                                             write_simulated_filterbank)
     from pulsarutils_tpu.models.simulate import disperse_array

@@ -21,7 +21,7 @@ from pulsarutils_tpu.analysis import (LintProject, lint_source,
 from pulsarutils_tpu.analysis import baseline as baseline_mod
 from pulsarutils_tpu.analysis import waivers as waivers_mod
 from pulsarutils_tpu.analysis.cli import run_lint
-from pulsarutils_tpu.obs import gate, names
+from pulsarutils_tpu.obs import names
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -857,37 +857,13 @@ def test_cli_select_narrows_the_run(tmp_path):
     assert res.returncode == 0  # broad-except not selected
 
 
-# -- the perf-gate hook -------------------------------------------------------
+# -- the budget counters' vocabulary -----------------------------------------
 
-def test_gate_accepts_clean_lint_report(tmp_path):
-    report = tmp_path / "lint.json"
-    clean = LintProject()
-    clean.check_source("x = 1\n", OPS)
-    report.write_text(json.dumps(clean.report()))
-    ok, detail = gate.check_lint_report(str(report))
-    assert ok, detail
-
-
-def test_gate_refuses_missing_or_dirty_lint_report(tmp_path):
-    ok, detail = gate.check_lint_report(str(tmp_path / "absent.json"))
-    assert not ok and "missing" in detail
-
-    dirty = _project_with_finding()
-    report = tmp_path / "dirty.json"
-    report.write_text(json.dumps(dirty.report()))
-    ok, detail = gate.check_lint_report(str(report))
-    assert not ok and "1 new" in detail
-
-    report.write_text('{"tool": "other"}')
-    ok, detail = gate.check_lint_report(str(report))
-    assert not ok
-
-
-def test_gate_flags_undeclared_budget_counter_names():
-    records = {"7": {"counters": {"dispatches": 3, "not_declared": 1}}}
-    assert gate.unknown_budget_counters(records) == ["not_declared"]
-    records["7"]["counters"].pop("not_declared")
-    assert gate.unknown_budget_counters(records) == []
+def test_undeclared_budget_counter_names_are_flagged():
+    counters = {"dispatches": 3, "not_declared": 1}
+    assert names.unknown_budget_counters(counters) == ["not_declared"]
+    counters.pop("not_declared")
+    assert names.unknown_budget_counters(counters) == []
 
 
 # -- review-hardening regressions (PR 6 code review) --------------------------

@@ -1,23 +1,20 @@
-"""Round-7 telemetry subsystem (ISSUE 3): span tracing, metrics
-registry, the perf gate — and the byte-compat contract that the span
-refactor did NOT change ``BUDGET_JSON``.
+"""Round-7 telemetry subsystem (ISSUE 3): span tracing, the metrics
+registry — and the byte-compat contract that the span refactor did NOT
+change ``BUDGET_JSON``.
 """
 import json
 import os
-import subprocess
-import sys
 import threading
 import time
 
 import numpy as np
 import pytest
 
-from pulsarutils_tpu.obs import gate, memory, metrics, trace
-from pulsarutils_tpu.utils.logging_utils import (BudgetAccountant,
+from pulsarutils_tpu.obs import memory, metrics, trace
+from pulsarutils_tpu.utils.logging_utils import (BUDGET_SCHEMA_VERSION,
+                                                 BudgetAccountant,
                                                  budget_bucket,
                                                  budget_count)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
@@ -513,221 +510,10 @@ def test_device_trace_still_works(tmp_path):
         pass
 
 
-# ---------------------------------------------------------------------------
-# perf gate
-# ---------------------------------------------------------------------------
-
-def _rec(cfg, value, unit):
-    return {"config": cfg, "value": value, "unit": unit}
-
-
-def test_gate_directions_and_tolerances():
-    base = {1: _rec(1, 100.0, "DM-trials/sec"),
-            7: _rec(7, 2.0, "s/chunk (wall, budget-attributed)")}
-    ok, rows = gate.compare(base, {1: _rec(1, 90.0, "DM-trials/sec"),
-                                   7: _rec(7, 2.5, "s/chunk")})
-    assert ok and all(r["status"] == "ok" for r in rows)
-    # throughput collapse fails
-    ok, rows = gate.compare(base, {1: _rec(1, 10.0, "DM-trials/sec"),
-                                   7: _rec(7, 2.0, "s/chunk")})
-    assert not ok and rows[0]["status"] == "regressed"
-    # latency blow-up fails
-    ok, rows = gate.compare(base, {1: _rec(1, 100.0, "DM-trials/sec"),
-                                   7: _rec(7, 20.0, "s/chunk")})
-    assert not ok and rows[1]["status"] == "regressed"
-    # a missing or errored config is a failure, not a skip
-    ok, rows = gate.compare(base, {1: _rec(1, 100.0, "DM-trials/sec")})
-    assert not ok and rows[1]["status"] == "missing"
-    ok, rows = gate.compare(base, {1: _rec(1, 100.0, "DM-trials/sec"),
-                                   7: {"config": 7, "error": "boom"}})
-    assert not ok and rows[1]["status"] == "error"
-    # improvements never fail, in either direction
-    ok, _ = gate.compare(base, {1: _rec(1, 1000.0, "DM-trials/sec"),
-                                7: _rec(7, 0.1, "s/chunk")})
-    assert ok
-    # per-config tolerance override
-    ok, _ = gate.compare(base, {1: _rec(1, 90.0, "DM-trials/sec"),
-                                7: _rec(7, 2.0, "s/chunk")},
-                         per_config_tol={1: 0.05})
-    assert not ok
-
-
-def test_gate_snapshot_loader(tmp_path):
-    p = str(tmp_path / "snap.jsonl")
-    with open(p, "w") as f:
-        f.write(json.dumps(_rec(1, 5.0, "DM-trials/sec")) + "\n")
-        f.write("\n")
-        f.write(json.dumps({"metrics": []}) + "\n")  # registry tail
-    snap = gate.load_snapshot(p)
-    assert list(snap) == [1] and snap[1]["value"] == 5.0
-
-
-def test_gate_rejects_missing_or_mismatched_schema_version(tmp_path):
-    # ISSUE 5 satellite: the gate refuses to compare snapshots whose
-    # schema_version header is absent or wrong — never silently
-    versioned = str(tmp_path / "v.jsonl")
-    with open(versioned, "w") as f:
-        f.write(json.dumps({"schema_version": gate.SCHEMA_VERSION}) + "\n")
-        f.write(json.dumps(_rec(1, 5.0, "DM-trials/sec")) + "\n")
-    snap = gate.load_snapshot(versioned,
-                              expect_version=gate.SCHEMA_VERSION)
-    assert snap[1]["value"] == 5.0
-
-    unversioned = str(tmp_path / "u.jsonl")
-    with open(unversioned, "w") as f:
-        f.write(json.dumps(_rec(1, 5.0, "DM-trials/sec")) + "\n")
-    # lenient load still works (ad-hoc tooling over old artifacts)...
-    assert gate.load_snapshot(unversioned)[1]["value"] == 5.0
-    # ...but the enforcing load refuses
-    with pytest.raises(ValueError, match="schema_version"):
-        gate.load_snapshot(unversioned,
-                           expect_version=gate.SCHEMA_VERSION)
-
-    drifted = str(tmp_path / "d.jsonl")
-    with open(drifted, "w") as f:
-        f.write(json.dumps({"schema_version": gate.SCHEMA_VERSION + 1})
-                + "\n")
-        f.write(json.dumps(_rec(1, 5.0, "DM-trials/sec")) + "\n")
-    with pytest.raises(ValueError, match="schema_version"):
-        gate.load_snapshot(drifted, expect_version=gate.SCHEMA_VERSION)
-
-
-def test_gate_cli_rejects_unversioned_snapshot(tmp_path):
-    # end-to-end: the CLI exits 2 (usage/baseline problem) on a fresh
-    # snapshot without the schema_version header
-    baseline = os.path.join(REPO, "BENCH_GATE_cpu.jsonl")
-    records = gate.load_snapshot(baseline)
-    unversioned = str(tmp_path / "old.jsonl")
-    with open(unversioned, "w") as f:
-        for rec in records.values():
-            f.write(json.dumps(rec) + "\n")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "perf_gate.py"),
-         "--snapshot", unversioned], env=env, cwd=REPO,
-        capture_output=True, text=True)
-    assert proc.returncode == 2, proc.stdout + proc.stderr
-    assert "schema_version" in proc.stderr
-
-
-def test_gate_cli_refuses_cross_lane_snapshot(tmp_path):
-    # ISSUE 17: the v3 header stamps the bench LANE (JAX backend +
-    # precision policy); the CLI must exit 2 — refuse, not score — when
-    # a snapshot from another lane is compared against the cpu baseline
-    baseline = os.path.join(REPO, "BENCH_GATE_cpu.jsonl")
-    hdr = gate.load_header(baseline)
-    assert hdr.get("backend") == "cpu"
-    assert hdr.get("precision_policy") == "f32"
-    records = gate.load_snapshot(baseline)
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    for key, val in (("backend", "tpu"),
-                     ("precision_policy", "bf16_operand_f32_accum")):
-        doctored = str(tmp_path / f"{key}.jsonl")
-        with open(doctored, "w") as f:
-            f.write(json.dumps(dict(hdr, **{key: val})) + "\n")
-            for rec in records.values():
-                f.write(json.dumps(rec) + "\n")
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "tools", "perf_gate.py"),
-             "--snapshot", doctored], env=env, cwd=REPO,
-            capture_output=True, text=True)
-        assert proc.returncode == 2, proc.stdout + proc.stderr
-        assert f"{key} mismatch" in proc.stderr
-    # --backend resolves the per-backend baseline file: an absent lane
-    # baseline is a usage error naming the resolved path, not a
-    # fall-through to another lane's numbers
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "perf_gate.py"),
-         "--backend", "tpu", "--snapshot", baseline], env=env, cwd=REPO,
-        capture_output=True, text=True)
-    assert proc.returncode == 2, proc.stdout + proc.stderr
-    assert "BENCH_GATE_tpu.jsonl" in proc.stderr
-    # and a baseline explicitly from ANOTHER lane than --backend asks
-    # for is refused up front
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "perf_gate.py"),
-         "--backend", "tpu", "--baseline", baseline,
-         "--snapshot", baseline], env=env, cwd=REPO,
-        capture_output=True, text=True)
-    assert proc.returncode == 2, proc.stdout + proc.stderr
-    assert "stamped for backend" in proc.stderr
-
-
-def test_header_mismatch_lane_rules(tmp_path):
-    # unit-level lane semantics: undeclared fields never clash (old
-    # artifacts keep gating), declared-and-different always does
-    assert gate.header_mismatch({}, {}) is None
-    assert gate.header_mismatch({"backend": "cpu"}, {}) is None
-    assert gate.header_mismatch({"backend": "cpu"},
-                                {"backend": "cpu"}) is None
-    assert "backend mismatch" in gate.header_mismatch(
-        {"backend": "cpu"}, {"backend": "tpu"})
-    assert "precision_policy mismatch" in gate.header_mismatch(
-        {"backend": "cpu", "precision_policy": "f32"},
-        {"backend": "cpu", "precision_policy": "f32_compensated"})
-    # load_header: header line parsed; header-less snapshot reads as {}
-    p = str(tmp_path / "h.jsonl")
-    with open(p, "w") as f:
-        f.write(json.dumps({"schema_version": gate.SCHEMA_VERSION,
-                            "backend": "cpu",
-                            "precision_policy": "f32"}) + "\n")
-        f.write(json.dumps({"config": 1, "value": 1.0}) + "\n")
-    assert gate.load_header(p)["backend"] == "cpu"
-    bare = str(tmp_path / "bare.jsonl")
-    with open(bare, "w") as f:
-        f.write(json.dumps({"config": 1, "value": 1.0}) + "\n")
-    assert gate.load_header(bare) == {}
-
-
 def test_budget_json_carries_schema_version():
-    from pulsarutils_tpu.utils.logging_utils import BudgetAccountant
-
     acct = BudgetAccountant()
     with acct.chunk(0):
         pass
     j = acct.to_json()
     assert list(j)[0] == "schema_version"
-    assert j["schema_version"] == gate.SCHEMA_VERSION
-
-
-def test_gate_cli_doctored_snapshot_fails(tmp_path):
-    # the acceptance demonstration, via the actual CLI: a doctored
-    # regressed snapshot must exit nonzero against the committed baseline
-    baseline = os.path.join(REPO, "BENCH_GATE_cpu.jsonl")
-    assert os.path.exists(baseline), "committed gate baseline missing"
-    records = gate.load_snapshot(baseline)
-    doctored = str(tmp_path / "doctored.jsonl")
-    with open(doctored, "w") as f:
-        f.write(json.dumps({"schema_version": gate.SCHEMA_VERSION}) + "\n")
-        for cfg, rec in records.items():
-            bad = dict(rec)
-            factor = 10.0 if gate.lower_is_better(rec.get("unit")) else 0.1
-            bad["value"] = rec["value"] * factor
-            f.write(json.dumps(bad) + "\n")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "perf_gate.py"),
-         "--snapshot", doctored], env=env, cwd=REPO,
-        capture_output=True, text=True)
-    assert proc.returncode == 1, proc.stdout + proc.stderr
-    assert "regressed" in proc.stdout
-    # and the baseline against itself passes
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "perf_gate.py"),
-         "--snapshot", baseline], env=env, cwd=REPO,
-        capture_output=True, text=True)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-
-
-@pytest.mark.slow
-def test_gate_cpu_run_against_committed_baseline():
-    """The full gate: run the two fast configs fresh (quick preset,
-    CPU) and compare against the committed baseline — the documented
-    one-line invocation, wired as a slow test so full suites enforce
-    the BENCH trajectory."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "perf_gate.py")],
-        env=env, cwd=REPO, capture_output=True, text=True, timeout=1800)
-    assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
-    assert "perf_gate: PASS" in proc.stdout
+    assert j["schema_version"] == BUDGET_SCHEMA_VERSION

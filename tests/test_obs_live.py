@@ -17,8 +17,6 @@ from pulsarutils_tpu.obs.canary import CanaryController
 from pulsarutils_tpu.obs.health import CRITICAL, DEGRADED, OK, HealthEngine
 from pulsarutils_tpu.obs.server import start_obs_server
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 def _get(url, timeout=5.0):
     """(status, body) — urllib raises on 5xx, we want the code."""
@@ -694,40 +692,44 @@ def test_report_amend_folds_sift_in(tmp_path):
     assert "No health engine" in md
 
 
-def test_gate_config10_recall_has_tight_tolerance(tmp_path):
-    """Review fix (r9): canary recall is deterministic — a 10% drop
-    (more than one of the 13 canaries) must FAIL the gate even though
-    the same drop on the wall-clock configs passes under the jitter
-    tolerance, while losing exactly ONE canary (12/13, a marginal
-    pulse flipping across BLAS/CPU rounding) must pass."""
-    import subprocess
-    import sys
+def test_canary_survey_with_rfi_storm_recall_and_health(tmp_path):
+    """A canary in EVERY chunk of a 13-chunk survey (recall from more than
+    ten injections) and one chunk hit by an injected broadband RFI storm:
+    at most one canary may be lost (a marginal pulse flipping across
+    BLAS/CPU rounding), the storm must flip the verdict to DEGRADED as a
+    candidate-rate spike, and the clean chunks behind it must bring it
+    back to OK."""
+    from pulsarutils_tpu.faults.inject import FaultPlan, FaultSpec
+    from pulsarutils_tpu.io.sigproc import write_simulated_filterbank
+    from pulsarutils_tpu.pipeline.search_pipeline import search_by_chunks
 
-    from pulsarutils_tpu.obs import gate
-
-    baseline = os.path.join(REPO, "BENCH_GATE_cpu.jsonl")
-    records = gate.load_snapshot(baseline)
-    assert 10 in records, "committed baseline is missing config 10"
-
-    def run_with_recall_ratio(ratio, name):
-        doctored = str(tmp_path / name)
-        with open(doctored, "w") as f:
-            f.write(json.dumps({"schema_version": gate.SCHEMA_VERSION})
-                    + "\n")
-            for cfg, rec in records.items():
-                bad = dict(rec)
-                if cfg == 10:
-                    bad["value"] = rec["value"] * ratio
-                f.write(json.dumps(bad) + "\n")
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        return subprocess.run(
-            [sys.executable, os.path.join(REPO, "tools", "perf_gate.py"),
-             "--snapshot", doctored], env=env, cwd=REPO,
-            capture_output=True, text=True)
-
-    proc = run_with_recall_ratio(0.9, "recall_drop.jsonl")
-    assert proc.returncode == 1, proc.stdout + proc.stderr
-    assert "config 10  regressed" in proc.stdout
-    proc = run_with_recall_ratio(12.0 / 13.0, "one_lost.jsonl")
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "config 10  ok" in proc.stdout
+    tsamp, nchan, hop, nhops = 0.0005, 64, 4096, 14
+    nsamples = nhops * hop
+    rng = np.random.default_rng(10)
+    array = np.abs(rng.normal(0, 0.5, (nchan, nsamples))) + 20.0
+    header = {"bandwidth": 200., "fbottom": 1200., "nchans": nchan,
+              "nsamples": nsamples, "tsamp": tsamp, "foff": 200. / nchan}
+    path = str(tmp_path / "canary.fil")
+    write_simulated_filterbank(path, array, header, descending=True)
+    # 8 impulses at 100 block-stds: bright enough that the wide boxcar
+    # widths light up ~2/3 of the DM trials (a denser storm
+    # self-suppresses — the row-std normalisation soaks it up)
+    plan = FaultPlan([FaultSpec(site="corrupt", kind="impulse",
+                                chunks=(5 * hop,), frac=0.001, times=1,
+                                amp=100.0)])
+    canary = CanaryController(rate=1.0, snr=15.0, seed=10)
+    engine = HealthEngine()
+    with plan.armed():
+        search_by_chunks(
+            path, chunk_length=hop * tsamp, dmmin=100, dmmax=200,
+            backend="jax", snr_threshold=6.5,
+            output_dir=str(tmp_path / "out"), make_plots=False,
+            resume=False, progress=False, canary=canary, health=engine)
+    assert plan.fired() == 1
+    s = canary.summary()
+    assert s["injected"] == 13
+    assert s["recall"] >= 12.0 / 13.0
+    moves = [(t["chunk"], t["from"], t["to"], t["reasons"])
+             for t in engine.transitions]
+    assert moves[0] == (5 * hop, OK, DEGRADED, ["candidate_storm"])
+    assert moves[-1][2] == OK and engine.verdict == OK

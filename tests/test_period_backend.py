@@ -318,6 +318,20 @@ class TestEndToEnd:
                 if a == 0.0]
         assert not zero or max(zero) < best["sigma"] / 2
 
+    def test_host_driver_matches_device_driver(self, pulsar_file,
+                                               direct_run, tmp_path):
+        """The whole job on the host reference (``backend="numpy"``)
+        lists the device run's candidates: discrete fields cell for
+        cell, scores to float tolerance."""
+        host = periodicity_search(pulsar_file, backend="numpy",
+                                  output_dir=str(tmp_path), **JOB)
+        dev = direct_run["candidates"]
+        assert len(host["candidates"]) == len(dev) > 0
+        for ch, cd in zip(host["candidates"], dev):
+            for k in ("dm_index", "accel_index", "freq_bin", "nharm"):
+                assert ch[k] == cd[k], k
+            assert ch["sigma"] == pytest.approx(cd["sigma"], rel=5e-3)
+
     def test_candidates_persisted_and_loadable(self, direct_run):
         cands, meta = load_candidates(direct_run["candidates_path"])
         assert len(cands) == len(direct_run["candidates"])

@@ -275,6 +275,45 @@ def test_policies_preserve_discrete_hits(formulation, policy, monkeypatch):
                                np.asarray(ref["snr"]), rtol=rtol)
 
 
+def test_bf16_sweep_meets_its_bound_against_a_float64_oracle():
+    """``bf16_operand_f32_accum`` end to end through the gather sweep: its
+    best candidate is plain f32's, at the injected trial, and its
+    dedispersed profile there stays inside the strategy's documented
+    bound (relative to the per-sample sum of absolute operands) of a
+    float64 roll-and-add."""
+    from pulsarutils_tpu.ops.search import _offsets_for
+    from pulsarutils_tpu.tuning.autotune import synthetic_chunk
+
+    nchan, nsamples, ndm = 16, (1 << 16) + 512, 8
+    geom = (1400.0, 400.0, 5e-4)
+    dms = np.linspace(40.0, 80.0, ndm)
+    offsets = _offsets_for(dms, nchan, *geom, nsamples)
+    inj = ndm // 2
+    data = synthetic_chunk(nchan, nsamples, offsets[inj], seed=21)
+
+    def run(policy, capture=False):
+        return dedispersion_search(data, None, None, *geom, backend="jax",
+                                   trial_dms=dms, kernel="gather",
+                                   precision=policy, capture_plane=capture)
+
+    def best(tbl):
+        i = tbl.argbest("snr")
+        return i, int(tbl["rebin"][i]), int(tbl["peak"][i])
+
+    t_bf16, plane = run("bf16_operand_f32_accum", capture=True)
+    assert best(t_bf16) == best(run("f32")) and best(t_bf16)[0] == inj
+
+    prof64 = np.zeros(nsamples)
+    abs64 = np.zeros(nsamples)
+    for c in range(nchan):
+        rolled = np.roll(data[c].astype(np.float64), -int(offsets[inj, c]))
+        prof64 += rolled
+        abs64 += np.abs(rolled)
+    bound = STRATEGIES["bf16_operand_f32_accum"].error_bound(nchan)
+    got = np.asarray(plane[inj], dtype=np.float64)
+    assert (np.abs(got - prof64) <= bound * abs64 + 1e-6).all()
+
+
 def test_policy_rejected_on_non_policy_backends():
     data, dms, geom = _problem()
     with pytest.raises(ValueError, match="precision"):

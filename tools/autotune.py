@@ -12,9 +12,9 @@ first-class artifact:
 * ``show`` — the per-key decision table of a cache file;
 * ``clear`` — drop entries (all, or ``--match`` substring) after a
   kernel change that invalidates old measurements;
-* ``verify`` — the perf-gate artifact check (schema version + shape)
-  plus a kernel-name sanity pass, exit 0/1 — the same rule
-  ``tools/perf_gate.py`` applies to the committed ``TUNE_cpu.json``.
+* ``verify`` — the artifact check (schema version + shape) plus a
+  kernel-name sanity pass, exit 0/1 — the rule tier-1 holds the
+  committed ``TUNE_cpu.json`` to.
 
 Examples::
 
@@ -32,8 +32,8 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-#: the repo-wide bench geometry (bench.py GEOM): start_freq MHz,
-#: bandwidth MHz, tsamp s — overridable per invocation
+#: the default geometry: start_freq MHz, bandwidth MHz, tsamp s —
+#: overridable per invocation
 GEOM = (1200.0, 200.0, 0.0005)
 
 
@@ -181,7 +181,7 @@ def main(argv=None):
     p.set_defaults(fn=cmd_clear)
 
     p = sub.add_parser("verify", help="schema/shape-check a cache "
-                                      "artifact (the perf-gate rule)")
+                                      "artifact")
     p.add_argument("--cache", default=None,
                    help="artifact path (default: TUNE_cpu.json)")
     p.add_argument("--expect-version", type=int, default=None)

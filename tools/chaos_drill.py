@@ -51,9 +51,8 @@ asserts the contracts ``docs/robustness.md`` documents:
   and decays back to OK at drain), both with survey outputs
   byte-identical to the capacity-off baseline.
 
-Wired as ``bench_suite.py`` config 9 so the drill result lands next to
-the perf-gate artifacts; the same matrix runs as a ``slow``+``chaos``
-pytest in ``tests/test_faults.py``.
+The same matrix runs as a ``slow``+``chaos`` pytest in
+``tests/test_faults.py``.
 
 Usage: JAX_PLATFORMS=cpu python tools/chaos_drill.py [--out drill.json]
 """
@@ -255,13 +254,8 @@ def _fault_classes():
     }
 
 
-def run_drill(quick=False, log=print, workdir=None, keep=False):
-    """Run the whole matrix; returns the result record (config-9 style).
-
-    ``quick`` currently runs the identical matrix (the survey is already
-    tier-1 sized); the flag is accepted so bench_suite's preset plumbing
-    stays uniform.
-    """
+def run_drill(log=print, workdir=None, keep=False):
+    """Run the whole matrix; returns the result record."""
     from pulsarutils_tpu.faults.audit import audit_run
     from pulsarutils_tpu.faults.inject import FaultPlan
     from pulsarutils_tpu.obs.health import HealthEngine
@@ -1138,8 +1132,8 @@ def make_pulsar_file(path):
     """Deterministic accelerated-pulsar survey for the periodicity
     class (a single-pulse file would make its byte-identity vacuous —
     empty candidate lists compare equal for free).  The injection
-    physics lives in ONE place (``models.simulate``) shared with bench
-    config 17 and the tests."""
+    physics lives in ONE place (``models.simulate``) shared with the
+    tests."""
     from pulsarutils_tpu.io.sigproc import write_simulated_filterbank
     from pulsarutils_tpu.models.simulate import simulate_accel_pulsar_data
 
@@ -1383,15 +1377,15 @@ def _fleet_oom_class(base_dir, path, baseline, fingerprint, log):
             "ok": bool(plan.fired()) and done and not diffs}
 
 
-def run_fleet_drill(quick=False, log=print, workdir=None, keep=False):
+def run_fleet_drill(log=print, workdir=None, keep=False):
     """The fleet chaos classes: killed_worker (SIGKILL while holding a
     lease, ISSUE 9), wedged_worker (hung far past the lease TTL, ISSUE
     9) and oom_worker (injected RESOURCE_EXHAUSTED recovered by the
     worker's own degradation ladder, ISSUE 12).  All must complete the
     survey byte-identical to the single-process baseline.  Slow
     (spawns real worker processes); runs as a ``slow``+``chaos``
-    pytest and via ``--fleet`` here — config 14 gates the fast
-    in-process equivalent.
+    pytest and via ``--fleet`` here — ``tests/test_fleet.py`` holds the
+    fast in-process equivalent.
     """
     t_start = time.time()
     base_dir = workdir or tempfile.mkdtemp(prefix="chaos_fleet_")
