@@ -390,6 +390,11 @@ METRIC_NAMES = {
         "stream chunks whose best S/N cleared the threshold",
     "putpu_tier_certified_total":
         "tier sweeps of a tiered search whose noise certificate held",
+    "putpu_tier_delay_bands_total":
+        "delay bands swept beyond a tier's first: a tier whose smallest "
+        "time tile does not fit the device beside its sweep's state is "
+        "swept a run of band delays at a time (0 wherever one sweep a "
+        "tile fits)",
     "putpu_tier_sweeps_total":
         "tier sweeps of a tiered search (dm_tiers): one per tier per "
         "chunk",
@@ -429,6 +434,8 @@ BUDGET_COUNTERS = frozenset({
     "readbacks",
     "rescore_calls",
     "rescore_rows",
+    "sweep_calls",
+    "sweep_samples",
     "uploads_ready",
 })
 

@@ -371,6 +371,11 @@ def _transform_setup(data, use_pallas):
 MERGE_ROW_BLOCK = 32
 
 
+#: bytes of double-buffered parent windows one grid step of the paired
+#: deep pass may ask for (:func:`_merge4_pallas`)
+DEEP_PAIR_VMEM_BYTES = 12 << 20
+
+
 def _state_tiles(state, t_tile):
     """A state in the layout the merge kernels read and write,
     ``(rows, t / t_tile, 8, t_tile / 8)``: from the flat ``(rows, t)``,
@@ -581,14 +586,22 @@ def _merge4_pallas(s4, idx, shift, t_tile, interpret):
     # its row block is kept smaller than MERGE_ROW_BLOCK to bound both
     # operand count and per-step VMEM
     row_block = min(max(1, MERGE_ROW_BLOCK // 2), rows_out)
+    L = t_tile // 8
+    max_shift = max(
+        int(s.max(initial=0))  # putpu-lint: disable=device-trip — host
+        for s in shift)
+    k_tiles = (max_shift // L + 23) // 8
+    # a step's parent windows, double-buffered, must fit the core's
+    # scoped VMEM (16 MiB): 16 rows x 4 parents x 3 tiles of 32 KiB is
+    # what every band up to MeerTRAP's asks (12 MiB); CHIME's deepest
+    # shifts reach a fourth tile (17.1 MiB asked, refused by the v5e's
+    # compiler), so such a pass takes its rows eight at a time
+    while row_block > 1 and row_block * 4 * k_tiles * t_tile * 4 * 2 \
+            > DEEP_PAIR_VMEM_BYTES:
+        row_block //= 2
     pad = (-rows_out) % row_block
     with kernel_build_span("fdmt_deep_pair", rows=rows_out + pad, t=t,
                            t_tile=t_tile):
-        L = t_tile // 8
-        max_shift = max(
-            int(s.max(initial=0))  # putpu-lint: disable=device-trip — host
-            for s in shift)
-        k_tiles = (max_shift // L + 23) // 8
         idx_p = [np.concatenate([i, i[-1:].repeat(pad)]) for i in idx]
         shift_p = [np.concatenate([s, s[-1:].repeat(pad)]) for s in shift]
         run = _build_merge4_kernel(rows_out + pad, t, t_tile, k_tiles,
@@ -723,14 +736,16 @@ def _head_verdict(nchan, start_freq, bandwidth, max_delay, n_lo, t):
 
 
 def coarse_head_tiles(nchan, nsamples, dmmin, dmmax, start_freq, bandwidth,
-                      sample_time):
+                      sample_time, delays=None):
     """``(computed, useful, declined, smem bytes)`` of the fused head in
     ONE coarse sweep of the ``fdmt``/``hybrid`` kernels over these
     arguments on this backend — the geometry
     ``ops/search.py:_search_jax_fdmt`` resolves, through the same
     functions: the (8, 256) tiles, why :func:`_head_choice` declined
     (None where the head runs) and the SMEM its tables take;
-    ``(0, 0, None, 0)`` where the Pallas merges are off.
+    ``(0, 0, None, 0)`` where the Pallas merges are off.  ``delays`` is
+    the ``(n_lo, n_hi)`` of a sweep over one delay band of that range
+    (a tier in delay bands, ``ops/search.py:_search_jax_fdmt_tiled``).
     """
     import jax
 
@@ -740,6 +755,8 @@ def coarse_head_tiles(nchan, nsamples, dmmin, dmmax, start_freq, bandwidth,
         return 0, 0, None, 0
     _, n_lo, n_hi = fdmt_trial_dms(nchan, dmmin, dmmax, start_freq,
                                    bandwidth, sample_time)
+    if delays is not None:
+        n_lo, n_hi = delays
     t_run = _padded_length(nsamples)
     choice, declined, smem = _head_verdict(
         nchan, float(start_freq), float(bandwidth), n_hi, n_lo, t_run)

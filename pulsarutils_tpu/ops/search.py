@@ -676,6 +676,8 @@ def _search_jax_fdmt(data, dmmin, dmmax, start_freq, bandwidth, sample_time,
     with budget_bucket("search/coarse"):
         out = run(data)
         budget_count("dispatches")
+        budget_count("sweep_calls")
+        budget_count("sweep_samples", t_orig)
     if capture_plane:
         stacked, plane_out = out  # plane stays device-resident
     else:
@@ -696,9 +698,10 @@ def time_tiles_of(data):
     for a tier the device cannot hold whole
     (:class:`~pulsarutils_tpu.pipeline.time_tiles.TiledTierArray`: a
     ``shape``, its ``tier``, ``own`` samples a tile, a ``halo``, how many
-    cleaned tiles a rescore may ``keep``, and ``tile(i, shift)``, the
-    cleaned ``own + halo`` samples from ``i * own + shift`` on, circular
-    over the chunk)."""
+    cleaned tiles a rescore may ``keep``, the delay ``bands`` a tile is
+    swept in (empty: one sweep) with their ``band_seconds``, and
+    ``tile(i, shift)``, the cleaned ``own + halo`` samples from ``i * own
+    + shift`` on, circular over the chunk)."""
     return int(getattr(data, "time_tiles", 1))
 
 
@@ -714,6 +717,11 @@ def _search_jax_fdmt_tiled(src, dmmin, dmmax, start_freq, bandwidth,
     chunk, which is what the certificate's bound and the reference
     assume.  A tile's coarse values are the untiled sweep's bit for bit
     (the same tree of adds); the scores agree to float32 summation order.
+
+    A tier in delay bands (``src.bands``) runs one such transform a band
+    on each cleaned tile, over the band's delays alone: a row's tree of
+    adds does not depend on which other rows are computed, so the bands'
+    rows, one after the other, are the one sweep's.
     """
     import jax
 
@@ -732,27 +740,46 @@ def _search_jax_fdmt_tiled(src, dmmin, dmmax, start_freq, bandwidth,
                          f"cannot hold band delays to {n_hi} (or no FDMT "
                          "tile divides it): the tile plan is not this "
                          "tier's")
-    run = _build_transform(nchan, float(start_freq), float(bandwidth), n_hi,
-                           length, t_tile, use_pallas, not use_pallas,
-                           n_lo=n_lo, with_scores=True, with_plane=False,
-                           t_orig=length, with_cert=True, windows=windows,
-                           partial=(src.own, total))
-    parts = []
+    bands = src.bands or ((n_lo, n_hi),)
+    if (bands[0][0], bands[-1][1]) != (n_lo, n_hi) or any(
+            a[1] + 1 != b[0] for a, b in zip(bands, bands[1:])):
+        raise ValueError(f"delay bands {bands} do not cover band delays "
+                         f"{n_lo}-{n_hi}: the tile plan is not this tier's")
+    runs = [_build_transform(nchan, float(start_freq), float(bandwidth), hi,
+                             length, t_tile, use_pallas, not use_pallas,
+                             n_lo=lo, with_scores=True, with_plane=False,
+                             t_orig=length, with_cert=True, windows=windows,
+                             partial=(src.own, total)) for lo, hi in bands]
+    parts = [[] for _ in bands]
+
+    def sweep(b, tile):
+        with budget_bucket("search/coarse"):
+            out = runs[b](tile)
+            budget_count("dispatches")
+            budget_count("sweep_calls")
+            budget_count("sweep_samples", length)
+        with budget_bucket("search/coarse_readback"):
+            parts[b].append(np.asarray(out))
+            budget_count("readbacks")
+
     for i in range(src.time_tiles):
         with trace_span("search/tile", tier=src.tier, tile=i,
                         samples=src.own, halo=src.halo):
             with budget_bucket("search/tile_clean"):
                 tile = src.tile(i)
                 budget_count("dispatches")
-            with budget_bucket("search/coarse"):
-                out = run(tile)
-                budget_count("dispatches")
+            if not src.bands:   # one sweep a tile: no band to name
+                sweep(0, tile)
+            for b, (lo, hi) in enumerate(src.bands):
+                with trace_span("search/band", tier=src.tier, band=b,
+                                n_lo=lo, n_hi=hi,
+                                tiles=src.time_tiles) as band_span:
+                    sweep(b, tile)
+                src.band_seconds[b] += band_span.dur
             del tile
-            with budget_bucket("search/coarse_readback"):
-                parts.append(np.asarray(out))
-                budget_count("readbacks")
-    scores = unstack_scores(combine_partials(parts, src.own, windows, total,
-                                             with_cert=True))
+    scores = unstack_scores(np.concatenate(
+        [combine_partials(p, src.own, windows, total, with_cert=True)
+         for p in parts], axis=1))
     return (trial_dms,) + tuple(scores[:5]) + (None, scores[5])
 
 

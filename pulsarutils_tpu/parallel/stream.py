@@ -83,6 +83,14 @@ def plan_chunks(nsamples, sample_time, dmmin, dmmax, start_freq, stop_freq,
 
 
 @dataclasses.dataclass(frozen=True)
+class DelayBand:
+    """One delay band of a tier swept in bands (:func:`plan_time_tiles`)."""
+    n_lo: int       # its first band delay, in the tier's own samples
+    n_hi: int       # its last (inclusive)
+    bytes: int      # device bytes reckoned while one of its sweeps runs
+
+
+@dataclasses.dataclass(frozen=True)
 class TierTiles:
     """One tier's share of a tile plan (:func:`plan_time_tiles`)."""
     tiles: int      # time tiles its axis is swept in (1: whole, as ever)
@@ -90,6 +98,9 @@ class TierTiles:
     halo: int       # samples of the next tile swept again (0 when whole)
     bytes: int      # device bytes reckoned while one of its tiles is swept
     keep: int = 0   # cleaned tiles an exact rescore may hold across rounds
+    bands: tuple = ()   # its DelayBands, in order, where one sweep of a
+    #                     tile over all its band delays does not fit (else
+    #                     empty: every configuration before CHIME's)
 
 
 def sweep_state_rows(nchan, start_freq, bandwidth, n_hi, n_lo):
@@ -110,14 +121,15 @@ def sweep_state_rows(nchan, start_freq, bandwidth, n_hi, n_lo):
 
 def plan_time_tiles(nchan, nsamples, start_freq, bandwidth, tiers,
                     budget_bytes, resident_bytes=0):
-    """How many time tiles each tier of a chunk is swept in, from the
-    device's memory.
+    """How many time tiles, and how many delay bands, each tier of a chunk
+    is swept in, from the device's memory.
 
     ``tiers`` is one ``(downsample, sample_time, trial_dms, windows)`` per
     tier (a flat plan is one tier at ``downsample`` 1) over a chunk of
     ``nsamples`` samples of the plan; ``resident_bytes`` is what the chunk
-    loop holds beside a sweep (the packed chunk and the next one's
-    prefetch).  A tier's sweep is reckoned at its input array plus
+    loop holds beside a sweep (the packed chunk, the frames a chunk
+    searched in tiles carries twice, and the next one's prefetch: its
+    caller's to state).  A tier's sweep is reckoned at its input array plus
     :func:`sweep_state_rows` rows of float32 over its time axis, beside
     the whole arrays of the tiers below it that exist by then: an upper
     bound, not a footprint (the planner's job is a plan that cannot run
@@ -126,15 +138,29 @@ def plan_time_tiles(nchan, nsamples, start_freq, bandwidth, tiers,
     longest track (the FDMT's highest band delay and the exact kernels'
     rebased offsets) and keeps a tile's axis divisible by the kernels'
     time tiles.  A tile cannot be shorter than its halo or than the
-    ladder's widest window: a tier whose smallest tile still does not fit
-    raises ``ValueError`` naming the tier and the bytes, at plan time.
+    ladder's widest window.
+
+    A tier whose smallest tile still does not fit (CHIME's 16,384 channels:
+    a halo of 24,576 samples beside 70,000 rows of state) is swept in
+    **delay bands**: its band delays halved into contiguous runs of equal
+    count, in order, until every band's sweep fits on one tile plan of the
+    tier (:class:`DelayBand`).  The bands share the tier's tiles: a tile
+    is cleaned once and every band is swept on it, halo and all (a band
+    of low delays that read less of the halo would read a copy, and the
+    copy does not fit beside the tile).  A banded tier is swept in two
+    tiles at least: the sweep that did not fit was a tile's.  A tier that
+    no number of bands fits raises ``ValueError`` naming the tier, the
+    band and the bytes, at plan time.
 
     The tiers are planned from the deepest up.  The deepest tier that is
     tiled lays the whole arrays of the tiers below it from its own tile
     cleans (``pipeline/time_tiles.py``), so it is reckoned beside all of
     them; a tiled tier above it is swept before they exist.  ``keep`` is
     how many cleaned tiles fit beside that and one more tile: what an
-    exact rescore may hold across its rounds.
+    exact rescore may hold across its rounds (CHIME's tiles of 3.5 GiB:
+    one; with the frames the chunk carries twice left out of
+    ``resident_bytes`` it was two, and the third tile's clean found 3.48
+    GiB free).
 
     ``budget_bytes=None`` (no accelerator to ask) plans every tier whole.
     Returns a list of :class:`TierTiles`, one per tier.
@@ -154,34 +180,63 @@ def plan_time_tiles(nchan, nsamples, start_freq, bandwidth, tiers,
         dm_lo, dm_hi = float(np.min(trial_dms)), float(np.max(trial_dms))
         _, n_lo, n_hi = fdmt_trial_dms(nchan, dm_lo, dm_hi, start_freq,
                                        bandwidth, sample_time)
-        rows = int(nchan) + sweep_state_rows(nchan, start_freq, bandwidth,
-                                             n_hi, n_lo)
         # the exact kernels' span is the highest trial's, rebased
         _, _, max_off = rebase_offsets(_offsets_for(
             [dm_lo, dm_hi], nchan, start_freq, bandwidth, sample_time, axis),
             axis)
         widest = scored_windows(windows, axis)[-1]
         held = int(resident_bytes) + below
-        tiles = 1
+        # the tier's legal tile plans, the whole axis first
+        shapes, tiles = [], 1
         while True:
-            own = axis // tiles
-            halo = 0
+            own, halo = axis // tiles, 0
             if tiles > 1:
                 quantum = min(8192, max(128, 1 << ((own // 8).bit_length()
                                                    - 1)))
                 halo = -(-max(n_hi, max_off) // quantum) * quantum
-            need = held + rows * (own + halo) * 4
-            if need <= budget_bytes:
-                break
+            shapes.append((tiles, own, halo))
             if (axis % (2 * tiles) or (own // 2) % widest
                     or own // 2 < max(halo, n_hi, max_off)):
+                break   # no shorter tile holds its own halo
+            tiles *= 2
+        # one sweep a tile over all the tier's delays, else its delays
+        # halved into bands until every band's sweep fits on one plan
+        count = n_hi - n_lo + 1
+        nbands = 1
+        while True:
+            edges = [n_lo + count * b // nbands for b in range(nbands + 1)]
+            spans = [(lo, hi - 1) for lo, hi in zip(edges, edges[1:])]
+            rows = [int(nchan) + sweep_state_rows(nchan, start_freq,
+                                                  bandwidth, hi, lo)
+                    for lo, hi in spans]
+            # the sweep that did not fit was a tile's: a banded tier is
+            # not swept whole
+            fit = next((shape for shape in shapes
+                        if (shape[0] > 1 or nbands == 1)
+                        and held + max(rows) * (shape[1] + shape[2]) * 4
+                        <= budget_bytes), None)
+            if fit is not None:
+                break
+            # what no band can go under: one delay, the tier's last, on
+            # the shortest tile
+            _, own, halo = shapes[-1]
+            least = held + (int(nchan) + sweep_state_rows(
+                nchan, start_freq, bandwidth, n_hi, n_hi)) * (own + halo) * 4
+            if 2 * nbands > count or least > budget_bytes:
+                worst = int(np.argmax(rows))
                 raise ValueError(
                     f"DM tier {k} (x{downsample}, band delays {n_lo}-{n_hi}) "
-                    f"cannot be searched on this device: a time tile of "
-                    f"{own} + {halo} samples needs {need} bytes of "
-                    f"{budget_bytes}, and a shorter tile would not hold "
-                    "its own halo")
-            tiles *= 2
+                    f"cannot be searched on this device: delay band {worst} "
+                    f"of {nbands} (band delays {spans[worst][0]}-"
+                    f"{spans[worst][1]}) on a time tile of {own} + {halo} "
+                    f"samples needs {held + rows[worst] * (own + halo) * 4} "
+                    f"bytes of {budget_bytes}, a shorter tile would not "
+                    "hold its own halo, and a band of one delay needs "
+                    f"{least}")
+            nbands *= 2
+        tiles, own, halo = fit
+        needs = [held + r * (own + halo) * 4 for r in rows]
+        need = max(needs)
         keep = 0
         if tiles > 1:
             tile_bytes = int(nchan) * (own + halo) * 4
@@ -190,7 +245,9 @@ def plan_time_tiles(nchan, nsamples, start_freq, bandwidth, tiers,
             below = 0   # tiers above are swept before anything is laid
         else:
             below += int(nchan) * axis * 4
-        out[k] = TierTiles(tiles, own, halo, need, keep)
+        bands = () if nbands == 1 else tuple(
+            DelayBand(lo, hi, n) for (lo, hi), n in zip(spans, needs))
+        out[k] = TierTiles(tiles, own, halo, need, keep, bands)
     return out
 
 

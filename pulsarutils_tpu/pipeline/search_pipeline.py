@@ -264,7 +264,7 @@ def _count_windows(windows, nsamples):
 
 
 def _count_head_tiles(state, route, shape, dmmin, dmmax, start_freq,
-                      bandwidth, tsamp):
+                      bandwidth, tsamp, delays=None):
     """Counts the (8, 256) tiles the FDMT's fused head computes in one
     sweep (``putpu_fdmt_head_tiles_total``: halo chunks and padded rows
     included; ``ops/fdmt.py:coarse_head_tiles``) and returns their
@@ -275,7 +275,8 @@ def _count_head_tiles(state, route, shape, dmmin, dmmax, start_freq,
     sweep carries to a power of two (``putpu_fdmt_pad_channels_total``,
     on any backend: the tree pads wherever it runs).  ``route`` is the
     call's ``(backend, kernel, mesh)``, ``state`` what a fall-back made
-    of it."""
+    of it; ``delays`` the ``(n_lo, n_hi)`` of a sweep over one delay band
+    of the DM range."""
     backend, kernel, mesh = route
     if (mesh is not None or state.get("backend", backend) != "jax"
             or state.get("kernel", kernel) not in ("hybrid", "fdmt")):
@@ -285,7 +286,8 @@ def _count_head_tiles(state, route, shape, dmmin, dmmax, start_freq,
     obs_metrics.counter("putpu_fdmt_pad_channels_total").inc(
         pad_channels(shape[0]))
     n, _, declined, smem = coarse_head_tiles(
-        shape[0], shape[1], dmmin, dmmax, start_freq, bandwidth, tsamp)
+        shape[0], shape[1], dmmin, dmmax, start_freq, bandwidth, tsamp,
+        delays=delays)
     obs_metrics.counter("putpu_fdmt_head_tiles_total").inc(n)
     obs_metrics.gauge("putpu_fdmt_head_smem_bytes").set(smem)
     if declined:
@@ -415,8 +417,11 @@ def _tile_geometry(header, plan, tiers, flat, packed_bits):
     budget, for a survey's plan: ``tiers`` is the tiered plan's list
     (``None``: the flat plan, ``flat`` its ``(dmmin, dmmax, windows)``),
     the last element what the chunk loop holds beside a sweep (the packed
-    chunk and the next one's prefetch)."""
+    chunk, the first :data:`~.time_tiles.MAX_BLOCK` frames a chunk
+    searched in tiles carries once more at its end, and the next one's
+    prefetch)."""
     from ..ops.plan import dedispersion_plan
+    from .time_tiles import MAX_BLOCK
 
     nchan = header["nchans"]
     if tiers:
@@ -429,7 +434,8 @@ def _tile_geometry(header, plan, tiers, flat, packed_bits):
             plan.sample_time), windows)]
     return (nchan, plan.step // plan.resample, header["fbottom"],
             header["bandwidth"], geometry,
-            2 * plan.step * (nchan * packed_bits // 8))
+            (2 * plan.step + min(MAX_BLOCK, plan.step))
+            * (nchan * packed_bits // 8))
 
 
 def _plan_tiles(device_memory_bytes, *survey):
@@ -657,8 +663,11 @@ def plan_survey(fname, chunk_length=None, new_sample_time=None, tmin=0,
                                 reader.packed_bits)
     if tile_plan:
         logger.info("tile plan: %s", "; ".join(
-            f"tier {k}: {t.tiles} x ({t.own} + {t.halo} halo), "
-            f"{t.bytes / 2**30:.2f} GiB reckoned, keeps {t.keep}"
+            f"tier {k}: {t.tiles} x ({t.own} + {t.halo} halo)"
+            + (f" x {len(t.bands)} delay bands ("
+               + ", ".join(f"{b.n_lo}-{b.n_hi}" for b in t.bands) + ")"
+               if t.bands else "")
+            + f", {t.bytes / 2**30:.2f} GiB reckoned, keeps {t.keep}"
             for k, t in enumerate(tile_plan)))
     else:
         logger.info("tile plan: every tier whole")
@@ -1657,12 +1666,30 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             tile_plan[k].tiles, tile_plan[k].halo, tier=k,
             keep=tile_plan[k].keep,
             lay=(tuple(f for f in chain_factors if f > factor)
-                 if k == laying_tier else ()))
+                 if k == laying_tier else ()),
+            bands=[(b.n_lo, b.n_hi) for b in tile_plan[k].bands])
 
     def _count_tiles(t):
         obs_metrics.counter("putpu_time_tiles_total").inc(t.tiles)
         obs_metrics.counter("putpu_tile_halo_samples_total").inc(
             t.tiles * t.halo)
+        obs_metrics.counter("putpu_tier_delay_bands_total").inc(
+            max(len(t.bands) - 1, 0))
+
+    def _count_sweeps(arr, mesh_, dm_lo, dm_hi, tsamp):
+        """:func:`_count_head_tiles` once for every sweep of ``arr``: a
+        tile, and a delay band of a tile.  Returns the bands' records for
+        ``BUDGET_JSON`` (none where the tier is not banded)."""
+        bands = getattr(arr, "bands", ())
+        for _ in range(time_tiles_of(arr)):
+            for delays in bands or (None,):
+                _count_head_tiles(fallback_state, (backend, kernel, mesh_),
+                                  _sweep_shape(arr), dm_lo, dm_hi,
+                                  start_freq, bandwidth, tsamp,
+                                  delays=delays)
+        return [{"n_lo": lo, "n_hi": hi, "coarse_s": round(sec, 4)}
+                for (lo, hi), sec in zip(bands, arr.band_seconds)] \
+            if bands else None
 
     def _search_tiers(cleaned, istart_, rec):
         """The tiered search of one cleaned chunk: downsample chain, then
@@ -1741,15 +1768,14 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             if certified:
                 obs_metrics.counter("putpu_tier_certified_total").inc()
             nwindows = _count_windows(tier.windows, arr.shape[1])
-            for _ in range(time_tiles_of(arr)):
-                _count_head_tiles(fallback_state, (backend, kernel, None),
-                                  _sweep_shape(arr), tier.dm_lo, tier.dm_hi,
-                                  start_freq, bandwidth, tier.sample_time)
+            bands = _count_sweeps(arr, None, tier.dm_lo, tier.dm_hi,
+                                  tier.sample_time)
             rec["tiers"].append({
                 "downsample": tier.downsample, "trials": ttable.nrows,
                 "coarse_s": round(coarse_s() - coarse0, 4),
                 "certified": certified, "windows": nwindows,
-                **({"tiles": tile_plan[k].tiles} if tile_plan else {})})
+                **({"tiles": tile_plan[k].tiles} if tile_plan else {}),
+                **({"bands": bands} if bands else {})})
             snr = np.asarray(ttable["snr"], dtype=np.float64)
             n_above += int(np.count_nonzero(snr > t["snr_threshold"]))
             best = ttable.best_row()
@@ -1968,11 +1994,10 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                             chunk=istart, policy=dispatch_policy,
                             windows=flat_windows)
                         _count_windows(flat_windows, array.shape[1])
-                        for _ in range(time_tiles_of(array)):
-                            _count_head_tiles(
-                                fallback_state, (backend, kernel, mesh),
-                                _sweep_shape(array), dmmin, dmmax,
-                                start_freq, bandwidth, eff_tsamp)
+                        bands = _count_sweeps(array, mesh, dmmin, dmmax,
+                                              eff_tsamp)
+                        if bands:
+                            ck["rec"]["bands"] = bands
             except _resilience_ladder.OOMFloorError as exc:
                 # the degradation ladder's floor itself OOMed: this
                 # chunk cannot be searched on this host at ANY geometry
@@ -2317,6 +2342,11 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
             if progress and nproc % 50 == 0:
                 logger.info("processed %d chunks (through sample %d/%d)",
                             nproc, iend, nsamples)
+            # the chunk's resident bytes go with its iteration, not when
+            # the next chunk's search rebinds these names: a device short
+            # of room (a chunk searched in tiles) has none for both, and a
+            # hit keeps its trimmed record, nothing else refers to them
+            array = src = info = top = None
           _drain_persist()
     except BaseException:
         reader_pool.shutdown(wait=False, cancel_futures=True)

@@ -210,10 +210,16 @@ class TiledTierArray:
     (:func:`tile_clean_program`): the first clean of each tile at its own
     place writes their pieces, and :meth:`laid` hands the whole arrays
     over once every tile has been cleaned.
+
+    ``bands`` are the ``(n_lo, n_hi)`` band delays of a tier swept in delay
+    bands (:func:`~pulsarutils_tpu.parallel.stream.plan_time_tiles`; empty:
+    one sweep a tile, as ever): each tile is still cleaned once, and the
+    coarse sweep runs once a band on it, leaving the seconds each band's
+    sweeps and readbacks took in ``band_seconds``.
     """
 
     def __init__(self, raw, nsamples, stats, mask, unpack, zero_dm, chain,
-                 tiles, halo, tier=0, keep=0, lay=()):
+                 tiles, halo, tier=0, keep=0, lay=(), bands=()):
         self.raw, self.mask = raw, mask
         self.factor, self.spec = stats
         self._key = (unpack, bool(zero_dm), int(nsamples))
@@ -228,6 +234,8 @@ class TiledTierArray:
         self.tier = tier
         self.keep = int(keep)
         self.lay = tuple(lay)
+        self.bands = tuple(bands)
+        self.band_seconds = [0.0] * len(self.bands)
         self._laid = None
         self._to_lay = set(range(self.time_tiles)) if self.lay else set()
 

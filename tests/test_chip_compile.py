@@ -577,3 +577,67 @@ def test_uwl_2bit_tile_clean_and_moments_at_832_byte_frames(as_tpu,
     assert m.alias_size_in_bytes >= sum(4 * r * t for r, t in deep)
     assert total_of(compiled) + held < HBM_BYTES
     assert not re.search(r"f32\[3328,\d+\]\S* reverse\(", compiled.as_text())
+
+
+CHIME = (16384, 400.0, 400.0)
+
+
+@pytest.mark.parametrize("band", [0, 3])
+def test_chime_band_sweep_on_its_tile(as_tpu, one_chip, band):
+    """ISSUE 49: CHIME/FRB's native tier (16,384 channels, band delays
+    0-20,731) is swept a delay band of 5,183 at a time on a tile of 32,768
+    + 24,576 samples.  The sweep of a band (fused head over 128 groups,
+    six merge levels, the deep pair, the partial scorer at the 8-window
+    ladder) compiles beside the two packed chunks of 1 GiB: in the last
+    band the deep pair's composed shifts reach a fourth 8,192-sample tile
+    of each parent window, which at sixteen rows a step is 17.1 MiB of the
+    core's 16 MiB of scoped VMEM (``ops/fdmt.py:_merge4_pallas`` takes
+    such a pass eight rows a step)."""
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops import fdmt
+    from pulsarutils_tpu.ops.search import boxcar_ladder
+
+    own, halo, total = 32768, 24576, 1 << 16
+    length = own + halo
+    lo, hi = band * 5183, band * 5183 + 5182
+    assert fdmt.head_active(*CHIME, hi, lo, length)
+    run = fdmt._build_transform(
+        *CHIME, hi, length, fdmt._pick_fdmt_tile(length), True, False,
+        n_lo=lo, with_scores=True, with_plane=False, t_orig=length,
+        with_cert=True, windows=boxcar_ladder(128), partial=(own, total))
+    compiled = run.lower(
+        _sds((CHIME[0], length), jnp.float32, one_chip)).compile()
+    text = compiled.as_text()
+    assert "fdmt_head" in text and "fdmt_deep_pair" in text
+    m = compiled.memory_analysis()
+    held = 2 * total * CHIME[0] + (1 << 15) * CHIME[0]  # chunks + the wrap
+    assert (m.temp_size_in_bytes + m.argument_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes
+            + held) < HBM_BYTES, m
+
+
+def test_chime_2x_tier_whole(as_tpu, one_chip):
+    """The eighth cell's second sweep: CHIME's 2x tier to DM 1,100, band
+    delays 10,366-10,882 on the whole axis of 32,768 samples (the head
+    over 128 groups, the 7-window ladder), beside the two packed chunks
+    and the frames the resident chunk carries twice."""
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops import fdmt
+    from pulsarutils_tpu.ops.search import boxcar_ladder
+
+    length, total, lo, hi = 1 << 15, 1 << 16, 10366, 10882
+    assert fdmt.head_active(*CHIME, hi, lo, length)
+    run = fdmt._build_transform(
+        *CHIME, hi, length, fdmt._pick_fdmt_tile(length), True, False,
+        n_lo=lo, with_scores=True, with_plane=False, t_orig=length,
+        with_cert=True, windows=boxcar_ladder(64))
+    compiled = run.lower(
+        _sds((CHIME[0], length), jnp.float32, one_chip)).compile()
+    assert "fdmt_head" in compiled.as_text()
+    m = compiled.memory_analysis()
+    held = 2 * total * CHIME[0] + (1 << 15) * CHIME[0]
+    assert (m.temp_size_in_bytes + m.argument_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes
+            + held) < HBM_BYTES, m

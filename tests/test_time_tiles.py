@@ -262,7 +262,10 @@ def _config_geometry(name):
         geometry = [(1, tsamp, dedispersion_plan(
             nchan, cfg["dmmin"], cfg["dmmax"], fbottom, bandwidth, tsamp),
             boxcar_ladder(boxcar))]
-    resident = 2 * cfg["chunk_samples"] * nchan * cfg["nbits"] // 8
+    # as ``search_pipeline._tile_geometry`` states it: the packed chunk
+    # with its first 2^15 frames once more at its end, and the prefetch
+    resident = ((2 * cfg["chunk_samples"] + min(1 << 15, cfg["chunk_samples"]))
+                * nchan * cfg["nbits"] // 8)
     return (nchan, cfg["chunk_samples"], fbottom, bandwidth, geometry,
             resident)
 
