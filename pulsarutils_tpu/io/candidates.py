@@ -386,8 +386,8 @@ class CandidateStore:
         peak + span + pad]`` with ``span`` the band-crossing delay at
         the candidate's DM and ``pad`` at least the hit's boxcar — then
         block-sum decimates if still over budget.  The passed ``info``
-        is untouched (a trimmed *copy* is
-        returned, or ``info`` itself when already under budget), with
+        is untouched (a trimmed *copy* is returned, or ``info`` itself
+        when already under budget and on the host), with
         ``cutout_start``/``cutout_decim`` recording the window (see
         :class:`..pipeline.pulse_info.PulseInfo`).
 
@@ -399,18 +399,31 @@ class CandidateStore:
         ``nbin - width``; consumers recover absolute columns as
         ``(cutout_start + j * cutout_decim) mod nbin``).
 
-        ``info.allprofs`` may be a device (jnp) array: the window is
-        sliced device-side, so only the cutout — not the multi-GB chunk
-        — crosses the host link (the streaming driver relies on this,
-        round 6).
+        ``info.allprofs`` may live on the device (a ``jax.Array``, or a
+        :class:`~pulsarutils_tpu.pipeline.time_tiles.TiledTierArray`
+        that cleans a stretch on demand): the record comes back on the
+        host and only what it holds crosses the link.  The window is cut
+        on the device; a window that is itself over the budget (864 MB at
+        16,384 channels where the chunk is 4 GB) is block-summed there
+        too, in float32 (``jit_window_resample``), and its
+        ``(nchan, (hi - lo) // decim)`` sums are what is read back.  A
+        NumPy array is cut and summed by NumPy, as ever.  The bytes read
+        back count in ``putpu_cutout_readback_bytes_total`` (and
+        ``putpu_bytes_readback_total``), a window summed on the device
+        in ``putpu_cutout_device_decim_total``.
         """
         import dataclasses
 
         import numpy as np
 
         wf = info.allprofs
-        if wf is None or wf.size <= self.WATERFALL_BUDGET:
+        if wf is None:
             return info
+        on_device = not isinstance(wf, np.ndarray)
+        if wf.size <= self.WATERFALL_BUDGET:
+            return (dataclasses.replace(
+                info, allprofs=self._read_back(np.asarray(wf)))
+                if on_device else info)
         nbin = wf.shape[1]
         tsamp = (1.0 / (info.pulse_freq * info.nbin)
                  if info.pulse_freq and info.nbin else None)
@@ -431,24 +444,45 @@ class CandidateStore:
         hi = peak + span + pad
         if hi - lo >= nbin:  # window covers the whole chunk
             lo, hi = 0, nbin
-        if lo >= 0 and hi <= nbin:
-            cut = np.asarray(wf[:, lo:hi])
-        else:
-            # circular window: the dispersed tail wrapped past an edge
-            cols = np.arange(lo, hi) % nbin
-            if isinstance(wf, np.ndarray):
-                cut = np.take(wf, cols, axis=1, mode="wrap")
-            else:  # device array: gather on device, read back the window
-                cut = np.asarray(wf[:, cols])
-            lo = lo % nbin
-        decim = 1
-        if cut.size > self.WATERFALL_BUDGET:
-            from ..ops.rebin import quick_resample
+        inside = lo >= 0 and hi <= nbin
+        # circular window: the dispersed tail wrapped past an edge
+        cols = slice(lo, hi) if inside else np.arange(lo, hi) % nbin
+        # what the window holds decides its decimation, before any of it
+        # is moved
+        decim = -(-(wf.shape[0] * (hi - lo)) // self.WATERFALL_BUDGET)
+        if not on_device:
+            cut = (wf[:, cols] if inside
+                   else np.take(wf, cols, axis=1, mode="wrap"))
+            if decim > 1:
+                from ..ops.rebin import quick_resample
 
-            decim = -(-cut.size // self.WATERFALL_BUDGET)
-            cut = np.asarray(quick_resample(cut, decim))
-        return dataclasses.replace(info, allprofs=cut, cutout_start=lo,
+                cut = np.asarray(quick_resample(cut, decim))
+        elif decim == 1:
+            cut = self._read_back(np.asarray(wf[:, cols]))
+        else:
+            import jax
+
+            from ..ops.rebin import window_resample_program
+
+            # a whole array is cut and summed in one program; a tiled
+            # tier's window, or one gathered over the chunk's end, is an
+            # array of its own already
+            src, start = ((wf, lo) if inside and isinstance(wf, jax.Array)
+                          else (wf[:, cols], 0))
+            cut = self._read_back(np.asarray(
+                window_resample_program(hi - lo, decim)(src,
+                                                        np.int32(start))))
+            _metrics.counter("putpu_cutout_device_decim_total").inc()
+        return dataclasses.replace(info, allprofs=cut,
+                                   cutout_start=lo % nbin,
                                    cutout_decim=decim)
+
+    @staticmethod
+    def _read_back(cut):
+        """``cut`` as it came off the device, counted."""
+        _metrics.counter("putpu_cutout_readback_bytes_total").inc(cut.nbytes)
+        _metrics.counter("putpu_bytes_readback_total").inc(cut.nbytes)
+        return cut
 
     # backward-compatible alias (pre-round-6 name)
     _trim_waterfall = trim_waterfall

@@ -126,6 +126,12 @@ METRIC_NAMES = {
         "chunks NaN-imputed by the sanitize policy",
     "putpu_chunks_total":
         "chunk budgets closed",
+    "putpu_cutout_device_decim_total":
+        "hits whose cut-out, a window over the store's budget, was "
+        "block-summed on the device before the read-back",
+    "putpu_cutout_readback_bytes_total":
+        "bytes of hits' cut-outs copied device -> host: the window's, or "
+        "its block sums' where the window was summed on the device",
     "putpu_device_bytes_in_use":
         "device memory currently allocated",
     "putpu_device_bytes_limit":

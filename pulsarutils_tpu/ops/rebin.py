@@ -11,6 +11,8 @@ the reference.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 
@@ -59,6 +61,57 @@ def quick_resample(counts, factor, xp=np):
         .sum(axis=2)
     )
     return out[0] if squeeze else out
+
+
+#: blocks of the output one product of :func:`window_resample_program` makes
+#: (the lane width of the accelerator's matrix unit)
+_RESAMPLE_BLOCK = 128
+
+
+@functools.lru_cache(maxsize=8)
+def window_resample_program(length, factor):
+    """``jit_window_resample``: ``(counts, start)`` ->
+    ``quick_resample(counts[:, start:start + length], factor)`` in float32,
+    for a window that is not worth reading back whole (a hit's cut-out
+    over the store's budget, :meth:`~pulsarutils_tpu.io.candidates.
+    CandidateStore.trim_waterfall`).  ``start`` is traced, so one program
+    serves every window of a length.
+
+    The block sums are products with a 0/1 matrix at the highest
+    precision, ``_RESAMPLE_BLOCK`` sums at a time: a float32 is three
+    bfloat16 pieces exactly, each times 1.0, accumulated in float32, so
+    the values are float32 sums of ``factor`` neighbours in the matrix
+    unit's order.  The v5e compiler reads the slice in place for it (no
+    temporary at 16,384 x 13,184 by 52); for ``lax.reduce_window`` or a
+    reshape over as many lanes it makes two window-sized relayout copies
+    first (1,646 MiB there; compiler here, PR 50).
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    n = length // factor
+    block = min(_RESAMPLE_BLOCK, n)
+
+    def window_resample(counts, start):
+        nchan = counts.shape[0]
+        ones = (jnp.arange(block * factor)[:, None] // factor
+                == jnp.arange(block)[None, :]).astype(jnp.float32)
+
+        def sums(b, out):
+            # the last block may begin inside the one before: the same
+            # sums from the same samples, written twice
+            first = jnp.minimum(b * block, n - block)
+            piece = lax.dynamic_slice(counts, (0, start + first * factor),
+                                      (nchan, block * factor))
+            piece = jnp.dot(piece.astype(jnp.float32), ones,
+                            precision=lax.Precision.HIGHEST)
+            return lax.dynamic_update_slice(out, piece, (0, first))
+
+        return lax.fori_loop(0, -(-n // block), sums,
+                             jnp.zeros((nchan, n), jnp.float32))
+
+    return jax.jit(window_resample)
 
 
 def downsample_chain(counts, factors, xp=np):

@@ -2235,20 +2235,20 @@ def search_by_chunks(fname, chunk_length=None, new_sample_time=None, tmin=0,
                         # convert device arrays to host now (retained in
                         # the hits list — an un-pulled hit would pin the
                         # whole chunk's HBM until the search ends)
-                        info.allprofs = np.asarray(info.allprofs)
+                        if not isinstance(info.allprofs, np.ndarray):
+                            info.allprofs = np.asarray(info.allprofs)
+                            obs_metrics.counter(
+                                "putpu_bytes_readback_total").inc(
+                                int(info.allprofs.nbytes))
                     else:
-                        # round 6: slice the pulse window DEVICE-side and
-                        # read back only the cutout.  The full cleaned
-                        # chunk is ~GBs over a slow link per hit — the
-                        # round-5 rehearsal's single largest unattributed
-                        # wall cost; the persisted record was this
-                        # trimmed cutout all along
+                        # the record's cut-out, on the host: the store
+                        # cuts the pulse's window where the chunk lies and
+                        # sums a window over its budget (hundreds of MB at
+                        # thousands of channels) there too, so the record
+                        # alone crosses the link; it counts those bytes
                         info = store.trim_waterfall(info, sci_table)
-                        info.allprofs = np.asarray(info.allprofs)
                     if n_rb:
                         timer.count("readbacks", int(n_rb))
-                    obs_metrics.counter("putpu_bytes_readback_total").inc(
-                        int(np.asarray(info.allprofs).nbytes))
                 info.compute_stats()
                 hits.append((istart, iend, info, sci_table))
                 obs_metrics.counter("putpu_hits_total").inc()

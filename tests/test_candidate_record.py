@@ -3,6 +3,11 @@
 ``PulseInfo.save`` writes stored npz members (no deflate) holding the
 same arrays bit for bit; records written the old way (deflated) still
 read through every reader; the bytes a run writes are counted.
+
+The record's cut-out of a chunk that lies on the device (ISSUE 50): the
+window is cut there, and a window over the store's budget is block-summed
+there too, so the record alone crosses the link; a NumPy chunk is cut and
+summed by NumPy, to the bit what it always was.
 """
 import dataclasses
 import os
@@ -215,3 +220,159 @@ def test_run_counts_bytes_written_per_hit(survey_file, tmp_path,
     assert {lo: c["save_bytes"] for lo, c in per_chunk.items()
             if "save_bytes" in c} == sizes
     assert len(per_chunk) == 3  # the noise chunk persisted nothing
+
+
+# -- the cut-out of a chunk on the device (ISSUE 50) -----------------------
+
+CUT_NCHAN, CUT_NBIN = 32, 1 << 15
+READBACK = ("putpu_cutout_readback_bytes_total", "putpu_bytes_readback_total")
+DEVICE_DECIM = "putpu_cutout_device_decim_total"
+
+
+def _cut_case(where, budget, tmp_path):
+    """A chunk of noise, a hit whose track (DM 350 over 400-500 MHz:
+    3,268 samples of 1 ms, 6,536 with the pads) lies where ``where``
+    says, and a store whose budget holds ``budget`` elements."""
+    rng = np.random.default_rng(50)
+    wf = rng.normal(3.0, 1.0, (CUT_NCHAN, CUT_NBIN)).astype(np.float32)
+    peak = {"inside": 9000, "wraps_the_end": CUT_NBIN - 50}[where]
+    info = PulseInfo(allprofs=wf, nbin=CUT_NBIN, nchan=CUT_NCHAN,
+                     start_freq=400.0, bandwidth=100.0,
+                     pulse_freq=1.0 / (CUT_NBIN * 1e-3), dm=350.0, snr=20.0)
+    table = ResultTable({"DM": np.array([350.0]), "snr": np.array([20.0]),
+                         "peak": np.array([peak]), "rebin": np.array([1])})
+    store = CandidateStore(str(tmp_path), None)
+    store.WATERFALL_BUDGET = budget
+    return wf, info, table, store, peak
+
+
+def _moved(names, run):
+    before = [_counter(n) for n in names]
+    out = run()
+    return out, [_counter(n) - b for n, b in zip(names, before)]
+
+
+def _window(wf, peak):
+    """The window ``trim_waterfall`` takes about ``peak``, written out."""
+    span, pad = 3268, 1634
+    cols = np.arange(peak - pad, peak + span + pad) % wf.shape[1]
+    return cols[0], np.take(wf, cols, axis=1)
+
+
+@pytest.mark.parametrize("where", ("inside", "wraps_the_end"))
+def test_a_numpy_cutout_is_the_parents_to_the_bit(where, tmp_path):
+    from pulsarutils_tpu.ops.rebin import quick_resample
+
+    wf, info, table, store, peak = _cut_case(where, 1 << 14, tmp_path)
+    cut, moved = _moved(READBACK + (DEVICE_DECIM,),
+                        lambda: store.trim_waterfall(info, table))
+    start, window = _window(wf, peak)
+    decim = -(-window.size // (1 << 14))
+    want = quick_resample(window, decim)
+    assert (cut.cutout_start, cut.cutout_decim) == (start, decim) \
+        and decim == 13
+    assert cut.allprofs.dtype == want.dtype == np.float32
+    assert cut.allprofs.tobytes() == want.tobytes()
+    assert info.allprofs is wf and moved == [0, 0, 0]  # nothing crossed
+
+
+@pytest.mark.parametrize("where", ("inside", "wraps_the_end"))
+def test_a_device_cutout_is_summed_where_it_lies(where, tmp_path):
+    """Same window, decimation, shape and dtype as the host path on the
+    same values; the float32 sums of ``decim`` neighbours in another
+    order; and what is counted as read back is the sums, not the window."""
+    import jax.numpy as jnp
+
+    wf, info, table, store, peak = _cut_case(where, 1 << 14, tmp_path)
+    host = store.trim_waterfall(info, table)
+    on_device = dataclasses.replace(info, allprofs=jnp.asarray(wf))
+    cut, moved = _moved(READBACK + (DEVICE_DECIM,),
+                        lambda: store.trim_waterfall(on_device, table))
+    assert (cut.cutout_start, cut.cutout_decim) \
+        == (host.cutout_start, host.cutout_decim)
+    assert isinstance(cut.allprofs, np.ndarray)
+    assert (cut.allprofs.shape, cut.allprofs.dtype) \
+        == (host.allprofs.shape, host.allprofs.dtype)
+    np.testing.assert_allclose(cut.allprofs, host.allprofs,
+                               rtol=1e-5, atol=1e-6)
+    _, window = _window(wf, peak)
+    n = cut.allprofs.shape[1]
+    exact = window[:, :n * 13].astype(np.float64).reshape(-1, n, 13).sum(2)
+    # 13 float32 additions of values near 3: a few units of 2^-24 x 39,
+    # and no further from the float64 sum than the host's are
+    worst = np.abs(host.allprofs - exact).max()
+    assert np.abs(cut.allprofs - exact).max() <= max(2 * worst, 1e-5)
+    assert moved == [cut.allprofs.nbytes, cut.allprofs.nbytes, 1]
+    assert cut.allprofs.nbytes * 12 < window.nbytes
+    assert on_device.allprofs.shape == wf.shape  # the chunk is untouched
+
+
+@pytest.mark.parametrize("what", ("window", "chunk"))
+def test_a_device_cutout_under_the_budget_crosses_as_it_is(what, tmp_path):
+    """A window (or a whole chunk) the budget holds is read back value
+    for value, as ever, and its own bytes are what is counted."""
+    import jax.numpy as jnp
+
+    wf, info, table, store, peak = _cut_case(
+        "wraps_the_end",
+        (1 << 18) if what == "window" else CUT_NCHAN * CUT_NBIN, tmp_path)
+    on_device = dataclasses.replace(info, allprofs=jnp.asarray(wf))
+    cut, moved = _moved(READBACK + (DEVICE_DECIM,),
+                        lambda: store.trim_waterfall(on_device, table))
+    start, window = _window(wf, peak)
+    want = window if what == "window" else wf
+    assert isinstance(cut.allprofs, np.ndarray)
+    assert cut.allprofs.tobytes() == want.tobytes()
+    assert (cut.cutout_start, cut.cutout_decim) \
+        == ((start, 1) if what == "window" else (None, None))
+    assert moved == [want.nbytes, want.nbytes, 0]
+    # the record, now on the host and under the budget, is what is saved
+    assert store.trim_waterfall(cut, table) is cut
+
+
+@pytest.mark.parametrize("length,factor,starts", [
+    (1561, 13, (0, 8610)),       # 120 sums: one block, under a lane's width
+    (13184, 52, (40, 19000)),    # 253: the second block overlaps the first
+    (6940, 7, (1, 25828)),       # 991 sums in eight blocks
+    (512, 2, (32256,)),          # 256: two whole blocks, at the array's end
+])
+def test_window_resample_program_is_quick_resample(length, factor, starts):
+    """``jit_window_resample`` against NumPy's block sums of the same
+    window, a trailing fragment truncated; one program whatever the
+    start."""
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops.rebin import (quick_resample,
+                                           window_resample_program)
+
+    rng = np.random.default_rng(length)
+    counts = rng.normal(0.0, 1.0, (8, CUT_NBIN)).astype(np.float32)
+    program = window_resample_program(length, factor)
+    assert program is window_resample_program(length, factor)
+    for start in starts:
+        got = np.asarray(program(jnp.asarray(counts), np.int32(start)))
+        want = quick_resample(counts[:, start:start + length], factor)
+        assert got.dtype == np.float32 and got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6)
+    assert program._cache_size() == 1
+
+
+def test_a_run_reads_back_its_records_and_nothing_more(survey_file, tmp_path,
+                                                       monkeypatch):
+    """Through ``search_by_chunks`` with the store's budget lowered under
+    both hits' windows: each record came summed off the device, and the
+    read-back counters moved by the records' bytes."""
+    monkeypatch.setattr(CandidateStore, "WATERFALL_BUDGET", 1 << 12)
+    (hits, _), moved = _moved(
+        READBACK + (DEVICE_DECIM,),
+        lambda: search_by_chunks(
+            survey_file, output_dir=str(tmp_path), dmmin=100, dmmax=200,
+            backend="jax", chunk_length=8192 * TSAMP, make_plots=False,
+            progress=False, snr_threshold=6.5))
+    assert len(hits) == 2
+    records = [info.allprofs for _, _, info, _ in hits]
+    assert all(isinstance(r, np.ndarray) and r.size <= 1 << 12
+               for r in records)
+    assert all(info.cutout_decim > 1 for _, _, info, _ in hits)
+    total = sum(r.nbytes for r in records)
+    assert moved == [total, total, 2]

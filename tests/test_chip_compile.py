@@ -641,3 +641,28 @@ def test_chime_2x_tier_whole(as_tpu, one_chip):
     assert (m.temp_size_in_bytes + m.argument_size_in_bytes
             + m.output_size_in_bytes - m.alias_size_in_bytes
             + held) < HBM_BYTES, m
+
+
+@pytest.mark.parametrize("nchan,source,length,factor", [
+    (16384, 13184, 13184, 52),      # CHIME: a tiled tier's window, DM 333
+    (3328, 18845, 18845, 15),       # Parkes UWL: the same, DM 74.3
+    (4096, 1 << 15, 6940, 7),       # MeerTRAP's 4x tier, a whole array
+])
+def test_a_hits_window_is_summed_in_place(no_compile_cache, one_chip, nchan,
+                                          source, length, factor):
+    """ISSUE 50: the cut-out of a hit in a wide tier is block-summed on
+    the device (``jit_window_resample``) so that the 16 MiB record alone
+    is read back.  The program holds no window-sized temporary: it reads
+    the slice in place (a reduction over ``factor`` lanes made the v5e
+    compiler copy the 864 MB window twice first), beside a chunk that
+    peaks at 11.3 of 16.9 GB."""
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.ops.rebin import window_resample_program
+
+    compiled = window_resample_program(length, factor).lower(
+        _sds((nchan, source), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip)).compile()
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes <= 4 << 22
+    assert m.temp_size_in_bytes <= 1 << 22, m

@@ -235,6 +235,103 @@ def test_a_tiled_tiers_cleans_lay_the_tiers_below_it(rng):
     assert layer.laid() is None
 
 
+# -- a hit's cut-out, summed where it lies (ISSUE 50) ----------------------
+
+CUTOUT_COUNTERS = ("putpu_cutout_readback_bytes_total",
+                   "putpu_bytes_readback_total",
+                   "putpu_cutout_device_decim_total")
+
+
+def _counted(run):
+    from pulsarutils_tpu.obs import metrics
+
+    before = [metrics.counter(n).value for n in CUTOUT_COUNTERS]
+    out = run()
+    return out, [metrics.counter(n).value - b
+                 for n, b in zip(CUTOUT_COUNTERS, before)]
+
+
+@pytest.mark.parametrize("where,peak", [("inside_a_tile", 5000),
+                                        ("wraps_the_chunks_end", T - 50)])
+def test_a_tiled_tiers_cutout_is_summed_where_it_lies(rng, tmp_path, where,
+                                                      peak):
+    """A window of a tiled tier over the store's budget (6,536 samples x
+    64 channels for 2^15 elements: sums of 13) is what the host path
+    makes of the whole tier read back: same start, decimation, shape and
+    dtype, the values within the tiles' tolerance; the sums are what is
+    counted as read back, not the window."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from pulsarutils_tpu.io.candidates import CandidateStore
+    from pulsarutils_tpu.io.lowbit import device_unpack_block
+    from pulsarutils_tpu.pipeline.pulse_info import PulseInfo
+    from pulsarutils_tpu.pipeline.time_tiles import (TiledTierArray,
+                                                     chunk_stats_program,
+                                                     wrap_rows_program)
+    from pulsarutils_tpu.utils.table import ResultTable
+
+    raw = jnp.asarray(rng.integers(60, 140, (T, NCHAN), dtype=np.uint8))
+    mask = jnp.zeros(NCHAN, bool).at[5].set(True)
+    unpack = (device_unpack_block, 8, NCHAN, True)
+    stats = chunk_stats_program(unpack, T)(raw, mask)
+    tier = TiledTierArray(wrap_rows_program()(raw), T, stats, mask, unpack,
+                          True, (), 2, 4096)
+    # DM 350 crosses 400-500 MHz in 3,268 samples of 1 ms
+    info = PulseInfo(allprofs=tier, nbin=T, nchan=NCHAN, start_freq=400.0,
+                     bandwidth=100.0, pulse_freq=1.0 / (T * 1e-3))
+    table = ResultTable({"DM": np.array([350.0]), "snr": np.array([20.0]),
+                         "peak": np.array([peak]), "rebin": np.array([1])})
+    store = CandidateStore(str(tmp_path), None)
+    store.WATERFALL_BUDGET = 1 << 15
+    cut, moved = _counted(lambda: store.trim_waterfall(info, table))
+    host, nothing = _counted(lambda: store.trim_waterfall(
+        dataclasses.replace(info, allprofs=np.asarray(tier)), table))
+    assert nothing == [0, 0, 0]
+    assert (cut.cutout_start, cut.cutout_decim) \
+        == (host.cutout_start, host.cutout_decim) == ((peak - 1634) % T, 13)
+    assert isinstance(cut.allprofs, np.ndarray)
+    assert (cut.allprofs.shape, cut.allprofs.dtype) \
+        == (host.allprofs.shape, host.allprofs.dtype) \
+        == ((NCHAN, 6536 // 13), np.float32)
+    np.testing.assert_allclose(cut.allprofs, host.allprofs,
+                               rtol=1e-5, atol=1e-6)
+    assert moved == [cut.allprofs.nbytes, cut.allprofs.nbytes, 1]
+    assert cut.allprofs.nbytes * 13 <= NCHAN * 6536 * 4
+
+
+@pytest.mark.parametrize("tiles", [1, 2])
+def test_a_searchs_cutouts_over_the_budget(untiled, force_time_tiles,
+                                           tmp_path, monkeypatch, tiles):
+    """Through ``search_by_chunks`` with the store's budget under the
+    hit's window: the whole chunk's record (a device array, cut and
+    summed in one program) and the tiled chunk's (a re-cleaned stretch,
+    then summed) are NumPy's block sums of the same window of the
+    untiled chunk, which the default budget reads back whole."""
+    from pulsarutils_tpu.io.candidates import CandidateStore
+    from pulsarutils_tpu.ops.rebin import quick_resample
+
+    path, whole, _ = untiled(8, "flat", MID)
+    monkeypatch.setattr(CandidateStore, "WATERFALL_BUDGET", 1 << 12)
+    if tiles > 1:
+        force_time_tiles(path, _kw("flat"), tiles)
+    (hits, _), moved = _counted(
+        lambda: _search(path, tmp_path / "out", _kw("flat")))
+    assert [h[:2] for h in hits] == [h[:2] for h in whole] and hits
+    for (_, _, info, _), (_, _, info0, _) in zip(hits, whole):
+        decim, n = info.cutout_decim, info.allprofs.shape[1]
+        assert decim > 1 and info0.cutout_start is None
+        cols = (info.cutout_start + np.arange(n * decim)) % T
+        want = quick_resample(info0.allprofs[:, cols], decim)
+        assert (info.allprofs.shape, info.allprofs.dtype) \
+            == (want.shape, want.dtype)
+        np.testing.assert_allclose(info.allprofs, want, rtol=1e-5,
+                                   atol=1e-6)
+    total = sum(h[2].allprofs.nbytes for h in hits)
+    assert moved == [total, total, len(hits)]
+
+
 # -- the planner ----------------------------------------------------------
 
 V5E_BYTES = int(15.75 * 2**30)
